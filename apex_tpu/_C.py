@@ -4,8 +4,9 @@ Mirrors the reference's lazy-import pattern for its C++ extensions (each
 Python module imports its kernel lib and degrades to a Python path when
 absent, e.g. apex/parallel/distributed.py:15-25 for apex_C.flatten).
 
-``HAVE_NATIVE`` tells callers whether apex_tpu_C is loaded. All four
-entry points below work identically either way:
+``HAVE_NATIVE`` tells callers whether apex_tpu_C is loaded, and
+``BUILD_ERROR`` why not when it should have been. All four entry points
+below work identically either way:
 
     flatten(arrays, out)        -> bytes copied
     unflatten_into(flat, outs)  -> bytes copied
@@ -18,85 +19,112 @@ import os
 import numpy as np
 
 
+def _checkout_root():
+    return os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
 def _build_in_place():
-    """Compile csrc/apex_tpu_C.cpp into the source tree on first import.
+    """Build csrc/apex_tpu_C.cpp into the source tree unless the binary
+    there was built from exactly this source, then load it.
 
     The reference requires an explicit `pip install --cpp_ext` step; here
-    the extension is one self-contained C++17 file, so an editable/source
-    checkout self-heals instead of silently running the numpy fallback.
-    Returns the imported module or None."""
+    the extension is one self-contained C++17 file, so a source checkout
+    builds it on first import. A sidecar file keeps the sha256 of the
+    source the binary was built from: a stale ``.so`` left in the tree
+    (git-ignored, so copied along with it) is rebuilt, never loaded.
+    Returns ``(module, None)`` or ``(None, reason)``."""
+    import hashlib
     import importlib.util
     import shutil
     import subprocess
+    import sys
     import sysconfig
-    import warnings
 
-    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    here = _checkout_root()
     src = os.path.join(here, "csrc", "apex_tpu_C.cpp")
     cxx = shutil.which("g++") or shutil.which("c++")
-    if not os.path.exists(src) or cxx is None:
-        return None
+    if cxx is None:
+        return None, "no C++ compiler (g++/c++) on PATH"
     so = os.path.join(
         here, "apex_tpu_C" + sysconfig.get_config_var("EXT_SUFFIX"))
+    stamp = so + ".sha256"
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()
 
-    def _load(path):
-        import sys
+    def _fresh():
+        if not (os.path.exists(so) and os.path.exists(stamp)):
+            return False
+        with open(stamp) as f:
+            return f.read().strip() == digest
 
-        spec = importlib.util.spec_from_file_location("apex_tpu_C", path)
+    def _load():
+        spec = importlib.util.spec_from_file_location("apex_tpu_C", so)
         mod = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(mod)
         sys.modules["apex_tpu_C"] = mod  # later imports reuse this instance
-        return mod
+        return mod, None
 
     # Serialize concurrent importers (the multiproc launcher's workers all
     # import at once) behind an flock: one process compiles, the rest wait
     # and load the finished artifact. Compile lands in a temp path then an
     # atomic rename, so a crashed builder never leaves a truncated .so.
-    tmp = f"{so}.{os.getpid()}.tmp"
-    lock_path = so + ".lock"
-    try:
-        import fcntl
+    import fcntl
 
-        with open(lock_path, "w") as lock:
+    tmp = f"{so}.{os.getpid()}.tmp"
+    try:
+        if _fresh():  # built before: no lock, no write to the tree
+            return _load()
+        with open(so + ".lock", "w") as lock:
             fcntl.flock(lock, fcntl.LOCK_EX)
-            try:
-                if os.path.exists(so):  # another process won the race
-                    return _load(so)
-                cmd = [cxx, "-O3", "-std=c++17", "-shared", "-fPIC",
-                       "-pthread", "-I" + sysconfig.get_path("include"),
-                       src, "-o", tmp]
-                proc = subprocess.run(cmd, capture_output=True, timeout=120)
-                if proc.returncode != 0:
-                    warnings.warn(
-                        "apex_tpu_C build failed; using the numpy "
-                        "fallback.\n"
-                        + proc.stderr.decode(errors="replace")[-2000:])
-                    return None
-                os.replace(tmp, so)
-                return _load(so)
-            finally:
-                fcntl.flock(lock, fcntl.LOCK_UN)
-    except Exception as e:  # no write permission, timeout, bad artifact
-        warnings.warn(f"apex_tpu_C build unavailable ({e!r}); "
-                      "using the numpy fallback")
-        return None
+            if _fresh():  # another process won the race
+                return _load()
+            cmd = [cxx, "-O3", "-std=c++17", "-shared", "-fPIC",
+                   "-pthread", "-I" + sysconfig.get_path("include"),
+                   src, "-o", tmp]
+            proc = subprocess.run(cmd, capture_output=True, timeout=120)
+            if proc.returncode != 0:
+                return None, ("g++ failed:\n"
+                              + proc.stderr.decode(errors="replace")[-2000:])
+            os.replace(tmp, so)
+            with open(stamp, "w") as f:
+                f.write(digest)
+            return _load()
+    except (OSError, subprocess.TimeoutExpired, ImportError) as e:
+        # read-only tree, compiler hang, unloadable artifact
+        return None, repr(e)
     finally:
         if os.path.exists(tmp):
-            try:
-                os.remove(tmp)
-            except OSError:
-                pass
+            os.remove(tmp)
 
 
-try:
-    import apex_tpu_C as _ext
+def _load_extension():
+    """``(module, error)``: built from this checkout's csrc/ when the
+    source is there (never a stale binary found on sys.path), else an
+    installed ``apex_tpu_C`` (``pip install`` builds it via setup.py)."""
+    if os.environ.get("APEX_TPU_NO_EXT", "").lower() not in (
+            "", "0", "false", "no"):
+        return None, None  # Python-only build, asked for
+    if os.path.exists(os.path.join(_checkout_root(), "csrc",
+                                   "apex_tpu_C.cpp")):
+        return _build_in_place()
+    try:
+        import apex_tpu_C
 
-    HAVE_NATIVE = True
-except ImportError:  # Python-only build (APEX_TPU_NO_EXT=1)
-    _no_ext = os.environ.get("APEX_TPU_NO_EXT", "").lower() not in (
-        "", "0", "false", "no")
-    _ext = None if _no_ext else _build_in_place()
-    HAVE_NATIVE = _ext is not None
+        return apex_tpu_C, None
+    except ImportError as e:
+        return None, repr(e)
+
+
+# BUILD_ERROR says why the numpy paths are in use when nobody asked for
+# them (None otherwise); chip_smoke.py fails on it instead of letting a
+# broken toolchain hide behind the fallback.
+_ext, BUILD_ERROR = _load_extension()
+HAVE_NATIVE = _ext is not None
+if BUILD_ERROR is not None:
+    import warnings
+
+    warnings.warn("apex_tpu_C unavailable; using the numpy fallback: "
+                  + BUILD_ERROR)
 
 
 def _require_contiguous(a, what):

@@ -37,47 +37,6 @@ import logging as _pylogging
 
 __version__ = "0.1.0"
 
-# --- jax version compat -----------------------------------------------------
-# The codebase targets the current jax API (jax.shard_map with check_vma,
-# lax.axis_size). Driver/CI containers may carry an older jax (0.4.x) where
-# shard_map lives in jax.experimental with the check_rep spelling and
-# axis_size does not exist; install the two shims once here so every call
-# site works unchanged on both.
-import jax as _jax
-from jax import lax as _lax
-
-if not hasattr(_lax, "axis_size"):
-    def _axis_size_shim(axis_name):
-        # the documented old-jax idiom: a psum of the constant 1 is folded
-        # to the concrete axis size (raises NameError when unbound, same
-        # contract as the modern lax.axis_size)
-        return _lax.psum(1, axis_name)
-
-    _lax.axis_size = _axis_size_shim
-
-if not hasattr(_jax, "shard_map"):
-    def _shard_map_shim(f, *, mesh, in_specs, out_specs, check_vma=False,
-                        **kw):
-        from jax.experimental.shard_map import shard_map as _sm
-
-        # check_vma=False is the repo-wide setting (the custom-vjp
-        # collective ops defeat the old rep checker too); map it onto
-        # check_rep and default it off.
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=check_vma, **kw)
-
-    _jax.shard_map = _shard_map_shim
-
-try:  # pltpu.CompilerParams was TPUCompilerParams before jax 0.6
-    from jax.experimental.pallas import tpu as _pltpu
-
-    if not hasattr(_pltpu, "CompilerParams") and \
-            hasattr(_pltpu, "TPUCompilerParams"):
-        _pltpu.CompilerParams = _pltpu.TPUCompilerParams
-except Exception:  # pallas unavailable on this backend: kernels gate off
-    pass
-# ---------------------------------------------------------------------------
-
 from apex_tpu._logging import RankInfoFormatter, deprecated_warning  # noqa: F401
 
 # Light-weight subpackages are imported eagerly so `import apex_tpu` gives the

@@ -1,12 +1,16 @@
-"""Opt-in persistent XLA compilation cache (one switch for tests, the
-driver dryrun and local tooling) — with hit/miss observability.
+"""The persistent XLA compilation cache, placed from OUTSIDE the
+program — one function (:func:`enable_compile_cache`) that every entry
+point calls (``chip_smoke.py``, ``bench.py``, ``__graft_entry__.py``,
+``tests/conftest.py``) — with hit/miss observability.
 
-Compile time dominates the L0 suite and the multichip dryrun on slow
-hosts; a warm cache cuts serial wall-clock substantially. Off by default:
-XLA:CPU AOT reload can log machine-feature-mismatch errors when the cache
-dir migrates across heterogeneous hosts. Enable on a fixed host with e.g.
+Placement rule:
 
-    APEX_TPU_COMPILE_CACHE=/tmp/apex_tpu_jit_cache pytest tests/L0 -q
+- ``JAX_COMPILATION_CACHE_DIR`` set: jax reads it itself and no code
+  here sets another directory — whoever runs the program (a chip
+  runner that keeps a cache between calls, CI) owns the location;
+- otherwise ``<checkout>/.jit_cache`` (git-ignored). The path is part
+  of nothing's identity but must be STABLE: a directory built from a
+  temporary name, a pid or the time is a cache that never hits.
 
 Enabling also installs ``jax.monitoring`` listeners for the persistent
 cache's hit/miss events, so :func:`cache_stats` (and the
@@ -19,7 +23,7 @@ full compile time while looking enabled.
 import os
 import threading
 
-_ENV_CACHE = "APEX_TPU_COMPILE_CACHE"
+ENV_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
 
 _HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _MISS_EVENT = "/jax/compilation_cache/cache_misses"
@@ -67,26 +71,29 @@ def cache_stats() -> dict:
         return dict(_STATS)
 
 
-def maybe_enable_compile_cache(min_compile_secs: float = 0.5) -> bool:
-    """Point jax at $APEX_TPU_COMPILE_CACHE if set. Returns True when
-    enabled. Call before the first compilation."""
-    cache_dir = os.environ.get(_ENV_CACHE, "")
-    if not cache_dir:
-        return False
+def default_cache_dir() -> str:
+    """``<checkout>/.jit_cache`` — next to the ``apex_tpu`` package."""
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    return os.path.join(here, ".jit_cache")
+
+
+def enable_compile_cache(min_compile_secs: float = 0.5) -> str:
+    """Turn the persistent cache on and return the directory in use.
+    Call before the first compilation. Where ``JAX_COMPILATION_CACHE_DIR``
+    is set the directory is jax's own reading of it and nothing is set
+    here; otherwise it is :func:`default_cache_dir`."""
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    if not os.environ.get(ENV_CACHE_DIR):
+        jax.config.update("jax_compilation_cache_dir", default_cache_dir())
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
                       min_compile_secs)
-    # jax caches its "is the cache used?" decision once per task; if
-    # anything compiled before we set the dir, that decision is a
-    # permanent False. Reset it (best-effort, private API) so enabling
-    # mid-process actually enables.
-    try:
-        from jax._src import compilation_cache as _jax_cc
+    # jax decides "is the cache used?" once per process; if anything
+    # compiled before the directory was known that decision is a
+    # permanent False. reset_cache() drops it so the next compile
+    # re-reads the config.
+    from jax._src import compilation_cache as _jax_cc
 
-        _jax_cc.reset_cache()
-    except Exception:
-        pass
+    _jax_cc.reset_cache()
     install_cache_counters()
-    return True
+    return jax.config.jax_compilation_cache_dir

@@ -5,4 +5,8 @@ deciding pallas-vs-oracle-vs-interpret for every kernel, master switch
 and ``choose_block`` from there; this module re-exports them for the
 existing decode-kernel call sites."""
 
-from apex_tpu.kernels.registry import PallasGate, choose_block  # noqa: F401
+from apex_tpu.kernels.registry import (  # noqa: F401
+    PallasGate,
+    choose_block,
+    lane_block_ok,
+)

@@ -13,10 +13,12 @@ accumulators, so VMEM use is independent of sequence length (validated to
 seq 65536 on-chip; see PERF.md). The forward emits the per-row
 log-sum-exp; the backward recomputes p = exp(q k^T scale - lse) per tile
 (flash-attention v2 style) instead of materializing the [s, s] matrix.
-Off-TPU both passes fall back to the reference einsum path; on TPU,
-sequence lengths that no block fits (not a multiple of any of 512/256/128
-and larger than 512) fall back the same way, while short sequences use
-the whole sequence as one block.
+Off-TPU both passes take the reference einsum path; on TPU, sequence
+lengths that no block fits (not a multiple of any of 512/256/128 and
+larger than 512) take it too, while short sequences use the whole
+sequence as one block. Gate ``flash_attention`` in the kernel registry:
+every call records its dispatch path, so an O(s^2) reference run shows
+up as ``kernels/dispatch/flash_attention_oracle``.
 """
 
 import functools
@@ -25,7 +27,13 @@ import numbers
 import jax
 import jax.numpy as jnp
 
-_INTERPRET = False
+from apex_tpu.kernels.registry import (
+    dispatch_path,
+    get_kernel_registry,
+    kernel_gate,
+)
+
+GATE = kernel_gate("flash_attention", default=True)
 
 # 512x512 measured fastest on-chip at seq 8192 (8.0 TFLOP/s vs 3.8 at
 # 128x128); both are min()'d down for shorter sequences.
@@ -34,15 +42,13 @@ DEFAULT_BLOCK_K = 512
 NEG_INF = -1e30
 
 
-def _use_pallas():
-    import os
-
-    if os.environ.get("APEX_TPU_DISABLE_PALLAS", "0") == "1":
-        return False
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+def _use_kernel(bq, bk):
+    """Record this call's dispatch path (trace time) and say whether
+    the Pallas kernels run: gate on AND a block divides the sequence."""
+    path = dispatch_path(GATE) if bq is not None and bk is not None \
+        else "oracle"
+    get_kernel_registry().dispatch("flash_attention", path)
+    return path != "oracle"
 
 
 def _causal_mask(scores, qi, kj, block_q, block_k, window=None):
@@ -229,7 +235,7 @@ def _flash_fwd_pallas(q, k, v, scale, causal, block_q, block_k,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_INTERPRET,
+        interpret=GATE.interpret,
     )(q3, k3, v3, slopes3)
     return out.reshape(b, n, s, d), lse.reshape(b, n, s)
 
@@ -386,7 +392,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, scale, causal, block_q,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_INTERPRET,
+        interpret=GATE.interpret,
     )(q3, k3, v3, do3, lse3, delta, slopes3)
 
     dk, dv = pl.pallas_call(
@@ -426,7 +432,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, scale, causal, block_q,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_INTERPRET,
+        interpret=GATE.interpret,
     )(k3, v3, q3, do3, lse3, delta, slopes3)
 
     rs = lambda x: x.reshape(b, n, s, d)  # noqa: E731
@@ -511,7 +517,7 @@ def flash_attention(q, k, v, causal=True, scale=None,
     through this op."""
     _check_window(window, causal)
     scale, bq, bk = _resolve(q, scale, block_q, block_k)
-    if _use_pallas() and bq is not None and bk is not None:
+    if _use_kernel(bq, bk):
         return _flash_fwd_pallas(q, k, v, scale, causal, bq, bk,
                                  window, alibi_slopes)[0]
     return _attention_reference(q, k, v, scale, causal, window,
@@ -522,7 +528,7 @@ def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k,
                     window=None, alibi_slopes=None):
     _check_window(window, causal)
     scale_, bq, bk = _resolve(q, scale, block_q, block_k)
-    if _use_pallas() and bq is not None and bk is not None:
+    if _use_kernel(bq, bk):
         out, lse = _flash_fwd_pallas(q, k, v, scale_, causal, bq, bk,
                                      window, alibi_slopes)
         return out, (q, k, v, out, lse, alibi_slopes)
@@ -536,7 +542,7 @@ def _flash_bwd_rule(causal, scale, block_q, block_k, window, res, g):
     scale_, bq, bk = _resolve(q, scale, block_q, block_k)
     none_slope_grad = (None if alibi_slopes is None
                        else jnp.zeros_like(alibi_slopes))
-    if lse is not None and _use_pallas():
+    if lse is not None:
         dq, dk, dv = _flash_bwd_pallas(q, k, v, out, lse, g, scale_,
                                        causal, bq, bk, window,
                                        alibi_slopes)

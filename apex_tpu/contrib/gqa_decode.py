@@ -29,7 +29,12 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.contrib._pallas_gate import PallasGate, choose_block
+from apex_tpu.contrib._pallas_gate import (
+    PallasGate,
+    choose_block,
+    lane_block_ok,
+)
+from apex_tpu.kernels.registry import dispatch_path, get_kernel_registry
 
 NEG_INF = -1e30
 DEFAULT_BLOCK_T = 512
@@ -64,13 +69,15 @@ def gqa_decode_reference(q, k, v, length, sm_scale, window=None,
 
 
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
-                   l_ref, *, sm_scale, softcap, window, block_t, num_t):
-    """One (batch, group, cache-tile) grid cell: the group's rep query
-    heads share the tile, online softmax across the streamed tile
-    axis."""
+                   l_ref, *, sm_scale, softcap, window, block_t, num_t, g,
+                   d):
+    """One (batch, cache-tile) grid cell: the tile carries every kv
+    group's lanes (``[block_t, g*d]`` — a lane-dense block the TPU
+    lowering accepts), each group's rep query heads share its lane
+    slice, online softmax across the streamed tile axis."""
     from jax.experimental import pallas as pl
 
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -86,32 +93,35 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
 
     @pl.when(live)
     def _step():
-        q = q_ref[0, 0].astype(jnp.float32) * sm_scale  # [rep, d]
-        k = k_ref[:, 0, 0, :].astype(jnp.float32)       # [block_t, d]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-        if softcap is not None:
-            cap = jnp.float32(softcap)
-            s = cap * jnp.tanh(s / cap)
-        t_ids = j * block_t + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        masked = t_ids >= length
-        if window is not None:
-            masked = masked | (t_ids < length - window)
-        s = jnp.where(masked, NEG_INF, s)
-        m_prev = m_ref[...]
-        l_prev = l_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1)[:, None])
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = alpha * l_prev + jnp.sum(p, axis=-1)[:, None]
-        m_ref[...] = m_new
-        vv = v_ref[:, 0, 0, :].astype(jnp.float32)
-        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-            p, vv, preferred_element_type=jnp.float32)
+        for gi in range(g):
+            lanes = slice(gi * d, (gi + 1) * d)
+            q = q_ref[0, gi].astype(jnp.float32) * sm_scale  # [rep, d]
+            k = k_ref[:, lanes].astype(jnp.float32)          # [block_t, d]
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            if softcap is not None:
+                cap = jnp.float32(softcap)
+                s = cap * jnp.tanh(s / cap)
+            t_ids = j * block_t + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            masked = t_ids >= length
+            if window is not None:
+                masked = masked | (t_ids < length - window)
+            s = jnp.where(masked, NEG_INF, s)
+            m_prev = m_ref[gi]
+            l_prev = l_ref[gi]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1)[:, None])
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[gi] = alpha * l_prev + jnp.sum(p, axis=-1)[:, None]
+            m_ref[gi] = m_new
+            vv = v_ref[:, lanes].astype(jnp.float32)
+            acc_ref[gi] = acc_ref[gi] * alpha + jnp.dot(
+                p, vv, preferred_element_type=jnp.float32)
 
     @pl.when(j == num_t - 1)
     def _finish():
-        o_ref[0, 0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
 
 
 def _decode_pallas(q, k, v, length, sm_scale, softcap, window, block_t):
@@ -123,9 +133,9 @@ def _decode_pallas(q, k, v, length, sm_scale, softcap, window, block_t):
     num_t = T // block_t
     kernel = functools.partial(_decode_kernel, sm_scale=sm_scale,
                                softcap=softcap, window=window,
-                               block_t=block_t, num_t=num_t)
+                               block_t=block_t, num_t=num_t, g=g, d=d)
 
-    def kv_index(bi, gi, j, len_ref):
+    def kv_index(bi, j, len_ref):
         # clamp into the live tile range: a repeated block index skips
         # the DMA, so neither the dead tail nor (with a window) the
         # expired head of the cache is ever fetched
@@ -134,23 +144,26 @@ def _decode_pallas(q, k, v, length, sm_scale, softcap, window, block_t):
             first = 0
         else:
             first = jnp.maximum(len_ref[0] - window, 0) // block_t
-        return (jnp.clip(j, first, last), bi, gi, 0)
+        return (jnp.clip(j, first, last), bi)
 
+    # K/V stream as [T, b*g*d] (a free view of the [T, b, g, d] cache):
+    # a (block_t, 1, 1, d) block of the 4-D buffer is refused by the TPU
+    # lowering (last two block dims must tile (8, 128) or span the array)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b, g, num_t),
+        grid=(b, num_t),
         in_specs=[
-            pl.BlockSpec((1, 1, rep, d),
-                         lambda bi, gi, j, len_ref: (bi, gi, 0, 0)),
-            pl.BlockSpec((block_t, 1, 1, d), kv_index),
-            pl.BlockSpec((block_t, 1, 1, d), kv_index),
+            pl.BlockSpec((1, g, rep, d),
+                         lambda bi, j, len_ref: (bi, 0, 0, 0)),
+            pl.BlockSpec((block_t, g * d), kv_index),
+            pl.BlockSpec((block_t, g * d), kv_index),
         ],
-        out_specs=pl.BlockSpec((1, 1, rep, d),
-                               lambda bi, gi, j, len_ref: (bi, gi, 0, 0)),
+        out_specs=pl.BlockSpec((1, g, rep, d),
+                               lambda bi, j, len_ref: (bi, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((rep, d), jnp.float32),  # acc
-            pltpu.VMEM((rep, 1), jnp.float32),  # running max
-            pltpu.VMEM((rep, 1), jnp.float32),  # running sum
+            pltpu.VMEM((g, rep, d), jnp.float32),  # acc
+            pltpu.VMEM((g, rep, 1), jnp.float32),  # running max
+            pltpu.VMEM((g, rep, 1), jnp.float32),  # running sum
         ],
     )
     return pl.pallas_call(
@@ -158,17 +171,27 @@ def _decode_pallas(q, k, v, length, sm_scale, softcap, window, block_t):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, g, rep, d), jnp.float32),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=_GATE.interpret,
-    )(jnp.asarray(length, jnp.int32).reshape(1), q, k, v)
+    )(jnp.asarray(length, jnp.int32).reshape(1), q,
+      k.reshape(T, b * g * d), v.reshape(T, b * g * d))
 
 
-def use_flash(cache_len: int, block_t: int = DEFAULT_BLOCK_T) -> bool:
-    """True when the kernel would actually run (TPU/interpret AND the
-    block ladder finds a tile dividing the cache buffer). Callers gate
-    on this so the non-kernel path is their own production einsum
-    formulation."""
-    return _GATE.enabled() and choose_block(cache_len, block_t) is not None
+def use_flash(cache_len: int, block_t: int = DEFAULT_BLOCK_T,
+              kv_shape=None) -> bool:
+    """True when the kernel would actually run: TPU/interpret, the
+    block ladder finds a tile dividing the cache buffer and — given the
+    ``[T, b, g, d]`` cache shape — the (block_t, g*d) tile of its
+    [T, b*g*d] view is one the TPU lowering accepts
+    (:func:`lane_block_ok`). Callers gate on this so the non-kernel
+    path is their own production einsum formulation."""
+    if not (_GATE.enabled() and choose_block(cache_len, block_t)
+            is not None):
+        return False
+    if kv_shape is None:
+        return True
+    _, b, g, d = kv_shape
+    return lane_block_ok(_GATE, b, g * d)
 
 
 def gqa_flash_decode(q, k, v, length, sm_scale, window=None, softcap=None,
@@ -182,12 +205,15 @@ def gqa_flash_decode(q, k, v, length, sm_scale, window=None, softcap=None,
     softcap: optional Gemma-2 tanh score cap.
     Returns ctx [b, g, rep, d] fp32.
 
-    Falls back to the einsum oracle off-TPU or when no block divides
-    the cache buffer (``use_flash`` tells a caller which way it goes).
+    Takes the einsum oracle off-TPU or when :func:`use_flash` declines
+    the shape; the path taken is recorded as
+    ``kernels/dispatch/gqa_decode_<path>``.
     """
     T = k.shape[0]
-    if not use_flash(T, block_t):
+    if not use_flash(T, block_t, k.shape):
+        get_kernel_registry().dispatch("gqa_decode", "oracle")
         return gqa_decode_reference(q, k, v, length, sm_scale, window,
                                     softcap)
+    get_kernel_registry().dispatch("gqa_decode", dispatch_path(_GATE))
     return _decode_pallas(q, k, v, length, sm_scale, softcap, window,
                           choose_block(T, block_t))

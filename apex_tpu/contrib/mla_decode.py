@@ -31,7 +31,12 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.contrib._pallas_gate import PallasGate, choose_block
+from apex_tpu.contrib._pallas_gate import (
+    PallasGate,
+    choose_block,
+    lane_block_ok,
+)
+from apex_tpu.kernels.registry import dispatch_path, get_kernel_registry
 
 NEG_INF = -1e30
 DEFAULT_BLOCK_T = 512
@@ -78,7 +83,7 @@ def _decode_kernel(len_ref, q_ref, c_ref, o_ref, acc_ref, m_ref, l_ref, *,
     @pl.when(j * block_t < length)
     def _step():
         q = q_ref[0].astype(jnp.float32) * scale      # [n, L]
-        c = c_ref[:, 0, :].astype(jnp.float32)        # [block_t, L]
+        c = c_ref[...].astype(jnp.float32)            # [block_t, L]
         s = jnp.dot(q, c.T, preferred_element_type=jnp.float32)
         t_ids = j * block_t + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1)
@@ -112,14 +117,17 @@ def _decode_pallas(q_full, cache, length, lat, scale, block_t):
         # clamp to the last live tile: a repeated block index skips the
         # DMA, so dead prefix tiles are never fetched
         last = jnp.maximum(len_ref[0] - 1, 0) // block_t
-        return (jnp.minimum(j, last), bi, 0)
+        return (jnp.minimum(j, last), bi)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b, num_t),
         in_specs=[
             pl.BlockSpec((1, n, L), lambda bi, j, len_ref: (bi, 0, 0)),
-            pl.BlockSpec((block_t, 1, L), cache_index),
+            # the cache streams as [T, b*L] (a free view): a
+            # (block_t, 1, L) block of the 3-D buffer is refused by the
+            # TPU lowering once b > 1
+            pl.BlockSpec((block_t, L), cache_index),
         ],
         out_specs=pl.BlockSpec((1, n, lat),
                                lambda bi, j, len_ref: (bi, 0, 0)),
@@ -136,15 +144,25 @@ def _decode_pallas(q_full, cache, length, lat, scale, block_t):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_GATE.interpret,
-    )(jnp.asarray(length, jnp.int32).reshape(1), q_full, cache)
+    )(jnp.asarray(length, jnp.int32).reshape(1), q_full,
+      cache.reshape(T, b * L))
 
 
-def use_flash(cache_len: int, block_t: int = DEFAULT_BLOCK_T) -> bool:
-    """True when the kernel would actually run (TPU/interpret AND the
-    block ladder finds a tile dividing the cache). Callers gate on this
-    so the non-kernel path is their own production einsum formulation,
-    not this module's fp32 reference fallback."""
-    return _GATE.enabled() and choose_block(cache_len, block_t) is not None
+def use_flash(cache_len: int, block_t: int = DEFAULT_BLOCK_T,
+              cache_shape=None) -> bool:
+    """True when the kernel would actually run: TPU/interpret, the
+    block ladder finds a tile dividing the cache and — given the
+    ``[T, b, L]`` cache shape — the (block_t, L) tile of its [T, b*L]
+    view is one the TPU lowering accepts (:func:`lane_block_ok`).
+    Callers gate on this so the non-kernel path is their own production
+    einsum formulation, not this module's fp32 reference fallback."""
+    if not (_GATE.enabled() and choose_block(cache_len, block_t)
+            is not None):
+        return False
+    if cache_shape is None:
+        return True
+    _, b, L = cache_shape
+    return lane_block_ok(_GATE, b, L)
 
 
 def mla_flash_decode(q_full, cache, length, lat, scale,
@@ -156,11 +174,14 @@ def mla_flash_decode(q_full, cache, length, lat, scale,
     length: [] int32 — live prefix length INCLUDING the current token.
     Returns ctx_lat [b, n, lat] fp32 (caller expands through W_v).
 
-    Falls back to the einsum oracle off-TPU or when no block divides the
-    cache length (``use_flash`` tells a caller which way it will go).
+    Takes the einsum oracle off-TPU or when no block divides the cache
+    length (``use_flash`` tells a caller which way it will go); the path
+    taken is recorded as ``kernels/dispatch/mla_decode_<path>``.
     """
     T = cache.shape[0]
-    if not use_flash(T, block_t):
+    if not use_flash(T, block_t, cache.shape):
+        get_kernel_registry().dispatch("mla_decode", "oracle")
         return mla_decode_reference(q_full, cache, length, lat, scale)
+    get_kernel_registry().dispatch("mla_decode", dispatch_path(_GATE))
     return _decode_pallas(q_full, cache, length, lat, scale,
                           choose_block(T, block_t))

@@ -49,6 +49,7 @@ from apex_tpu.kernels.registry import (
     choose_block,
     get_kernel_registry,
     kernel_gate,
+    lane_block_ok,
 )
 from apex_tpu.telemetry.comm import axis_world, record_collective
 
@@ -278,12 +279,37 @@ def verify_scope(enabled):
         _VERIFY_ENABLED = old
 
 
-def use_window(cache_len, block_t=DEFAULT_BLOCK_T):
-    """True when the window kernel would actually run (gate on, the
-    serving scope hasn't opted out, and a tile divides the cache
-    buffer)."""
-    return GATE.enabled() and _VERIFY_ENABLED \
-        and choose_block(cache_len, block_t) is not None
+# scratch rows (g * q-block * rep) one window-kernel grid cell may
+# hold: acc/m/l are fp32 with the lane dim padded to 128, so 2048 rows
+# is ~3 MB of VMEM next to the streamed K/V tiles
+_WINDOW_ROW_BUDGET = 2048
+
+
+def _q_block(w, g, rep):
+    """Window positions per grid cell: the whole window when its rows
+    fit the scratch budget, else the largest 8-row-aligned divisor of
+    ``w`` that does. None -> the kernel declines."""
+    for bq in (w, 512, 256, 128, 64, 32, 16, 8):
+        if bq <= w and w % bq == 0 \
+                and g * bq * rep <= _WINDOW_ROW_BUDGET \
+                and (bq == w or (bq * rep) % 8 == 0):
+            return bq
+    return None
+
+
+def use_window(cache_len, block_t=DEFAULT_BLOCK_T, q_shape=None):
+    """True when the window kernel would actually run: gate on, the
+    serving scope hasn't opted out, a tile divides the cache buffer
+    and — given the ``[w, b, g, rep, d]`` query shape — the K/V lane
+    block is legal and a query block fits the scratch budget."""
+    if not (GATE.enabled() and _VERIFY_ENABLED
+            and choose_block(cache_len, block_t) is not None):
+        return False
+    if q_shape is None:
+        return True
+    w, b, g, rep, d = q_shape
+    return lane_block_ok(GATE, b, g * d) \
+        and _q_block(w, g, rep) is not None
 
 
 def window_attention_reference(qg, kt, vt, start, sm_scale,
@@ -310,14 +336,30 @@ def window_attention_reference(qg, kt, vt, start, sm_scale,
                       preferred_element_type=jnp.float32)
 
 
+def _online_softmax_step(gi, s, v, acc_ref, m_ref, l_ref):
+    """Fold one masked score tile ``s [rows, block_t]`` and its value
+    tile ``v [block_t, d]`` into group ``gi``'s running (acc, m, l)."""
+    m_prev = m_ref[gi]
+    l_prev = l_ref[gi]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1)[:, None])
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    l_ref[gi] = alpha * l_prev + jnp.sum(p, axis=-1)[:, None]
+    m_ref[gi] = m_new
+    acc_ref[gi] = acc_ref[gi] * alpha + jnp.dot(
+        p, v, preferred_element_type=jnp.float32)
+
+
 def _window_kernel(start_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
                    m_ref, l_ref, *, sm_scale, softcap, window, block_t,
-                   num_t, w, rep):
-    """One (batch, group, cache-tile) cell: all w*rep query rows of
-    the verify window share the streamed tile, online softmax across
-    the tile axis, per-row causal mask at each window position."""
+                   num_t, bq, rep, g, d):
+    """One (batch, query-block, cache-tile) cell: the tile carries
+    every kv group's lanes (``[block_t, g*d]``); each group's bq*rep
+    query rows share its lane slice, online softmax across the tile
+    axis, per-row causal mask at each window position."""
     from jax.experimental import pallas as pl
 
+    qi = pl.program_id(1)
     j = pl.program_id(2)
 
     @pl.when(j == 0)
@@ -326,43 +368,35 @@ def _window_kernel(start_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    start = start_ref[0]
-    live = j * block_t <= start + w - 1
+    first = start_ref[0] + qi * bq       # this block's first query
+    live = j * block_t <= first + bq - 1
 
     @pl.when(live)
     def _step():
-        d = q_ref.shape[-1]
-        q = q_ref[...].reshape(w * rep, d).astype(jnp.float32) \
-            * sm_scale
-        k = k_ref[:, 0, 0, :].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-        if softcap is not None:
-            cap = jnp.float32(softcap)
-            s = cap * jnp.tanh(s / cap)
-        t_ids = j * block_t + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        qpos = start + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 0) // rep
-        masked = t_ids > qpos
-        if window is not None:
-            masked = masked | (qpos - t_ids >= window)
-        s = jnp.where(masked, NEG_INF, s)
-        m_prev = m_ref[...]
-        l_prev = l_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1)[:, None])
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = alpha * l_prev + jnp.sum(p, axis=-1)[:, None]
-        m_ref[...] = m_new
-        vv = v_ref[:, 0, 0, :].astype(jnp.float32)
-        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-            p, vv, preferred_element_type=jnp.float32)
+        for gi in range(g):
+            lanes = slice(gi * d, (gi + 1) * d)
+            q = q_ref[0, gi].astype(jnp.float32) * sm_scale
+            k = k_ref[:, lanes].astype(jnp.float32)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            if softcap is not None:
+                cap = jnp.float32(softcap)
+                s = cap * jnp.tanh(s / cap)
+            t_ids = j * block_t + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            qpos = first + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 0) // rep
+            masked = t_ids > qpos
+            if window is not None:
+                masked = masked | (qpos - t_ids >= window)
+            s = jnp.where(masked, NEG_INF, s)
+            _online_softmax_step(
+                gi, s, v_ref[:, lanes].astype(jnp.float32), acc_ref,
+                m_ref, l_ref)
 
     @pl.when(j == num_t - 1)
     def _finish():
-        d = q_ref.shape[-1]
-        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)) \
-            .reshape(w, 1, 1, rep, d)
+        o_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
 
 
 def _window_pallas(qg, kt, vt, start, sm_scale, softcap, window,
@@ -373,42 +407,54 @@ def _window_pallas(qg, kt, vt, start, sm_scale, softcap, window,
     w, b, g, rep, d = qg.shape
     T = kt.shape[0]
     num_t = T // block_t
+    bq = _q_block(w, g, rep)
+    rows = bq * rep
     kernel = functools.partial(
         _window_kernel, sm_scale=sm_scale, softcap=softcap,
-        window=window, block_t=block_t, num_t=num_t, w=w, rep=rep)
+        window=window, block_t=block_t, num_t=num_t, bq=bq, rep=rep,
+        g=g, d=d)
 
-    def kv_index(bi, gi, j, start_ref):
+    def kv_index(bi, qi, j, start_ref):
         # clamp into the live tile range: a repeated block index skips
-        # the DMA for the dead tail beyond the verify window
-        last = jnp.maximum(start_ref[0] + w - 1, 0) // block_t
-        return (jnp.minimum(j, last), bi, gi, 0)
+        # the DMA for the dead tail beyond this query block
+        last = jnp.maximum(start_ref[0] + (qi + 1) * bq - 1, 0) \
+            // block_t
+        return (jnp.minimum(j, last), bi)
 
+    def q_index(bi, qi, j, start_ref):
+        return (bi, 0, qi, 0)
+
+    # K/V stream as [T, b*g*d] (a free view of the [T, b, g, d] cache):
+    # a (block_t, 1, 1, d) block of the 4-D buffer is refused by the
+    # TPU lowering (last two block dims must tile (8, 128) or span the
+    # array). Queries go in as [b, g, w*rep, d] so a cell reads each
+    # group's rows without an in-kernel relayout.
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b, g, num_t),
+        grid=(b, w // bq, num_t),
         in_specs=[
-            pl.BlockSpec((w, 1, 1, rep, d),
-                         lambda bi, gi, j, start_ref: (0, bi, gi, 0, 0)),
-            pl.BlockSpec((block_t, 1, 1, d), kv_index),
-            pl.BlockSpec((block_t, 1, 1, d), kv_index),
+            pl.BlockSpec((1, g, rows, d), q_index),
+            pl.BlockSpec((block_t, g * d), kv_index),
+            pl.BlockSpec((block_t, g * d), kv_index),
         ],
-        out_specs=pl.BlockSpec(
-            (w, 1, 1, rep, d),
-            lambda bi, gi, j, start_ref: (0, bi, gi, 0, 0)),
+        out_specs=pl.BlockSpec((1, g, rows, d), q_index),
         scratch_shapes=[
-            pltpu.VMEM((w * rep, d), jnp.float32),  # acc
-            pltpu.VMEM((w * rep, 1), jnp.float32),  # running max
-            pltpu.VMEM((w * rep, 1), jnp.float32),  # running sum
+            pltpu.VMEM((g, rows, d), jnp.float32),  # acc
+            pltpu.VMEM((g, rows, 1), jnp.float32),  # running max
+            pltpu.VMEM((g, rows, 1), jnp.float32),  # running sum
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((w, b, g, rep, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, g, w * rep, d), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=GATE.interpret,
-    )(jnp.asarray(start, jnp.int32).reshape(1), qg, kt, vt)
+    )(jnp.asarray(start, jnp.int32).reshape(1),
+      qg.transpose(1, 2, 0, 3, 4).reshape(b, g, w * rep, d),
+      kt.reshape(T, b * g * d), vt.reshape(T, b * g * d))
+    return out.reshape(b, g, w, rep, d).transpose(2, 0, 1, 3, 4)
 
 
 def window_attention(qg, kt, vt, start, sm_scale, window=None,
@@ -421,9 +467,9 @@ def window_attention(qg, kt, vt, start, sm_scale, window=None,
     kt, vt: [T, b, g, d] cache buffers with the window rows written.
     start:  [] int32 — absolute position of the first window query.
     Returns ctx [w, b, g, rep, d] fp32.  Falls back to the einsum
-    oracle when the gate is off or no tile divides the buffer."""
+    oracle when :func:`use_window` declines."""
     T = kt.shape[0]
-    if not use_window(T, block_t):
+    if not use_window(T, block_t, qg.shape):
         record("oracle")
         return window_attention_reference(qg, kt, vt, start, sm_scale,
                                           window, softcap)
@@ -450,17 +496,30 @@ def spec_verify_reference(q, kq, ks, vq, vs, start, sm_scale):
         q[:, None], k[:, None], v[:, None], start, sm_scale)[:, 0]
 
 
+def _dequant_lanes(q_ref, s_ref, lo, hi, B):
+    """fp32 lanes ``[lo, hi)`` of a ``[block_t, nb*B]`` int8 tile, each
+    lane scaled by its own quantization block's ``[block_t, 1]`` scale
+    column — static slices only (a group's lanes sit inside one block
+    whenever ``B % d == 0``, the serving layout)."""
+    parts = []
+    while lo < hi:
+        blk = lo // B
+        end = min(hi, (blk + 1) * B)
+        parts.append(q_ref[:, lo:end].astype(jnp.float32)
+                     * s_ref[:, blk:blk + 1])
+        lo = end
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+
+
 def _verify_kernel(start_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref,
                    o_ref, acc_ref, m_ref, l_ref, *, sm_scale, block_t,
-                   num_t, w, rep, d):
-    """int8-KV verify cell: the tile's quantized blocks are widened
-    and scaled IN VMEM (``kq * ks`` per block), so the dequantized
-    cache never exists in HBM — the fused alternative to
-    ``materialize_rows`` + einsum."""
+                   num_t, w, rep, g, d, B):
+    """int8-KV verify cell: each group's quantized lanes are widened
+    and scaled IN VMEM, so the dequantized cache never exists in HBM —
+    the fused alternative to ``materialize_rows`` + einsum."""
     from jax.experimental import pallas as pl
 
-    gi = pl.program_id(0)
-    j = pl.program_id(1)
+    j = pl.program_id(0)
 
     @pl.when(j == 0)
     def _init():
@@ -473,35 +532,24 @@ def _verify_kernel(start_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref,
 
     @pl.when(live)
     def _step():
-        q = q_ref[:, 0].reshape(w * rep, d).astype(jnp.float32) \
-            * sm_scale
-        # in-register dequant: [block_t, nb, B] * [block_t, nb, 1]
-        kt = (kq_ref[...].astype(jnp.float32) * ks_ref[...]) \
-            .reshape(block_t, -1)
-        k = jax.lax.dynamic_slice_in_dim(kt, gi * d, d, axis=1)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-        t_ids = j * block_t + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        qpos = start + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 0) // rep
-        s = jnp.where(t_ids > qpos, NEG_INF, s)
-        m_prev = m_ref[...]
-        l_prev = l_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1)[:, None])
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = alpha * l_prev + jnp.sum(p, axis=-1)[:, None]
-        m_ref[...] = m_new
-        vt = (vq_ref[...].astype(jnp.float32) * vs_ref[...]) \
-            .reshape(block_t, -1)
-        vv = jax.lax.dynamic_slice_in_dim(vt, gi * d, d, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-            p, vv, preferred_element_type=jnp.float32)
+        for gi in range(g):
+            lo, hi = gi * d, (gi + 1) * d
+            q = q_ref[gi].astype(jnp.float32) * sm_scale
+            k = _dequant_lanes(kq_ref, ks_ref, lo, hi, B)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            t_ids = j * block_t + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            qpos = start + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 0) // rep
+            s = jnp.where(t_ids > qpos, NEG_INF, s)
+            _online_softmax_step(
+                gi, s, _dequant_lanes(vq_ref, vs_ref, lo, hi, B),
+                acc_ref, m_ref, l_ref)
 
     @pl.when(j == num_t - 1)
     def _finish():
-        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)) \
-            .reshape(w, 1, rep, d)
+        o_ref[...] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
 
 
 def spec_verify_attention(q, kq, ks, vq, vs, start, sm_scale,
@@ -523,41 +571,50 @@ def spec_verify_attention(q, kq, ks, vq, vs, start, sm_scale,
     block = choose_block(T, block_t)
     num_t = T // block
     nb, B = kq.shape[1], kq.shape[2]
+    rows = w * rep
     kernel = functools.partial(
         _verify_kernel, sm_scale=sm_scale, block_t=block, num_t=num_t,
-        w=w, rep=rep, d=d)
+        w=w, rep=rep, g=g, d=d, B=B)
 
-    def kv_index(gi, j, start_ref):
+    def kv_index(j, start_ref):
         last = jnp.maximum(start_ref[0] + w - 1, 0) // block
-        return (jnp.minimum(j, last), 0, 0)
+        return (jnp.minimum(j, last), 0)
 
+    def whole(j, start_ref):
+        return (0, 0, 0)
+
+    # codes stream lane-dense as [T, nb*B] and scales as [T, nb] (free
+    # views): every group reads a STATIC lane slice — the Pallas TPU
+    # lowering has no dynamic_slice on a value
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(g, num_t),
+        grid=(num_t,),
         in_specs=[
-            pl.BlockSpec((w, 1, rep, d),
-                         lambda gi, j, start_ref: (0, gi, 0, 0)),
-            pl.BlockSpec((block, nb, B), kv_index),
-            pl.BlockSpec((block, nb, 1), kv_index),
-            pl.BlockSpec((block, nb, B), kv_index),
-            pl.BlockSpec((block, nb, 1), kv_index),
+            pl.BlockSpec((g, rows, d), whole),
+            pl.BlockSpec((block, nb * B), kv_index),
+            pl.BlockSpec((block, nb), kv_index),
+            pl.BlockSpec((block, nb * B), kv_index),
+            pl.BlockSpec((block, nb), kv_index),
         ],
-        out_specs=pl.BlockSpec((w, 1, rep, d),
-                               lambda gi, j, start_ref: (0, gi, 0, 0)),
+        out_specs=pl.BlockSpec((g, rows, d), whole),
         scratch_shapes=[
-            pltpu.VMEM((w * rep, d), jnp.float32),
-            pltpu.VMEM((w * rep, 1), jnp.float32),
-            pltpu.VMEM((w * rep, 1), jnp.float32),
+            pltpu.VMEM((g, rows, d), jnp.float32),
+            pltpu.VMEM((g, rows, 1), jnp.float32),
+            pltpu.VMEM((g, rows, 1), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((w, g, rep, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((g, rows, d), jnp.float32),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=GATE.interpret,
-    )(jnp.asarray(start, jnp.int32).reshape(1), q, kq, ks, vq, vs)
+    )(jnp.asarray(start, jnp.int32).reshape(1),
+      q.transpose(1, 0, 2, 3).reshape(g, rows, d),
+      kq.reshape(T, nb * B), ks.reshape(T, nb),
+      vq.reshape(T, nb * B), vs.reshape(T, nb))
+    return out.reshape(g, w, rep, d).transpose(1, 0, 2, 3)
 
 
 # ---------------------------------------------------------------------------

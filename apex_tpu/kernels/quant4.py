@@ -36,7 +36,7 @@ off TPU. Gate: ``quant4``.
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.kernels.registry import get_kernel_registry, kernel_gate
+from apex_tpu.kernels.registry import kernel_gate, record_dispatch
 
 GATE = kernel_gate("quant4", default=True)
 
@@ -46,14 +46,6 @@ _SCALE_QMAX = 255.0
 # int8 tiles at 32 sublanes; one grid cell covers 32 blocks (the same
 # cell the int8 compression kernels use)
 _ROWS = 32
-
-
-def record(path=None):
-    gate = GATE
-    if path is None:
-        path = ("interpret" if gate.interpret else "pallas") \
-            if gate.enabled() else "oracle"
-    get_kernel_registry().dispatch("quant4", path)
 
 
 def int4_block_scales(absmax):
@@ -179,7 +171,7 @@ def _cellwise(kernel, out_dtype, out_cols, x2d, *extra):
 
 def quantize_int4(x2d, scales):
     """[nb, B] fp32 + effective scales -> int4-valued int8 codes."""
-    if GATE.enabled():
+    if record_dispatch("quant4", GATE):
         def k(x_ref, s_ref, q_ref):
             _quant_kernel(x_ref, s_ref, q_ref)
         return _cellwise(k, jnp.int8, x2d.shape[1], x2d, scales)
@@ -188,7 +180,7 @@ def quantize_int4(x2d, scales):
 
 def dequantize_int4(q2d, scales):
     """int4 codes (or int32 psum partials) + effective scales -> fp32."""
-    if GATE.enabled() and q2d.dtype == jnp.int8:
+    if q2d.dtype == jnp.int8 and record_dispatch("quant4", GATE):
         def k(q_ref, s_ref, o_ref):
             _dequant_kernel(q_ref, s_ref, o_ref)
         return _cellwise(k, jnp.float32, q2d.shape[1], q2d, scales)
@@ -198,7 +190,7 @@ def dequantize_int4(q2d, scales):
 def pack_int4(q2d):
     """[nb, B] int4 codes -> [nb, ceil(B/2)] uint8 split-half nibbles
     (a ragged odd-B tail pads one zero lane)."""
-    if GATE.enabled():
+    if record_dispatch("quant4", GATE):
         q2d = _pad_even_lanes(q2d)
 
         def k(q_ref, p_ref):
@@ -210,7 +202,7 @@ def pack_int4(q2d):
 def unpack_int4(p2d, n=None):
     """[nb, B/2] uint8 nibbles -> [nb, B] int4-valued int8 codes;
     ``n`` truncates a ragged tail's pad lane back off."""
-    if GATE.enabled():
+    if record_dispatch("quant4", GATE):
         def k(p_ref, q_ref):
             _unpack_kernel(p_ref, q_ref)
         out = _cellwise(k, jnp.int8, p2d.shape[1] * 2, p2d)

@@ -59,10 +59,7 @@ def _warn_legacy(legacy_env, env_var):
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 class PallasGate:
@@ -123,6 +120,13 @@ def choose_block(cache_len: int, preferred: int):
         if b <= cache_len and cache_len % b == 0:
             return b
     return None
+
+
+def lane_block_ok(gate: PallasGate, batch: int, lanes: int) -> bool:
+    """Can a ``[T, batch*lanes]`` cache view stream in ``(block_t,
+    lanes)`` tiles? The TPU lowering wants the lane block to tile 128
+    or span the array; the interpreter has no tiling rule."""
+    return gate.interpret or batch == 1 or lanes % 128 == 0
 
 
 class KernelRegistry:

@@ -235,7 +235,8 @@ class MLAAttention(nn.Module):
             from apex_tpu.contrib import mla_decode as _mla_decode
 
             if (mode == "step" and s == 1
-                    and _mla_decode.use_flash(max_len)):
+                    and _mla_decode.use_flash(
+                        max_len, cache_shape=cache.value.shape)):
                 # Single-token hot loop: the streaming Pallas kernel —
                 # cache read once for all heads, no [b, n, 1, T] score
                 # round-trip through HBM, dead prefix tiles never

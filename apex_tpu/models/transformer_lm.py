@@ -857,7 +857,7 @@ class ParallelAttention(nn.Module):
             # for windowed layers, before the window (contrib/gqa_decode)
             from apex_tpu.contrib import gqa_decode
 
-            if gqa_decode.use_flash(kv_len):
+            if gqa_decode.use_flash(kv_len, kv_shape=kt.shape):
                 import math
 
                 sm = 1.0 / math.sqrt(cfg.query_pre_attn_scalar or kv)
@@ -875,7 +875,7 @@ class ParallelAttention(nn.Module):
             # (kernels/fused_cc, family b)
             from apex_tpu.kernels import fused_cc
 
-            if fused_cc.use_window(kv_len):
+            if fused_cc.use_window(kv_len, q_shape=qg.shape):
                 import math
 
                 sm = 1.0 / math.sqrt(cfg.query_pre_attn_scalar or kv)
@@ -918,14 +918,10 @@ class ParallelAttention(nn.Module):
 
 
 def _flash_available(seq, head_dim):
-    try:
-        import jax
+    from apex_tpu.contrib.fmha import GATE
 
-        if jax.default_backend() != "tpu":
-            return False
-    except Exception:
-        return False
-    return seq % 128 == 0 and head_dim in (64, 128, 256)
+    return (GATE.enabled() and seq % 128 == 0
+            and head_dim in (64, 128, 256))
 
 
 class ParallelMLP(nn.Module):

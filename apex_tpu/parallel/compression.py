@@ -50,7 +50,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from apex_tpu.kernels import quant4 as _quant4
-from apex_tpu.kernels.registry import kernel_gate
+from apex_tpu.kernels.registry import kernel_gate, record_dispatch
 from apex_tpu.telemetry import comm as _telemetry_comm
 
 # ~256 lanes per scale: 2 TPU lane-groups wide, 0.4% scale overhead.
@@ -184,14 +184,14 @@ def quantize_blockwise(flat, block_size: int = BLOCK_SIZE, scales=None):
     x2d = pad_to_blocks(flat, block_size)
     if scales is None:
         scales = block_scales(x2d)
-    if _gate().enabled():
+    if record_dispatch("quant", _gate()):
         return _quantize_pallas(x2d, scales), scales
     return _quantize_jnp(x2d, scales), scales
 
 
 def dequantize_blockwise(q2d, scales, n=None):
     """(q [nblocks, b] int8/int32, scales [nblocks, 1]) -> [n] fp32."""
-    if _gate().enabled():
+    if record_dispatch("quant", _gate()):
         out = _dequantize_pallas(q2d, scales)
     else:
         out = _dequantize_jnp(q2d, scales)
@@ -215,7 +215,7 @@ def quantize_rows_blockwise(x, block_size: int = BLOCK_SIZE):
                    ((0, 0), (0, nb * block_size - n)))
     flat = flat.reshape(-1, block_size)
     scales = block_scales(flat)
-    q = (_quantize_pallas(flat, scales) if _gate().enabled()
+    q = (_quantize_pallas(flat, scales) if record_dispatch("quant", _gate())
          else _quantize_jnp(flat, scales))
     return (q.reshape(*lead, nb, block_size),
             scales.reshape(*lead, nb, 1))
@@ -230,7 +230,7 @@ def dequantize_rows_blockwise(q, scales, n=None):
     block_size = q.shape[-1]
     flat = q.reshape(-1, block_size)
     s = scales.reshape(-1, 1)
-    out = (_dequantize_pallas(flat, s) if _gate().enabled()
+    out = (_dequantize_pallas(flat, s) if record_dispatch("quant", _gate())
            else _dequantize_jnp(flat, s))
     out = out.reshape(*lead, q.shape[-2] * block_size)
     return out if n is None else out[..., :n]
@@ -286,7 +286,6 @@ def _psum_int4(flat, axis_name, *, residual, block_size=BLOCK_SIZE):
         g = g + residual.astype(jnp.float32)
     x2d = pad_to_blocks(g, block_size)
     scales = _shared_int4_scales(x2d, axis_name)
-    _quant4.record()
     q = _quant4.quantize_int4(x2d, scales)
     _telemetry_comm.record_collective(
         "psum", elements=q.size, dtype=jnp.int8, bits=4,
@@ -401,7 +400,6 @@ def psum_scatter_compressed(flat, axis_name, *, mode="int8", residual=None,
     nb = x2d.shape[0]
     if mode == "int4":
         scales = _shared_int4_scales(x2d, axis_name)
-        _quant4.record()
         q = _quant4.quantize_int4(x2d, scales)
         _telemetry_comm.record_collective(
             "psum_scatter", elements=q.size, dtype=jnp.int8, bits=4,
@@ -481,7 +479,6 @@ def _all_gather_int4(shard, axis_name, *, block_size=BLOCK_SIZE):
     if fused:
         packed = _fused_cc.quantize_pack_int4(x2d, scales)
     else:
-        _quant4.record()
         q = _quant4.quantize_int4(x2d, scales)
         packed = _quant4.pack_int4(q)
     for elems, dt in ((packed.size, jnp.uint8), (sq.size, jnp.uint8),

@@ -568,15 +568,9 @@ def _ddp_bwd(fn, axis_name, gradient_average, vjp, g):
     # first one.
     axes = (tuple(axis_name) if isinstance(axis_name, (tuple, list))
             else (axis_name,))
-    if not hasattr(jax, "typeof"):
-        # jax < 0.6 has no vma typing at all: the experimental shard_map
-        # used there runs check_rep=False (apex_tpu.testing.shard_map),
-        # i.e. always the unchecked regime — DDP performs the allreduce.
-        states = {False}
-    else:
-        states = {
-            ax in getattr(jax.typeof(lax.axis_index(ax)), "vma", frozenset())
-            for ax in axes}
+    states = {
+        ax in getattr(jax.typeof(lax.axis_index(ax)), "vma", frozenset())
+        for ax in axes}
     if len(states) != 1:
         raise ValueError(
             f"mixed vma checking states across mesh axes {axes}; DDP "

@@ -1270,8 +1270,8 @@ class ServeEngine:
         :class:`~apex_tpu.serving.robust.RobustConfig`) and ``guard``
         (a :class:`~apex_tpu.resilience.preemption.PreemptionGuard`)
         pass through to the scheduler. The convenience entry point
-        bench.py's ``serve_decode``/``serve_chaos`` and the oneproc
-        serve smokes drive."""
+        bench.py's ``serve_decode``/``serve_chaos`` and
+        ``chip_smoke.py`` drive."""
         from apex_tpu.serving.scheduler import Scheduler
 
         sched = Scheduler(self, registry=self._registry, robust=robust,

@@ -13,9 +13,9 @@ Four host-side pieces (nothing here touches the traced program):
 
 - :func:`step_memory` — wraps ``lowered.compile().memory_analysis()``
   into one report dict: argument / output / temp / generated-code
-  bytes, the derived ``peak_bytes``, the backend's HBM capacity
-  (per-backend table, ``APEX_TPU_HBM_GB`` override, or the device's own
-  ``memory_stats()['bytes_limit']`` when it reports one) and the
+  bytes, the derived ``peak_bytes``, the device's HBM capacity
+  (``APEX_TPU_HBM_GB`` override, else the device's own
+  ``memory_stats()['bytes_limit']``; a CPU stand-in for tests) and the
   ``headroom_frac`` that lands in the ``memory/hbm_headroom`` gauge.
   Every report is appended to an in-process headroom trend ring — the
   post-mortem's "how did it trend" answer.
@@ -54,15 +54,12 @@ ENV_DIR = "APEX_TPU_MEMORY_DIR"
 POSTMORTEM_BASENAME = "memory-postmortem-rank{rank}.json"
 TREND_LENGTH = 64
 
-# Per-backend HBM capacity defaults, bytes. Heuristic stand-ins — chip
-# generations differ (TPU v4 32G, v5e 16G, v5p 95G) and the CPU "HBM"
-# is host RAM; the authoritative sources are, in order,
-# $APEX_TPU_HBM_GB and the device's own memory_stats()['bytes_limit'].
-_HBM_DEFAULTS_BYTES = {
-    "tpu": int(32e9),
-    "gpu": int(80e9),
-    "cpu": int(16e9),
-}
+# The CPU "HBM" is host RAM and this figure a stand-in for tests. A TPU
+# has no row here on purpose: its capacity is the device's own
+# ``memory_stats()['bytes_limit']`` (or $APEX_TPU_HBM_GB), never a
+# platform-wide guess — chip generations differ (v4 32G, v5e 16G,
+# v5p 95G).
+_CPU_CAPACITY_BYTES = int(16e9)
 
 
 class MemoryBudgetError(RuntimeError):
@@ -78,41 +75,40 @@ class HBMExhaustedError(RuntimeError):
 
 
 def _default_backend():
-    try:
-        import jax
+    import jax
 
-        return jax.default_backend()
-    except Exception:
-        return "cpu"
+    return jax.default_backend()
 
 
 def _device_bytes_limit():
-    """The accelerator's own reported capacity, when it reports one
-    (real TPUs do via ``Device.memory_stats()``; CPU returns None)."""
-    try:
-        import jax
+    """The accelerator's own reported capacity (TPUs report one via
+    ``Device.memory_stats()``; the CPU backend reports no stats)."""
+    import jax
 
-        stats = jax.local_devices()[0].memory_stats()
-        limit = (stats or {}).get("bytes_limit")
-        return int(limit) if limit else None
-    except Exception:
-        return None
+    stats = jax.local_devices()[0].memory_stats()
+    limit = (stats or {}).get("bytes_limit")
+    return int(limit) if limit else None
 
 
 def hbm_capacity_bytes(backend=None):
     """HBM capacity in bytes for ``backend`` (default: the current jax
     default backend). Resolution order: ``$APEX_TPU_HBM_GB`` (decimal
-    GB) > the device's measured ``bytes_limit`` > the per-backend
-    default table."""
+    GB) > the device's own ``bytes_limit`` > the CPU stand-in. An
+    accelerator that reports no limit raises: headroom against a
+    guessed capacity is not headroom."""
     env = os.environ.get(ENV_HBM_GB)
     if env:
         return int(float(env) * 1e9)
-    measured = _device_bytes_limit()
-    if measured:
-        return measured
     if backend is None:
         backend = _default_backend()
-    return _HBM_DEFAULTS_BYTES.get(backend, _HBM_DEFAULTS_BYTES["tpu"])
+    if backend == "cpu":
+        return _CPU_CAPACITY_BYTES
+    measured = _device_bytes_limit()
+    if measured is None:
+        raise RuntimeError(
+            f"the {backend} device reports no memory_stats()"
+            f"['bytes_limit']; set ${ENV_HBM_GB} to its HBM capacity")
+    return measured
 
 
 # -- step memory accounting -------------------------------------------------

@@ -22,7 +22,7 @@ Exposure is two-channel:
   behind a stdlib ``http.server`` scrape endpoint
   (:meth:`Monitor.serve`, gated by ``APEX_TPU_MONITOR_PORT``). The
   renderer's output round-trips :func:`parse_openmetrics`, a strict
-  conformance parser the tests and the oneproc smoke both run.
+  conformance parser the tests run.
 - ``tools/monitor_dash.py`` — terminal dashboard over a telemetry dir
   (live tail or ``--once``).
 

@@ -1,5 +1,5 @@
 """XLA cost accounting: FLOPs / bytes-accessed for a jitted step, and
-achieved MFU / HBM-utilization against a per-backend peak table.
+achieved MFU / HBM-utilization against a per-device-kind peak table.
 
 ``step_cost(jitted, *args)`` extracts XLA's own cost analysis from the
 lowered (or compiled) computation — the measured counterpart to the
@@ -10,43 +10,39 @@ large model can take tens of minutes to compile on this host's 1-core
 CPU). Pass ``use_compiled=True`` for post-optimization numbers when a
 compile is acceptable (or already cached).
 
-The peak table is deliberately small: per-backend (peak FLOP/s, peak
-HBM bytes/s), overridable via ``APEX_TPU_PEAK_TFLOPS`` and
-``APEX_TPU_PEAK_HBM_GBPS``. The TPU default is the measured 154 bf16
-TFLOP/s of this chip class (PERF.md), matching ``bench.py``.
+The peak table is keyed by ``device_kind`` — what
+``jax.devices()[0].device_kind`` reports — and every row names its
+source. A device that is not in the table is an error, never a default:
+a utilization against another chip's peak is not a utilization.
 """
 
-import os
-
-# (peak_flops_per_sec, peak_hbm_bytes_per_sec) by jax backend platform.
-# CPU numbers are order-of-magnitude placeholders — the CPU mesh exists
-# for tests, not rooflines.
-_PEAK_DEFAULTS = {
-    "tpu": (154e12, 1.23e12),
-    "gpu": (312e12, 2.0e12),
+# device_kind -> (peak bf16 FLOP/s, peak HBM bytes/s)
+_PEAKS_BY_DEVICE_KIND = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB of
+    # HBM at 819 GB/s per chip
+    "TPU v5 lite": (197e12, 819e9),
+    # order-of-magnitude placeholder: the CPU mesh exists for tests,
+    # not rooflines
     "cpu": (0.1e12, 0.05e12),
 }
 
 
-def peak_table(backend=None):
-    """(peak_flops_per_sec, peak_hbm_bytes_per_sec) for ``backend``
-    (default: the current jax default backend), honoring the env
-    overrides."""
-    if backend is None:
-        try:
-            import jax
+def peak_table(device_kind=None):
+    """(peak_flops_per_sec, peak_hbm_bytes_per_sec) for ``device_kind``
+    (default: the first jax device's own). Raises ``ValueError`` for a
+    kind the table does not hold."""
+    if device_kind is None:
+        import jax
 
-            backend = jax.default_backend()
-        except Exception:
-            backend = "cpu"
-    flops, hbm = _PEAK_DEFAULTS.get(backend, _PEAK_DEFAULTS["tpu"])
-    env_flops = os.environ.get("APEX_TPU_PEAK_TFLOPS")
-    if env_flops:
-        flops = float(env_flops) * 1e12
-    env_hbm = os.environ.get("APEX_TPU_PEAK_HBM_GBPS")
-    if env_hbm:
-        hbm = float(env_hbm) * 1e9
-    return flops, hbm
+        device_kind = jax.devices()[0].device_kind
+    try:
+        return _PEAKS_BY_DEVICE_KIND[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device_kind {device_kind!r}: add a "
+            f"row (with its source) to telemetry.xla_cost."
+            f"_PEAKS_BY_DEVICE_KIND — known: "
+            f"{sorted(_PEAKS_BY_DEVICE_KIND)}") from None
 
 
 def _normalize(analysis):
@@ -93,7 +89,7 @@ def step_cost(jitted, *args, use_compiled=False, **kwargs):
 
 
 def utilization(flops_per_step, step_seconds, *, bytes_per_step=None,
-                backend=None):
+                device_kind=None):
     """Achieved fractions of peak: ``{"mfu", "hbm_util", ...}``.
 
     ``mfu`` = model FLOP/s over peak FLOP/s (PaLM convention — pass
@@ -101,7 +97,7 @@ def utilization(flops_per_step, step_seconds, *, bytes_per_step=None,
     ``hbm_util`` = bytes-accessed/s over peak HBM bandwidth (an upper
     bound on demand — XLA's bytes-accessed counts every operand touch,
     not DRAM traffic)."""
-    peak_flops, peak_hbm = peak_table(backend)
+    peak_flops, peak_hbm = peak_table(device_kind)
     out = {
         "flops_per_sec": flops_per_step / step_seconds,
         "mfu": flops_per_step / step_seconds / peak_flops,
@@ -112,7 +108,8 @@ def utilization(flops_per_step, step_seconds, *, bytes_per_step=None,
     return out
 
 
-def record_step_cost(cost, step_seconds, *, registry=None, backend=None):
+def record_step_cost(cost, step_seconds, *, registry=None,
+                     device_kind=None):
     """Fold a :func:`step_cost` result + measured step time into the
     registry: ``mfu`` / ``hbm_util`` / ``model_flops_per_step_xla``
     gauges. Returns the :func:`utilization` dict (or None)."""
@@ -122,7 +119,7 @@ def record_step_cost(cost, step_seconds, *, registry=None, backend=None):
         return None
     util = utilization(cost["flops"], step_seconds,
                        bytes_per_step=cost.get("bytes_accessed"),
-                       backend=backend)
+                       device_kind=device_kind)
     reg = registry or get_registry()
     if reg.enabled:
         reg.gauge("model_flops_per_step_xla").set(cost["flops"])

@@ -22,15 +22,8 @@ def shard_map(fn=None, *, mesh, in_specs, out_specs):
     still exactly SPMD. Usable as a decorator or a function.
     """
     def wrap(f):
-        if hasattr(jax, "shard_map"):
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        # jax < 0.6: shard_map lives in jax.experimental and the
-        # replication checker is spelled check_rep
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
+        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     if fn is None:
         return wrap

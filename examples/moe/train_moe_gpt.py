@@ -46,8 +46,14 @@ def main():
                    help="force the virtual CPU platform")
     args = p.parse_args()
 
-    if args.cpu or len(jax.devices()) < args.ep * args.tp:
+    if args.cpu:
         jax.config.update("jax_platforms", "cpu")
+    if len(jax.devices()) < args.ep * args.tp:
+        raise SystemExit(
+            f"need {args.ep * args.tp} devices (ep x tp), have "
+            f"{len(jax.devices())}; pass --cpu with XLA_FLAGS="
+            f"--xla_force_host_platform_device_count=N for the virtual "
+            f"CPU mesh")
 
     from apex_tpu.models.transformer_lm import TransformerConfig
     from apex_tpu.optimizers import FusedAdam

@@ -134,7 +134,7 @@ class TestSeededViolations:
         assert "custom_call @" in report.findings[0].where
 
     def test_no_f64(self):
-        from jax.experimental import enable_x64
+        from jax import enable_x64
 
         with enable_x64():
             report = lint_fn(lambda x: x.astype(jnp.float64) * 2.0,
@@ -408,8 +408,7 @@ class TestShardingRules:
 
     @pytest.mark.slow  # one XLA SPMD-partitioner compile (~50s on the
     # 8-way virtual CPU mesh); the text-seeded test above keeps the
-    # rule under tier-1 and the oneproc `sharding` smoke runs this
-    # end-to-end at capture time
+    # rule under tier-1
     @pytest.mark.multi_device
     def test_implicit_reshard_fires_on_real_gspmd_program(self, dp_mesh):
         """The real thing: mismatched in/out shardings force the SPMD

@@ -1,8 +1,7 @@
-"""tools/bench_schema_check over the repo's checked-in BENCH_*.json
-files + the live bench._emit output format (ISSUE 2 satellite: the
-bench JSON contract — incl. the telemetry fields — is now enforced)."""
+"""tools/bench_schema_check over wrapper records + the live bench._emit
+output format (ISSUE 2 satellite: the bench JSON contract — incl. the
+telemetry fields — is enforced)."""
 
-import glob
 import json
 import os
 import sys
@@ -15,19 +14,6 @@ sys.path.insert(0, os.path.join(ROOT, "tools"))
 sys.path.insert(0, ROOT)
 
 import bench_schema_check as schema  # noqa: E402
-
-
-def test_checked_in_bench_jsons_valid():
-    files = sorted(glob.glob(os.path.join(ROOT, "BENCH_*.json")))
-    assert files, "no checked-in BENCH_*.json found"
-    errors = []
-    for path in files:
-        schema.check_file(path, errors)
-    assert errors == []
-
-
-def test_cli_over_repo_root():
-    assert schema.main([ROOT]) == 0
 
 
 def test_wrapper_schema_rejects_bad_records():
@@ -55,7 +41,7 @@ def test_metric_line_requires_telemetry_fields_since_round7():
 
 def test_bench_error_contract_by_round():
     err = {"metric": "bench_error", "value": 0, "unit": "error",
-           "vs_baseline": 0.0, "kind": "wedge"}
+           "vs_baseline": 0.0, "kind": "no_tpu"}
     assert schema.check_metric_line(dict(err), round_n=5, errors=[]) == []
     msgs = schema.check_metric_line(dict(err), round_n=6, errors=[])
     assert any("comm_bytes_per_step" in m for m in msgs)
@@ -632,6 +618,38 @@ def test_live_bench_error_passes_current_schema(capsys):
     bench._emit_bench_error("unit test error", "crash")
     line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert schema.check_metric_line(line, round_n=7, errors=[]) == []
+
+
+def test_cli_refuses_the_cpu_unless_asked(monkeypatch, capsys):
+    """``python bench.py`` measures on a TPU. The CPU mesh is a choice
+    the caller states with JAX_PLATFORMS=cpu; without it the CLI exits
+    non-zero with a parseable ``no_tpu`` line instead of quietly timing
+    whatever backend it found."""
+    import bench
+
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert bench._require_tpu_unless_cpu_asked() is None
+    assert capsys.readouterr().out == ""
+    monkeypatch.delenv("JAX_PLATFORMS")
+    with pytest.raises(SystemExit) as exc:
+        bench._require_tpu_unless_cpu_asked()
+    assert exc.value.code == 2
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert line["metric"] == "bench_error" and line["kind"] == "no_tpu"
+    assert schema.check_metric_line(line, round_n=7, errors=[]) == []
+
+
+def test_dryrun_multichip_raises_on_too_few_devices(monkeypatch):
+    """No silent switch of platform: asked for more devices than the
+    platform in use has, the driver entry raises."""
+    import __graft_entry__
+
+    # not the asked-for CPU dry run: neither the device-count flag nor
+    # JAX_PLATFORMS=cpu is in the environment of this call
+    monkeypatch.setenv("XLA_FLAGS", "")
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    with pytest.raises(RuntimeError, match="need 64 devices, have"):
+        __graft_entry__.dryrun_multichip(64)
 
 
 @pytest.mark.parametrize("bad", [

@@ -390,17 +390,24 @@ class TestCompileCacheCounters:
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           before_min)
         # drop the cache object pointing at the (temporary) test dir so
-        # the rest of the suite compiles uncached again
+        # the rest of the suite goes back to the session's cache
         from jax._src import compilation_cache as jax_cc
 
         jax_cc.reset_cache()
 
+    @staticmethod
+    def _enable_at(monkeypatch, path):
+        """The outside-placement rule: with JAX_COMPILATION_CACHE_DIR
+        set the code sets no directory, so the test (standing in for
+        jax's import-time read of the variable) puts it in the config."""
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(path))
+        jax.config.update("jax_compilation_cache_dir", str(path))
+        assert _compile_cache.enable_compile_cache(
+            min_compile_secs=0.0) == str(path)
+
     def test_hits_and_misses_counted(self, monkeypatch, tmp_path,
                                      restore_cache_config):
-        monkeypatch.setenv("APEX_TPU_COMPILE_CACHE",
-                           str(tmp_path / "cache"))
-        assert _compile_cache.maybe_enable_compile_cache(
-            min_compile_secs=0.0) is True
+        self._enable_at(monkeypatch, tmp_path / "cache")
         before = _compile_cache.cache_stats()
         x = jnp.ones((64,))
         # two distinct pjit instances of the same program: the first
@@ -414,9 +421,7 @@ class TestCompileCacheCounters:
 
     def test_registry_counters_ride_along(self, monkeypatch, tmp_path,
                                           restore_cache_config):
-        monkeypatch.setenv("APEX_TPU_COMPILE_CACHE",
-                           str(tmp_path / "cache2"))
-        _compile_cache.maybe_enable_compile_cache(min_compile_secs=0.0)
+        self._enable_at(monkeypatch, tmp_path / "cache2")
         with use_registry(MetricsRegistry(enabled=True)) as reg:
             x = jnp.ones((48,))
             jax.jit(lambda v: v * 9 - 1)(x)

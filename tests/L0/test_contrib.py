@@ -23,8 +23,7 @@ class TestFlashAttention:
     def _interpret_pallas(self, monkeypatch):
         """Run the Pallas kernel in interpreter mode on CPU so the TPU code
         path is exercised by the CPU test suite."""
-        monkeypatch.setattr(fmha_mod, "_INTERPRET", True)
-        monkeypatch.setattr(fmha_mod, "_use_pallas", lambda: True)
+        monkeypatch.setattr(fmha_mod.GATE, "interpret", True)
 
     @pytest.mark.parametrize("causal", [True, False])
     def test_matches_reference(self, rng, causal):

@@ -239,6 +239,30 @@ class TestVerifyWindow:
                                        np.asarray(want),
                                        rtol=2e-5, atol=2e-5)
 
+    def test_window_attention_query_blocks(self, rng, interpret,
+                                           monkeypatch):
+        """A window whose rows overflow the scratch budget splits into
+        8-row-aligned query blocks, each with its own causal bound and
+        tile clamp — a prefill-sized window must match the oracle
+        across block edges, and a window no block fits declines."""
+        monkeypatch.setattr(fused_cc, "_WINDOW_ROW_BUDGET", 32)
+        w, b, g, rep, d, T = 16, 1, 2, 2, 16, 64
+        assert fused_cc._q_block(w, g, rep) == 8
+        qg = jnp.asarray(rng.randn(w, b, g, rep, d).astype(np.float32))
+        kt = jnp.asarray(rng.randn(T, b, g, d).astype(np.float32))
+        vt = jnp.asarray(rng.randn(T, b, g, d).astype(np.float32))
+        for start in (0, 21, T - w):
+            want = fused_cc.window_attention_reference(
+                qg, kt, vt, start, 0.25, window=9)
+            got = fused_cc.window_attention(
+                qg, kt, vt, start, 0.25, window=9, block_t=16)
+            np.testing.assert_allclose(np.asarray(got),
+                                       np.asarray(want),
+                                       rtol=2e-5, atol=2e-5)
+        # 3 rows x 2 reps never align to 8 and overflow the budget
+        monkeypatch.setattr(fused_cc, "_WINDOW_ROW_BUDGET", 8)
+        assert not fused_cc.use_window(T, q_shape=(3, b, g, rep, d))
+
     @pytest.mark.parametrize("d", [64, 40])
     def test_spec_verify_parity_including_ragged_tail(self, rng,
                                                       interpret, d):
@@ -656,10 +680,7 @@ class TestBenchTooling:
         early = bsc.check_metric_line(full, round_n=20, errors=[])
         assert any("only defined from round 21" in e for e in early)
 
-    def test_bench_specs_and_capture_plan_carry_fused_cc(self):
+    def test_bench_specs_carry_fused_cc(self):
         import bench
 
         assert "fused_cc" in bench.BENCH_SPECS
-        src = open(os.path.join(_ROOT, "tools",
-                                "oneproc_capture.py")).read()
-        assert '("fused_cc", None' in src

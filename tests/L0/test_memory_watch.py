@@ -50,7 +50,18 @@ class TestStepMemory:
         assert memory.hbm_capacity_bytes() == int(2.5e9)
         monkeypatch.delenv(memory.ENV_HBM_GB)
         assert memory.hbm_capacity_bytes("cpu") == \
-            memory._HBM_DEFAULTS_BYTES["cpu"]
+            memory._CPU_CAPACITY_BYTES
+
+    def test_accelerator_without_bytes_limit_raises(self, monkeypatch):
+        """No platform-wide TPU default: the device's own figure or an
+        error."""
+        monkeypatch.delenv(memory.ENV_HBM_GB, raising=False)
+        monkeypatch.setattr(memory, "_device_bytes_limit", lambda: None)
+        with pytest.raises(RuntimeError, match="bytes_limit"):
+            memory.hbm_capacity_bytes("tpu")
+        monkeypatch.setattr(memory, "_device_bytes_limit",
+                            lambda: int(15.75e9))
+        assert memory.hbm_capacity_bytes("tpu") == int(15.75e9)
 
     def test_gauge_and_event_and_trend(self, tmp_path):
         memory.reset_trend()

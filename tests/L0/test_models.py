@@ -108,8 +108,7 @@ def test_gpt_flash_attention_path_jits(monkeypatch, rng):
     import apex_tpu.contrib.fmha as fmha_mod
     import apex_tpu.models.transformer_lm as tlm
 
-    monkeypatch.setattr(fmha_mod, "_INTERPRET", True)
-    monkeypatch.setattr(fmha_mod, "_use_pallas", lambda: True)
+    monkeypatch.setattr(fmha_mod.GATE, "interpret", True)
     monkeypatch.setattr(tlm, "_flash_available", lambda s, d: True)
 
     from apex_tpu.models import GPTModel, TransformerConfig
@@ -157,8 +156,7 @@ def test_gpt_sliding_window_flash_matches_masked_path(monkeypatch, rng):
         return np.asarray(model.apply(params, tokens))
 
     masked = logits(use_flash=False)
-    monkeypatch.setattr(fmha_mod, "_INTERPRET", True)
-    monkeypatch.setattr(fmha_mod, "_use_pallas", lambda: True)
+    monkeypatch.setattr(fmha_mod.GATE, "interpret", True)
     monkeypatch.setattr(tlm, "_flash_available", lambda s, d: True)
     flash = logits(use_flash=True)
     np.testing.assert_allclose(flash, masked, rtol=2e-4, atol=2e-4)
@@ -185,8 +183,7 @@ def test_gpt_alibi_flash_matches_masked_path(monkeypatch, rng):
         return np.asarray(model.apply(params, tokens))
 
     masked = logits(use_flash=False)
-    monkeypatch.setattr(fmha_mod, "_INTERPRET", True)
-    monkeypatch.setattr(fmha_mod, "_use_pallas", lambda: True)
+    monkeypatch.setattr(fmha_mod.GATE, "interpret", True)
     monkeypatch.setattr(tlm, "_flash_available", lambda s, d: True)
     flash = logits(use_flash=True)
     np.testing.assert_allclose(flash, masked, rtol=2e-4, atol=2e-4)

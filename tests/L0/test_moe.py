@@ -848,21 +848,11 @@ class TestMoEPipelineParallel:
         parallel_state.destroy_model_parallel()
         return losses, state[0]
 
-    # jax < 0.6: the per-stage aux-loss pullback trips an AssertionError
-    # inside lax.gather's transpose rule (old-jax bug, fixed upstream);
-    # the non-aux MoE+pp paths above still cover the composition there.
-    _OLD_JAX = tuple(
-        int(x) for x in jax.__version__.split(".")[:2]) < (0, 6)
-
-    @pytest.mark.skipif(_OLD_JAX, reason="jax<0.6 gather-transpose bug "
-                        "in the pp aux-loss pullback")
     def test_moe_pp_training_loss_decreases(self):
         losses, _ = self._run(aux_coeff=1e-2, steps=10)
         assert np.isfinite(losses).all()
         assert losses[-1] < 0.8 * losses[0], losses
 
-    @pytest.mark.skipif(_OLD_JAX, reason="jax<0.6 gather-transpose bug "
-                        "in the pp aux-loss pullback")
     def test_router_aux_grads_reach_first_stage(self):
         """The aux coefficient must change the FIRST pipeline stage's
         router update — proof the per-stage aux cotangent flows (with

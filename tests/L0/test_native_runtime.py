@@ -26,6 +26,25 @@ def test_native_extension_is_built():
     assert _C.HAVE_NATIVE, "apex_tpu_C should be built in this environment"
 
 
+def test_stale_binary_is_rebuilt_not_loaded():
+    """The .so in the tree is only loaded when it was built from exactly
+    this csrc/: a recorded source hash that differs forces a rebuild."""
+    import glob
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    (stamp,) = glob.glob(os.path.join(root, "apex_tpu_C*.so.sha256"))
+    with open(stamp) as f:
+        good = f.read()
+    with open(stamp, "w") as f:
+        f.write("built from some other source")
+    mod, err = _C._build_in_place()
+    assert err is None and mod.assign_buckets([4, 4], 8) == [0, 0]
+    with open(stamp) as f:
+        assert f.read() == good
+
+
 @pytest.fixture(params=["native", "fallback"])
 def c_impl(request, monkeypatch):
     """Run the _C entry points through both the native extension and the

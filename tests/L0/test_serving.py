@@ -426,18 +426,16 @@ class TestMeshServing:
 # ---------------------------------------------------------------------------
 
 class TestServeBenchE2E:
-    # tier-1 budget (ISSUE 12): the oneproc `serve` smoke stage runs
-    # this exact bench contract on every capture, and the in-process
-    # two-trace / sharded-ladder e2es above keep the flat-compile
-    # invariant in tier-1 — same precedent as the fleet bench e2e
+    # tier-1 budget (ISSUE 12): the in-process two-trace /
+    # sharded-ladder e2es above keep the flat-compile invariant in
+    # tier-1 — same precedent as the fleet bench e2e
     @pytest.mark.slow
     def test_serve_decode_bench_contract(self, monkeypatch, capsys):
         """bench.py serve_decode on the (up to) 8-device CPU mesh:
         emits tokens/sec, p50/p99 TTFT + per-token latency,
         kv_cache_bytes and compile_count; zero compiles during trace B
         (different arrival pattern, same ladder); int8 bytes cut
-        >= 3.5x vs the fp32-equivalent store. Mirrors what the oneproc
-        serve smoke asserts on-capture."""
+        >= 3.5x vs the fp32-equivalent store."""
         monkeypatch.setenv("APEX_TPU_SERVE_SMOKE", "1")
         monkeypatch.syspath_prepend(ROOT)
         import bench

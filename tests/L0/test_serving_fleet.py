@@ -463,8 +463,7 @@ class TestAutoscale:
 
 @pytest.mark.multi_device
 class TestFleetChaosE2E:
-    @pytest.mark.slow  # duplicate coverage: the oneproc fleet smoke
-    # drives the same kill-mid-trace path (tier-1 budget, 14s)
+    @pytest.mark.slow  # tier-1 budget (14s)
     def test_kill_replica_mid_trace_token_identity(self, tiny):
         """ISSUE-11 acceptance: a 2-replica x 4-device fleet on the
         8-device CPU mesh, replica 0 killed mid-Poisson-trace ->

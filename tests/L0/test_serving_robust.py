@@ -643,10 +643,8 @@ class TestChaosE2E:
 # ---------------------------------------------------------------------------
 
 class TestServeChaosBench:
-    # tier-1 budget (ISSUE 12): the oneproc `serve_chaos` smoke stage
-    # runs this exact bench contract on every capture; the in-process
-    # 8-dev chaos acceptance above stays in tier-1 — same precedent
-    # as the fleet bench e2e
+    # tier-1 budget (ISSUE 12): the in-process 8-dev chaos acceptance
+    # above stays in tier-1 — same precedent as the fleet bench e2e
     @pytest.mark.slow
     def test_serve_chaos_bench_contract(self, monkeypatch, capsys):
         monkeypatch.setenv("APEX_TPU_SERVE_SMOKE", "1")

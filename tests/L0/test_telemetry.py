@@ -160,12 +160,13 @@ def test_record_step_cost_sets_mfu_gauge():
     assert snap["gauges"]["model_flops_per_step_xla"] == 1e9
 
 
-def test_peak_table_env_override(monkeypatch):
-    monkeypatch.setenv("APEX_TPU_PEAK_TFLOPS", "100")
-    monkeypatch.setenv("APEX_TPU_PEAK_HBM_GBPS", "1000")
-    flops, hbm = telemetry.xla_cost.peak_table("tpu")
-    assert flops == 100e12
-    assert hbm == 1000e9
+def test_peak_table_is_keyed_by_device_kind():
+    """Published v5e peaks for the kind jax reports on that chip; an
+    unknown kind is an error, never another chip's row."""
+    assert telemetry.xla_cost.peak_table("TPU v5 lite") == (197e12, 819e9)
+    for unknown in ("TPU v4", "tpu", "NVIDIA H100"):
+        with pytest.raises(ValueError, match="no published peaks"):
+            telemetry.xla_cost.peak_table(unknown)
 
 
 # ---------------------------------------------------------------------------

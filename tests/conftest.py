@@ -27,18 +27,17 @@ os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
 if os.environ.get("APEX_TPU_TEST_FULL_OPT") != "1":
     os.environ["XLA_FLAGS"] += " --xla_backend_optimization_level=0"
 
+# Tests always run on the virtual CPU mesh; chip_smoke.py is the run on
+# the chip.
+os.environ["JAX_PLATFORMS"] = "cpu"
+
 import jax  # noqa: E402
 
-# Tests always run on the virtual CPU mesh (the env-var route is ignored
-# when a TPU PJRT plugin registers itself, so set the config directly);
-# run bench.py / examples for real-TPU execution.
-jax.config.update("jax_platforms", "cpu")
+# The persistent compilation cache, placed by the one rule every entry
+# point shares (apex_tpu/_compile_cache.py).
+from apex_tpu._compile_cache import enable_compile_cache  # noqa: E402
 
-# Opt-in persistent compilation cache (VERDICT r2 item 8) — see
-# apex_tpu/_compile_cache.py for the rationale and usage.
-from apex_tpu._compile_cache import maybe_enable_compile_cache  # noqa: E402
-
-maybe_enable_compile_cache()
+enable_compile_cache(min_compile_secs=0.0)
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 

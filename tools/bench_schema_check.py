@@ -38,7 +38,7 @@ number ``n`` (old checked-in records stay valid):
   apex_tpu.analysis; null means the bench ran without
   ``APEX_TPU_HLO_LINT=1``).
 - ``n >= 15``: successful metric lines must carry ``backend`` (the
-  one-shot probe verdict, ``"cpu-mesh"`` or ``"tpu"`` — which perf
+  backend stamp, ``"cpu-mesh"`` or ``"tpu"`` — which perf
   series the line belongs to), and ``ddp_overlapped`` metric lines
   must carry the overlap contract — ``overlap_segments``,
   ``comm_hidden_pct`` and ``baseline_step_ms`` — next to their
@@ -177,7 +177,7 @@ LINT_FIELDS_SINCE_ROUND = 14
 # a ddp_overlapped metric line must carry the measured overlap
 # accounting — segment count, the in-invocation bucketed-baseline step
 # time, and the % of baseline comm cost hidden — and EVERY successful
-# line must carry the one-shot backend probe verdict ("cpu-mesh" |
+# line must carry the backend stamp ("cpu-mesh" |
 # "tpu"), the field that makes the CPU-mesh numbers a first-class
 # tracked series; pre-round-15 records carrying the overlap fields are
 # flagged (they did not exist yet), while `backend` follows the
@@ -324,7 +324,7 @@ FUSED_CC_REQUIRED_FIELDS = (
     "hbm_intermediates_unfused_int4_ring",
     "hbm_intermediates_fused_int4_ring")
 COMM_BYTES_SINCE_ROUND = 6
-# bench_error lines grew the wedge/crash discriminator in round 3
+# bench_error lines grew the kind discriminator in round 3
 ERROR_KIND_SINCE_ROUND = 3
 
 _NUM = (int, float)
@@ -355,9 +355,9 @@ def check_metric_line(obj, *, round_n=None, errors=None, where=""):
                 f"wanted {types}")
     if obj.get("metric") == "bench_error":
         if ((round_n is None or round_n >= ERROR_KIND_SINCE_ROUND)
-                and obj.get("kind") not in ("crash", "wedge")):
+                and obj.get("kind") not in ("crash", "no_tpu")):
             bad(f"bench_error kind {obj.get('kind')!r} not in "
-                f"('crash', 'wedge')")
+                f"('crash', 'no_tpu')")
         if (round_n is not None and round_n >= COMM_BYTES_SINCE_ROUND
                 and "comm_bytes_per_step" not in obj):
             bad("bench_error missing comm_bytes_per_step "

@@ -2,12 +2,13 @@
 opt level, per-iteration loss/grad-norm dumped to committed JSON.
 
 Parity: reference tests/L1/common/main_amp.py (trace dump per opt level)
-+ compare.py (closeness vs the O0 baseline); VERDICT r2 item 7 asks the
-comparison run on the real chip with the real models (BASELINE
-functional configs 1/2/4), not the CPU-mesh stand-ins in tests/L1.
++ compare.py (closeness vs the O0 baseline), run on the real chip with
+the real models (BASELINE functional configs 1/2/4), not the CPU-mesh
+stand-ins in tests/L1.
 
-One config per invocation (fresh process per point — wedge/OOM
-containment, same policy as tools/mfu_sweep.py):
+One config per invocation (fresh process per point — OOM containment,
+same policy as tools/mfu_sweep.py; one process per chip, so never two
+at once):
 
     python tools/l1_onchip.py resnet_O0        # ... resnet_O1 _O2 _O3
     python tools/l1_onchip.py bert_O0          # ... bert_O2
@@ -294,9 +295,9 @@ def capture(name):
     import jax
 
     if not TINY:
-        from bench import _enable_bench_compile_cache
+        from apex_tpu._compile_cache import enable_compile_cache
 
-        _enable_bench_compile_cache()
+        enable_compile_cache()
     t0 = time.perf_counter()
     losses, gnorms = CONFIGS[name]()
     os.makedirs(TRACE_DIR, exist_ok=True)
