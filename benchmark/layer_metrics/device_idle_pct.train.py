@@ -1,0 +1,9 @@
+"""Device: share of the traced window in which no operation ran on the
+chip (1 - union of the device operations' intervals over the window)."""
+
+
+def read(ctx):
+    tr = ctx["trace"]
+    if tr is None or not tr.ops:
+        return None
+    return 100.0 * (1.0 - tr.busy_s() / tr.window_s)
