@@ -88,6 +88,22 @@ def enable_compile_cache(min_compile_secs: float = 0.5) -> str:
         jax.config.update("jax_compilation_cache_dir", default_cache_dir())
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
                       min_compile_secs)
+    # jax leaves an instruction's metadata (op_name: module names,
+    # named scopes, kernel names) out of the cache key, so a program
+    # that differs from a cached one only in its scopes would get the
+    # old executable back, old op_names and all, and
+    # telemetry.scopes.scope_table would read yesterday's scopes. With
+    # the metadata in the key an executable's scopes are those of the
+    # code that asked for it; the price is one cold compile after a
+    # traced source line moves. The metadata also holds each
+    # operation's source location, by default with up to ten frames of
+    # its call stack, those of whoever called the jitted function among
+    # them: keep the innermost frame only, or the same step lowered from
+    # two call sites has two keys. (The frames are only shortened:
+    # jax_include_full_tracebacks_in_locations=False would also cut
+    # every op_name down to its primitive.)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_traceback_in_locations_limit", 1)
     # jax decides "is the cache used?" once per process; if anything
     # compiled before the directory was known that decision is a
     # permanent False. reset_cache() drops it so the next compile

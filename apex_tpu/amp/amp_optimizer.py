@@ -57,9 +57,11 @@ class AmpOptimizer(object):
     def step(self, grads, state, params, *, lr=None):
         scaler_state: ScalerState = state["scaler"]
         leaves, treedef = jax.tree_util.tree_flatten(grads)
-        inv = 1.0 / scaler_state.loss_scale
-        unscaled, found_inf = multi_tensor_applier(
-            multi_tensor_scale, jnp.zeros((), jnp.float32), [leaves, leaves], inv)
+        with jax.named_scope("amp/unscale"):
+            inv = 1.0 / scaler_state.loss_scale
+            unscaled, found_inf = multi_tensor_applier(
+                multi_tensor_scale, jnp.zeros((), jnp.float32),
+                [leaves, leaves], inv)
         grads = jax.tree_util.tree_unflatten(treedef, unscaled)
 
         if "amp_master" in state["inner"]:
@@ -67,20 +69,27 @@ class AmpOptimizer(object):
             masters = state["inner"]["amp_master"]
             inner_wo_master = {k: v for k, v in state["inner"].items()
                                if k != "amp_master"}
-            new_masters, new_inner = self.inner.step(
-                grads, inner_wo_master, masters, lr=lr, found_inf=found_inf)
-            new_params = jax.tree_util.tree_map(
-                lambda m, p: m.astype(p.dtype), new_masters, params)
+            with jax.named_scope("optimizer"):
+                new_masters, new_inner = self.inner.step(
+                    grads, inner_wo_master, masters, lr=lr,
+                    found_inf=found_inf)
+            with jax.named_scope("amp/master_to_model"):
+                new_params = jax.tree_util.tree_map(
+                    lambda m, p: m.astype(p.dtype), new_masters, params)
             new_inner["amp_master"] = new_masters
         else:
-            new_params, new_inner = self.inner.step(
-                grads, state["inner"], params, lr=lr, found_inf=found_inf)
+            with jax.named_scope("optimizer"):
+                new_params, new_inner = self.inner.step(
+                    grads, state["inner"], params, lr=lr,
+                    found_inf=found_inf)
 
-        new_scaler = self.scaler.update(scaler_state, found_inf)
+        with jax.named_scope("amp/scaler_update"):
+            new_scaler = self.scaler.update(scaler_state, found_inf)
         new_state = {"inner": new_inner, "scaler": new_scaler}
         self.last_state = new_state
         return new_params, new_state
 
+    @jax.named_scope("loss")
     def scale_loss(self, loss, state=None):
         sstate = state["scaler"] if state is not None else self.scaler._state
         return loss.astype(jnp.float32) * sstate.loss_scale

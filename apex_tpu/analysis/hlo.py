@@ -1,4 +1,5 @@
-"""Structural parsing of lowered StableHLO text.
+"""Structural parsing of lowered StableHLO text (and, at the end, of a
+compiled module's optimised HLO text: :func:`instruction_scopes`).
 
 The lint rules (``apex_tpu/analysis/rules.py``) need a handful of facts
 about a ``jax.jit(...).lower(...)`` artifact that the ad-hoc test greps
@@ -17,6 +18,7 @@ degrade to "not matched", never to an exception — a lint pass must not
 crash on an HLO shape it has never seen.
 """
 
+import collections
 import re
 
 # element-type byte widths for tensor<...> size accounting; anything
@@ -295,3 +297,71 @@ def large_constant_bytes(text, min_bytes):
         if nbytes >= min_bytes:
             out.append((i, nbytes, tensors[-1]))
     return out
+
+
+# -- optimised HLO (``compiled.as_text()``) ---------------------------------
+
+_HLO_COMPUTATION_RE = re.compile(r"^(?:ENTRY\s+)?%([\w\-.]+)\s.*\{\s*$")
+_HLO_INSTRUCTION_RE = re.compile(r"^\s+(?:ROOT\s+)?%([\w\-.]+)\s*=\s")
+_HLO_OP_NAME_RE = re.compile(r'\bop_name="((?:[^"\\]|\\.)*)"')
+_HLO_CALLS_RE = re.compile(r"\bcalls=%([\w\-.]+)")
+# what a fusion's time goes to, where it holds one: a matrix product (a
+# dot is a convolution on the TPU) or a Mosaic kernel
+_HLO_HEAVY_RE = re.compile(
+    r' (?:convolution|dot)\(|custom_call_target="tpu_custom_call"')
+
+
+def _commonest_stack(op_names):
+    """Of a computation's ``op_name``s, the last one under the name stack
+    (the path without its final primitive) that most of them share."""
+    stacks = collections.Counter(n.rpartition("/")[0] for n in op_names)
+    stack = stacks.most_common(1)[0][0]
+    return next(n for n in reversed(op_names)
+                if n.rpartition("/")[0] == stack)
+
+
+def instruction_scopes(text):
+    """``{instruction name: op_name}`` over every computation of an
+    optimised HLO module (``jit(f).lower(...).compile().as_text()``).
+
+    ``op_name`` is the jax name stack the instruction was traced under
+    (``jit(step)/jvp(Model)/layer_0/mlp/dot_general``): flax module
+    names, ``jax.named_scope`` and a Pallas kernel's ``name=`` all land
+    there, and XLA keeps it through optimisation on the instruction that
+    replaces the original. A fusion's own is its root's, which says
+    little where XLA has fused work of several scopes (amp's unscale,
+    the whole Adam update and the cast of the masters are one loop
+    fusion whose root is the cast). So an instruction that ``calls=`` a
+    computation answers for what is inside it: with the ``op_name`` of
+    the matrix product or Mosaic kernel there if it holds one, since
+    that is where its time goes, else with one from the name stack most
+    of its instructions were traced under; with its own only where
+    nothing inside has any. Any other instruction without an
+    ``op_name`` (asynchronous ``copy-start``/``copy-done``, parameters
+    XLA moved) is left out. Instruction names are unique in a module,
+    and they are what a profile's ``XLA Ops`` events are named by."""
+    scopes, calls, inside, heavy = {}, {}, {}, {}
+    computation = None
+    for line in text.splitlines():
+        m = _HLO_INSTRUCTION_RE.match(line)
+        if m is None:
+            c = _HLO_COMPUTATION_RE.match(line)
+            if c is not None:
+                computation = c.group(1)
+            continue
+        name = m.group(1)
+        op = _HLO_OP_NAME_RE.search(line, m.end())
+        if op is not None:
+            scopes[name] = op.group(1)
+            inside.setdefault(computation, []).append(op.group(1))
+            if _HLO_HEAVY_RE.search(line, m.end()):
+                heavy.setdefault(computation, op.group(1))
+        called = _HLO_CALLS_RE.search(line, m.end())
+        if called is not None:
+            calls[name] = called.group(1)
+    for name, called in calls.items():
+        if called in heavy:
+            scopes[name] = heavy[called]
+        elif called in inside:
+            scopes[name] = _commonest_stack(inside[called])
+    return scopes

@@ -236,6 +236,7 @@ def _flash_fwd_pallas(q, k, v, scale, causal, block_q, block_k,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=GATE.interpret,
+        name="self_attention_flash_fwd",
     )(q3, k3, v3, slopes3)
     return out.reshape(b, n, s, d), lse.reshape(b, n, s)
 
@@ -393,6 +394,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, scale, causal, block_q,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=GATE.interpret,
+        name="self_attention_flash_dq",
     )(q3, k3, v3, do3, lse3, delta, slopes3)
 
     dk, dv = pl.pallas_call(
@@ -433,6 +435,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, scale, causal, block_q,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=GATE.interpret,
+        name="self_attention_flash_dkv",
     )(k3, v3, q3, do3, lse3, delta, slopes3)
 
     rs = lambda x: x.reshape(b, n, s, d)  # noqa: E731

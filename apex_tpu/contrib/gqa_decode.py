@@ -173,6 +173,7 @@ def _decode_pallas(q, k, v, length, sm_scale, softcap, window, block_t):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_GATE.interpret,
+        name="gqa_decode",
     )(jnp.asarray(length, jnp.int32).reshape(1), q,
       k.reshape(T, b * g * d), v.reshape(T, b * g * d))
 

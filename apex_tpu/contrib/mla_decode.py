@@ -144,6 +144,7 @@ def _decode_pallas(q_full, cache, length, lat, scale, block_t):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_GATE.interpret,
+        name="mla_decode",
     )(jnp.asarray(length, jnp.int32).reshape(1), q_full,
       cache.reshape(T, b * L))
 

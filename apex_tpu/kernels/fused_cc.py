@@ -117,6 +117,7 @@ def _matmul(x, w):
         out_specs=pl.BlockSpec((rb, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((mp, n), jnp.float32),
         interpret=GATE.interpret,
+        name="fused_cc_matmul",
     )(x2, w)
     return out[:m].reshape(*lead, n).astype(
         jnp.result_type(x.dtype, w.dtype))
@@ -451,6 +452,7 @@ def _window_pallas(qg, kt, vt, start, sm_scale, softcap, window,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=GATE.interpret,
+        name="fused_cc_window_attention",
     )(jnp.asarray(start, jnp.int32).reshape(1),
       qg.transpose(1, 2, 0, 3, 4).reshape(b, g, w * rep, d),
       kt.reshape(T, b * g * d), vt.reshape(T, b * g * d))
@@ -610,6 +612,7 @@ def spec_verify_attention(q, kq, ks, vq, vs, start, sm_scale,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=GATE.interpret,
+        name="fused_cc_spec_verify",
     )(jnp.asarray(start, jnp.int32).reshape(1),
       q.transpose(1, 0, 2, 3).reshape(g, rows, d),
       kq.reshape(T, nb * B), ks.reshape(T, nb),
@@ -621,7 +624,7 @@ def spec_verify_attention(q, kq, ks, vq, vs, start, sm_scale,
 # family (c): quantize-into-ring int4
 # ---------------------------------------------------------------------------
 
-def _cellwise(kernel, out_dtype, out_cols, x2d, *extra):
+def _cellwise(name, kernel, out_dtype, out_cols, x2d, *extra):
     """quant4's 32-row-cell launcher, under THIS gate's interpret flag
     (the two gates may be toggled independently in benches)."""
     from jax.experimental import pallas as pl
@@ -648,6 +651,7 @@ def _cellwise(kernel, out_dtype, out_cols, x2d, *extra):
         out_shape=jax.ShapeDtypeStruct((x2d.shape[0], out_cols),
                                        out_dtype),
         interpret=GATE.interpret,
+        name=name,
     )(*args)
     return out[:nb]
 
@@ -676,8 +680,8 @@ def quantize_pack_int4(x2d, scales):
         x2d = jnp.pad(x2d, ((0, 0), (0, 1)))
     if GATE.enabled():
         record()
-        return _cellwise(_qp_kernel, jnp.uint8, x2d.shape[1] // 2,
-                         x2d, scales)
+        return _cellwise("fused_cc_quantize_pack", _qp_kernel, jnp.uint8,
+                         x2d.shape[1] // 2, x2d, scales)
     record("oracle")
     return _quant4._pack_jnp(_quant4._quantize_jnp(x2d, scales))
 
@@ -688,7 +692,8 @@ def unpack_dequantize_int4(p2d, scales, n=None):
     kernel."""
     if GATE.enabled():
         record()
-        out = _cellwise(_ud_kernel, jnp.float32, p2d.shape[1] * 2,
+        out = _cellwise("fused_cc_unpack_dequantize", _ud_kernel,
+                        jnp.float32, p2d.shape[1] * 2,
                         p2d, scales)
     else:
         record("oracle")

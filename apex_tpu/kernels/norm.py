@@ -103,8 +103,10 @@ def _rms_bwd_kernel(dy_ref, x_ref, w_ref, dx_ref, *, eps, affine):
     dx_ref[...] = dx.astype(dx_ref.dtype)
 
 
-def pallas_rowwise(kernel, outs_dtype, x2d, *vectors, interpret=False):
-    """Launch a row-blocked kernel: x2d [n, h] gridded over rows, each
+def pallas_rowwise(name, kernel, outs_dtype, x2d, *vectors,
+                   interpret=False):
+    """Launch a row-blocked kernel (``name`` is its name in the compiled
+    program and the profile): x2d [n, h] gridded over rows, each
     vector arg [h] broadcast to every block (a same-shape [n, h] arg —
     the backward's dy — rides the row grid instead)."""
     from jax.experimental import pallas as pl
@@ -132,6 +134,7 @@ def pallas_rowwise(kernel, outs_dtype, x2d, *vectors, interpret=False):
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((n, h), outs_dtype),
         interpret=interpret,
+        name=name,
     )(*args)
 
 
@@ -149,8 +152,8 @@ def ln_fwd(x2d, weight, bias, eps, *, interpret=False):
     w = weight if affine else _ones(h)
     b = bias if bias is not None else jnp.zeros((h,), jnp.float32)
     kernel = functools.partial(_ln_fwd_kernel, eps=eps, affine=affine)
-    return pallas_rowwise(kernel, x2d.dtype, x2d, w, b,
-                          interpret=interpret)
+    return pallas_rowwise("layer_norm_fwd", kernel, x2d.dtype, x2d, w,
+                          b, interpret=interpret)
 
 
 def ln_bwd_dx(dy2d, x2d, weight, eps, *, interpret=False):
@@ -161,7 +164,7 @@ def ln_bwd_dx(dy2d, x2d, weight, eps, *, interpret=False):
 
     def k(x_ref, dy_ref, w_ref, dx_ref):
         kernel(dy_ref, x_ref, w_ref, dx_ref)
-    return pallas_rowwise(k, x2d.dtype, x2d, dy2d, w,
+    return pallas_rowwise("layer_norm_bwd", k, x2d.dtype, x2d, dy2d, w,
                           interpret=interpret)
 
 
@@ -173,7 +176,8 @@ def rms_fwd(x2d, weight, eps, *, interpret=False):
 
     def k(x_ref, w_ref, y_ref):
         kernel(x_ref, w_ref, y_ref)
-    return pallas_rowwise(k, x2d.dtype, x2d, w, interpret=interpret)
+    return pallas_rowwise("rms_norm_fwd", k, x2d.dtype, x2d, w,
+                          interpret=interpret)
 
 
 def rms_bwd_dx(dy2d, x2d, weight, eps, *, interpret=False):
@@ -184,5 +188,5 @@ def rms_bwd_dx(dy2d, x2d, weight, eps, *, interpret=False):
 
     def k(x_ref, dy_ref, w_ref, dx_ref):
         kernel(dy_ref, x_ref, w_ref, dx_ref)
-    return pallas_rowwise(k, x2d.dtype, x2d, dy2d, w,
+    return pallas_rowwise("rms_norm_bwd", k, x2d.dtype, x2d, dy2d, w,
                           interpret=interpret)

@@ -56,7 +56,8 @@ def _to_blocks(flat):
     return out.reshape(rows, BLOCK), n
 
 
-def _blocked_call(kernel, scalars, arrays, n_out, out_dtype, interpret):
+def _blocked_call(name, kernel, scalars, arrays, n_out, out_dtype,
+                  interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -79,6 +80,7 @@ def _blocked_call(kernel, scalars, arrays, n_out, out_dtype, interpret):
         out_specs=[spec] * n_out,
         out_shape=[jax.ShapeDtypeStruct((rows, BLOCK), out_dtype)] * n_out,
         interpret=interpret,
+        name=name,
     )(s, *blocked)
     return [o.reshape(-1)[:n] for o in outs]
 
@@ -117,8 +119,8 @@ def fused_adam_update(g, p, m, v, *, lr, bc1, bc2, b1, b2, eps,
             _adam_kernel, b1=b1, b2=b2, eps=eps, wd=weight_decay,
             adam_w=adam_w)
         p_new, m_new, v_new = _blocked_call(
-            kernel, (lr, bc1, bc2), (g, p, m, v), 3, jnp.float32,
-            GATE_ADAM.interpret)
+            "fused_adam", kernel, (lr, bc1, bc2), (g, p, m, v), 3,
+            jnp.float32, GATE_ADAM.interpret)
         return p_new, m_new, v_new
     if not adam_w:
         g = g + weight_decay * p
@@ -166,8 +168,8 @@ def fused_lamb_mvu(g, p, m, v, *, bc1, bc2, b1, b2, beta3, eps,
             _lamb_kernel, b1=b1, b2=b2, beta3=beta3, eps=eps,
             wd=weight_decay, adam_w=adam_w)
         m_new, v_new, update = _blocked_call(
-            kernel, (bc1, bc2), (g, p, m, v), 3, jnp.float32,
-            GATE_LAMB.interpret)
+            "fused_lamb", kernel, (bc1, bc2), (g, p, m, v), 3,
+            jnp.float32, GATE_LAMB.interpret)
         return m_new, v_new, update
     if not adam_w and weight_decay != 0:
         g = g + weight_decay * p

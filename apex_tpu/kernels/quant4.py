@@ -136,7 +136,7 @@ def _pad_rows(x2d, rows=_ROWS):
     return x2d, nb
 
 
-def _cellwise(kernel, out_dtype, out_cols, x2d, *extra):
+def _cellwise(name, kernel, out_dtype, out_cols, x2d, *extra):
     """Launch a 32-row-cell kernel over [nb, cols] operands (scales
     pad with ones so padded rows divide by 1)."""
     from jax.experimental import pallas as pl
@@ -161,6 +161,7 @@ def _cellwise(kernel, out_dtype, out_cols, x2d, *extra):
         out_shape=jax.ShapeDtypeStruct((x2d.shape[0], out_cols),
                                        out_dtype),
         interpret=GATE.interpret,
+        name=name,
     )(*args)
     return out[:nb]
 
@@ -174,7 +175,8 @@ def quantize_int4(x2d, scales):
     if record_dispatch("quant4", GATE):
         def k(x_ref, s_ref, q_ref):
             _quant_kernel(x_ref, s_ref, q_ref)
-        return _cellwise(k, jnp.int8, x2d.shape[1], x2d, scales)
+        return _cellwise("quant4_quantize", k, jnp.int8, x2d.shape[1], x2d,
+                         scales)
     return _quantize_jnp(x2d, scales)
 
 
@@ -183,7 +185,8 @@ def dequantize_int4(q2d, scales):
     if q2d.dtype == jnp.int8 and record_dispatch("quant4", GATE):
         def k(q_ref, s_ref, o_ref):
             _dequant_kernel(q_ref, s_ref, o_ref)
-        return _cellwise(k, jnp.float32, q2d.shape[1], q2d, scales)
+        return _cellwise("quant4_dequantize", k, jnp.float32, q2d.shape[1],
+                         q2d, scales)
     return _dequantize_jnp(q2d, scales)
 
 
@@ -195,7 +198,8 @@ def pack_int4(q2d):
 
         def k(q_ref, p_ref):
             _pack_kernel(q_ref, p_ref)
-        return _cellwise(k, jnp.uint8, q2d.shape[1] // 2, q2d)
+        return _cellwise("quant4_pack", k, jnp.uint8, q2d.shape[1] // 2,
+                         q2d)
     return _pack_jnp(q2d)
 
 
@@ -205,6 +209,6 @@ def unpack_int4(p2d, n=None):
     if record_dispatch("quant4", GATE):
         def k(p_ref, q_ref):
             _unpack_kernel(p_ref, q_ref)
-        out = _cellwise(k, jnp.int8, p2d.shape[1] * 2, p2d)
+        out = _cellwise("quant4_unpack", k, jnp.int8, p2d.shape[1] * 2, p2d)
         return out if n is None else out[:, :n]
     return _unpack_jnp(p2d, n)

@@ -99,7 +99,7 @@ def _bwd_kernel(y_ref, dy_ref, dx_ref, *, scale):
     dx_ref[...] = (scale * y * (dy - t)).astype(dx_ref.dtype)
 
 
-def _rowwise_call(kernel, x2d, *extra, out_dtype):
+def _rowwise_call(name, kernel, x2d, *extra, out_dtype):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -114,11 +114,13 @@ def _rowwise_call(kernel, x2d, *extra, out_dtype):
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((n, sk), out_dtype),
         interpret=GATE.interpret,
+        name=name,
     )(x2d, *extra)
 
 
 def _bwd_rows(y2d, dy2d, scale, out_dtype):
-    return _rowwise_call(functools.partial(_bwd_kernel, scale=scale),
+    return _rowwise_call("softmax_bwd",
+                         functools.partial(_bwd_kernel, scale=scale),
                          y2d, dy2d, out_dtype=out_dtype)
 
 
@@ -136,7 +138,8 @@ def scaled_softmax(x, scale):
 
 def _scaled_fwd(x, scale):
     x2d = x.reshape(-1, x.shape[-1])
-    y = _rowwise_call(functools.partial(_fwd_kernel, scale=scale),
+    y = _rowwise_call("softmax_fwd",
+                      functools.partial(_fwd_kernel, scale=scale),
                       x2d, out_dtype=x.dtype)
     y = y.reshape(x.shape)
     return y, y
@@ -164,7 +167,7 @@ def scaled_masked_softmax(x, maskf, scale):
 def _masked_fwd(x, maskf, scale):
     sk = x.shape[-1]
     y = _rowwise_call(
-        functools.partial(_masked_fwd_kernel, scale=scale),
+        "softmax_fwd", functools.partial(_masked_fwd_kernel, scale=scale),
         x.reshape(-1, sk), maskf.reshape(-1, sk), out_dtype=x.dtype)
     y = y.reshape(x.shape)
     return y, (y, maskf)
@@ -196,6 +199,7 @@ def _causal_fwd(x, scale):
     x2d = x.reshape(b * sq, sk)
     rb = _row_block(b * sq, sk)
     y = _rowwise_call(
+        "softmax_fwd",
         functools.partial(_causal_fwd_kernel, scale=scale, sq=sq, sk=sk,
                           rb=rb),
         x2d, out_dtype=x.dtype)

@@ -7,6 +7,7 @@ head (dense + gelu + LN + tied-vocab projection) and binary NSP head.
 
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from apex_tpu.models.gpt import _fold_tp
@@ -42,67 +43,76 @@ class BertModel(nn.Module):
             "BERT is bidirectional: config.attn_mask_type must be "
             "AttnMaskType.padding (got causal; the transformer stack would "
             "silently apply a causal mask)")
-        emb = VocabParallelEmbedding(
-            num_embeddings=cfg.vocab_size, embedding_dim=cfg.hidden_size,
-            params_dtype=cfg.params_dtype, name="word_embeddings")
-        h = emb(tokens)
-        if position_ids is None:
-            position_ids = jnp.arange(tokens.shape[-1])[None, :]
-        pos = self.param("position_embeddings", nn.initializers.normal(0.02),
-                         (cfg.max_position_embeddings, cfg.hidden_size),
-                         cfg.params_dtype)
-        h = h + pos[position_ids]
-        if tokentype_ids is not None:
-            tt = self.param("tokentype_embeddings",
-                            nn.initializers.normal(0.02),
-                            (self.num_tokentypes, cfg.hidden_size),
-                            cfg.params_dtype)
-            h = h + tt[tokentype_ids]
-        h = h.astype(cfg.compute_dtype).transpose(1, 0, 2)  # [s, b, h]
+        with jax.named_scope("embedding"):
+            emb = VocabParallelEmbedding(
+                num_embeddings=cfg.vocab_size, embedding_dim=cfg.hidden_size,
+                params_dtype=cfg.params_dtype, name="word_embeddings")
+            h = emb(tokens)
+            if position_ids is None:
+                position_ids = jnp.arange(tokens.shape[-1])[None, :]
+            pos = self.param(
+                "position_embeddings", nn.initializers.normal(0.02),
+                (cfg.max_position_embeddings, cfg.hidden_size),
+                cfg.params_dtype)
+            h = h + pos[position_ids]
+            if tokentype_ids is not None:
+                tt = self.param("tokentype_embeddings",
+                                nn.initializers.normal(0.02),
+                                (self.num_tokentypes, cfg.hidden_size),
+                                cfg.params_dtype)
+                h = h + tt[tokentype_ids]
+            h = h.astype(cfg.compute_dtype).transpose(1, 0, 2)  # [s, b, h]
 
         # padding mask: [b, s] 1=keep -> attention mask [b, 1, s, s]
         attention_mask = None
         if padding_mask is not None:
-            keep = padding_mask.astype(bool)
-            attention_mask = ~(keep[:, None, None, :] & keep[:, None, :, None])
+            with jax.named_scope("attention_mask"):
+                keep = padding_mask.astype(bool)
+                attention_mask = ~(keep[:, None, None, :]
+                                   & keep[:, None, :, None])
 
         h = ParallelTransformer(cfg, name="transformer")(h, attention_mask)
         h = FusedLayerNorm(normalized_shape=cfg.hidden_size,
                            eps=cfg.layernorm_epsilon, param_dtype=jnp.float32,
                            name="final_layernorm")(h.astype(jnp.float32))
 
-        # MLM head (reference BertLMHead): dense+gelu+LN then vocab proj
-        x = nn.Dense(cfg.hidden_size, param_dtype=cfg.params_dtype,
-                     name="lm_dense")(h.astype(cfg.compute_dtype))
-        x = jnp.asarray(nn.gelu(x.astype(jnp.float32)), cfg.compute_dtype)
-        x = FusedLayerNorm(normalized_shape=cfg.hidden_size,
-                           eps=cfg.layernorm_epsilon, param_dtype=jnp.float32,
-                           name="lm_layernorm")(x.astype(jnp.float32))
-        tp = get_tensor_model_parallel_world_size()
-        vocab_per_rank = divide(cfg.vocab_size, tp)
-        head = self.param(
-            "lm_head",
-            lambda key, shape, dtype: nn.initializers.normal(0.02)(
-                _fold_tp(key), shape, dtype),
-            (cfg.hidden_size, vocab_per_rank), cfg.params_dtype)
-        x = copy_to_tensor_model_parallel_region(x.astype(cfg.compute_dtype))
-        mlm_logits = jnp.einsum("sbh,hv->sbv", x,
-                                head.astype(cfg.compute_dtype),
-                                preferred_element_type=jnp.float32)
-        mlm_logits = mlm_logits.transpose(1, 0, 2)
+        with jax.named_scope("head"):
+            # MLM head (reference BertLMHead): dense+gelu+LN then vocab proj
+            x = nn.Dense(cfg.hidden_size, param_dtype=cfg.params_dtype,
+                         name="lm_dense")(h.astype(cfg.compute_dtype))
+            x = jnp.asarray(nn.gelu(x.astype(jnp.float32)), cfg.compute_dtype)
+            x = FusedLayerNorm(
+                normalized_shape=cfg.hidden_size, eps=cfg.layernorm_epsilon,
+                param_dtype=jnp.float32,
+                name="lm_layernorm")(x.astype(jnp.float32))
+            tp = get_tensor_model_parallel_world_size()
+            vocab_per_rank = divide(cfg.vocab_size, tp)
+            head = self.param(
+                "lm_head",
+                lambda key, shape, dtype: nn.initializers.normal(0.02)(
+                    _fold_tp(key), shape, dtype),
+                (cfg.hidden_size, vocab_per_rank), cfg.params_dtype)
+            x = copy_to_tensor_model_parallel_region(
+                x.astype(cfg.compute_dtype))
+            mlm_logits = jnp.einsum("sbh,hv->sbv", x,
+                                    head.astype(cfg.compute_dtype),
+                                    preferred_element_type=jnp.float32)
+            mlm_logits = mlm_logits.transpose(1, 0, 2)
 
-        nsp_logits = None
-        if self.add_binary_head:
-            # pooled [CLS] (first token) -> tanh dense -> binary head
-            pooled = nn.Dense(cfg.hidden_size, param_dtype=cfg.params_dtype,
-                              name="pooler")(h[0].astype(cfg.compute_dtype))
-            pooled = jnp.tanh(pooled.astype(jnp.float32))
-            nsp_logits = nn.Dense(2, param_dtype=cfg.params_dtype,
-                                  name="binary_head")(
-                pooled.astype(cfg.compute_dtype)).astype(jnp.float32)
+            nsp_logits = None
+            if self.add_binary_head:
+                # pooled [CLS] (first token) -> tanh dense -> binary head
+                pooled = nn.Dense(
+                    cfg.hidden_size, param_dtype=cfg.params_dtype,
+                    name="pooler")(h[0].astype(cfg.compute_dtype))
+                pooled = jnp.tanh(pooled.astype(jnp.float32))
+                nsp_logits = nn.Dense(2, param_dtype=cfg.params_dtype,
+                                      name="binary_head")(
+                    pooled.astype(cfg.compute_dtype)).astype(jnp.float32)
         return mlm_logits, nsp_logits
 
 
+@jax.named_scope("loss")
 def bert_loss_fn(mlm_logits, nsp_logits, labels, loss_mask,
                  nsp_labels=None):
     """MLM CE (vocab-parallel) + optional NSP CE

@@ -49,27 +49,29 @@ class GPTModel(nn.Module):
         tp = get_tensor_model_parallel_world_size()
 
         if self.pre_process:
-            emb = VocabParallelEmbedding(
-                num_embeddings=cfg.vocab_size, embedding_dim=cfg.hidden_size,
-                params_dtype=cfg.params_dtype, name="word_embeddings")
-            h = emb(tokens)
-            if cfg.position_embedding_type == "learned":
-                if position_ids is None:
-                    position_ids = jnp.arange(tokens.shape[-1])[None, :]
-                pos = self.param(
-                    "position_embeddings", nn.initializers.normal(0.02),
-                    (cfg.max_position_embeddings, cfg.hidden_size),
-                    cfg.params_dtype)
-                h = h + pos[position_ids]
-            h = h.astype(cfg.compute_dtype)
-            if cfg.embedding_multiplier is not None:
-                h = h * jnp.asarray(cfg.embedding_multiplier,
-                                    cfg.compute_dtype)
-            if cfg.embedding_layernorm:  # BLOOM: LN right after embed
-                h = _make_norm(cfg, "embedding_layernorm")(
-                    h.astype(jnp.float32)).astype(cfg.compute_dtype)
-            # [b, s, h] -> [s, b, h] (Megatron layout: seq-major for SP)
-            h = h.transpose(1, 0, 2)
+            with jax.named_scope("embedding"):
+                emb = VocabParallelEmbedding(
+                    num_embeddings=cfg.vocab_size,
+                    embedding_dim=cfg.hidden_size,
+                    params_dtype=cfg.params_dtype, name="word_embeddings")
+                h = emb(tokens)
+                if cfg.position_embedding_type == "learned":
+                    if position_ids is None:
+                        position_ids = jnp.arange(tokens.shape[-1])[None, :]
+                    pos = self.param(
+                        "position_embeddings", nn.initializers.normal(0.02),
+                        (cfg.max_position_embeddings, cfg.hidden_size),
+                        cfg.params_dtype)
+                    h = h + pos[position_ids]
+                h = h.astype(cfg.compute_dtype)
+                if cfg.embedding_multiplier is not None:
+                    h = h * jnp.asarray(cfg.embedding_multiplier,
+                                        cfg.compute_dtype)
+                if cfg.embedding_layernorm:  # BLOOM: LN right after embed
+                    h = _make_norm(cfg, "embedding_layernorm")(
+                        h.astype(jnp.float32)).astype(cfg.compute_dtype)
+                # [b, s, h] -> [s, b, h] (Megatron layout: seq-major for SP)
+                h = h.transpose(1, 0, 2)
         else:
             h = hidden_input
 
@@ -87,45 +89,47 @@ class GPTModel(nn.Module):
             return h
 
         h = _make_norm(cfg, "final_layernorm")(h.astype(jnp.float32))
-        h = copy_to_tensor_model_parallel_region(h.astype(cfg.compute_dtype))
-        if cfg.tie_word_embeddings:
-            # Tied head (reference parallel_lm_logits): logits through the
-            # embedding table. Requires embed and head on the same program
-            # (pre_process and post_process both true — pipeline stages
-            # must use the untied head instead).
-            if not self.pre_process:
-                raise ValueError(
-                    "tie_word_embeddings needs the embedding on this "
-                    "stage; pipeline-split models must untie")
-            logits = emb.attend(h)  # [s, b, vocab/tp]
-        else:
-            vocab_per_rank = divide(cfg.vocab_size, tp)
-            head = self.param(
-                "lm_head",
-                lambda key, shape, dtype: nn.initializers.normal(0.02)(
-                    _fold_tp(key), shape, dtype),
-                (cfg.hidden_size, vocab_per_rank), cfg.params_dtype)
-            logits = jnp.einsum("sbh,hv->sbv", h,
-                                head.astype(cfg.compute_dtype),
-                                preferred_element_type=jnp.float32)
-            if cfg.lm_head_bias:
-                logits = logits + self.param(
-                    "lm_head_bias", nn.initializers.zeros,
-                    (vocab_per_rank,), cfg.params_dtype).astype(
-                        logits.dtype)
-        if cfg.logits_scaling != 1.0:
-            # Granite: logits are DIVIDED by the scaling (elementwise,
-            # shard-safe)
-            logits = logits / jnp.asarray(cfg.logits_scaling,
-                                          logits.dtype)
-        if cfg.final_logit_softcapping is not None:
-            # Gemma-2: logits -> cap * tanh(logits / cap), fp32 (HF
-            # modeling_gemma2 Gemma2ForCausalLM.forward). Elementwise, so
-            # valid on each vocab-parallel shard independently.
-            cap = jnp.float32(cfg.final_logit_softcapping)
-            logits = (cap * jnp.tanh(logits.astype(jnp.float32) / cap)
-                      ).astype(logits.dtype)
-        return logits.transpose(1, 0, 2)  # [b, s, vocab/tp]
+        with jax.named_scope("head"):
+            h = copy_to_tensor_model_parallel_region(
+                h.astype(cfg.compute_dtype))
+            if cfg.tie_word_embeddings:
+                # Tied head (reference parallel_lm_logits): logits through the
+                # embedding table. Requires embed and head on the same program
+                # (pre_process and post_process both true — pipeline stages
+                # must use the untied head instead).
+                if not self.pre_process:
+                    raise ValueError(
+                        "tie_word_embeddings needs the embedding on this "
+                        "stage; pipeline-split models must untie")
+                logits = emb.attend(h)  # [s, b, vocab/tp]
+            else:
+                vocab_per_rank = divide(cfg.vocab_size, tp)
+                head = self.param(
+                    "lm_head",
+                    lambda key, shape, dtype: nn.initializers.normal(0.02)(
+                        _fold_tp(key), shape, dtype),
+                    (cfg.hidden_size, vocab_per_rank), cfg.params_dtype)
+                logits = jnp.einsum("sbh,hv->sbv", h,
+                                    head.astype(cfg.compute_dtype),
+                                    preferred_element_type=jnp.float32)
+                if cfg.lm_head_bias:
+                    logits = logits + self.param(
+                        "lm_head_bias", nn.initializers.zeros,
+                        (vocab_per_rank,), cfg.params_dtype).astype(
+                            logits.dtype)
+            if cfg.logits_scaling != 1.0:
+                # Granite: logits are DIVIDED by the scaling (elementwise,
+                # shard-safe)
+                logits = logits / jnp.asarray(cfg.logits_scaling,
+                                              logits.dtype)
+            if cfg.final_logit_softcapping is not None:
+                # Gemma-2: logits -> cap * tanh(logits / cap), fp32 (HF
+                # modeling_gemma2 Gemma2ForCausalLM.forward). Elementwise, so
+                # valid on each vocab-parallel shard independently.
+                cap = jnp.float32(cfg.final_logit_softcapping)
+                logits = (cap * jnp.tanh(logits.astype(jnp.float32) / cap)
+                          ).astype(logits.dtype)
+            return logits.transpose(1, 0, 2)  # [b, s, vocab/tp]
 
 
 def _fold_tp(key):
@@ -136,6 +140,7 @@ def _fold_tp(key):
     return jax.random.fold_in(key, rank)
 
 
+@jax.named_scope("loss")
 def gpt_loss_fn(vocab_parallel_logits, labels, loss_mask=None):
     """Mean per-token vocab-parallel CE loss (reference
     standalone_transformer_lm.py post_language_model_processing)."""

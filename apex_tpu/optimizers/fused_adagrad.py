@@ -29,6 +29,7 @@ class FusedAdagrad(FusedOptimizerBase):
             "sum": zeros_like_tree(params),
         }
 
+    @jax.named_scope("fused_adagrad")
     def step(self, grads, state, params, *, lr: Optional[float] = None,
              found_inf=None, scale: float = 1.0):
         lr = self.lr if lr is None else lr

@@ -55,6 +55,7 @@ class FusedAdam(FusedOptimizerBase):
             state["master"] = master_copy_tree(params)
         return state
 
+    @jax.named_scope("fused_adam")
     def step(self, grads, state, params, *, lr: Optional[float] = None,
              found_inf=None, scale: float = 1.0):
         lr = self.lr if lr is None else lr

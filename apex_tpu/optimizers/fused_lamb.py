@@ -43,6 +43,7 @@ class FusedLAMB(FusedOptimizerBase):
             "exp_avg_sq": zeros_like_tree(params),
         }
 
+    @jax.named_scope("fused_lamb")
     def step(self, grads, state, params, *, lr: Optional[float] = None,
              found_inf=None, scale: float = 1.0):
         lr = self.lr if lr is None else lr

@@ -48,6 +48,7 @@ class FusedMixedPrecisionLamb(FusedOptimizerBase):
             "master": master_copy_tree(params),
         }
 
+    @jax.named_scope("fused_mixed_precision_lamb")
     def step(self, grads, state, params, *, lr: Optional[float] = None,
              found_inf=None, scale: float = 1.0):
         lr = self.lr if lr is None else lr

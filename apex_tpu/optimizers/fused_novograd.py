@@ -47,6 +47,7 @@ class FusedNovoGrad(FusedOptimizerBase):
             "exp_avg_sq": jnp.zeros((n,), jnp.float32),
         }
 
+    @jax.named_scope("fused_novograd")
     def step(self, grads, state, params, *, lr: Optional[float] = None,
              found_inf=None, scale: float = 1.0):
         lr = self.lr if lr is None else lr

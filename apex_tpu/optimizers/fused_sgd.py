@@ -41,6 +41,7 @@ class FusedSGD(FusedOptimizerBase):
             "momentum_buffer": zeros_like_tree(params),
         }
 
+    @jax.named_scope("fused_sgd")
     def step(self, grads, state, params, *, lr: Optional[float] = None,
              found_inf=None, scale: float = 1.0):
         lr = self.lr if lr is None else lr

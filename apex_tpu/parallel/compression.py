@@ -153,6 +153,7 @@ def _quantize_pallas(x2d, scales):
         out_specs=pl.BlockSpec((_ROWS_PER_CELL, bs), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x2d.shape, jnp.int8),
         interpret=_gate().interpret,
+        name="quant_quantize",
     )(x2d, s)
     return q[:nb]
 
@@ -171,6 +172,7 @@ def _dequantize_pallas(q2d, scales):
         out_specs=pl.BlockSpec((_ROWS_PER_CELL, bs), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(q2d.shape, jnp.float32),
         interpret=_gate().interpret,
+        name="quant_dequantize",
     )(q2d, s)
     return out[:nb]
 

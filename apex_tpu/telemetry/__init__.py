@@ -9,9 +9,13 @@ The observability layer under the parallel/optimizer/bench stack:
   ``jax.named_scope``), causal identity (:class:`TraceContext` on a
   contextvar; spans emit begin/end events carrying
   trace/span/parent ids — the substrate ``tools/trace_export.py``
-  turns into a Perfetto-loadable Chrome trace), and a
-  ``start_profiler_trace``/``stop`` pair gated by
-  ``APEX_TPU_PROFILE_DIR``.
+  turns into a Perfetto-loadable Chrome trace).
+- :mod:`scopes`    — device time by module:
+  :func:`~apex_tpu.telemetry.scopes.scope_table` reads, from a compiled
+  step, the program scope of every instruction a device profile names,
+  and :func:`~apex_tpu.telemetry.scopes.classify` folds it into a block
+  (``attention``, ``optimizer``, ``amp``, ...) and a phase (forward,
+  backward, recompute, update).
 - :mod:`xla_cost`  — ``lower().cost_analysis()`` extraction for a
   jitted step + achieved MFU / HBM-utilization against a per-backend
   peak table.
@@ -81,8 +85,6 @@ from apex_tpu.telemetry.trace import (  # noqa: F401
     new_span_id,
     new_trace_id,
     span,
-    start_profiler_trace,
-    stop_profiler_trace,
     trace_context,
 )
 from apex_tpu.telemetry import comm  # noqa: F401
@@ -90,6 +92,7 @@ from apex_tpu.telemetry import compile_watch  # noqa: F401
 from apex_tpu.telemetry import memory  # noqa: F401
 from apex_tpu.telemetry import numerics  # noqa: F401
 from apex_tpu.telemetry import recorder  # noqa: F401
+from apex_tpu.telemetry import scopes  # noqa: F401
 from apex_tpu.telemetry import xla_cost  # noqa: F401
 from apex_tpu.telemetry.attribution import (  # noqa: F401
     PipelineAttributor,

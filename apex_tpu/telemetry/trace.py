@@ -27,9 +27,9 @@ disabled adds zero overhead to jitted step functions. Identity is part
 of the same contract: a disabled registry mints no ids and never
 touches the contextvar.
 
-``start_profiler_trace()``/``stop_profiler_trace()`` bracket a real
-``jax.profiler`` trace, gated by ``APEX_TPU_PROFILE_DIR`` so production
-entry points can call them unconditionally.
+A device profile is ``jax.profiler.trace(dir)`` around the steps; which
+module each of its operations belongs to is read from the compiled step
+(:mod:`apex_tpu.telemetry.scopes`).
 """
 
 import contextlib
@@ -39,8 +39,6 @@ import os
 import time
 
 from apex_tpu.telemetry.registry import get_registry
-
-ENV_PROFILE_DIR = "APEX_TPU_PROFILE_DIR"
 
 
 # -- causal identity --------------------------------------------------------
@@ -159,34 +157,24 @@ def emit_flow(name, flow_id, phase, *, registry=None, trace_id=None,
 
 
 def device_sync():
-    """Fence outstanding device work (best-effort; the TPU analog of
+    """Fence outstanding device work (the TPU analog of
     ``torch.cuda.synchronize``)."""
-    try:
-        import jax
+    import jax
 
-        jax.effects_barrier()
-    except Exception:
-        pass
+    jax.effects_barrier()
 
 
 def _annotations(name):
-    """TraceAnnotation + named_scope, each best-effort (profiling
-    support can be absent on exotic backends)."""
-    stack = contextlib.ExitStack()
-    try:
-        import jax
+    """``TraceAnnotation`` (the host timeline of a device profile) and
+    ``named_scope`` (the ``op_name`` of what is traced inside), entered
+    together. A profiler that cannot annotate raises here, where the
+    span opens, and is not hidden."""
+    import jax
 
-        try:
-            stack.enter_context(jax.profiler.TraceAnnotation(name))
-        except Exception:
-            pass
-        try:
-            stack.enter_context(jax.named_scope(
-                name.replace("/", "_").replace(" ", "_")))
-        except Exception:
-            pass
-    except Exception:
-        pass
+    stack = contextlib.ExitStack()
+    stack.enter_context(jax.profiler.TraceAnnotation(name))
+    stack.enter_context(jax.named_scope(
+        name.replace("/", "_").replace(" ", "_")))
     return stack
 
 
@@ -278,46 +266,3 @@ class Span:
 def span(name, *, sync=False, registry=None, **attrs):
     """``with span("train/step"): ...`` — see :class:`Span`."""
     return Span(name, sync=sync, registry=registry, **attrs)
-
-
-_PROFILER_ACTIVE = False
-
-
-def start_profiler_trace(logdir=None):
-    """Start a ``jax.profiler`` trace when ``APEX_TPU_PROFILE_DIR`` (or
-    ``logdir``) names a directory; returns True iff a trace started.
-    Safe to call unconditionally and when a trace is already running."""
-    global _PROFILER_ACTIVE
-    logdir = logdir or os.environ.get(ENV_PROFILE_DIR)
-    if not logdir or _PROFILER_ACTIVE:
-        return False
-    try:
-        import jax
-
-        jax.profiler.start_trace(logdir)
-    except Exception:
-        return False
-    _PROFILER_ACTIVE = True
-    reg = get_registry()
-    if reg.enabled:
-        reg.event("profiler", "start", logdir=logdir)
-    return True
-
-
-def stop_profiler_trace():
-    """Stop the trace started by :func:`start_profiler_trace`; returns
-    True iff one was stopped."""
-    global _PROFILER_ACTIVE
-    if not _PROFILER_ACTIVE:
-        return False
-    _PROFILER_ACTIVE = False
-    try:
-        import jax
-
-        jax.profiler.stop_trace()
-    except Exception:
-        return False
-    reg = get_registry()
-    if reg.enabled:
-        reg.event("profiler", "stop")
-    return True

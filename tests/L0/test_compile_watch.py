@@ -410,21 +410,51 @@ class TestCompileCacheCounters:
         self._enable_at(monkeypatch, tmp_path / "cache")
         before = _compile_cache.cache_stats()
         x = jnp.ones((64,))
-        # two distinct pjit instances of the same program: the first
-        # populates the persistent cache, the second must hit it
-        jax.jit(lambda v: v * 7 + 3)(x)
+
+        # two distinct pjit instances of the same program, from the same
+        # source line (the line is part of the key): the first populates
+        # the persistent cache, the second must hit it
+        def program():
+            return jax.jit(lambda v: v * 7 + 3)
+
+        program()(x)
         mid = _compile_cache.cache_stats()
         assert mid["misses"] > before["misses"]
-        jax.jit(lambda v: v * 7 + 3)(x)
+        program()(x)
         after = _compile_cache.cache_stats()
         assert after["hits"] > mid["hits"]
+
+    def test_a_scope_is_part_of_the_key(self, monkeypatch, tmp_path,
+                                        restore_cache_config):
+        """A program that differs from a cached one only in a named
+        scope is compiled again, so its executable's ``op_name``s are its
+        own and not those of the code that filled the cache."""
+        self._enable_at(monkeypatch, tmp_path / "cache3")
+
+        def program(scope):
+            def f(v):
+                with jax.named_scope(scope):
+                    return v * 5 + 2
+            return jax.jit(f)
+
+        x = jnp.ones((32,))
+        program("yesterday").lower(x).compile()
+        mid = _compile_cache.cache_stats()
+        text = program("today").lower(x).compile().as_text()
+        after = _compile_cache.cache_stats()
+        assert after["misses"] > mid["misses"]
+        assert "today" in text and "yesterday" not in text
+        # ... and who calls the program is not: one frame a location
+        again = (lambda: program("today").lower(x).compile())()
+        assert _compile_cache.cache_stats()["hits"] > after["hits"]
+        assert "today" in again.as_text()
 
     def test_registry_counters_ride_along(self, monkeypatch, tmp_path,
                                           restore_cache_config):
         self._enable_at(monkeypatch, tmp_path / "cache2")
         with use_registry(MetricsRegistry(enabled=True)) as reg:
             x = jnp.ones((48,))
-            jax.jit(lambda v: v * 9 - 1)(x)
-            jax.jit(lambda v: v * 9 - 1)(x)
+            for _ in range(2):      # one source line: one cache key
+                jax.jit(lambda v: v * 9 - 1)(x)
             assert reg.counter_value("compile_cache/misses") >= 1
             assert reg.counter_value("compile_cache/hits") >= 1

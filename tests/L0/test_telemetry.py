@@ -123,12 +123,6 @@ def test_span_timing_works_with_telemetry_off():
         assert sp.stop() >= 0.0
 
 
-def test_profiler_pair_gated_by_env(monkeypatch):
-    monkeypatch.delenv(telemetry.trace.ENV_PROFILE_DIR, raising=False)
-    assert telemetry.start_profiler_trace() is False
-    assert telemetry.stop_profiler_trace() is False
-
-
 # ---------------------------------------------------------------------------
 # xla cost accounting
 # ---------------------------------------------------------------------------

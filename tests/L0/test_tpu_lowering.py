@@ -14,6 +14,7 @@ Shapes: GPT-2 345M (16 heads of 64, cache 1024) and one GQA layout
 """
 
 import os
+import re
 import sys
 
 import jax
@@ -22,7 +23,9 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
+from apex_tpu.analysis.hlo import instruction_scopes
 from apex_tpu.kernels import registry as kreg
+from apex_tpu.telemetry.scopes import classify
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)))))
@@ -54,14 +57,15 @@ def tpu():
     mp.undo()
 
 
-def _compile(fn, devices, *avals):
+def _compile(fn, devices, *avals, options=_FAST, donate=()):
     """Lower + compile ``fn`` for the first topology device; returns
     the compiled text (holds one ``tpu_custom_call`` per kernel)."""
     sh = SingleDeviceSharding(devices[0])
     args = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
         avals)
-    return jax.jit(fn).lower(*args).compile(_FAST).as_text()
+    return jax.jit(fn, donate_argnums=donate).lower(*args).compile(
+        options).as_text()
 
 
 def _sds(shape, dtype):
@@ -84,12 +88,64 @@ def test_every_registered_kernel_is_covered():
     assert {"gqa_decode", "mla_decode"} <= covered
 
 
+# The names the kernels give themselves (``pl.pallas_call(name=)``), by
+# the first words of the case that runs them: a profile and the compiled
+# text name each Mosaic call after its kernel, not after whoever called it.
+KERNEL_NAMES = {
+    "flash_attention": ("self_attention_flash_fwd",
+                        "self_attention_flash_dq",
+                        "self_attention_flash_dkv"),
+    "gqa_decode": ("gqa_decode",),
+    "mla_decode": ("mla_decode",),
+    "fused_cc window": ("fused_cc_window_attention",),
+    "fused_cc int8": ("fused_cc_spec_verify",),
+    "fused_cc quantize_pack_int4": ("fused_cc_quantize_pack",),
+    "fused_cc unpack_dequantize_int4": ("fused_cc_unpack_dequantize",),
+    "quant4 quantize": ("quant4_quantize",),
+    "quant4 pack/unpack": ("quant4_pack", "quant4_unpack"),
+    "quant4 dequantize": ("quant4_dequantize",),
+    "quant quantize_rows_blockwise": ("quant_quantize",),
+    "quant dequantize_rows_blockwise": ("quant_dequantize",),
+    "softmax": ("softmax_fwd", "softmax_bwd"),
+    "adam": ("fused_adam",),
+    "lamb": ("fused_lamb",),
+    "layernorm": ("layer_norm_fwd", "layer_norm_bwd"),
+    "rmsnorm": ("rms_norm_fwd", "rms_norm_bwd"),
+}
+_TEXTS = {}
+
+
+def _case_text(devices, case):
+    """The case's compiled text, compiled once for the tests below
+    (``--dist loadfile`` keeps a file's tests in one process)."""
+    if case.name not in _TEXTS:
+        _TEXTS[case.name] = _compile(case.kernel, devices,
+                                     *jax.eval_shape(case.make_args))
+    return _TEXTS[case.name]
+
+
 @pytest.mark.parametrize("case", CASES, ids=lambda c: c.name)
 def test_kernel_compiles_for_v5e(tpu, case):
-    text = _compile(case.kernel, tpu, *jax.eval_shape(case.make_args))
+    text = _case_text(tpu, case)
     assert "tpu_custom_call" in text, (
         f"{case.name}: no Mosaic kernel in the compiled program — did "
         f"the oracle path run instead?")
+
+
+@pytest.mark.parametrize("case", CASES, ids=lambda c: c.name)
+def test_kernel_is_named_in_the_compiled_program(tpu, case):
+    names = next(v for k, v in KERNEL_NAMES.items()
+                 if case.name.startswith(k))
+    scopes = instruction_scopes(_case_text(tpu, case))
+    for name in names:
+        # the instruction is named after the kernel (``%softmax_bwd.1``;
+        # ``%transpose_jvp_softmax_bwd__.1`` where the kernel's is the
+        # outermost scope under the transformation), and the kernel's
+        # name is the scope component that holds the call
+        called = [s for i, s in scopes.items() if name in i]
+        assert called, f"{case.name}: no instruction named {name}"
+        assert all(re.search(rf"[/(]{name}\)*/pallas_call$", s)
+                   for s in called), called
 
 
 def test_matmul_collectives_compile_under_tp4(tpu):
@@ -179,3 +235,74 @@ def test_generate_compiles_for_v5e(tpu, gpt2_two_layers):
             _sds((2,), jnp.uint32), _sds((2,), jnp.bool_))
     text = _compile(decode_all, tpu, params, init)
     assert text.count("tpu_custom_call") >= 2
+
+
+@pytest.fixture(scope="module")
+def gpt2_train_step_text(tpu):
+    """The README quick-start step (amp O2 + ``FusedAdam``, flash
+    attention, recomputation) for GPT-2 345M at full width, two layers,
+    batch 2: its optimised HLO for one ``TPU v5 lite``."""
+    from apex_tpu import amp
+    from apex_tpu.models import GPTModel, TransformerConfig
+    from apex_tpu.models.gpt import gpt_loss_fn
+    from apex_tpu.optimizers import FusedAdam
+
+    model = GPTModel(TransformerConfig(
+        hidden_size=1024, num_layers=2, num_attention_heads=16,
+        vocab_size=50304, max_position_embeddings=1024,
+        compute_dtype=BF16, tie_word_embeddings=True,
+        use_flash_attention=True, activation_checkpointing=True))
+    tokens = _sds((2, 1024), I32)
+    params = jax.eval_shape(lambda: amp.frontend.cast_model(
+        model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), I32))["params"],
+        BF16, keep_batchnorm_fp32=True))
+    _, opt = amp.initialize({}, FusedAdam(lr=1e-4), opt_level="O2",
+                            verbosity=0)
+
+    def train_step(params, opt_state, tokens):
+        scale = opt_state["scaler"].loss_scale
+        value, grads = jax.value_and_grad(lambda p: gpt_loss_fn(
+            model.apply({"params": p}, tokens), tokens) * scale)(params)
+        params, opt_state = opt.step(grads, opt_state, params)
+        return params, opt_state, value / scale
+
+    # XLA's whole pipeline and the donated state, as the chip runs it:
+    # what is fused into what decides which instruction carries which scope
+    return _compile(train_step, tpu, params, jax.eval_shape(opt.init, params),
+                    tokens, options=None, donate=(0, 1))
+
+
+def test_train_step_names_its_attention_kernels(gpt2_train_step_text):
+    """Four Mosaic calls a layer: flash forward, its recomputed copy, dq
+    and dk/dv, each under the name ``attention_roofline`` selects."""
+    kernels = re.findall(
+        r"%([\w\-]+?)[.\d]* = .* custom_call_target=\"tpu_custom_call\"",
+        gpt2_train_step_text)
+    assert sorted(kernels) == sorted(
+        2 * ["self_attention_flash_fwd"] * 2
+        + 2 * ["self_attention_flash_dq", "self_attention_flash_dkv"])
+
+
+def test_train_step_leaves_nothing_of_the_update_or_loss_bare(
+        gpt2_train_step_text):
+    """Every fusion of the step belongs to a block of the program, but
+    for the scalars the step function computes itself (``loss * scale``,
+    ``value / scale``)."""
+    scopes = instruction_scopes(gpt2_train_step_text)
+    fusion = re.compile(
+        r"^\s+(?:ROOT\s+)?%([\w\-.]+) = (.*?) fusion\(", re.M)
+    # the entry computation's: the operations the device is handed (a
+    # fusion inside a fused computation is part of its caller)
+    entry = gpt2_train_step_text[gpt2_train_step_text.index("\nENTRY "):]
+    fusions = fusion.findall(entry)
+    assert len(fusions) > 100
+    blocks = set()
+    for name, shape in fusions:
+        assert name in scopes, f"fusion {name} has no scope"
+        block, _ = classify(scopes[name])
+        blocks.add(block and block.split("/")[0])
+        if block is None:
+            assert not re.search(r"\[\d", shape), (
+                f"{name} = {shape} under {scopes[name]!r} is in no block")
+    assert blocks >= {"embedding", "layernorm", "attention", "mlp", "head",
+                      "loss", "amp", "optimizer"}
