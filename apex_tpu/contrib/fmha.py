@@ -26,6 +26,7 @@ import numbers
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from apex_tpu.kernels.registry import (
     dispatch_path,
@@ -40,6 +41,9 @@ GATE = kernel_gate("flash_attention", default=True)
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 NEG_INF = -1e30
+# checkpoint_name tags of the kernel path's residuals (out, lse): what a
+# jax.checkpoint policy names to keep them across recomputation.
+FLASH_RESIDUAL_NAMES = ("flash_out", "flash_lse")
 
 
 def _use_kernel(bq, bk):
@@ -534,6 +538,15 @@ def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k,
     if _use_kernel(bq, bk):
         out, lse = _flash_fwd_pallas(q, k, v, scale_, causal, bq, bk,
                                      window, alibi_slopes)
+        # The two residuals only another run of the kernel can rebuild,
+        # named so that a checkpointed layer can keep them (``lse`` in
+        # its [b, n, s] form: the kernel's [b*n, s, 1] pads its last
+        # dimension to 128 lanes in HBM). Metadata outside jax.checkpoint.
+        # The forward has to go on from the named ``out``: named for the
+        # residuals alone, the recomputed layer would still need the
+        # kernel's own ``out`` for what follows it, and run the kernel.
+        out = checkpoint_name(out, FLASH_RESIDUAL_NAMES[0])
+        lse = checkpoint_name(lse, FLASH_RESIDUAL_NAMES[1])
         return out, (q, k, v, out, lse, alibi_slopes)
     return (_attention_reference(q, k, v, scale_, causal, window,
                                  alibi_slopes),

@@ -102,7 +102,12 @@ class TransformerConfig:
     # OFF when the model fits HBM without it — backward then reuses the
     # forward's activations instead of re-running every layer (~25-30%
     # fewer executed FLOPs per train step, the single biggest single-chip
-    # MFU lever at GPT-2-345M scale).
+    # MFU lever at GPT-2-345M scale). Kept across the recomputation: the
+    # layer's input and, where the flash kernel ran, its output and
+    # log-sum-exp (b*s*h*2 + b*n*s*4 bytes a layer, about one more layer
+    # input; twice that on a TPU at head dimension 64, see
+    # ParallelTransformer), so the backward re-runs everything of a layer
+    # but the attention kernel. Nothing else is kept.
     activation_checkpointing: bool = True
     # Mixture-of-experts (no reference equivalent; SURVEY.md §2.3 note).
     # None -> dense ParallelMLP everywhere. Every ``moe_layer_freq``-th
@@ -1093,10 +1098,40 @@ class _ScanBlock(nn.Module):
         return h, None
 
 
+def _remat_keeping_flash_residuals(block, wrapped, **kwargs):
+    """``nn.remat(block)`` under the policy that keeps the flash forward's
+    output and log-sum-exp (``contrib.fmha.FLASH_RESIDUAL_NAMES``) and
+    nothing else: q, k, v come back from the recomputed qkv matmul, these
+    two only from another run of the kernel. Where the kernel did not run
+    in the block (a mask, a soft cap, the oracle path, flash off) no such
+    name exists and nothing is kept. Counts the ``wrapped`` layers (one
+    for a scanned block) as ``remat/save_flash_residuals`` at trace time;
+    ``kernels/dispatch/flash_attention_pallas`` beside it says the kernel
+    ran in them."""
+    from apex_tpu.contrib.fmha import FLASH_RESIDUAL_NAMES
+    from apex_tpu.telemetry.registry import get_registry
+
+    get_registry().counter("remat/save_flash_residuals").inc(wrapped)
+    return nn.remat(
+        block, static_argnums=(),
+        policy=jax.checkpoint_policies.save_only_these_names(
+            *FLASH_RESIDUAL_NAMES),
+        **kwargs)
+
+
 class ParallelTransformer(nn.Module):
     """A stack of layers, optionally rematerialized per layer
     (reference ParallelTransformer with activation checkpointing -> here
-    ``jax.checkpoint`` over each layer)."""
+    ``jax.checkpoint`` over each layer, or over the scanned block).
+
+    A checkpointed layer keeps its input and, where flash attention's
+    kernel ran in it, the kernel's output ``[b, n, s, d]`` in the compute
+    dtype and log-sum-exp ``[b, n, s]`` in float32: ``b*s*h*2 + b*n*s*4``
+    bytes a layer, for one kernel run a layer less in the backward. On a
+    TPU the output is kept in the kernel's own layout, where a head
+    dimension of 64 pads to 128 lanes: GPT-2 345M at 16 x 1024 keeps
+    67 + 1 MB a layer, 1.69 GB over 24 layers (PERF.md section 6, PR 27).
+    The rest of the layer is recomputed."""
 
     config: TransformerConfig
     num_layers: Optional[int] = None
@@ -1118,8 +1153,8 @@ class ParallelTransformer(nn.Module):
                     "must be 1 (every layer MoE) or num_moe_experts None")
             block = _ScanBlock
             if remat_on and not self.decode:
-                block = nn.remat(block, static_argnums=(),
-                                 prevent_cse=False)
+                block = _remat_keeping_flash_residuals(block, 1,
+                                                       prevent_cse=False)
             scanned = nn.scan(
                 block,
                 variable_axes={"params": 0, "moe_losses": 0, "cache": 0},
@@ -1133,8 +1168,7 @@ class ParallelTransformer(nn.Module):
             return h
         layer = ParallelTransformerLayer
         if remat_on and not self.decode:
-            layer = nn.checkpoint(ParallelTransformerLayer,
-                                  static_argnums=())
+            layer = _remat_keeping_flash_residuals(layer, n)
         for i in range(n):
             hidden_states = layer(cfg, layer_number=i, decode=self.decode,
                                   name=f"layer_{i}")(

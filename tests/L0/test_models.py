@@ -187,3 +187,162 @@ def test_gpt_alibi_flash_matches_masked_path(monkeypatch, rng):
     monkeypatch.setattr(tlm, "_flash_available", lambda s, d: True)
     flash = logits(use_flash=True)
     np.testing.assert_allclose(flash, masked, rtol=2e-4, atol=2e-4)
+
+
+# --- recomputation keeps the flash forward's output and log-sum-exp -------
+
+_REMAT_LAYERS = 2
+_REMAT_STACKS = [
+    pytest.param(scan, window,
+                 id=f"{'scan' if scan else 'unrolled'}-"
+                    f"{'windowed' if window else 'causal'}")
+    for scan in (False, True) for window in (None, 40)]
+
+
+@pytest.fixture
+def flash_interpreted(monkeypatch):
+    """The flash kernels in interpret mode, at widths the gate of the
+    model would refuse on a chip."""
+    import apex_tpu.contrib.fmha as fmha_mod
+    import apex_tpu.models.transformer_lm as tlm
+
+    monkeypatch.setattr(fmha_mod.GATE, "interpret", True)
+    monkeypatch.setattr(tlm, "_flash_available", lambda s, d: True)
+    return monkeypatch
+
+
+def _remat_stack(scan, window, *, checkpointing=True, flash=True):
+    """A float32 two-layer ``ParallelTransformer``, its parameters, its
+    input and the gradient of a scalar of its output by both."""
+    from apex_tpu.models import TransformerConfig
+    from apex_tpu.models.transformer_lm import ParallelTransformer
+
+    stack = ParallelTransformer(TransformerConfig(
+        hidden_size=64, num_layers=_REMAT_LAYERS, num_attention_heads=2,
+        vocab_size=128, max_position_embeddings=128,
+        compute_dtype=jnp.float32, use_flash_attention=flash,
+        sliding_window=window, scan_layers=scan,
+        activation_checkpointing=checkpointing))
+    hidden = jax.random.normal(jax.random.PRNGKey(1), (128, 2, 64))
+    params = stack.init(jax.random.PRNGKey(0), hidden)
+
+    def loss(params, hidden):
+        return jnp.sum(stack.apply(params, hidden) ** 2)
+
+    return params, hidden, loss, jax.grad(loss, argnums=(0, 1))
+
+
+def _bare_remat(monkeypatch):
+    """The parent's checkpointing: ``nn.remat`` with no policy."""
+    import flax.linen as nn
+
+    import apex_tpu.models.transformer_lm as tlm
+
+    monkeypatch.setattr(
+        tlm, "_remat_keeping_flash_residuals",
+        lambda block, wrapped, **kw: nn.remat(block, static_argnums=(),
+                                              **kw))
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs in its parameters."""
+    from apex_tpu.analysis.rules import _iter_subjaxprs
+
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in _iter_subjaxprs(eqn):
+            yield from _equations(sub)
+
+
+def _flash_forwards(grad, params, hidden):
+    return sum(eqn.primitive.name == "pallas_call"
+               and eqn.params["name"] == "self_attention_flash_fwd"
+               for eqn in _equations(
+                   jax.make_jaxpr(grad)(params, hidden).jaxpr))
+
+
+@pytest.mark.parametrize("scan,window", _REMAT_STACKS)
+def test_remat_keeping_flash_residuals_leaves_gradients_as_they_were(
+        flash_interpreted, scan, window):
+    """The kept ``out`` and ``lse`` are what the second run of the kernel
+    on the same q, k, v gave: float32 gradients are the bare
+    ``nn.remat``'s to the bit, and checkpointing off's to rounding."""
+    params, hidden, _, grad = _remat_stack(scan, window)
+    kept = jax.jit(grad)(params, hidden)
+    *_, grad_off = _remat_stack(scan, window, checkpointing=False)
+    off = jax.jit(grad_off)(params, hidden)
+    _bare_remat(flash_interpreted)
+    *_, grad_bare = _remat_stack(scan, window)
+    bare = jax.jit(grad_bare)(params, hidden)
+    jax.tree_util.tree_map(np.testing.assert_array_equal, kept, bare)
+    scale = max(float(jnp.abs(g).max())
+                for g in jax.tree_util.tree_leaves(off))
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-6,
+                                                atol=1e-6 * scale),
+        kept, off)
+
+
+@pytest.mark.parametrize("scan,window", _REMAT_STACKS)
+def test_remat_runs_the_flash_forward_once_a_layer(flash_interpreted, scan,
+                                                   window):
+    """One forward kernel a layer in the gradient's jaxpr (a scanned
+    stack holds its one block once), where the bare ``nn.remat`` holds
+    the recomputed one as well."""
+    blocks = 1 if scan else _REMAT_LAYERS
+    params, hidden, _, grad = _remat_stack(scan, window)
+    assert _flash_forwards(grad, params, hidden) == blocks
+    _bare_remat(flash_interpreted)
+    *_, grad_bare = _remat_stack(scan, window)
+    assert _flash_forwards(grad_bare, params, hidden) == 2 * blocks
+
+
+@pytest.mark.parametrize("scan", [False, True], ids=["unrolled", "scan"])
+def test_remat_without_flash_names_and_saves_nothing(monkeypatch, capsys,
+                                                     scan):
+    """Without the kernel no residual is named, so the policy keeps what
+    the bare ``nn.remat`` keeps: the arguments and each layer's input."""
+    def residuals():
+        params, hidden, loss, grad = _remat_stack(scan, None, flash=False)
+        names = [eqn for eqn in _equations(
+            jax.make_jaxpr(grad)(params, hidden).jaxpr)
+            if eqn.primitive.name == "name"]
+        jax.ad_checkpoint.print_saved_residuals(loss, params, hidden)
+        return names, capsys.readouterr().out.splitlines()
+
+    names, kept = residuals()
+    assert names == []
+    assert not any("named" in line for line in kept)
+    # the parameters, the stack's input, the later layers' inputs (one
+    # stacked array under scan) and the loss's own square
+    inner = [line for line in kept if "from the argument" not in line]
+    assert len(inner) == (2 if scan else _REMAT_LAYERS), inner
+    assert all(line.startswith("f32[") and "128,2,64]" in line
+               for line in inner), inner
+    _bare_remat(monkeypatch)
+    assert residuals() == (names, kept)
+
+
+@pytest.mark.parametrize("scan", [False, True], ids=["unrolled", "scan"])
+def test_remat_counts_the_layers_it_wraps(flash_interpreted, scan):
+    """``remat/save_flash_residuals`` reads one per checkpointed layer of
+    a trace (one for a scanned block), beside the kernel path's own
+    counter, and nothing with checkpointing off."""
+    from apex_tpu.telemetry.registry import MetricsRegistry, use_registry
+
+    def counts(checkpointing):
+        params, hidden, _, grad = _remat_stack(
+            scan, None, checkpointing=checkpointing)
+        with use_registry(MetricsRegistry(enabled=True)) as reg:
+            jax.make_jaxpr(grad)(params, hidden)
+            return (reg.counter_value("remat/save_flash_residuals"),
+                    reg.counter_value(
+                        "kernels/dispatch/flash_attention_interpret"))
+
+    # (the kernel's counter reads every trace of the body: scan and
+    # differentiation trace it more than once)
+    wrapped, dispatched = counts(True)
+    assert wrapped == (1 if scan else _REMAT_LAYERS)
+    assert dispatched >= wrapped
+    wrapped, dispatched = counts(False)
+    assert wrapped == 0 and dispatched >= 1
