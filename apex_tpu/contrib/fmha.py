@@ -110,9 +110,23 @@ def _window_last_q_pos(kj, block_k, window):
     return (kj + 1) * block_k - 1 + window - 1
 
 
+def _selected(sel_ref):
+    """The selection tile ``[block_q, block_k]`` as booleans."""
+    return sel_ref[0].astype(jnp.int32) != 0
+
+
+def _and_run(run, tile_selected):
+    """``run`` (a Python ``True`` off the causal path) and the tile's
+    flag, where the call carries a selection."""
+    if tile_selected is None:
+        return run
+    return tile_selected if run is True else run & tile_selected
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, slopes_ref, o_ref, lse_ref,
                       acc_ref, m_ref, l_ref, *, scale, causal, block_q,
-                      block_k, num_kv, window, alibi):
+                      block_k, num_kv, window, alibi, sel_ref=None,
+                      tile_selected=None):
     """One (head, q-block, kv-block) grid cell of online-softmax attention.
 
     K/V arrive as [1, block_k, d] VMEM tiles streamed by the grid — VMEM
@@ -134,7 +148,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, slopes_ref, o_ref, lse_ref,
 
     # Causal: kv blocks entirely above the diagonal (or, windowed, fully
     # below the band) contribute nothing.
-    run = _stream_kv_run(qi, kj, block_q, block_k, causal, window)
+    run = _and_run(_stream_kv_run(qi, kj, block_q, block_k, causal, window),
+                   tile_selected)
 
     @pl.when(run)
     def _step():
@@ -146,11 +161,18 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, slopes_ref, o_ref, lse_ref,
             s = s + _alibi_bias(slopes_ref, kj, block_q, block_k)
         if causal:
             s = _causal_mask(s, qi, kj, block_q, block_k, window)
+        if sel_ref is not None:
+            sel = _selected(sel_ref)
+            s = jnp.where(sel, s, NEG_INF)
         m_prev = m_ref[...]
         l_prev = l_ref[...]
         m_cur = jnp.max(s, axis=-1)[:, None]
         m_new = jnp.maximum(m_prev, m_cur)
         p = jnp.exp(s - m_new)
+        if sel_ref is not None:
+            # a row with nothing selected so far has m_new == NEG_INF,
+            # where exp(s - m_new) is 1 on its masked entries
+            p = jnp.where(sel, p, 0.0)
         alpha = jnp.exp(m_prev - m_new)
         l_ref[...] = alpha * l_prev + jnp.sum(p, axis=-1)[:, None]
         m_ref[...] = m_new
@@ -247,7 +269,8 @@ def _flash_fwd_pallas(q, k, v, scale, causal, block_q, block_k,
 
 def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                      slopes_ref, dq_ref, dq_acc, *, scale, causal,
-                     block_q, block_k, num_kv, window, alibi):
+                     block_q, block_k, num_kv, window, alibi, sel_ref=None,
+                     tile_selected=None):
     """dq for one q block, streaming kv blocks (innermost grid dim):
     p = exp(q k^T scale - lse); ds = p * (do v^T - delta); dq += ds k scale.
     """
@@ -260,7 +283,8 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    run = _stream_kv_run(qi, kj, block_q, block_k, causal, window)
+    run = _and_run(_stream_kv_run(qi, kj, block_q, block_k, causal, window),
+                   tile_selected)
 
     @pl.when(run)
     def _step():
@@ -275,6 +299,8 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             s = s + _alibi_bias(slopes_ref, kj, block_q, block_k)
         if causal:
             s = _causal_mask(s, qi, kj, block_q, block_k, window)
+        if sel_ref is not None:
+            s = jnp.where(_selected(sel_ref), s, NEG_INF)
         p = jnp.exp(s - lse)
         dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
         ds = p * (dp - delta)
@@ -289,7 +315,7 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _flash_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
                       slopes_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
                       scale, causal, block_q, block_k, num_q, window,
-                      alibi):
+                      alibi, sel_ref=None, tile_selected=None):
     """dk/dv for one kv block, streaming q blocks (innermost grid dim):
     dv += p^T do;  dk += ds^T q scale."""
     from jax.experimental import pallas as pl
@@ -304,7 +330,8 @@ def _flash_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
 
     # Causal: q blocks entirely above this kv block (or, windowed, beyond
     # the band) contribute nothing.
-    run = _stream_q_run(qi, kj, block_q, block_k, causal, window)
+    run = _and_run(_stream_q_run(qi, kj, block_q, block_k, causal, window),
+                   tile_selected)
 
     @pl.when(run)
     def _step():
@@ -319,6 +346,8 @@ def _flash_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
             s = s + _alibi_bias(slopes_ref, kj, block_q, block_k)
         if causal:
             s = _causal_mask(s, qi, kj, block_q, block_k, window)
+        if sel_ref is not None:
+            s = jnp.where(_selected(sel_ref), s, NEG_INF)
         p = jnp.exp(s - lse)
         dv_acc[...] += jnp.dot(p.T, do,
                                preferred_element_type=jnp.float32)
@@ -446,10 +475,244 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, scale, causal, block_q,
     return rs(dq), rs(dk), rs(dv)
 
 
-def _attention_reference(q, k, v, scale, causal, window=None,
-                         alibi_slopes=None):
-    """Reference einsum attention (fp32 softmax), used for the backward
-    rematerialization and the non-TPU fallback."""
+# ------------------------------------------------ with a selection operand
+#
+# ``selection`` ``[b, s, s]`` int8 says, for every query, which keys it
+# may see (DeepSeek Sparse Attention: a learned indexer chooses them;
+# models/transformer_lm.py ``SparseIndexer``). It is one more operand of
+# the same three kernel bodies, streamed by tile and shared by a batch
+# row's heads; ``_tile_flags`` tells each grid cell, through scalar
+# prefetch, whether its tile selects anything, and cells whose tile does
+# not are skipped like those above the diagonal. These calls are named
+# ``sparse_attention_*``.
+
+def _tile_flags(selection, block_q, block_k):
+    """``[b * nq * nk]`` int32: does tile (i, j) of row ``b`` select any
+    (query, key) pair?"""
+    b, s, _ = selection.shape
+    nq, nk = s // block_q, s // block_k
+    tiles = selection.reshape(b, nq, block_q, nk, block_k)
+    return (jnp.max(tiles, axis=(2, 4)) != 0).astype(jnp.int32).reshape(-1)
+
+
+def _flag(flags_ref, row, qi, kj, num_q, num_kv):
+    return flags_ref[(row * num_q + qi) * num_kv + kj] != 0
+
+
+def _sparse_fwd_kernel(flags_ref, q_ref, k_ref, v_ref, sel_ref, o_ref,
+                       lse_ref, acc_ref, m_ref, l_ref, *, heads, num_q,
+                       **kw):
+    from jax.experimental import pallas as pl
+
+    tile = _flag(flags_ref, pl.program_id(0) // heads, pl.program_id(1),
+                 pl.program_id(2), num_q, kw["num_kv"])
+    _flash_fwd_kernel(q_ref, k_ref, v_ref, None, o_ref, lse_ref, acc_ref,
+                      m_ref, l_ref, sel_ref=sel_ref, tile_selected=tile,
+                      window=None, alibi=False, **kw)
+
+
+def _sparse_dq_kernel(flags_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                      delta_ref, sel_ref, dq_ref, dq_acc, *, heads, num_q,
+                      **kw):
+    from jax.experimental import pallas as pl
+
+    tile = _flag(flags_ref, pl.program_id(0) // heads, pl.program_id(1),
+                 pl.program_id(2), num_q, kw["num_kv"])
+    _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, None,
+                     dq_ref, dq_acc, sel_ref=sel_ref, tile_selected=tile,
+                     window=None, alibi=False, **kw)
+
+
+def _sparse_dkv_kernel(flags_ref, k_ref, v_ref, q_ref, do_ref, lse_ref,
+                       delta_ref, sel_ref, dk_ref, dv_ref, dk_acc, dv_acc,
+                       *, heads, num_kv, **kw):
+    from jax.experimental import pallas as pl
+
+    tile = _flag(flags_ref, pl.program_id(0) // heads, pl.program_id(2),
+                 pl.program_id(1), kw["num_q"], num_kv)
+    _flash_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref, None,
+                      dk_ref, dv_ref, dk_acc, dv_acc, sel_ref=sel_ref,
+                      tile_selected=tile, window=None, alibi=False, **kw)
+
+
+def _head_probs_kernel(flags_ref, q_ref, k_ref, lse_ref, sel_ref, p_ref, *,
+                       scale, causal, block_q, block_k, num_q, num_kv):
+    """One (row, q-block, kv-block, head) cell: add this head's
+    ``exp(q k^T scale - lse)`` on the selected pairs to the tile's sum.
+    The head axis is innermost, so the output tile stays in VMEM over
+    it."""
+    from jax.experimental import pallas as pl
+
+    qi, kj, h = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+
+    @pl.when(h == 0)
+    def _init():
+        p_ref[...] = jnp.zeros_like(p_ref)
+
+    run = _and_run(_stream_kv_run(qi, kj, block_q, block_k, causal, None),
+                   _flag(flags_ref, pl.program_id(0), qi, kj, num_q, num_kv))
+
+    @pl.when(run)
+    def _step():
+        q = q_ref[0].astype(jnp.float32)
+        k = k_ref[0].astype(jnp.float32)
+        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        if causal:
+            s = _causal_mask(s, qi, kj, block_q, block_k)
+        s = jnp.where(_selected(sel_ref), s, NEG_INF)
+        p_ref[0] += jnp.exp(s - lse_ref[0])
+
+
+def _sparse_specs(d, block_q, block_k, heads):
+    """BlockSpecs over a ``(b * n, q-block, kv-block)`` grid with one
+    scalar-prefetch operand (index maps take it last)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def spec(shape, index):
+        return pl.BlockSpec(shape, index, memory_space=pltpu.VMEM)
+
+    return {
+        "q": spec((1, block_q, d), lambda h, i, j, f: (h, i, 0)),
+        "kv": spec((1, block_k, d), lambda h, i, j, f: (h, j, 0)),
+        "row": spec((1, block_q, 1), lambda h, i, j, f: (h, i, 0)),
+        "sel": spec((1, block_q, block_k),
+                    lambda h, i, j, f: (h // heads, i, j)),
+    }
+
+
+def _sparse_fwd_pallas(q, k, v, selection, scale, causal, block_q, block_k):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, n, s, d = q.shape
+    q3, k3, v3 = (x.reshape(b * n, s, d) for x in (q, k, v))
+    num_q, num_kv = s // block_q, s // block_k
+    sp = _sparse_specs(d, block_q, block_k, n)
+    out, lse = pl.pallas_call(
+        functools.partial(_sparse_fwd_kernel, heads=n, num_q=num_q,
+                          scale=scale, causal=causal, block_q=block_q,
+                          block_k=block_k, num_kv=num_kv),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b * n, num_q, num_kv),
+            in_specs=[sp["q"], sp["kv"], sp["kv"], sp["sel"]],
+            out_specs=[sp["q"], sp["row"]],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, d), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+            ]),
+        out_shape=[
+            jax.ShapeDtypeStruct((b * n, s, d), q.dtype),
+            jax.ShapeDtypeStruct((b * n, s, 1), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=GATE.interpret,
+        name="sparse_attention_flash_fwd",
+    )(_tile_flags(selection, block_q, block_k), q3, k3, v3, selection)
+    return out.reshape(b, n, s, d), lse.reshape(b, n, s)
+
+
+def _sparse_bwd_pallas(q, k, v, o, lse, do, selection, scale, causal,
+                       block_q, block_k):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, n, s, d = q.shape
+    q3, k3, v3 = (x.reshape(b * n, s, d) for x in (q, k, v))
+    o3, do3 = (x.reshape(b * n, s, d) for x in (o, do))
+    lse3 = lse.reshape(b * n, s, 1)
+    delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32),
+                    axis=-1, keepdims=True)
+    num_q, num_kv = s // block_q, s // block_k
+    flags = _tile_flags(selection, block_q, block_k)
+    sp = _sparse_specs(d, block_q, block_k, n)
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
+    static = dict(scale=scale, causal=causal, block_q=block_q,
+                  block_k=block_k, heads=n)
+
+    dq = pl.pallas_call(
+        functools.partial(_sparse_dq_kernel, num_q=num_q, num_kv=num_kv,
+                          **static),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b * n, num_q, num_kv),
+            in_specs=[sp["q"], sp["kv"], sp["kv"], sp["q"], sp["row"],
+                      sp["row"], sp["sel"]],
+            out_specs=sp["q"],
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b * n, s, d), q.dtype),
+        compiler_params=params, interpret=GATE.interpret,
+        name="sparse_attention_flash_dq",
+    )(flags, q3, k3, v3, do3, lse3, delta, selection)
+
+    # the dkv grid is (b * n, kv-block, q-block): swap the roles of the
+    # two block indices in every spec
+    def swapped(spec):
+        return pl.BlockSpec(spec.block_shape,
+                            lambda h, j, i, f: spec.index_map(h, i, j, f),
+                            memory_space=pltpu.VMEM)
+
+    sw = {name: swapped(spec) for name, spec in sp.items()}
+    dk, dv = pl.pallas_call(
+        functools.partial(_sparse_dkv_kernel, num_q=num_q, num_kv=num_kv,
+                          **static),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b * n, num_kv, num_q),
+            in_specs=[sw["kv"], sw["kv"], sw["q"], sw["q"], sw["row"],
+                      sw["row"], sw["sel"]],
+            out_specs=[sw["kv"], sw["kv"]],
+            scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                            pltpu.VMEM((block_k, d), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((b * n, s, d), k.dtype),
+                   jax.ShapeDtypeStruct((b * n, s, d), v.dtype)],
+        compiler_params=params, interpret=GATE.interpret,
+        name="sparse_attention_flash_dkv",
+    )(flags, k3, v3, q3, do3, lse3, delta, selection)
+
+    rs = lambda x: x.reshape(b, n, s, d)  # noqa: E731
+    return rs(dq), rs(dk), rs(dv)
+
+
+def _head_probs_pallas(q, k, lse, selection, scale, causal, block_q,
+                       block_k):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, n, s, d = q.shape
+    num_q, num_kv = s // block_q, s // block_k
+
+    def spec(shape, index):
+        return pl.BlockSpec(shape, index, memory_space=pltpu.VMEM)
+
+    return pl.pallas_call(
+        functools.partial(_head_probs_kernel, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k, num_q=num_q,
+                          num_kv=num_kv),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b, num_q, num_kv, n),
+            in_specs=[
+                spec((1, block_q, d), lambda r, i, j, h, f: (r * n + h, i, 0)),
+                spec((1, block_k, d), lambda r, i, j, h, f: (r * n + h, j, 0)),
+                spec((1, block_q, 1), lambda r, i, j, h, f: (r * n + h, i, 0)),
+                spec((1, block_q, block_k), lambda r, i, j, h, f: (r, i, j)),
+            ],
+            out_specs=spec((1, block_q, block_k),
+                           lambda r, i, j, h, f: (r, i, j))),
+        out_shape=jax.ShapeDtypeStruct((b, s, s), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
+        interpret=GATE.interpret,
+        name="sparse_attention_head_probs",
+    )(_tile_flags(selection, block_q, block_k), q.reshape(b * n, s, d),
+      k.reshape(b * n, s, d), lse.reshape(b * n, s, 1), selection)
+
+
+def _reference_scores(q, k, scale, causal, window=None, alibi_slopes=None,
+                      selection=None):
+    """Masked float32 scores ``[b, n, s, s]`` of the reference path."""
     s = jnp.einsum("bnqd,bnkd->bnqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     if alibi_slopes is not None:
@@ -463,7 +726,17 @@ def _attention_reference(q, k, v, scale, causal, window=None,
             mask = mask & jnp.triu(jnp.ones((sq, sk), bool),
                                    k=sk - sq - window + 1)
         s = jnp.where(mask, s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
+    if selection is not None:
+        s = jnp.where(selection[:, None] != 0, s, NEG_INF)
+    return s
+
+
+def _attention_reference(q, k, v, scale, causal, window=None,
+                         alibi_slopes=None, selection=None):
+    """Reference einsum attention (fp32 softmax), used for the backward
+    rematerialization and the non-TPU fallback."""
+    p = jax.nn.softmax(_reference_scores(q, k, scale, causal, window,
+                                         alibi_slopes, selection), axis=-1)
     return jnp.einsum("bnqk,bnkd->bnqd", p, v.astype(jnp.float32)).astype(q.dtype)
 
 
@@ -511,8 +784,16 @@ def _check_window(window, causal):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal=True, scale=None,
                     block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
-                    window=None, alibi_slopes=None):
+                    window=None, alibi_slopes=None, selection=None):
     """Flash attention over [batch, heads, seq, head_dim] inputs.
+
+    ``selection``: int8 ``[batch, seq, seq]``, non-zero where a query
+    (second axis) may see a key (third axis), shared by the heads; the
+    softmax runs over the selected keys alone (with ``causal`` also
+    below the diagonal only). Every query has to select a key. Tiles
+    that select nothing are skipped. Not differentiable; composes with
+    neither ``window`` nor ``alibi_slopes``. Without it the kernels are
+    the ones they are without this argument.
 
     ``window``: sliding-window band (key j visible to query i iff
     0 <= i - j < window); blocks fully outside the band are skipped, so
@@ -523,21 +804,42 @@ def flash_attention(q, k, v, causal=True, scale=None,
     convention) — trained-ALiBi variants must not route slope gradients
     through this op."""
     _check_window(window, causal)
+    _check_selection(selection, q, window, alibi_slopes)
     scale, bq, bk = _resolve(q, scale, block_q, block_k)
     if _use_kernel(bq, bk):
+        if selection is not None:
+            return _sparse_fwd_pallas(q, k, v, selection, scale, causal,
+                                      bq, bk)[0]
         return _flash_fwd_pallas(q, k, v, scale, causal, bq, bk,
                                  window, alibi_slopes)[0]
     return _attention_reference(q, k, v, scale, causal, window,
-                                alibi_slopes)
+                                alibi_slopes, selection)
+
+
+def _check_selection(selection, q, window, alibi_slopes):
+    if selection is None:
+        return
+    if window is not None or alibi_slopes is not None:
+        raise ValueError("flash_attention selection composes with neither "
+                         "window nor alibi_slopes")
+    want = (q.shape[0], q.shape[2], q.shape[2])
+    if selection.shape != want or selection.dtype != jnp.int8:
+        raise ValueError(f"flash_attention selection must be int8 {want}, "
+                         f"got {selection.dtype} {selection.shape}")
 
 
 def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k,
-                    window=None, alibi_slopes=None):
+                    window=None, alibi_slopes=None, selection=None):
     _check_window(window, causal)
+    _check_selection(selection, q, window, alibi_slopes)
     scale_, bq, bk = _resolve(q, scale, block_q, block_k)
     if _use_kernel(bq, bk):
-        out, lse = _flash_fwd_pallas(q, k, v, scale_, causal, bq, bk,
-                                     window, alibi_slopes)
+        if selection is not None:
+            out, lse = _sparse_fwd_pallas(q, k, v, selection, scale_, causal,
+                                          bq, bk)
+        else:
+            out, lse = _flash_fwd_pallas(q, k, v, scale_, causal, bq, bk,
+                                         window, alibi_slopes)
         # The two residuals only another run of the kernel can rebuild,
         # named so that a checkpointed layer can keep them (``lse`` in
         # its [b, n, s] form: the kernel's [b*n, s, 1] pads its last
@@ -547,31 +849,89 @@ def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k,
         # kernel's own ``out`` for what follows it, and run the kernel.
         out = checkpoint_name(out, FLASH_RESIDUAL_NAMES[0])
         lse = checkpoint_name(lse, FLASH_RESIDUAL_NAMES[1])
-        return out, (q, k, v, out, lse, alibi_slopes)
+        return out, (q, k, v, out, lse, alibi_slopes, selection)
     return (_attention_reference(q, k, v, scale_, causal, window,
-                                 alibi_slopes),
-            (q, k, v, None, None, alibi_slopes))
+                                 alibi_slopes, selection),
+            (q, k, v, None, None, alibi_slopes, selection))
 
 
 def _flash_bwd_rule(causal, scale, block_q, block_k, window, res, g):
-    q, k, v, out, lse, alibi_slopes = res
+    q, k, v, out, lse, alibi_slopes, selection = res
     scale_, bq, bk = _resolve(q, scale, block_q, block_k)
     none_slope_grad = (None if alibi_slopes is None
                        else jnp.zeros_like(alibi_slopes))
+    if lse is not None and selection is not None:
+        dq, dk, dv = _sparse_bwd_pallas(q, k, v, out, lse, g, selection,
+                                        scale_, causal, bq, bk)
+        return dq, dk, dv, none_slope_grad, None
     if lse is not None:
         dq, dk, dv = _flash_bwd_pallas(q, k, v, out, lse, g, scale_,
                                        causal, bq, bk, window,
                                        alibi_slopes)
-        return dq, dk, dv, none_slope_grad
+        return dq, dk, dv, none_slope_grad, None
     _, vjp = jax.vjp(
         lambda q_, k_, v_: _attention_reference(q_, k_, v_, scale_,
                                                 causal, window,
-                                                alibi_slopes),
+                                                alibi_slopes, selection),
         q, k, v)
-    return (*vjp(g), none_slope_grad)
+    return (*vjp(g), none_slope_grad, None)
 
 
 flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+
+
+def head_summed_probs(q, k, selection, causal=True, scale=None,
+                      block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
+                      lse=None):
+    """``sum_n softmax_{selected u}(q[n, t] . k[n, u] * scale)``, float32
+    ``[batch, seq, seq]``, zero off the selection: what a sparse-attention
+    indexer is trained towards (DeepSeek-V3.2-Exp, the target of its KL
+    loss). ``q``, ``k`` ``[batch, heads, seq, head_dim]``, ``selection``
+    as :func:`flash_attention` takes it, ``lse`` ``[batch, heads, seq]``
+    the forward kernel's log-sum-exp where the caller holds it (without
+    it the forward kernel runs once more for its statistics). No gradient
+    flows through it. On the kernel path
+    (``sparse_attention_head_probs``) the sum is built tile by tile,
+    never ``[heads, seq, seq]`` at once."""
+    q, k = jax.lax.stop_gradient((q, k))
+    _check_selection(selection, q, None, None)
+    scale, bq, bk = _resolve(q, scale, block_q, block_k)
+    if lse is not None or _use_kernel(bq, bk):
+        if lse is None:
+            # v plays no part in the statistics; k stands in for it
+            lse = _sparse_fwd_pallas(q, k, k, selection, scale, causal, bq,
+                                     bk)[1]
+        return _head_probs_pallas(q, k, jax.lax.stop_gradient(lse),
+                                  selection, scale, causal, bq, bk)
+    p = jax.nn.softmax(_reference_scores(q, k, scale, causal,
+                                         selection=selection), axis=-1)
+    return jnp.sum(jnp.where(selection[:, None] != 0, p, 0.0), axis=1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def sparse_attention(q, k, v, selection, causal=True, scale=None,
+                     block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K):
+    """``flash_attention(..., selection=selection)`` and, from the same
+    log-sum-exp, :func:`head_summed_probs`: ``(out, probs)``. The
+    gradient is ``flash_attention``'s; ``probs`` carries none."""
+    return _sparse_fwd_rule(q, k, v, selection, causal, scale, block_q,
+                            block_k)[0]
+
+
+def _sparse_fwd_rule(q, k, v, selection, causal, scale, block_q, block_k):
+    out, res = _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k,
+                               None, None, selection)
+    probs = head_summed_probs(q, k, selection, causal, scale, block_q,
+                              block_k, lse=res[4])
+    return (out, probs), res
+
+
+def _sparse_bwd_rule(causal, scale, block_q, block_k, res, g):
+    return _flash_bwd_rule(causal, scale, block_q, block_k, None, res,
+                           g[0])[:3] + (None,)
+
+
+sparse_attention.defvjp(_sparse_fwd_rule, _sparse_bwd_rule)
 
 
 class FMHA:

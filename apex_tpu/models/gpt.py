@@ -77,7 +77,8 @@ class GPTModel(nn.Module):
 
         # rope consumes positions inside attention (seq-major [s, b]);
         # packed-sequence callers pass per-document position_ids [b, s]
-        rope_positions = (position_ids.transpose(1, 0)
+        # (multi-component positions [c, b, s] go in as [c, s, b])
+        rope_positions = (jnp.swapaxes(position_ids, -1, -2)
                           if (cfg.position_embedding_type == "rope"
                               and position_ids is not None) else None)
         h = ParallelTransformer(cfg, num_layers=self.num_layers,

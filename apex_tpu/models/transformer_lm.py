@@ -217,6 +217,27 @@ class TransformerConfig:
     normalization: str = "layernorm"  # or "rmsnorm"
     # BLOOM applies a layernorm directly after the token embeddings.
     embedding_layernorm: bool = False
+    # False -> no bias on the attention projections (query_key_value and
+    # dense), as the Llama/Qwen families publish them.
+    attention_bias: bool = True
+    # Multi-component rotary positions (Qwen2-VL "mrope_section"): the
+    # rotary frequencies are split into consecutive sections and section
+    # c takes its position from component c of ``position_ids``
+    # ``[len(sections), b, s]``. None -> one component.
+    rope_sections: Optional[tuple] = None
+    # DeepSeek Sparse Attention (DeepSeek-V3.2-Exp): a learned indexer of
+    # ``indexer_heads`` heads of ``indexer_head_dim`` scores every causal
+    # (query, key) pair and each query attends over its ``indexer_topk``
+    # best keys alone (SparseIndexer). None -> dense attention.
+    indexer_heads: Optional[int] = None
+    indexer_head_dim: int = 64
+    indexer_topk: int = 2048
+    # An expert layer that holds ``moe_local_experts`` of the
+    # ``num_moe_experts`` it routes over, those from ``moe_expert_offset``
+    # on, with no 'ep' mesh axis: one rank's share of an expert-parallel
+    # layer, without the exchange (SwitchMLP). None -> all of them.
+    moe_local_experts: Optional[int] = None
+    moe_expert_offset: int = 0
     # Tie the LM head to the word-embedding table (reference
     # parallel_lm_logits ties by default). Off here because the SPMD
     # pipeline harness needs untied heads (first/last stages run the same
@@ -357,6 +378,41 @@ class TransformerConfig:
         if self.context_parallel_algo not in ("ring", "ulysses"):
             raise ValueError(f"unknown context_parallel_algo "
                              f"{self.context_parallel_algo!r}")
+        if self.rope_sections is not None:
+            object.__setattr__(self, "rope_sections",
+                               tuple(int(n) for n in self.rope_sections))
+            if self.position_embedding_type != "rope":
+                raise ValueError("rope_sections requires "
+                                 "position_embedding_type='rope'")
+            rotary = int(self.kv_channels * self.rotary_percent + 1e-6)
+            if 2 * sum(self.rope_sections) != rotary:
+                raise ValueError(
+                    f"rope_sections {self.rope_sections} must add up to "
+                    f"half the rotary width ({rotary})")
+        if self.indexer_heads is not None:
+            if self.indexer_heads < 1 or self.indexer_topk < 1:
+                raise ValueError("indexer_heads and indexer_topk must be "
+                                 ">= 1")
+            if (self.attn_mask_type != AttnMaskType.causal
+                    or self.sliding_window is not None
+                    or self.attn_logit_softcapping is not None
+                    or self.query_pre_attn_scalar is not None
+                    or self.position_embedding_type != "rope"
+                    or self.context_parallel or self.sequence_parallel):
+                raise ValueError(
+                    "the sparse-attention indexer needs causal rope "
+                    "attention without a window, a soft cap, a custom "
+                    "softmax scale or context / sequence parallelism")
+        if self.moe_local_experts is not None:
+            if self.num_moe_experts is None or not (
+                    0 <= self.moe_expert_offset
+                    and self.moe_local_experts >= 1
+                    and self.moe_expert_offset + self.moe_local_experts
+                    <= self.num_moe_experts):
+                raise ValueError(
+                    f"moe_local_experts ({self.moe_local_experts}) from "
+                    f"moe_expert_offset ({self.moe_expert_offset}) must lie "
+                    f"within num_moe_experts ({self.num_moe_experts})")
         if self.num_query_groups is not None:
             if (self.num_query_groups < 1
                     or self.num_attention_heads % self.num_query_groups):
@@ -410,7 +466,8 @@ def _warn_sliding_window_flash_once(window, seq):
 
 def apply_rotary_emb(x, base: float = 10000.0, positions=None,
                      percent: float = 1.0, interleaved: bool = False,
-                     scaling: Optional[RopeScaling] = None):
+                     scaling: Optional[RopeScaling] = None,
+                     sections: Optional[tuple] = None):
     """Rotary position embedding (rotate-half convention) on [s, b, n, d].
 
     ``positions`` is [s] (shared across the batch) or [s, b] (per-sequence
@@ -421,6 +478,9 @@ def apply_rotary_emb(x, base: float = 10000.0, positions=None,
     the leading dims of each head: rotary_ndims = int(d * percent) sets
     the frequency normalization, and 2*ceil(rotary_ndims/2) dims rotate
     (the HF convention — an odd rotary_ndims still pairs up).
+    ``sections`` (Qwen2-VL mrope_section) splits the frequencies into
+    consecutive runs; with it ``positions`` is [len(sections), s] or
+    [len(sections), s, b] and run c is rotated by component c.
     """
     d_full = x.shape[-1]
     if percent < 1.0:
@@ -429,13 +489,22 @@ def apply_rotary_emb(x, base: float = 10000.0, positions=None,
         rot_n = int(d_full * percent + 1e-6)  # HF rotary_ndims (may be odd)
         width = 2 * ((rot_n + 1) // 2)  # dims actually rotated
         out = _rope_core(x[..., :width], base, positions, rot_n,
-                         interleaved, scaling)
+                         interleaved, scaling, sections)
         return jnp.concatenate([out, x[..., width:]], axis=-1)
-    return _rope_core(x, base, positions, d_full, interleaved, scaling)
+    return _rope_core(x, base, positions, d_full, interleaved, scaling,
+                      sections)
+
+
+def _has_components(positions, sections, s):
+    """Are ``positions`` multi-component (``[len(sections), s(, b)]``)
+    rather than ``[s]`` or ``[s, b]``?"""
+    return (sections is not None and positions is not None
+            and positions.ndim > 1
+            and positions.shape[0] == len(sections) != s)
 
 
 def _rope_core(x, base, positions, freq_dim, interleaved=False,
-               scaling=None):
+               scaling=None, sections=None):
     s, _, _, d = x.shape
     if positions is None:
         positions = jnp.arange(s)
@@ -444,6 +513,13 @@ def _rope_core(x, base, positions, freq_dim, interleaved=False,
     if scaling is not None:
         inv = _scale_rope_freqs(inv, scaling)
     freqs = positions[..., None].astype(jnp.float32) * inv  # [s(,b), d/2]
+    if _has_components(positions, sections, s):
+        # [c, s(,b), d/2]: frequency i keeps the component of its section
+        import numpy as np
+
+        component = np.repeat(np.arange(len(sections)), sections)
+        freqs = sum(jnp.where(component == c, freqs[c], 0.0)
+                    for c in range(len(sections)))
     if freqs.ndim == 2:  # [s, d/2] -> broadcast over batch and heads
         freqs = freqs[:, None, :]
     cos = jnp.cos(freqs)[:, :, None, :]
@@ -501,6 +577,166 @@ def _make_norm(cfg, name):
     return FusedLayerNorm(normalized_shape=cfg.hidden_size,
                           eps=cfg.layernorm_epsilon,
                           param_dtype=jnp.float32, name=name)
+
+
+INDEXER_CHUNK = 512   # queries scored at a time (DSA's q_chunk_size)
+
+
+def topk_selection(scores, topk: int):
+    """``[b, s, s]`` int8: for each query ``t`` the ``min(t + 1, topk)``
+    keys ``u <= t`` of largest ``scores[b, t, u]``. By a threshold a row,
+    the ``topk``-th largest score found by bisection over the float32's
+    bits (32 counting passes, no sort, no gather); scores that tie with
+    the threshold are all kept."""
+    b, s, _ = scores.shape
+    t = jnp.arange(s)
+    causal = t[None, :] <= t[:, None]
+    want = jnp.minimum(t + 1, topk)
+
+    def keys():
+        # float32 order as uint32 order; 0 stands for "not causal"
+        bits = jax.lax.bitcast_convert_type(scores, jnp.uint32)
+        flipped = jnp.where(bits >> 31 == 1, ~bits,
+                            bits | jnp.uint32(0x80000000))
+        return jnp.where(causal, flipped, jnp.uint32(0))
+
+    def body(i, found):
+        cand = found | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        count = jnp.sum(keys() >= cand[..., None], axis=-1, dtype=jnp.int32)
+        return jnp.where(count >= want, cand, found)
+
+    threshold = jax.lax.fori_loop(0, 32, body,
+                                  jnp.zeros((b, s), jnp.uint32))
+    return ((keys() >= threshold[..., None]) & causal).astype(jnp.int8)
+
+
+class SparseIndexer(nn.Module):
+    """DeepSeek Sparse Attention's lightning indexer (DeepSeek-V3.2-Exp
+    technical report, eq. 1-2, and its ``inference/model.py`` ``Indexer``).
+
+    From the layer's normed input, detached: ``qI = x WqI`` (``heads``
+    of ``head_dim``), one key head ``kI = LayerNorm(x WkI)``, head
+    weights ``w = x Ww``; rotary on the whole of ``qI`` and ``kI`` from
+    the first position component;
+    ``I[t, u] = sum_j w[t, j] * heads^-1/2 * head_dim^-1/2 *
+    relu(qI[t, j] . kI[u])``. ``__call__`` -> ``(I [b, s, s] float32,
+    selection [b, s, s] int8)``, the selection being each query's
+    ``indexer_topk`` best causal keys (:func:`topk_selection`). Scores
+    are computed one sequence and ``INDEXER_CHUNK`` queries at a time, so
+    ``[heads, s, s]`` is never live; selection and loss run a sequence at
+    a time too.
+
+    ``loss`` sows ``indexer_loss`` into ``moe_losses``:
+    ``mean_t KL(p_t || softmax_{selected u} I[t, u])`` with ``p_t`` the
+    attention's head-summed probabilities over the selection,
+    L1-normalised and detached (V3.2's sparse training stage). The
+    indexer's parameters get gradient from it alone, and nothing else
+    does: its input and its target are detached, and the selection
+    carries no gradient.
+    """
+
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, hidden_states, position_ids=None):
+        from apex_tpu.telemetry.registry import get_registry
+
+        cfg = self.config
+        heads, dim = cfg.indexer_heads, cfg.indexer_head_dim
+        s, b, h = hidden_states.shape
+        x = jax.lax.stop_gradient(hidden_states).astype(cfg.compute_dtype)
+        init = nn.initializers.normal(0.02)
+
+        def weight(name, width):
+            return self.param(name, init, (h, width),
+                              cfg.params_dtype).astype(cfg.compute_dtype)
+
+        with jax.named_scope("indexer/project"):
+            q = jnp.dot(x, weight("wq", heads * dim)).reshape(s, b, heads,
+                                                               dim)
+            k = jnp.dot(x, weight("wk", dim),
+                        preferred_element_type=jnp.float32)
+            k = FusedLayerNorm(normalized_shape=dim,
+                               eps=cfg.layernorm_epsilon,
+                               param_dtype=jnp.float32, name="k_norm")(k)
+            w = jnp.dot(x, weight("weights_proj", heads),
+                        preferred_element_type=jnp.float32)
+            w = w * (heads ** -0.5 * dim ** -0.5)
+            if _has_components(position_ids, cfg.rope_sections, s):
+                position_ids = position_ids[0]
+            q = apply_rotary_emb(q, cfg.rotary_base, position_ids)
+            k = apply_rotary_emb(
+                k.astype(cfg.compute_dtype)[:, :, None, :], cfg.rotary_base,
+                position_ids)[:, :, 0, :]
+
+        chunk = INDEXER_CHUNK if s % INDEXER_CHUNK == 0 else s
+
+        @jax.checkpoint
+        def chunk_scores(qc, wc, kr):
+            # [c, heads, dim] x [s, dim] -> [heads, c, s]
+            logits = jnp.einsum("chd,ud->hcu", qc, kr,
+                                preferred_element_type=jnp.float32)
+            return jnp.einsum("hcu,ch->cu", jax.nn.relu(logits), wc)
+
+        def row_scores(qwk):
+            qr, wr, kr = qwk        # one sequence: [s, heads, dim], ...
+            return jax.lax.map(
+                lambda qw: chunk_scores(*qw, kr),
+                (qr.reshape(s // chunk, chunk, heads, dim),
+                 wr.reshape(s // chunk, chunk, heads))).reshape(s, s)
+
+        # a sequence, and within it a chunk of queries, at a time
+        with jax.named_scope("indexer/scores"):
+            scores = jax.lax.map(row_scores, (q.transpose(1, 0, 2, 3),
+                                              w.transpose(1, 0, 2),
+                                              k.transpose(1, 0, 2)))
+        with jax.named_scope("indexer/select"):
+            selection = jax.lax.map(
+                lambda row: topk_selection(row[None], cfg.indexer_topk)[0],
+                jax.lax.stop_gradient(scores))
+        get_registry().gauge("attention/selected_keys_max").set(
+            min(cfg.indexer_topk, s))
+        return scores, selection
+
+    def loss(self, scores, selection, probs):
+        """Sow ``KL(target || softmax over the selection of scores)``,
+        mean over queries and batch; ``probs`` ``[b, s, s]`` are the
+        attention's head-summed probabilities (zero off the selection)."""
+        @jax.checkpoint
+        def row_loss(row):
+            score, chosen, prob = row           # one sequence, [s, s] each
+            chosen = chosen != 0
+            target = prob / jnp.maximum(
+                jnp.sum(prob, axis=-1, keepdims=True), 1e-30)
+            logq = jax.nn.log_softmax(
+                jnp.where(chosen, score, -1e30), axis=-1)
+            live = chosen & (target > 0)
+            kl = jnp.where(
+                live,
+                target * (jnp.log(jnp.where(live, target, 1.0)) - logq), 0.0)
+            return jnp.mean(jnp.sum(kl, axis=-1))
+
+        with jax.named_scope("indexer/loss"):
+            value = jnp.mean(jax.lax.map(
+                row_loss, (scores, selection, jax.lax.stop_gradient(probs))))
+        self.sow("moe_losses", "indexer_loss", value)
+        return value
+
+
+def indexer_loss_from_variables(variables):
+    """The sum over layers of the sparse-attention indexers' losses, from
+    the ``moe_losses`` collection of ``model.apply(...,
+    mutable=["moe_losses"])`` (beside
+    ``transformer.moe.moe_loss_from_variables``)."""
+    import flax
+
+    losses = variables.get("moe_losses", variables)
+    total = jnp.zeros((), jnp.float32)
+    for path, val in flax.traverse_util.flatten_dict(dict(losses)).items():
+        if path[-1] == "indexer_loss":
+            total = total + jnp.sum(
+                sum(val) if isinstance(val, (tuple, list)) else val)
+    return total
 
 
 class ParallelAttention(nn.Module):
@@ -561,12 +797,19 @@ class ParallelAttention(nn.Module):
         if self.decode and cfg.sequence_parallel:
             raise ValueError("decode mode does not compose with "
                              "sequence parallelism")
+        if cfg.indexer_heads is not None and (
+                self.decode or attention_mask is not None or tp > 1):
+            raise ValueError(
+                "sparse attention (indexer_heads) supports training "
+                "without an explicit attention_mask on one "
+                "tensor-parallel rank; there is no decode path")
 
         if cfg.query_groups == cfg.num_attention_heads:
             qkv = ColumnParallelLinear(
                 input_size=cfg.hidden_size,
                 output_size=3 * cfg.num_attention_heads * kv,
-                gather_output=False, bias=True, params_dtype=cfg.params_dtype,
+                gather_output=False, bias=cfg.attention_bias,
+                params_dtype=cfg.params_dtype,
                 sequence_parallel_enabled=cfg.sequence_parallel,
                 name="query_key_value")(x)
             # [s, b, 3*h/tp] -> [s, b, np_local, 3*kv]
@@ -586,7 +829,8 @@ class ParallelAttention(nn.Module):
                 input_size=cfg.hidden_size,
                 output_size=(cfg.num_attention_heads
                              + 2 * cfg.query_groups) * kv,
-                gather_output=False, bias=True, params_dtype=cfg.params_dtype,
+                gather_output=False, bias=cfg.attention_bias,
+                params_dtype=cfg.params_dtype,
                 sequence_parallel_enabled=cfg.sequence_parallel,
                 name="query_key_value")(x)
             seq_full = proj.shape[0]
@@ -629,11 +873,11 @@ class ParallelAttention(nn.Module):
             q = apply_rotary_emb(q, rope_base, position_ids,
                                  cfg.rotary_percent,
                                  cfg.rotary_interleaved,
-                                 rope_scale)
+                                 rope_scale, cfg.rope_sections)
             k = apply_rotary_emb(k, rope_base, position_ids,
                                  cfg.rotary_percent,
                                  cfg.rotary_interleaved,
-                                 rope_scale)
+                                 rope_scale, cfg.rope_sections)
         if k.shape[2] != np_local:
             # broadcast each K/V group to its query heads
             rep = np_local // k.shape[2]
@@ -645,6 +889,22 @@ class ParallelAttention(nn.Module):
         win = (layer_win
                if (layer_win is not None and layer_win < seq_full)
                else None)
+
+        if cfg.indexer_heads is not None:
+            from apex_tpu.contrib.fmha import sparse_attention
+
+            indexer = SparseIndexer(cfg, name="indexer")
+            scores, selection = indexer(hidden_states, position_ids)
+            # one path: the kernels where they run, their oracle elsewhere
+            ctx, probs = sparse_attention(
+                q.transpose(1, 2, 0, 3).astype(cfg.compute_dtype),
+                k.transpose(1, 2, 0, 3).astype(cfg.compute_dtype),
+                v.transpose(1, 2, 0, 3).astype(cfg.compute_dtype),
+                selection, True)
+            indexer.loss(scores, selection, probs)
+            ctx = ctx.transpose(2, 0, 1, 3).reshape(seq_full, b,
+                                                    np_local * kv)
+            return self._output_proj(cfg, ctx)
 
         # flash handles the built-in causal/full patterns and the
         # sliding-window band (kernel block-skip); an explicit
@@ -755,7 +1015,8 @@ class ParallelAttention(nn.Module):
         return RowParallelLinear(
             input_size=cfg.num_attention_heads * cfg.kv_channels,
             output_size=cfg.hidden_size,
-            input_is_parallel=True, bias=True, params_dtype=cfg.params_dtype,
+            input_is_parallel=True, bias=cfg.attention_bias,
+            params_dtype=cfg.params_dtype,
             sequence_parallel_enabled=(cfg.sequence_parallel
                                        and not self.decode),
             name="dense")(ctx.astype(cfg.compute_dtype))
@@ -1055,6 +1316,8 @@ class ParallelTransformerLayer(nn.Module):
                 activation=cfg.activation,
                 params_dtype=cfg.params_dtype,
                 compute_dtype=cfg.compute_dtype,
+                local_experts=cfg.moe_local_experts,
+                expert_offset=cfg.moe_expert_offset,
                 sequence_parallel_enabled=cfg.sequence_parallel, name="mlp")
         else:
             mlp = ParallelMLP(cfg, name="mlp")

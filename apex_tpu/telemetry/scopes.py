@@ -41,6 +41,12 @@ _BLOCKS = {
                   "post_mlp_norm"),
     "attention": ("self_attention", "attention_mask"),
     "mlp": ("mlp",),
+    # inside attention and the mlp, and named for themselves: the
+    # sparse-attention indexer (flax module ``indexer``, scopes
+    # ``indexer/{project,scores,select,loss}``) and the expert layer's
+    # routed path (scopes ``moe/{router,dispatch,experts,combine}``)
+    "indexer": ("indexer",),
+    "moe": ("moe",),
     "head": ("head", "word_embeddings.attend", "lm_dense", "lm_layernorm",
              "lm_head", "lm_head_bias", "pooler", "binary_head"),
     "loss": ("loss",),
@@ -53,6 +59,14 @@ _BLOCKS = {
 }
 _BLOCK_OF = {name: block for block, names in _BLOCKS.items()
              for name in names}
+# blocks that sit inside another block's module and take its time out of it
+_INNER = ("indexer", "moe")
+# XLA replaces ``lax.ragged_dot`` by a grouped-matmul kernel of its own and
+# names it, and the call that prepares its group metadata, by what it is
+# and not by the scope it was traced under (``op_name="ragged-dot-none"``):
+# the expert layer is the package's one user, so these are its block; the
+# phase is lost with the scope and reads ``update``
+_RAGGED_DOT = re.compile(r"ragged-dot(-\w+)*")
 # parallel/distributed.py and parallel/pipeline.py number or suffix theirs
 _COLLECTIVE = re.compile(r"ddp_allreduce_bucket_\d+|pp_\w+")
 # inside the model but in none of its blocks: what a layer or the model
@@ -86,12 +100,14 @@ def classify(scope: str) -> tuple:
     ``block`` is set by the first component of the path that the table
     knows: ``embedding``, ``layernorm``, ``attention`` (``attention/qkv``,
     ``attention/kernel``, ``attention/dense`` where a later component
-    says which part), ``mlp``, ``head``, ``loss``, ``amp``, ``optimizer``,
-    ``collective``; ``residual`` for a scope inside the model that names
-    none of them; ``None`` for any other. ``phase`` is ``recompute``
-    under ``jax.checkpoint``'s ``rematted_computation``, else ``backward``
-    under a transposed jvp, ``forward`` under a jvp, and ``update``
-    outside differentiation."""
+    says which part; ``indexer`` where a later component is the
+    sparse-attention indexer), ``mlp`` (``moe`` where a later component
+    is one of the expert layer's scopes), ``head``, ``loss``, ``amp``,
+    ``optimizer``, ``collective``; ``residual`` for a scope inside the
+    model that names none of them; ``None`` for any other. ``phase`` is
+    ``recompute`` under ``jax.checkpoint``'s ``rematted_computation``,
+    else ``backward`` under a transposed jvp, ``forward`` under a jvp,
+    and ``update`` outside differentiation."""
     if "rematted_computation" in scope:
         phase = "recompute"
     elif "transpose(jvp(" in scope:
@@ -104,7 +120,13 @@ def classify(scope: str) -> tuple:
     for i, part in enumerate(parts):
         if _COLLECTIVE.fullmatch(part):
             return "collective", phase
+        if _RAGGED_DOT.fullmatch(part):
+            return "moe", phase
         block = _BLOCK_OF.get(part)
+        if block in ("attention", "mlp"):
+            for sub in parts[i + 1:]:
+                if _BLOCK_OF.get(sub) in _INNER:
+                    return _BLOCK_OF[sub], phase
         if block == "attention":
             for sub in parts[i + 1:]:
                 if sub in _ATTENTION_PARTS:

@@ -18,7 +18,7 @@ and must be grad-synced over the full dp x ep set — see
 ``parallel_state.get_data_parallel_axes`` and ``is_expert_param``.
 """
 
-from typing import Any
+from typing import Any, Optional
 
 import flax.linen as nn
 import jax
@@ -294,6 +294,21 @@ class SwitchMLP(nn.Module):
       actually drop tokens (capacity < T — preserving drop semantics),
       else "ragged". expert_choice routing always uses its dense path
       (C is small by design there).
+
+    ``local_experts`` is the held-share mode: the layer holds that many
+    of the ``num_experts`` it routes over, those from ``expert_offset``
+    on, and no 'ep' mesh axis exists: one rank's share of an
+    expert-parallel layer, run without its exchange. The router, its
+    top-k, the gates' renormalisation and its losses are over all
+    ``num_experts``; assignments to experts that are not held are dropped
+    before the gather, and the output is the held experts' part of the
+    routed sum (the shares of all ranks add up to the uncut layer's
+    output). Ragged path only. The held assignments are gathered into
+    ``capacity_factor`` times their expected number of rows (a static
+    shape); what would not fit is dropped and shows in
+    ``dropped_fraction``. Sows, beside the losses, ``held_assignments``
+    (the share of the k*T assignments that fell on held experts) and
+    ``held_load_max_over_mean``.
     """
 
     hidden_size: int
@@ -315,11 +330,20 @@ class SwitchMLP(nn.Module):
     # the caller didn't pass mutable=["moe_losses"]; set False for
     # inference/eval modules where dropping them is intended.
     warn_on_dropped_losses: bool = True
+    local_experts: Optional[int] = None
+    expert_offset: int = 0
 
     def _resolve_dispatch(self, ep: int, capacity: int, num_tokens: int):
         mode = self.dispatch_mode
         if mode not in ("auto", "einsum", "scatter", "ragged"):
             raise ValueError(f"unknown dispatch_mode {mode!r}")
+        if self.local_experts is not None:
+            if (mode not in ("auto", "ragged") or ep > 1
+                    or self.router_type != "top_k"):
+                raise ValueError(
+                    "local_experts (the held-share mode) runs the ragged "
+                    "path of the top_k router without an 'ep' mesh axis")
+            return "ragged"
         if self.router_type != "top_k":
             if mode in ("scatter", "ragged"):
                 raise ValueError(
@@ -339,7 +363,8 @@ class SwitchMLP(nn.Module):
     @nn.compact
     def __call__(self, hidden_states):
         ep = get_expert_model_parallel_world_size()
-        n_local = divide(self.num_experts, ep)
+        n_local = (divide(self.num_experts, ep) if self.local_experts is None
+                   else self.local_experts)
 
         if self.sequence_parallel_enabled:
             # Full sequence on every tp rank; routing is deterministic so
@@ -358,14 +383,16 @@ class SwitchMLP(nn.Module):
         capacity = expert_capacity(num_tokens, self.num_experts, self.top_k,
                                    self.capacity_factor)
         mode = self._resolve_dispatch(ep, capacity, num_tokens)
-        routing = TopKRouter(
+        router = TopKRouter(
             num_experts=self.num_experts, top_k=self.top_k,
             capacity_factor=self.capacity_factor, jitter_eps=self.jitter_eps,
             router_type=self.router_type,
             normalize_topk=self.normalize_topk,
             routing_format={"einsum": "dense", "scatter": "sorted",
                             "ragged": "sorted_dropless"}[mode],
-            params_dtype=self.params_dtype, name="router")(tokens)
+            params_dtype=self.params_dtype, name="router")
+        with jax.named_scope("moe/router"):
+            routing = router(tokens)
         sown = self.sow("moe_losses", "aux_loss", routing.aux_loss)
         self.sow("moe_losses", "z_loss", routing.z_loss)
         # observability, not a loss: moe_loss_from_variables sums only the
@@ -387,7 +414,19 @@ class SwitchMLP(nn.Module):
         x = tokens.astype(self.compute_dtype)
         hidden = orig_shape[-1]
 
-        if mode == "ragged":
+        if self.local_experts is not None:
+            token_idx, expert_idx, gate, counts = self._held_share(
+                routing, num_tokens)
+            with jax.named_scope("moe/dispatch"):
+                sorted_x = x[token_idx] * (gate > 0)[:, None].astype(x.dtype)
+            with jax.named_scope("moe/experts"):
+                y = experts(sorted_x, group_sizes=counts,
+                            expert_idx=expert_idx)
+            with jax.named_scope("moe/combine"):
+                contrib = y.astype(jnp.float32) * gate[:, None]
+                out = jnp.zeros((num_tokens, hidden), jnp.float32)
+                out = out.at[token_idx].add(contrib)
+        elif mode == "ragged":
             # Zero-padding dropless path: gather rows into expert-sorted
             # order (grad = scatter-add, the gather's XLA transpose), run
             # the grouped matmuls, weight by gate, scatter-add back.
@@ -461,3 +500,38 @@ class SwitchMLP(nn.Module):
         if self.sequence_parallel_enabled:
             out = scatter_to_sequence_parallel_region(out)
         return out
+
+    def _held_share(self, routing, num_tokens):
+        """The held experts' rows of a dropless sorted routing, in a
+        static number of rows: -> (token_idx, expert_idx local, gate,
+        counts), each over ``rows`` rows but ``counts`` ``[local_experts]``.
+        The sorted order puts the held experts' assignments in one run;
+        rows past its end carry gate 0 and join the last group, where a
+        zeroed input adds nothing to output or gradients."""
+        from apex_tpu.telemetry.registry import get_registry
+
+        n, off, E = self.local_experts, self.expert_offset, self.num_experts
+        N = self.top_k * num_tokens
+        rows = min(N, -(-int(N * n / E * self.capacity_factor) // 8) * 8)
+        get_registry().gauge("moe/held_experts").set(n)
+        get_registry().gauge("moe/published_experts").set(E)
+        ends = jnp.cumsum(routing.counts)
+        start = ends[off] - routing.counts[off]
+        held = routing.counts[off:off + n]
+        # group ends within the gather, the overflow cut off the last ones
+        local_ends = jnp.minimum(ends[off:off + n] - start, rows)
+        counts = jnp.diff(local_ends, prepend=0)
+        kept = local_ends[-1]
+        counts = counts.at[n - 1].add(rows - kept)
+        row = jnp.arange(rows, dtype=jnp.int32)
+        source = jnp.minimum(start + row, N - 1)
+        valid = row < kept
+        total = jnp.sum(held)
+        self.sow("moe_losses", "held_assignments", total / N)
+        self.sow("moe_losses", "held_load_max_over_mean",
+                 jnp.max(held) * n / jnp.maximum(total, 1))
+        self.sow("moe_losses", "held_dropped_fraction",
+                 jax.lax.stop_gradient(1.0 - kept / jnp.maximum(total, 1)))
+        return (routing.token_idx[source],
+                jnp.clip(routing.expert_idx[source] - off, 0, n - 1),
+                jnp.where(valid, routing.gate[source], 0.0), counts)

@@ -369,6 +369,41 @@ def kernel_cases():
                 randn(i, (2, g * rep, T, 64)) for i in range(3)),
             TOL_MXU)
 
+    # -- the same kernels with a selection operand (sparse attention: each
+    # query its 512 best of the causal keys by a seeded score, one tile
+    # with nothing selected) and the head-summed probabilities, at the
+    # head size 128 the sparse configurations run
+    def selection_args():
+        s = 2048
+        score = jax.random.normal(jax.random.PRNGKey(20), (1, s, s))
+        causal = jnp.tril(jnp.ones((s, s), bool))
+        score = jnp.where(causal, score, -jnp.inf)
+        kth = jnp.sort(score, axis=-1)[..., -512][..., None]
+        sel = (score >= kth) & causal
+        sel = sel.at[:, 1536:, :512].set(False)
+        return (*(randn(i, (1, 8, s, 128)) for i in (21, 22, 23)),
+                sel.astype(jnp.int8))
+
+    def sparse(attend):
+        def run(q, k, v, sel):
+            (out, probs), vjp = jax.vjp(
+                lambda q, k, v: attend(q, k, v, sel), q, k, v)
+            return out, probs, vjp((out, jnp.zeros_like(probs)))
+        return run
+
+    def sparse_oracle(q, k, v, sel):
+        scale = q.shape[-1] ** -0.5
+        p = jax.nn.softmax(fmha._reference_scores(
+            q, k, scale, True, selection=sel), axis=-1)
+        return (fmha._attention_reference(q, k, v, scale, True,
+                                          selection=sel),
+                jnp.sum(jnp.where(sel[:, None] != 0, p, 0.0), axis=1))
+
+    add("flash_attention selection fwd+bwd+probs heads=8 seq=2048 d=128",
+        sparse(lambda q, k, v, sel: fmha.sparse_attention(q, k, v, sel,
+                                                          True)),
+        sparse(sparse_oracle), selection_args, TOL_MXU)
+
     # -- gqa_decode at three fill levels; GQA with window + soft cap -------
     for (g, rep, T), kw in zip(layouts,
                                ({}, dict(window=1000, softcap=30.0))):

@@ -92,6 +92,10 @@ def test_every_registered_kernel_is_covered():
 # the first words of the case that runs them: a profile and the compiled
 # text name each Mosaic call after its kernel, not after whoever called it.
 KERNEL_NAMES = {
+    "flash_attention selection": ("sparse_attention_flash_fwd",
+                                  "sparse_attention_flash_dq",
+                                  "sparse_attention_flash_dkv",
+                                  "sparse_attention_head_probs"),
     "flash_attention": ("self_attention_flash_fwd",
                         "self_attention_flash_dq",
                         "self_attention_flash_dkv"),
