@@ -6,6 +6,11 @@ apex/contrib/multihead_attn (CUTLASS-based fused attention). The TPU
 version is a general flash-attention: online-softmax over KV blocks, fp32
 accumulators, causal or full, any seq multiple of the block size.
 
+Two entries to one set of kernel bodies: ``flash_attention`` over
+``[b, n, s, d]`` and ``flash_attention_bsnd`` over ``[b, s, n * d]``, the
+layout the projections around attention write and read (heads folded into
+the lanes; a grid cell takes the heads of one 128-lane column).
+
 Forward and backward are Pallas kernels over 3-D grids (batch*heads x
 outer-blocks x streamed-blocks, innermost/"arbitrary"): K/V (forward, dq)
 or Q/dO (dk/dv) stream through VMEM one tile at a time with fp32 scratch
@@ -46,12 +51,14 @@ NEG_INF = -1e30
 FLASH_RESIDUAL_NAMES = ("flash_out", "flash_lse")
 
 
-def _use_kernel(bq, bk):
-    """Record this call's dispatch path (trace time) and say whether
-    the Pallas kernels run: gate on AND a block divides the sequence."""
+def _use_kernel(bq, bk, names=("flash_attention",)):
+    """Record this call's dispatch path (trace time) under ``names`` and
+    say whether the Pallas kernels run: gate on AND a block divides the
+    sequence."""
     path = dispatch_path(GATE) if bq is not None and bk is not None \
         else "oracle"
-    get_kernel_registry().dispatch("flash_attention", path)
+    for name in names:
+        get_kernel_registry().dispatch(name, path)
     return path != "oracle"
 
 
@@ -74,8 +81,8 @@ def _alibi_bias(slopes_ref, kj, block_q, block_k):
     constants cancel in softmax, so slope * absolute-key-index is the
     whole bias (HF build_alibi_tensor form)."""
     k_ids = kj * block_k + jax.lax.broadcasted_iota(
-        jnp.float32, (block_q, block_k), 1)
-    return slopes_ref[0, 0, 0] * k_ids
+        jnp.int32, (block_q, block_k), 1)    # Mosaic's iota is integer
+    return slopes_ref[0, 0, 0] * k_ids.astype(jnp.float32)
 
 
 def _stream_kv_run(qi, kj, block_q, block_k, causal, window):
@@ -108,6 +115,34 @@ def _window_first_kv_block(qi, block_q, block_k, window):
 def _window_last_q_pos(kj, block_k, window):
     """Largest query index that can see any key in kv block kj."""
     return (kj + 1) * block_k - 1 + window - 1
+
+
+def _fetched_kv_block(qi, kj, block_q, block_k, causal, window):
+    """The kv block cell (qi, kj) of the forward / dq grid fetches. Causal:
+    masked blocks are clamped into the contributing range; Pallas skips
+    the DMA when a block index repeats, so fully-above-diagonal (and,
+    windowed, fully-below-band) K/V tiles are never fetched."""
+    if not causal:
+        return kj
+    last = ((qi + 1) * block_q - 1) // block_k
+    kj = jnp.minimum(kj, last)
+    if window is not None:
+        kj = jnp.maximum(kj, _window_first_kv_block(
+            qi, block_q, block_k, window))
+    return kj
+
+
+def _fetched_q_block(kj, qi, block_q, block_k, causal, window):
+    """The q block cell (kj, qi) of the dkv grid fetches (as
+    :func:`_fetched_kv_block`, for the streamed q side)."""
+    if not causal:
+        return qi
+    first = (kj * block_k) // block_q
+    qi = jnp.maximum(qi, first)
+    if window is not None:
+        qi = jnp.minimum(
+            qi, _window_last_q_pos(kj, block_k, window) // block_q)
+    return qi
 
 
 def _selected(sel_ref):
@@ -216,20 +251,9 @@ def _flash_fwd_pallas(q, k, v, scale, causal, block_q, block_k,
         block_k=block_k, num_kv=num_kv, window=window,
         alibi=alibi_slopes is not None)
 
-    if causal:
-        # Clamp masked kv blocks into the contributing range: Pallas
-        # skips the DMA when a block index repeats, so fully-above-diagonal
-        # (and, windowed, fully-below-band) K/V tiles are never fetched.
-        def kv_index(h, i, j):
-            last = ((i + 1) * block_q - 1) // block_k
-            j = jnp.minimum(j, last)
-            if window is not None:
-                j = jnp.maximum(j, _window_first_kv_block(
-                    i, block_q, block_k, window))
-            return (h, j, 0)
-    else:
-        def kv_index(h, i, j):
-            return (h, j, 0)
+    def kv_index(h, i, j):
+        return (h, _fetched_kv_block(i, j, block_q, block_k, causal,
+                                     window), 0)
 
     out, lse = pl.pallas_call(
         kernel,
@@ -379,25 +403,13 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, scale, causal, block_q,
     slopes3 = _slopes_input(alibi_slopes, b, n)
     alibi = alibi_slopes is not None
 
-    if causal:
-        def kv_index(h, i, j):
-            last = ((i + 1) * block_q - 1) // block_k
-            j = jnp.minimum(j, last)
-            if window is not None:
-                j = jnp.maximum(j, _window_first_kv_block(
-                    i, block_q, block_k, window))
-            return (h, j, 0)
+    def kv_index(h, i, j):
+        return (h, _fetched_kv_block(i, j, block_q, block_k, causal,
+                                     window), 0)
 
-        def q_index_for_kv(h, j, i):
-            first = (j * block_k) // block_q
-            i = jnp.maximum(i, first)
-            if window is not None:
-                i = jnp.minimum(
-                    i, _window_last_q_pos(j, block_k, window) // block_q)
-            return (h, i, 0)
-    else:
-        kv_index = lambda h, i, j: (h, j, 0)            # noqa: E731
-        q_index_for_kv = lambda h, j, i: (h, i, 0)      # noqa: E731
+    def q_index_for_kv(h, j, i):
+        return (h, _fetched_q_block(j, i, block_q, block_k, causal,
+                                    window), 0)
 
     dq = pl.pallas_call(
         functools.partial(_flash_dq_kernel, scale=scale, causal=causal,
@@ -473,6 +485,284 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, scale, causal, block_q,
 
     rs = lambda x: x.reshape(b, n, s, d)  # noqa: E731
     return rs(dq), rs(dk), rs(dv)
+
+
+# ------------------------------------------- batch-major, heads in lanes
+#
+# The same three kernel bodies over q, k, v, ``out`` and their gradients
+# as ``[b, s, n * d]``: the layout the qkv projection writes and the
+# output projection reads, heads folded into the lane dimension, so that
+# no transposed copy stands around the kernels and a head of 64 pads
+# nothing to 128 lanes. A grid cell takes the heads of one 128-lane
+# column block (two at ``d`` = 64, one at 128 or 256) and runs the body
+# once a head on lane slices of its blocks. ``lse`` and delta are
+# ``[b, n / c, c, s]`` (``c`` heads a cell), the sequence in lanes: a
+# ``[.., s, 1]`` operand pads every number to 128 lanes in HBM. The
+# bodies work on columns, so the cells turn a block's rows into columns
+# in VMEM and back. Delta is formed in the dq kernel, which holds dO and
+# is handed ``out``, and goes to the dkv kernel as rows: reduced by XLA
+# over 64 of 128 lanes it costs two passes over a float32 product.
+
+def _heads_per_cell(heads, d):
+    """Heads a grid cell of the batch-major kernels takes so that its
+    blocks are whole 128-lane columns; ``None`` where ``[b, s, n * d]``
+    cannot be cut so."""
+    if d % 128 == 0:
+        return 1
+    if 128 % d == 0 and heads % (128 // d) == 0:
+        return 128 // d
+    return None
+
+
+def fits_batch_major(heads, head_dim):
+    """Does :func:`flash_attention_bsnd` take ``heads`` heads of
+    ``head_dim``?"""
+    return _heads_per_cell(heads, head_dim) is not None
+
+
+class _HeadLanes:
+    """Head ``h`` of a cell's ``[1, block, c * d]`` block, read and
+    written as the kernel bodies do it (``ref[0]``, ``ref[0] = x``,
+    ``ref.dtype``). Not a ``ref.at[...]`` view: Mosaic takes a static
+    lane slice of 64 in a load or a store, not in a view of the ref."""
+
+    def __init__(self, ref, h, d):
+        self.ref, self.lanes, self.dtype = ref, slice(h * d, (h + 1) * d), \
+            ref.dtype
+
+    def __getitem__(self, row):
+        return self.ref[row, :, self.lanes]
+
+    def __setitem__(self, row, value):
+        self.ref[row, :, self.lanes] = value
+
+
+def _turned(x):
+    """A ``[n, 1]`` column as a ``[1, n]`` row, or the row as the column
+    (in VMEM; the bodies hold ``lse`` and delta as columns)."""
+    return x.T
+
+
+class _HeadSlope:
+    """Head ``h``'s slope of a cell's ``[c, 1, 1]`` block, as the bodies
+    read it (``ref[0, 0, 0]``)."""
+
+    def __init__(self, ref, h):
+        self.ref, self.h = ref, h
+
+    def __getitem__(self, _):
+        return self.ref[self.h, 0, 0]
+
+
+def _bsnd_fwd_kernel(q_ref, k_ref, v_ref, slopes_ref, o_ref, lse_ref,
+                     *scratch, heads, d, num_kv, **kw):
+    """A cell's heads through :func:`_flash_fwd_kernel`, one after the
+    other; ``scratch`` holds (acc, m, l, lse column) a head."""
+    from jax.experimental import pallas as pl
+
+    for h in range(heads):
+        acc_ref, m_ref, l_ref, lse_col = scratch[4 * h:4 * h + 4]
+        _flash_fwd_kernel(
+            _HeadLanes(q_ref, h, d), _HeadLanes(k_ref, h, d),
+            _HeadLanes(v_ref, h, d), _HeadSlope(slopes_ref, h),
+            _HeadLanes(o_ref, h, d), lse_col, acc_ref, m_ref, l_ref,
+            num_kv=num_kv, **kw)
+
+    @pl.when(pl.program_id(2) == num_kv - 1)
+    def _lse_rows():
+        for h in range(heads):
+            lse_ref[0, 0, h:h + 1, :] = _turned(scratch[4 * h + 3][0])
+
+
+def _bsnd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, slopes_ref,
+                    dq_ref, delta_ref, *scratch, heads, d, **kw):
+    """As :func:`_bsnd_fwd_kernel` for :func:`_flash_dq_kernel`;
+    ``scratch`` holds (dq acc, lse column, delta column) a head. The q
+    block stays over the streamed kv blocks, so its first cell turns the
+    ``lse`` rows and forms delta_i = rowsum(do_i * o_i), which it also
+    writes out, as rows, for the dkv kernel."""
+    from jax.experimental import pallas as pl
+
+    for h in range(heads):
+        dq_acc, lse_col, delta_col = scratch[3 * h:3 * h + 3]
+        do_h = _HeadLanes(do_ref, h, d)
+
+        @pl.when(pl.program_id(2) == 0)
+        def _columns():
+            lse_col[0] = _turned(lse_ref[0, 0, h:h + 1, :])
+            delta = jnp.sum(
+                do_h[0].astype(jnp.float32)
+                * _HeadLanes(o_ref, h, d)[0].astype(jnp.float32),
+                axis=-1, keepdims=True)
+            delta_col[0] = delta
+            delta_ref[0, 0, h:h + 1, :] = _turned(delta)
+
+        _flash_dq_kernel(
+            _HeadLanes(q_ref, h, d), _HeadLanes(k_ref, h, d),
+            _HeadLanes(v_ref, h, d), do_h, lse_col, delta_col,
+            _HeadSlope(slopes_ref, h), _HeadLanes(dq_ref, h, d), dq_acc,
+            **kw)
+
+
+def _bsnd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
+                     slopes_ref, dk_ref, dv_ref, *scratch, heads, d, **kw):
+    """As :func:`_bsnd_fwd_kernel` for :func:`_flash_dkv_kernel`;
+    ``scratch`` holds (dk acc, dv acc, lse column, delta column) a head.
+    The q side streams, so every cell that runs turns its rows."""
+    from jax.experimental import pallas as pl
+
+    run = _stream_q_run(pl.program_id(2), pl.program_id(1), kw["block_q"],
+                        kw["block_k"], kw["causal"], kw["window"])
+    for h in range(heads):
+        dk_acc, dv_acc, lse_col, delta_col = scratch[4 * h:4 * h + 4]
+
+        @pl.when(run)
+        def _columns():
+            lse_col[0] = _turned(lse_ref[0, 0, h:h + 1, :])
+            delta_col[0] = _turned(delta_ref[0, 0, h:h + 1, :])
+
+        _flash_dkv_kernel(
+            _HeadLanes(k_ref, h, d), _HeadLanes(v_ref, h, d),
+            _HeadLanes(q_ref, h, d), _HeadLanes(do_ref, h, d), lse_col,
+            delta_col, _HeadSlope(slopes_ref, h), _HeadLanes(dk_ref, h, d),
+            _HeadLanes(dv_ref, h, d), dk_acc, dv_acc, **kw)
+
+
+def _bsnd_specs(heads, d, block_q, block_k, q_of, kv_of):
+    """BlockSpecs over a ``(b * n / c, x, y)`` grid; ``q_of(x, y)`` and
+    ``kv_of(x, y)`` give the q and the kv block a cell fetches."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    per_cell = _heads_per_cell(heads, d)
+    cells = heads // per_cell
+
+    def spec(shape, index):
+        return pl.BlockSpec(shape, index, memory_space=pltpu.VMEM)
+
+    return {
+        "q": spec((1, block_q, per_cell * d),
+                  lambda g, x, y: (g // cells, q_of(x, y), g % cells)),
+        "kv": spec((1, block_k, per_cell * d),
+                   lambda g, x, y: (g // cells, kv_of(x, y), g % cells)),
+        "row": spec((1, 1, per_cell, block_q),
+                    lambda g, x, y: (g // cells, g % cells, 0, q_of(x, y))),
+        "slopes": spec((per_cell, 1, 1), lambda g, x, y: (g % cells, 0, 0)),
+    }
+
+
+def _bsnd_slopes(alibi_slopes, heads):
+    """``[n]`` per-head slopes -> the ``[n, 1, 1]`` grid input."""
+    if alibi_slopes is None:
+        return jnp.zeros((heads, 1, 1), jnp.float32)
+    return alibi_slopes.astype(jnp.float32).reshape(heads, 1, 1)
+
+
+# Under ``jax.jit``: a model's layers then share one trace and one
+# lowering of each kernel (every static argument is in the key, the
+# gate's interpreter switch among them), where each bare ``pallas_call``
+# is traced and lowered to Mosaic again, two heads' worth a cell here.
+_bsnd_jit = functools.partial(jax.jit, static_argnames=(
+    "heads", "scale", "causal", "block_q", "block_k", "window", "interpret"))
+
+
+@_bsnd_jit
+def _bsnd_fwd_pallas(q, k, v, alibi_slopes, *, heads, scale, causal,
+                     block_q, block_k, window, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, s, width = q.shape
+    d = width // heads
+    per_cell = _heads_per_cell(heads, d)
+    cells = heads // per_cell
+    num_kv = s // block_k
+    sp = _bsnd_specs(
+        heads, d, block_q, block_k, lambda i, j: i,
+        lambda i, j: _fetched_kv_block(i, j, block_q, block_k, causal,
+                                       window))
+    return pl.pallas_call(
+        functools.partial(
+            _bsnd_fwd_kernel, heads=per_cell, d=d, scale=scale,
+            causal=causal, block_q=block_q, block_k=block_k, num_kv=num_kv,
+            window=window, alibi=alibi_slopes is not None),
+        grid=(b * cells, s // block_q, num_kv),
+        in_specs=[sp["q"], sp["kv"], sp["kv"], sp["slopes"]],
+        out_specs=[sp["q"], sp["row"]],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, s, width), q.dtype),
+            jax.ShapeDtypeStruct((b, cells, per_cell, s), jnp.float32),
+        ],
+        scratch_shapes=per_cell * [
+            pltpu.VMEM((block_q, d), jnp.float32),      # acc
+            pltpu.VMEM((block_q, 1), jnp.float32),      # running max
+            pltpu.VMEM((block_q, 1), jnp.float32),      # running sum
+            pltpu.VMEM((1, block_q, 1), jnp.float32),   # lse column
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name="self_attention_flash_fwd",
+    )(q, k, v, _bsnd_slopes(alibi_slopes, heads))
+
+
+@_bsnd_jit
+def _bsnd_bwd_pallas(q, k, v, o, lse, do, alibi_slopes, *, heads, scale,
+                     causal, block_q, block_k, window, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, s, width = q.shape
+    d = width // heads
+    per_cell = _heads_per_cell(heads, d)
+    cells = heads // per_cell
+    num_q, num_kv = s // block_q, s // block_k
+    slopes = _bsnd_slopes(alibi_slopes, heads)
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
+    static = dict(heads=per_cell, d=d, scale=scale, causal=causal,
+                  block_q=block_q, block_k=block_k, window=window,
+                  alibi=alibi_slopes is not None)
+    column = pltpu.VMEM((1, block_q, 1), jnp.float32)
+
+    sp = _bsnd_specs(
+        heads, d, block_q, block_k, lambda i, j: i,
+        lambda i, j: _fetched_kv_block(i, j, block_q, block_k, causal,
+                                       window))
+    dq, delta = pl.pallas_call(
+        functools.partial(_bsnd_dq_kernel, num_kv=num_kv, **static),
+        grid=(b * cells, num_q, num_kv),
+        in_specs=[sp["q"], sp["kv"], sp["kv"], sp["q"], sp["q"], sp["row"],
+                  sp["slopes"]],
+        out_specs=[sp["q"], sp["row"]],
+        out_shape=[jax.ShapeDtypeStruct((b, s, width), q.dtype),
+                   jax.ShapeDtypeStruct(lse.shape, jnp.float32)],
+        scratch_shapes=per_cell * [
+            pltpu.VMEM((block_q, d), jnp.float32), column, column],
+        compiler_params=params, interpret=interpret,
+        name="self_attention_flash_dq",
+    )(q, k, v, do, o, lse, slopes)
+
+    sp = _bsnd_specs(
+        heads, d, block_q, block_k,
+        lambda j, i: _fetched_q_block(j, i, block_q, block_k, causal,
+                                      window),
+        lambda j, i: j)
+    dk, dv = pl.pallas_call(
+        functools.partial(_bsnd_dkv_kernel, num_q=num_q, **static),
+        grid=(b * cells, num_kv, num_q),
+        in_specs=[sp["kv"], sp["kv"], sp["q"], sp["q"], sp["row"],
+                  sp["row"], sp["slopes"]],
+        out_specs=[sp["kv"], sp["kv"]],
+        out_shape=[jax.ShapeDtypeStruct((b, s, width), k.dtype),
+                   jax.ShapeDtypeStruct((b, s, width), v.dtype)],
+        scratch_shapes=per_cell * [
+            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d), jnp.float32), column, column],
+        compiler_params=params, interpret=interpret,
+        name="self_attention_flash_dkv",
+    )(k, v, q, do, lse, delta, slopes)
+    return dq, dk, dv
 
 
 # ------------------------------------------------ with a selection operand
@@ -752,10 +1042,13 @@ def _fit_block(block, s):
 
 
 def _resolve(q, scale, block_q, block_k):
-    import numbers
+    """(scale, block_q, block_k) for ``[.., seq, head_dim]`` operands."""
+    return _resolve_sizes(q.shape[-1], q.shape[-2], scale, block_q, block_k)
 
+
+def _resolve_sizes(head_dim, s, scale, block_q, block_k):
     if scale is None:
-        scale = 1.0 / (q.shape[-1] ** 0.5)
+        scale = 1.0 / (head_dim ** 0.5)
     elif not isinstance(scale, numbers.Number):
         # scale sits in custom_vjp nondiff_argnums: a traced value (e.g.
         # 1/jnp.sqrt(d)) surfaces as a cryptic UnexpectedTracerError deep
@@ -764,7 +1057,6 @@ def _resolve(q, scale, block_q, block_k):
             "flash_attention scale must be a python number (it is a "
             f"static argument of the custom_vjp), got {type(scale)}; "
             "pass scale=None for the 1/sqrt(head_dim) default")
-    s = q.shape[-2]
     return scale, _fit_block(block_q, s), _fit_block(block_k, s)
 
 
@@ -785,7 +1077,12 @@ def _check_window(window, causal):
 def flash_attention(q, k, v, causal=True, scale=None,
                     block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
                     window=None, alibi_slopes=None, selection=None):
-    """Flash attention over [batch, heads, seq, head_dim] inputs.
+    """Flash attention over [batch, heads, seq, head_dim] inputs (head
+    major: for callers that hold their heads so; on a TPU a head_dim
+    under 128 pads to 128 lanes in every operand. A caller that holds
+    ``[batch, seq, heads * head_dim]``, as a projection writes it, calls
+    :func:`flash_attention_bsnd`: the same kernels, nothing transposed
+    or padded).
 
     ``selection``: int8 ``[batch, seq, seq]``, non-zero where a query
     (second axis) may see a key (third axis), shared by the heads; the
@@ -878,6 +1175,99 @@ def _flash_bwd_rule(causal, scale, block_q, block_k, window, res, g):
 
 
 flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+
+
+def _to_head_major(x, heads):
+    """``[b, s, n * d]`` -> ``[b, n, s, d]``."""
+    b, s, width = x.shape
+    return x.reshape(b, s, heads, width // heads).transpose(0, 2, 1, 3)
+
+
+def _to_batch_major(x):
+    """``[b, n, s, d]`` -> ``[b, s, n * d]``."""
+    b, n, s, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b, s, n * d)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def flash_attention_bsnd(q, k, v, heads, causal=True, scale=None,
+                         block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
+                         window=None, alibi_slopes=None):
+    """:func:`flash_attention` over ``[batch, seq, heads * head_dim]``
+    inputs: the layout a qkv projection writes and an output projection
+    reads, so that nothing is transposed around the kernels and a head
+    narrower than 128 lanes pads nothing in HBM. The same kernel bodies
+    under other index maps; a grid cell takes the heads of one 128-lane
+    column, so ``head_dim`` has to be a multiple of 128, or divide 128
+    with ``heads`` a multiple of ``128 // head_dim``. ``causal``,
+    ``scale``, ``window`` and ``alibi_slopes`` as :func:`flash_attention`
+    has them. The residuals a checkpointed layer can keep
+    (``FLASH_RESIDUAL_NAMES``) are the kernel's own: ``out`` ``[b, s,
+    n * d]`` in the operands' dtype and the log-sum-exp ``[b, n / c, c,
+    s]`` float32, ``c`` heads a cell; the backward kernels read both as
+    they are. Counted as ``kernels/dispatch/flash_attention_bsnd_<path>``
+    beside ``flash_attention``'s own counter."""
+    return _bsnd_fwd_rule(q, k, v, heads, causal, scale, block_q, block_k,
+                          window, alibi_slopes)[0]
+
+
+def _bsnd_resolve(q, heads, scale, block_q, block_k):
+    """(scale, block_q, block_k, does the kernel run), recording the
+    call under both counters."""
+    width = q.shape[-1]
+    if width % heads or not fits_batch_major(heads, width // heads):
+        raise ValueError(
+            f"flash_attention_bsnd cannot cut {heads} heads over {width} "
+            "lanes into 128-lane columns; use flash_attention")
+    scale, bq, bk = _resolve_sizes(width // heads, q.shape[1], scale,
+                                   block_q, block_k)
+    return scale, bq, bk, _use_kernel(
+        bq, bk, ("flash_attention", "flash_attention_bsnd"))
+
+
+def _bsnd_reference(q, k, v, heads, scale, causal, window, alibi_slopes):
+    return _to_batch_major(_attention_reference(
+        *(_to_head_major(x, heads) for x in (q, k, v)), scale, causal,
+        window, alibi_slopes))
+
+
+def _bsnd_fwd_rule(q, k, v, heads, causal, scale, block_q, block_k,
+                   window=None, alibi_slopes=None):
+    _check_window(window, causal)
+    scale_, bq, bk, kernel = _bsnd_resolve(q, heads, scale, block_q,
+                                           block_k)
+    if kernel:
+        out, lse = _bsnd_fwd_pallas(
+            q, k, v, alibi_slopes, heads=heads, scale=scale_, causal=causal,
+            block_q=bq, block_k=bk, window=window, interpret=GATE.interpret)
+        # as _flash_fwd_rule; both are kept as the kernel wrote them
+        out = checkpoint_name(out, FLASH_RESIDUAL_NAMES[0])
+        lse = checkpoint_name(lse, FLASH_RESIDUAL_NAMES[1])
+        return out, (q, k, v, out, lse, alibi_slopes)
+    return (_bsnd_reference(q, k, v, heads, scale_, causal, window,
+                            alibi_slopes),
+            (q, k, v, None, None, alibi_slopes))
+
+
+def _bsnd_bwd_rule(heads, causal, scale, block_q, block_k, window, res, g):
+    q, k, v, out, lse, alibi_slopes = res
+    scale_, bq, bk = _resolve_sizes(q.shape[-1] // heads, q.shape[1], scale,
+                                    block_q, block_k)
+    none_slope_grad = (None if alibi_slopes is None
+                       else jnp.zeros_like(alibi_slopes))
+    if lse is not None:
+        return (*_bsnd_bwd_pallas(
+            q, k, v, out, lse, g, alibi_slopes, heads=heads, scale=scale_,
+            causal=causal, block_q=bq, block_k=bk, window=window,
+            interpret=GATE.interpret), none_slope_grad)
+    _, vjp = jax.vjp(
+        lambda q_, k_, v_: _bsnd_reference(q_, k_, v_, heads, scale_,
+                                           causal, window, alibi_slopes),
+        q, k, v)
+    return (*vjp(g), none_slope_grad)
+
+
+flash_attention_bsnd.defvjp(_bsnd_fwd_rule, _bsnd_bwd_rule)
 
 
 def head_summed_probs(q, k, selection, causal=True, scale=None,

@@ -804,6 +804,31 @@ class ParallelAttention(nn.Module):
                 "without an explicit attention_mask on one "
                 "tensor-parallel rank; there is no decode path")
 
+        # flash handles the built-in causal/full patterns and the
+        # sliding-window band (kernel block-skip); an explicit
+        # attention_mask (e.g. padding), a softcap, or a non-default
+        # softmax scale must take the masked softmax path or they would
+        # be silently ignored.
+        seq_full = s * tp if cfg.sequence_parallel else s
+        flash = (cfg.use_flash_attention and attention_mask is None
+                 and cfg.attn_logit_softcapping is None
+                 and cfg.query_pre_attn_scalar in (None, kv)
+                 and _flash_available(seq_full, kv))
+        # Where the heads fill whole 128-lane columns the kernels take
+        # q, k, v and give the context as [b, s, n*d], the projections'
+        # own layout: each of q, k, v then comes from a matmul of its
+        # own over its columns of the stored weight, so that no
+        # activation is sliced, transposed or lane-padded on the way.
+        from apex_tpu.contrib import fmha
+
+        batch_major = (flash and not self.decode
+                       and not cfg.context_parallel
+                       and cfg.indexer_heads is None
+                       and fmha.fits_batch_major(np_local, kv))
+
+        def heads_of(t):   # [s, b, n * kv] -> [s, b, n, kv]
+            return t.reshape(*t.shape[:-1], -1, kv)
+
         if cfg.query_groups == cfg.num_attention_heads:
             qkv = ColumnParallelLinear(
                 input_size=cfg.hidden_size,
@@ -811,11 +836,18 @@ class ParallelAttention(nn.Module):
                 gather_output=False, bias=cfg.attention_bias,
                 params_dtype=cfg.params_dtype,
                 sequence_parallel_enabled=cfg.sequence_parallel,
-                name="query_key_value")(x)
-            # [s, b, 3*h/tp] -> [s, b, np_local, 3*kv]
-            seq_full = qkv.shape[0]
-            qkv = qkv.reshape(seq_full, b, np_local, 3 * kv)
-            q, k, v = jnp.split(qkv, 3, axis=-1)
+                name="query_key_value")
+            if batch_major:
+                def q_k_v(w):   # the columns are [np_local, 3, kv]
+                    w = w.reshape(*w.shape[:-1], np_local, 3, kv)
+                    return tuple(t.reshape(*w.shape[:-3], -1)
+                                 for t in jnp.split(w, 3, axis=-2))
+
+                q, k, v = map(heads_of, qkv(x, column_groups=q_k_v))
+            else:
+                # [s, b, 3*h/tp] -> [s, b, np_local, 3*kv]
+                qkv = qkv(x).reshape(seq_full, b, np_local, 3 * kv)
+                q, k, v = jnp.split(qkv, 3, axis=-1)
         else:
             # Grouped-query attention: fewer K/V head groups; ONE fused
             # projection (a single SP all-gather / matmul dispatch) whose
@@ -832,12 +864,24 @@ class ParallelAttention(nn.Module):
                 gather_output=False, bias=cfg.attention_bias,
                 params_dtype=cfg.params_dtype,
                 sequence_parallel_enabled=cfg.sequence_parallel,
-                name="query_key_value")(x)
-            seq_full = proj.shape[0]
-            q = proj[..., :np_local * kv].reshape(seq_full, b, np_local, kv)
-            kvp = proj[..., np_local * kv:].reshape(seq_full, b, g_local,
-                                                    2 * kv)
-            k, v = jnp.split(kvp, 2, axis=-1)
+                name="query_key_value")
+
+            if batch_major:
+                def q_k_v(w):   # the columns are [q heads | g_local, 2, kv]
+                    kvp = w[..., np_local * kv:].reshape(
+                        *w.shape[:-1], g_local, 2, kv)
+                    return (w[..., :np_local * kv],
+                            *(kvp[..., i, :].reshape(*w.shape[:-1], -1)
+                              for i in range(2)))
+
+                q, k, v = map(heads_of, proj(x, column_groups=q_k_v))
+            else:
+                proj = proj(x)
+                q = proj[..., :np_local * kv].reshape(seq_full, b, np_local,
+                                                      kv)
+                kvp = proj[..., np_local * kv:].reshape(seq_full, b, g_local,
+                                                        2 * kv)
+                k, v = jnp.split(kvp, 2, axis=-1)
 
         if cfg.qkv_clip is not None:  # DBRX: clamp projection outputs
             clip = jnp.asarray(cfg.qkv_clip, q.dtype)
@@ -906,28 +950,30 @@ class ParallelAttention(nn.Module):
                                                     np_local * kv)
             return self._output_proj(cfg, ctx)
 
-        # flash handles the built-in causal/full patterns and the
-        # sliding-window band (kernel block-skip); an explicit
-        # attention_mask (e.g. padding), a softcap, or a non-default
-        # softmax scale must take the masked softmax path below or they
-        # would be silently ignored.
-        if (cfg.use_flash_attention and attention_mask is None
-                and cfg.attn_logit_softcapping is None
-                and cfg.query_pre_attn_scalar in (None, kv)
-                and _flash_available(seq_full, kv)):
-            from apex_tpu.contrib.fmha import flash_attention
-
+        if flash:
             slopes = (_local_alibi_slopes(cfg, np_local)
                       if cfg.position_embedding_type == "alibi" else None)
-            # [s, b, n, d] -> [b, n, s, d]
-            qt = q.transpose(1, 2, 0, 3)
-            kt = k.transpose(1, 2, 0, 3)
-            vt = v.transpose(1, 2, 0, 3)
-            ctx = flash_attention(
-                qt, kt, vt,
-                causal=(cfg.attn_mask_type == AttnMaskType.causal),
-                window=win, alibi_slopes=slopes)
-            ctx = ctx.transpose(2, 0, 1, 3)  # [s, b, n, d]
+            causal = cfg.attn_mask_type == AttnMaskType.causal
+            if batch_major:
+                # [s, b, n, d] -> [b, s, n*d]: XLA folds the transpose
+                # into whatever wrote q, k, v (the matmul, or rotary /
+                # QK-norm / clip), and the one back into the dense
+                # matmul. Heads first, then the transpose: transposed as
+                # [s, b, n, d] each array is copied three times a layer.
+                q, k, v = (t.reshape(seq_full, b, np_local * kv)
+                           .transpose(1, 0, 2) for t in (q, k, v))
+                ctx = fmha.flash_attention_bsnd(
+                    q, k, v, np_local, causal, window=win,
+                    alibi_slopes=slopes)
+                ctx = ctx.transpose(1, 0, 2)  # [s, b, n*d]
+            else:
+                # [s, b, n, d] -> [b, n, s, d]
+                qt = q.transpose(1, 2, 0, 3)
+                kt = k.transpose(1, 2, 0, 3)
+                vt = v.transpose(1, 2, 0, 3)
+                ctx = fmha.flash_attention(qt, kt, vt, causal=causal,
+                                           window=win, alibi_slopes=slopes)
+                ctx = ctx.transpose(2, 0, 1, 3)  # [s, b, n, d]
         else:
             if win is not None:
                 # fold the window band into the mask (masked-softmax path
@@ -1370,7 +1416,8 @@ def _remat_keeping_flash_residuals(block, wrapped, **kwargs):
     name exists and nothing is kept. Counts the ``wrapped`` layers (one
     for a scanned block) as ``remat/save_flash_residuals`` at trace time;
     ``kernels/dispatch/flash_attention_pallas`` beside it says the kernel
-    ran in them."""
+    ran in them, ``kernels/dispatch/flash_attention_bsnd_pallas`` that it
+    ran through its batch-major entry."""
     from apex_tpu.contrib.fmha import FLASH_RESIDUAL_NAMES
     from apex_tpu.telemetry.registry import get_registry
 
@@ -1388,13 +1435,16 @@ class ParallelTransformer(nn.Module):
     ``jax.checkpoint`` over each layer, or over the scanned block).
 
     A checkpointed layer keeps its input and, where flash attention's
-    kernel ran in it, the kernel's output ``[b, n, s, d]`` in the compute
-    dtype and log-sum-exp ``[b, n, s]`` in float32: ``b*s*h*2 + b*n*s*4``
-    bytes a layer, for one kernel run a layer less in the backward. On a
-    TPU the output is kept in the kernel's own layout, where a head
-    dimension of 64 pads to 128 lanes: GPT-2 345M at 16 x 1024 keeps
-    67 + 1 MB a layer, 1.69 GB over 24 layers (PERF.md section 6, PR 27).
-    The rest of the layer is recomputed."""
+    kernel ran in it, the kernel's output in the compute dtype and
+    log-sum-exp in float32, both as the kernel wrote them: ``b*s*h*2 +
+    b*n*s*4`` bytes a layer, for one kernel run a layer less in the
+    backward. Where the heads fill whole 128-lane columns
+    (``contrib.fmha.flash_attention_bsnd``) that is ``[b, s, n*d]`` and
+    ``[b, n/c, c, s]``, nothing padded: GPT-2 345M at 16 x 1024 keeps
+    33.5 + 1 MB a layer, 0.83 GB over 24 layers. Through the head-major
+    call the output is ``[b, n, s, d]``, where a head of 64 pads to 128
+    lanes on a TPU (67 MB a layer there; PERF.md section 6, PRs 27 and
+    29). The rest of the layer is recomputed."""
 
     config: TransformerConfig
     num_layers: Optional[int] = None

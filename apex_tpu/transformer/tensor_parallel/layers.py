@@ -104,6 +104,10 @@ def linear_with_grad_accumulation_and_async_allreduce(
       (fwd) / reduce-scatter the input grad on exit (bwd).
     - else async_grad_allreduce: identity fwd / allreduce of input grad bwd.
     The flags select collectives; accumulation fusion is XLA's job.
+
+    ``weight`` (and with it ``bias``) may be a tuple of column groups of
+    one weight: the input is gathered once, each group gets a matmul of
+    its own and the outputs come back as a tuple.
     """
     if sequence_parallel_enabled:
         total_input = gather_from_sequence_parallel_region(input, True, axis_name)
@@ -111,11 +115,19 @@ def linear_with_grad_accumulation_and_async_allreduce(
         total_input = copy_to_tensor_model_parallel_region(input, axis_name)
     else:
         total_input = input
-    out = jnp.matmul(total_input, weight, preferred_element_type=jnp.float32)
-    out = out.astype(input.dtype)
-    if bias is not None:
-        out = out + bias
-    return out
+
+    def linear(weight, bias):
+        out = jnp.matmul(total_input, weight,
+                         preferred_element_type=jnp.float32)
+        out = out.astype(input.dtype)
+        if bias is not None:
+            out = out + bias
+        return out
+
+    if isinstance(weight, tuple):
+        return tuple(map(linear, weight,
+                         bias if bias is not None else len(weight) * (None,)))
+    return linear(weight, bias)
 
 
 class ColumnParallelLinear(nn.Module):
@@ -139,7 +151,14 @@ class ColumnParallelLinear(nn.Module):
     axis_name: str = TENSOR_PARALLEL_AXIS
 
     @nn.compact
-    def __call__(self, input_):
+    def __call__(self, input_, column_groups=None):
+        """``column_groups``: a function that cuts an array's last axis,
+        the local shard's columns, into a tuple of groups. The call then
+        returns one output a group, each written by a matmul of its own
+        in its own array, where slicing them out of the one output would
+        copy each (fused projections whose parts go to a kernel: q, k and
+        v). The stored weight is the same; needs ``gather_output=False``
+        and the bias added here."""
         world = get_tensor_model_parallel_world_size()
         out_per_partition = divide(self.output_size, world)
         weight = self.param(
@@ -147,6 +166,12 @@ class ColumnParallelLinear(nn.Module):
             (self.input_size, out_per_partition), self.params_dtype)
         b = (self.param("bias", nn.initializers.zeros, (out_per_partition,),
                         self.params_dtype) if self.bias else None)
+        if column_groups is not None:
+            if self.gather_output or self.skip_bias_add:
+                raise ValueError("column_groups needs gather_output=False "
+                                 "and skip_bias_add=False")
+            weight = column_groups(weight)
+            b = None if b is None else column_groups(b)
         bias_for_matmul = None if self.skip_bias_add else b
         out_parallel = linear_with_grad_accumulation_and_async_allreduce(
             input_, weight, bias_for_matmul,

@@ -149,7 +149,7 @@ def train_leg(cfg):
 
     model, params, opt, opt_state = init_training(cfg)
     tokens, labels = seeded_batch(cfg)
-    with counted_dispatches(["flash_attention"]):
+    with counted_dispatches(["flash_attention", "flash_attention_bsnd"]):
         t0 = time.perf_counter()
         step = jax.jit(make_train_step(model, opt),
                        donate_argnums=(0, 1)).lower(
@@ -367,6 +367,19 @@ def kernel_cases():
                 q, k, v, sm, True)),
             lambda g=g, rep=rep, T=T: tuple(
                 randn(i, (2, g * rep, T, 64)) for i in range(3)),
+            TOL_MXU)
+
+    # -- the same kernels through their batch-major entry (q, k, v and the
+    # context [b, s, n*d]): GPT-2 345M's heads, two to a 128-lane column,
+    # and one head a column at head size 128
+    for heads, d, T in ((16, 64, SEQ), (8, 128, 2048)):
+        add(f"flash_attention bsnd fwd+bwd heads={heads} d={d} seq={T}",
+            fwd_bwd(lambda q, k, v, heads=heads: fmha.flash_attention_bsnd(
+                q, k, v, heads, True)),
+            fwd_bwd(lambda q, k, v, heads=heads, d=d: fmha._bsnd_reference(
+                q, k, v, heads, d ** -0.5, True, None, None)),
+            lambda heads=heads, d=d, T=T: tuple(
+                randn(i, (2, T, heads * d)) for i in range(3)),
             TOL_MXU)
 
     # -- the same kernels with a selection operand (sparse attention: each

@@ -96,6 +96,9 @@ KERNEL_NAMES = {
                                   "sparse_attention_flash_dq",
                                   "sparse_attention_flash_dkv",
                                   "sparse_attention_head_probs"),
+    "flash_attention bsnd": ("self_attention_flash_fwd",
+                             "self_attention_flash_dq",
+                             "self_attention_flash_dkv"),
     "flash_attention": ("self_attention_flash_fwd",
                         "self_attention_flash_dq",
                         "self_attention_flash_dkv"),
@@ -242,10 +245,11 @@ def test_generate_compiles_for_v5e(tpu, gpt2_two_layers):
 
 
 @pytest.fixture(scope="module")
-def gpt2_train_step_text(tpu):
+def gpt2_train_step(tpu):
     """The README quick-start step (amp O2 + ``FusedAdam``, flash
     attention, recomputation) for GPT-2 345M at full width, two layers,
-    batch 2: its optimised HLO for one ``TPU v5 lite``."""
+    batch 2: its optimised HLO for one ``TPU v5 lite``, and the counters
+    its tracing left."""
     from apex_tpu import amp
     from apex_tpu.models import GPTModel, TransformerConfig
     from apex_tpu.models.gpt import gpt_loss_fn
@@ -272,8 +276,18 @@ def gpt2_train_step_text(tpu):
 
     # XLA's whole pipeline and the donated state, as the chip runs it:
     # what is fused into what decides which instruction carries which scope
-    return _compile(train_step, tpu, params, jax.eval_shape(opt.init, params),
-                    tokens, options=None, donate=(0, 1))
+    from apex_tpu.telemetry.registry import MetricsRegistry, use_registry
+
+    with use_registry(MetricsRegistry(enabled=True)) as reg:
+        text = _compile(train_step, tpu, params,
+                        jax.eval_shape(opt.init, params), tokens,
+                        options=None, donate=(0, 1))
+    return text, reg.snapshot()["counters"]
+
+
+@pytest.fixture(scope="module")
+def gpt2_train_step_text(gpt2_train_step):
+    return gpt2_train_step[0]
 
 
 def test_train_step_names_its_attention_kernels(gpt2_train_step_text):
@@ -287,6 +301,38 @@ def test_train_step_names_its_attention_kernels(gpt2_train_step_text):
     assert sorted(kernels) == sorted(
         2 * ["self_attention_flash_fwd", "self_attention_flash_dq",
              "self_attention_flash_dkv"])
+
+
+def test_train_step_leaves_no_lane_padded_activation_around_attention(
+        gpt2_train_step):
+    """Both layers took the kernels' batch-major entry (its counter is
+    the forward's, the recomputed layer's and the backward's traces), and
+    under ``self_attention`` nothing copies or transposes an array whose
+    minor dimension is a head of 64: q, k, v, the context and their
+    gradients stay ``[b, s, n * d]`` between the matmuls and the
+    kernels."""
+    gpt2_train_step_text, counters = gpt2_train_step
+    assert counters["kernels/dispatch/flash_attention_bsnd_pallas"] >= 2
+    assert counters["kernels/dispatch/flash_attention_bsnd_pallas"] == \
+        counters["kernels/dispatch/flash_attention_pallas"]
+    scopes = instruction_scopes(gpt2_train_step_text)
+    moves = re.compile(
+        r"^\s+(?:ROOT\s+)?%([\w\-.]+) = (\S+) (copy|transpose)\((.*)$",
+        re.M)
+    shapes = dict(re.findall(
+        r"^\s+(?:ROOT\s+)?%([\w\-.]+) = (\S+) ", gpt2_train_step_text,
+        re.M))
+    seen = 0
+    for name, shape, _, operands in moves.findall(gpt2_train_step_text):
+        if "self_attention" not in scopes.get(name, ""):
+            continue
+        seen += 1
+        operand = re.match(r"%([\w\-.]+)", operands)
+        for array in (shape, shapes.get(operand and operand.group(1), "")):
+            assert not re.search(r",64\]", array), (
+                f"{name} = {shape} {operands[:60]} under {scopes[name]!r} "
+                f"moves a lane-padded activation ({array})")
+    assert seen, "no copy under self_attention at all: is the scope gone?"
 
 
 def test_train_step_leaves_nothing_of_the_update_or_loss_bare(
