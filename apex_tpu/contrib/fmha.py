@@ -33,13 +33,9 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from apex_tpu.kernels.registry import (
-    dispatch_path,
-    get_kernel_registry,
-    kernel_gate,
-)
+from apex_tpu.kernels.registry import get_kernel_registry, kernel_gate
 
-GATE = kernel_gate("flash_attention", default=True)
+GATE = kernel_gate("flash_attention")
 
 # 512x512 measured fastest on-chip at seq 8192 (8.0 TFLOP/s vs 3.8 at
 # 128x128); both are min()'d down for shorter sequences.
@@ -51,15 +47,31 @@ NEG_INF = -1e30
 FLASH_RESIDUAL_NAMES = ("flash_out", "flash_lse")
 
 
-def _use_kernel(bq, bk, names=("flash_attention",)):
-    """Record this call's dispatch path (trace time) under ``names`` and
-    say whether the Pallas kernels run: gate on AND a block divides the
-    sequence."""
-    path = dispatch_path(GATE) if bq is not None and bk is not None \
-        else "oracle"
-    for name in names:
-        get_kernel_registry().dispatch(name, path)
-    return path != "oracle"
+def _takes(bq, bk) -> str:
+    """This call's path, counted: the kernels run where a block divides
+    the sequence (:func:`_fit_block` found ``bq`` and ``bk``)."""
+    return GATE.path(fits=bq is not None and bk is not None)
+
+
+def dense_layout(seq, heads, head_dim):
+    """What a model asks before it lays out q, k and v: do the dense
+    kernels take ``heads`` heads of ``head_dim`` over a sequence of
+    ``seq``, and in which layout? ``None`` (they do not: the model keeps
+    its own softmax path), ``"bsnd"`` (:func:`flash_attention_bsnd`, the
+    projections' own layout) or ``"bnsd"`` (:func:`flash_attention`). Not
+    counted; the entry the model then calls counts.
+
+    Narrower than a direct call's rule (:func:`_fit_block`: any sequence
+    that a block of 512, 256 or 128, or the sequence itself, divides, at
+    any head size): whole 128-row tiles and head sizes 64, 128 and 256
+    are where ``ParallelAttention`` has always left its softmax path, and
+    what every cell and test of a model pins. Whether a model gains from
+    the kernels at the shapes between the two rules (a 96-long sequence,
+    heads of 32) is not measured; to widen the rule, widen it here."""
+    fits = seq % 128 == 0 and head_dim in (64, 128, 256)
+    if GATE.path(fits=fits, record=False) == "oracle":
+        return None
+    return "bnsd" if _heads_per_cell(heads, head_dim) is None else "bsnd"
 
 
 def _causal_mask(scores, qi, kj, block_q, block_k, window=None):
@@ -512,12 +524,6 @@ def _heads_per_cell(heads, d):
     if 128 % d == 0 and heads % (128 // d) == 0:
         return 128 // d
     return None
-
-
-def fits_batch_major(heads, head_dim):
-    """Does :func:`flash_attention_bsnd` take ``heads`` heads of
-    ``head_dim``?"""
-    return _heads_per_cell(heads, head_dim) is not None
 
 
 class _HeadLanes:
@@ -1103,7 +1109,7 @@ def flash_attention(q, k, v, causal=True, scale=None,
     _check_window(window, causal)
     _check_selection(selection, q, window, alibi_slopes)
     scale, bq, bk = _resolve(q, scale, block_q, block_k)
-    if _use_kernel(bq, bk):
+    if _takes(bq, bk) != "oracle":
         if selection is not None:
             return _sparse_fwd_pallas(q, k, v, selection, scale, causal,
                                       bq, bk)[0]
@@ -1130,7 +1136,7 @@ def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k,
     _check_window(window, causal)
     _check_selection(selection, q, window, alibi_slopes)
     scale_, bq, bk = _resolve(q, scale, block_q, block_k)
-    if _use_kernel(bq, bk):
+    if _takes(bq, bk) != "oracle":
         if selection is not None:
             out, lse = _sparse_fwd_pallas(q, k, v, selection, scale_, causal,
                                           bq, bk)
@@ -1215,14 +1221,15 @@ def _bsnd_resolve(q, heads, scale, block_q, block_k):
     """(scale, block_q, block_k, does the kernel run), recording the
     call under both counters."""
     width = q.shape[-1]
-    if width % heads or not fits_batch_major(heads, width // heads):
+    if width % heads or _heads_per_cell(heads, width // heads) is None:
         raise ValueError(
             f"flash_attention_bsnd cannot cut {heads} heads over {width} "
             "lanes into 128-lane columns; use flash_attention")
     scale, bq, bk = _resolve_sizes(width // heads, q.shape[1], scale,
                                    block_q, block_k)
-    return scale, bq, bk, _use_kernel(
-        bq, bk, ("flash_attention", "flash_attention_bsnd"))
+    path = _takes(bq, bk)
+    get_kernel_registry().dispatch("flash_attention_bsnd", path)
+    return scale, bq, bk, path != "oracle"
 
 
 def _bsnd_reference(q, k, v, heads, scale, causal, window, alibi_slopes):
@@ -1286,7 +1293,7 @@ def head_summed_probs(q, k, selection, causal=True, scale=None,
     q, k = jax.lax.stop_gradient((q, k))
     _check_selection(selection, q, None, None)
     scale, bq, bk = _resolve(q, scale, block_q, block_k)
-    if lse is not None or _use_kernel(bq, bk):
+    if lse is not None or _takes(bq, bk) != "oracle":
         if lse is None:
             # v plays no part in the statistics; k stands in for it
             lse = _sparse_fwd_pallas(q, k, k, selection, scale, causal, bq,

@@ -29,17 +29,16 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.contrib._pallas_gate import (
-    PallasGate,
+from apex_tpu.kernels.registry import (
     choose_block,
+    kernel_gate,
     lane_block_ok,
 )
-from apex_tpu.kernels.registry import dispatch_path, get_kernel_registry
 
 NEG_INF = -1e30
 DEFAULT_BLOCK_T = 512
 
-_GATE = PallasGate("APEX_TPU_DECODE_FLASH")
+_GATE = kernel_gate("gqa_decode")
 
 
 def force_interpret(on: bool):
@@ -178,21 +177,26 @@ def _decode_pallas(q, k, v, length, sm_scale, softcap, window, block_t):
       k.reshape(T, b * g * d), v.reshape(T, b * g * d))
 
 
-def use_flash(cache_len: int, block_t: int = DEFAULT_BLOCK_T,
-              kv_shape=None) -> bool:
-    """True when the kernel would actually run: TPU/interpret, the
-    block ladder finds a tile dividing the cache buffer and — given the
-    ``[T, b, g, d]`` cache shape — the (block_t, g*d) tile of its
-    [T, b*g*d] view is one the TPU lowering accepts
-    (:func:`lane_block_ok`). Callers gate on this so the non-kernel
-    path is their own production einsum formulation."""
-    if not (_GATE.enabled() and choose_block(cache_len, block_t)
-            is not None):
+def _fits(cache_len, block_t, kv_shape=None) -> bool:
+    """The kernel's own half of the rule: the block ladder finds a tile
+    dividing the cache buffer and — given the ``[T, b, g, d]`` cache
+    shape — the (block_t, g*d) tile of its [T, b*g*d] view is one the
+    TPU lowering accepts (:func:`lane_block_ok`)."""
+    if choose_block(cache_len, block_t) is None:
         return False
     if kv_shape is None:
         return True
     _, b, g, d = kv_shape
     return lane_block_ok(_GATE, b, g * d)
+
+
+def use_flash(cache_len: int, block_t: int = DEFAULT_BLOCK_T,
+              kv_shape=None) -> bool:
+    """True when :func:`gqa_flash_decode` would run the kernel
+    (TPU/interpret and :func:`_fits`; not counted). Callers gate on this
+    so the non-kernel path is their own production einsum formulation."""
+    return _GATE.path(fits=_fits(cache_len, block_t, kv_shape),
+                      record=False) != "oracle"
 
 
 def gqa_flash_decode(q, k, v, length, sm_scale, window=None, softcap=None,
@@ -211,10 +215,8 @@ def gqa_flash_decode(q, k, v, length, sm_scale, window=None, softcap=None,
     ``kernels/dispatch/gqa_decode_<path>``.
     """
     T = k.shape[0]
-    if not use_flash(T, block_t, k.shape):
-        get_kernel_registry().dispatch("gqa_decode", "oracle")
+    if _GATE.path(fits=_fits(T, block_t, k.shape)) == "oracle":
         return gqa_decode_reference(q, k, v, length, sm_scale, window,
                                     softcap)
-    get_kernel_registry().dispatch("gqa_decode", dispatch_path(_GATE))
     return _decode_pallas(q, k, v, length, sm_scale, softcap, window,
                           choose_block(T, block_t))

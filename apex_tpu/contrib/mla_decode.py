@@ -31,17 +31,16 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.contrib._pallas_gate import (
-    PallasGate,
+from apex_tpu.kernels.registry import (
     choose_block,
+    kernel_gate,
     lane_block_ok,
 )
-from apex_tpu.kernels.registry import dispatch_path, get_kernel_registry
 
 NEG_INF = -1e30
 DEFAULT_BLOCK_T = 512
 
-_GATE = PallasGate("APEX_TPU_MLA_FLASH")
+_GATE = kernel_gate("mla_decode")
 
 
 def force_interpret(on: bool):
@@ -149,21 +148,27 @@ def _decode_pallas(q_full, cache, length, lat, scale, block_t):
       cache.reshape(T, b * L))
 
 
-def use_flash(cache_len: int, block_t: int = DEFAULT_BLOCK_T,
-              cache_shape=None) -> bool:
-    """True when the kernel would actually run: TPU/interpret, the
-    block ladder finds a tile dividing the cache and — given the
-    ``[T, b, L]`` cache shape — the (block_t, L) tile of its [T, b*L]
-    view is one the TPU lowering accepts (:func:`lane_block_ok`).
-    Callers gate on this so the non-kernel path is their own production
-    einsum formulation, not this module's fp32 reference fallback."""
-    if not (_GATE.enabled() and choose_block(cache_len, block_t)
-            is not None):
+def _fits(cache_len, block_t, cache_shape=None) -> bool:
+    """The kernel's own half of the rule: the block ladder finds a tile
+    dividing the cache and — given the ``[T, b, L]`` cache shape — the
+    (block_t, L) tile of its [T, b*L] view is one the TPU lowering
+    accepts (:func:`lane_block_ok`)."""
+    if choose_block(cache_len, block_t) is None:
         return False
     if cache_shape is None:
         return True
     _, b, L = cache_shape
     return lane_block_ok(_GATE, b, L)
+
+
+def use_flash(cache_len: int, block_t: int = DEFAULT_BLOCK_T,
+              cache_shape=None) -> bool:
+    """True when :func:`mla_flash_decode` would run the kernel
+    (TPU/interpret and :func:`_fits`; not counted). Callers gate on this
+    so the non-kernel path is their own production einsum formulation,
+    not this module's fp32 reference fallback."""
+    return _GATE.path(fits=_fits(cache_len, block_t, cache_shape),
+                      record=False) != "oracle"
 
 
 def mla_flash_decode(q_full, cache, length, lat, scale,
@@ -180,9 +185,7 @@ def mla_flash_decode(q_full, cache, length, lat, scale,
     taken is recorded as ``kernels/dispatch/mla_decode_<path>``.
     """
     T = cache.shape[0]
-    if not use_flash(T, block_t, cache.shape):
-        get_kernel_registry().dispatch("mla_decode", "oracle")
+    if _GATE.path(fits=_fits(T, block_t, cache.shape)) == "oracle":
         return mla_decode_reference(q_full, cache, length, lat, scale)
-    get_kernel_registry().dispatch("mla_decode", dispatch_path(_GATE))
     return _decode_pallas(q_full, cache, length, lat, scale,
                           choose_block(T, block_t))

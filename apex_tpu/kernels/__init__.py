@@ -1,12 +1,12 @@
 """apex_tpu.kernels — the Pallas fused-kernel layer (csrc parity).
 
-One registry (:mod:`apex_tpu.kernels.registry`: ``APEX_TPU_KERNELS``
-master switch, per-kernel env overrides, jnp oracle fallback always
-available, interpreter mode for CPU tests) gating four kernel families
-behind their existing Python entry points:
+One registry (:mod:`apex_tpu.kernels.registry`) holds every hand-written
+kernel's gate, and one rule there (``PallasGate.path``) says which path a
+call takes: the compiled kernel on a TPU, the Pallas interpreter where a
+test forced it, the jnp oracle otherwise, and everywhere under
+``APEX_TPU_KERNELS=0``, the one switch. The kernel families behind their
+existing Python entry points:
 
-- :mod:`apex_tpu.kernels.norm` — RMSNorm/LayerNorm fwd + bwd-dx
-  (entry: ``apex_tpu.normalization`` via ``apex_tpu.ops.layer_norm``)
 - :mod:`apex_tpu.kernels.softmax` — scaled-masked / upper-triangular
   softmax fwd + fused bwd (entry:
   ``apex_tpu.transformer.functional.fused_softmax``)
@@ -14,12 +14,18 @@ behind their existing Python entry points:
   over the bucket-domain ZeRO state (entry: the
   ``apex_tpu.contrib.optimizers`` ZeRO classes)
 - :mod:`apex_tpu.kernels.quant4` — int4 dual-quantization pack/unpack
-  (entry: ``apex_tpu.parallel.compression`` ``compress="int4"``)
+  (entry: ``apex_tpu.parallel.compression`` ``compress="int4"``; the
+  int8 ``quant`` pair lives in ``compression`` itself)
+- :mod:`apex_tpu.kernels.fused_cc` — matmul + collective, the verify
+  window's flash attention, int4 quantize + pack around a collective
+- ``apex_tpu.contrib.fmha`` (``flash_attention``),
+  ``apex_tpu.contrib.gqa_decode`` and ``apex_tpu.contrib.mla_decode``
+  register their gates here too.
 
-See docs/kernels.md for env vars, parity bounds, and wire formats.
+See docs/kernels.md for the rule, parity bounds, and wire formats.
 """
 
-from apex_tpu.kernels import norm, optim, quant4, softmax  # noqa: F401
+from apex_tpu.kernels import optim, quant4, softmax  # noqa: F401
 from apex_tpu.kernels.registry import (  # noqa: F401
     KernelRegistry,
     PallasGate,

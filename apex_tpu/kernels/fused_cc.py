@@ -47,13 +47,12 @@ from jax import lax
 from apex_tpu.kernels import quant4 as _quant4
 from apex_tpu.kernels.registry import (
     choose_block,
-    get_kernel_registry,
     kernel_gate,
     lane_block_ok,
 )
 from apex_tpu.telemetry.comm import axis_world, record_collective
 
-GATE = kernel_gate("fused_cc", default=True)
+GATE = kernel_gate("fused_cc")
 
 NEG_INF = -1e30
 DEFAULT_BLOCK_T = 512
@@ -75,14 +74,6 @@ FUSED_CC_CUSTOM_CALL_TARGETS = {
 }
 
 
-def record(path=None):
-    gate = GATE
-    if path is None:
-        path = ("interpret" if gate.interpret else "pallas") \
-            if gate.enabled() else "oracle"
-    get_kernel_registry().dispatch("fused_cc", path)
-
-
 # ---------------------------------------------------------------------------
 # family (a): matmul <-> collective fusion (mesh2d TP blocks)
 # ---------------------------------------------------------------------------
@@ -94,10 +85,8 @@ def _mm_kernel(x_ref, w_ref, o_ref):
 
 def _matmul(x, w):
     """``x @ w`` with the trailing contraction run as a row-tiled
-    Pallas GEMM when the gate is on (the compute half of every fused
-    form); jnp fallback otherwise."""
-    if not GATE.enabled():
-        return x @ w
+    Pallas GEMM: the compute half of every fused form (their oracle
+    branches do not come here)."""
     from jax.experimental import pallas as pl
 
     lead, k = x.shape[:-1], x.shape[-1]
@@ -148,13 +137,11 @@ def matmul_reduce_from(x, w, axis_name, tiles=DEFAULT_TILES):
 
 def _matmul_reduce_from_fwd(x, w, axis_name, tiles):
     n = w.shape[-1]
-    if not GATE.enabled():
-        record("oracle")
+    if GATE.path() == "oracle":
         partial = x @ w
         record_collective("psum", elements=partial.size,
                           dtype=partial.dtype, axis_name=axis_name)
         return lax.psum(partial, axis_name), (x, w)
-    record()
     t = _col_tiles(n, tiles)
     tn = n // t
     outs = []
@@ -194,8 +181,7 @@ def matmul_reduce_scatter(x, w, axis_name):
     payload/g == one reduce-scatter of payload."""
     m = x.shape[0]
     g = axis_world(axis_name)
-    if not GATE.enabled() or g <= 1 or m % g:
-        record("oracle")
+    if GATE.path(fits=g > 1 and m % g == 0) == "oracle":
         partial = x @ w
         record_collective("psum_scatter", elements=partial.size,
                           dtype=partial.dtype, axis_name=axis_name)
@@ -203,7 +189,6 @@ def matmul_reduce_scatter(x, w, axis_name):
             return partial
         return lax.psum_scatter(partial, axis_name,
                                 scatter_dimension=0, tiled=True)
-    record()
     chunk = m // g
     r = lax.axis_index(axis_name)
     perm = [(i, (i + 1) % g) for i in range(g)]
@@ -234,14 +219,12 @@ def all_gather_matmul(x_shard, w, axis_name):
     Wire bytes: g-1 permutes of the shard == one all-gather."""
     ms, k = x_shard.shape
     g = axis_world(axis_name)
-    if not GATE.enabled() or g <= 1:
-        record("oracle")
+    if GATE.path(fits=g > 1) == "oracle":
         record_collective("all_gather", elements=x_shard.size,
                           dtype=x_shard.dtype, axis_name=axis_name)
         full = x_shard if g <= 1 else lax.all_gather(
             x_shard, axis_name, axis=0, tiled=True)
         return full @ w
-    record()
     r = lax.axis_index(axis_name)
     perm = [(i, (i + 1) % g) for i in range(g)]
     n = w.shape[-1]
@@ -298,19 +281,25 @@ def _q_block(w, g, rep):
     return None
 
 
-def use_window(cache_len, block_t=DEFAULT_BLOCK_T, q_shape=None):
-    """True when the window kernel would actually run: gate on, the
-    serving scope hasn't opted out, a tile divides the cache buffer
-    and — given the ``[w, b, g, rep, d]`` query shape — the K/V lane
-    block is legal and a query block fits the scratch budget."""
-    if not (GATE.enabled() and _VERIFY_ENABLED
-            and choose_block(cache_len, block_t) is not None):
+def _window_fits(cache_len, block_t, q_shape=None):
+    """The window kernel's own half of the rule: the serving scope hasn't
+    opted out, a tile divides the cache buffer and — given the
+    ``[w, b, g, rep, d]`` query shape — the K/V lane block is legal and a
+    query block fits the scratch budget."""
+    if not _VERIFY_ENABLED or choose_block(cache_len, block_t) is None:
         return False
     if q_shape is None:
         return True
     w, b, g, rep, d = q_shape
     return lane_block_ok(GATE, b, g * d) \
         and _q_block(w, g, rep) is not None
+
+
+def use_window(cache_len, block_t=DEFAULT_BLOCK_T, q_shape=None):
+    """True when :func:`window_attention` would run the kernel (asked by
+    a caller that lays its operands out for it; not counted)."""
+    return GATE.path(fits=_window_fits(cache_len, block_t, q_shape),
+                     record=False) != "oracle"
 
 
 def window_attention_reference(qg, kt, vt, start, sm_scale,
@@ -471,11 +460,9 @@ def window_attention(qg, kt, vt, start, sm_scale, window=None,
     Returns ctx [w, b, g, rep, d] fp32.  Falls back to the einsum
     oracle when :func:`use_window` declines."""
     T = kt.shape[0]
-    if not use_window(T, block_t, qg.shape):
-        record("oracle")
+    if GATE.path(fits=_window_fits(T, block_t, qg.shape)) == "oracle":
         return window_attention_reference(qg, kt, vt, start, sm_scale,
                                           window, softcap)
-    record()
     return _window_pallas(qg, kt, vt, start, sm_scale, softcap, window,
                           choose_block(T, block_t))
 
@@ -563,10 +550,8 @@ def spec_verify_attention(q, kq, ks, vq, vs, start, sm_scale,
     quantization padding).  Returns ctx [w, g, rep, d] fp32."""
     T = kq.shape[0]
     w, g, rep, d = q.shape
-    if not use_window(T, block_t):
-        record("oracle")
+    if GATE.path(fits=_window_fits(T, block_t)) == "oracle":
         return spec_verify_reference(q, kq, ks, vq, vs, start, sm_scale)
-    record()
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -678,11 +663,9 @@ def quantize_pack_int4(x2d, scales):
     never lands in HBM before the collective."""
     if x2d.shape[1] % 2:
         x2d = jnp.pad(x2d, ((0, 0), (0, 1)))
-    if GATE.enabled():
-        record()
+    if GATE.path() != "oracle":
         return _cellwise("fused_cc_quantize_pack", _qp_kernel, jnp.uint8,
                          x2d.shape[1] // 2, x2d, scales)
-    record("oracle")
     return _quant4._pack_jnp(_quant4._quantize_jnp(x2d, scales))
 
 
@@ -690,13 +673,11 @@ def unpack_dequantize_int4(p2d, scales, n=None):
     """Receive-side fusion of unpack + dequantize: [nb, B/2] uint8 ->
     [nb, B] fp32 (optionally truncated to ``n`` real lanes) in ONE
     kernel."""
-    if GATE.enabled():
-        record()
+    if GATE.path() != "oracle":
         out = _cellwise("fused_cc_unpack_dequantize", _ud_kernel,
                         jnp.float32, p2d.shape[1] * 2,
                         p2d, scales)
     else:
-        record("oracle")
         out = _quant4._dequantize_jnp(_quant4._unpack_jnp(p2d), scales)
     return out[:, :n] if n is not None else out
 

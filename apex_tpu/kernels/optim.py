@@ -31,19 +31,13 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.kernels.registry import get_kernel_registry, kernel_gate
+from apex_tpu.kernels.registry import kernel_gate
 
-GATE_ADAM = kernel_gate("adam", default=True)
-GATE_LAMB = kernel_gate("lamb", default=True)
+GATE_ADAM = kernel_gate("adam")
+GATE_LAMB = kernel_gate("lamb")
 
 BLOCK = 256      # lanes per row — the compression block domain
 _ROWS = 8        # fp32 sublane tile
-
-
-def _record(name, gate):
-    path = ("interpret" if gate.interpret else "pallas") \
-        if gate.enabled() else "oracle"
-    get_kernel_registry().dispatch(name, path)
 
 
 def _to_blocks(flat):
@@ -113,8 +107,7 @@ def fused_adam_update(g, p, m, v, *, lr, bc1, bc2, b1, b2, eps,
     """One fused Adam update over a flat fp32 shard/bucket: returns
     ``(p_new, m_new, v_new)``. The oracle is byte-for-byte the update
     the ZeRO optimizers ran before the kernel existed."""
-    _record("adam", GATE_ADAM)
-    if GATE_ADAM.enabled():
+    if GATE_ADAM.path() != "oracle":
         kernel = functools.partial(
             _adam_kernel, b1=b1, b2=b2, eps=eps, wd=weight_decay,
             adam_w=adam_w)
@@ -162,8 +155,7 @@ def fused_lamb_mvu(g, p, m, v, *, bc1, bc2, b1, b2, beta3, eps,
     the ``p - lr * ratio * update`` apply stay with the caller — the
     ratio couples buckets through the existing segment-norm scalar
     join, which a bucket-local kernel must not absorb."""
-    _record("lamb", GATE_LAMB)
-    if GATE_LAMB.enabled():
+    if GATE_LAMB.path() != "oracle":
         kernel = functools.partial(
             _lamb_kernel, b1=b1, b2=b2, beta3=beta3, eps=eps,
             wd=weight_decay, adam_w=adam_w)

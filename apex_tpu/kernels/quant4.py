@@ -36,9 +36,9 @@ off TPU. Gate: ``quant4``.
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.kernels.registry import kernel_gate, record_dispatch
+from apex_tpu.kernels.registry import kernel_gate
 
-GATE = kernel_gate("quant4", default=True)
+GATE = kernel_gate("quant4")
 
 QMAX4 = 7.0
 _SCALE_QMAX = 255.0
@@ -172,7 +172,7 @@ def _cellwise(name, kernel, out_dtype, out_cols, x2d, *extra):
 
 def quantize_int4(x2d, scales):
     """[nb, B] fp32 + effective scales -> int4-valued int8 codes."""
-    if record_dispatch("quant4", GATE):
+    if GATE.path() != "oracle":
         def k(x_ref, s_ref, q_ref):
             _quant_kernel(x_ref, s_ref, q_ref)
         return _cellwise("quant4_quantize", k, jnp.int8, x2d.shape[1], x2d,
@@ -182,7 +182,7 @@ def quantize_int4(x2d, scales):
 
 def dequantize_int4(q2d, scales):
     """int4 codes (or int32 psum partials) + effective scales -> fp32."""
-    if q2d.dtype == jnp.int8 and record_dispatch("quant4", GATE):
+    if GATE.path(fits=q2d.dtype == jnp.int8) != "oracle":
         def k(q_ref, s_ref, o_ref):
             _dequant_kernel(q_ref, s_ref, o_ref)
         return _cellwise("quant4_dequantize", k, jnp.float32, q2d.shape[1],
@@ -193,7 +193,7 @@ def dequantize_int4(q2d, scales):
 def pack_int4(q2d):
     """[nb, B] int4 codes -> [nb, ceil(B/2)] uint8 split-half nibbles
     (a ragged odd-B tail pads one zero lane)."""
-    if record_dispatch("quant4", GATE):
+    if GATE.path() != "oracle":
         q2d = _pad_even_lanes(q2d)
 
         def k(q_ref, p_ref):
@@ -206,7 +206,7 @@ def pack_int4(q2d):
 def unpack_int4(p2d, n=None):
     """[nb, B/2] uint8 nibbles -> [nb, B] int4-valued int8 codes;
     ``n`` truncates a ragged tail's pad lane back off."""
-    if record_dispatch("quant4", GATE):
+    if GATE.path() != "oracle":
         def k(p_ref, q_ref):
             _unpack_kernel(p_ref, q_ref)
         out = _cellwise("quant4_unpack", k, jnp.int8, p2d.shape[1] * 2, p2d)

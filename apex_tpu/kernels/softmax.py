@@ -30,9 +30,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.kernels.registry import get_kernel_registry, kernel_gate
+from apex_tpu.kernels.registry import kernel_gate
 
-GATE = kernel_gate("softmax", default=True)
+GATE = kernel_gate("softmax")
 
 _MASK_VALUE = -10000.0
 
@@ -45,14 +45,12 @@ def _row_block(n_rows: int, sk: int) -> int:
     return rows
 
 
-def usable(scale) -> bool:
-    """The kernel path needs a static scale (it is baked into the
-    kernel); a traced scale falls back to the oracle."""
-    return isinstance(scale, (int, float)) and GATE.enabled()
-
-
-def record(path: str):
-    get_kernel_registry().dispatch("softmax", path)
+def usable(scale, fits: bool = True) -> bool:
+    """Does this call take the kernel (and count the call)? The kernel
+    needs a static scale (it is baked in); a traced scale takes the
+    oracle, as does a shape the caller says does not fit."""
+    return GATE.path(
+        fits=fits and isinstance(scale, (int, float))) != "oracle"
 
 
 # ---------------------------------------------------------------------------

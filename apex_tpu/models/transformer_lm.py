@@ -809,22 +809,23 @@ class ParallelAttention(nn.Module):
         # attention_mask (e.g. padding), a softcap, or a non-default
         # softmax scale must take the masked softmax path or they would
         # be silently ignored.
+        from apex_tpu.contrib import fmha
+
         seq_full = s * tp if cfg.sequence_parallel else s
+        layout = fmha.dense_layout(seq_full, np_local, kv)
         flash = (cfg.use_flash_attention and attention_mask is None
                  and cfg.attn_logit_softcapping is None
                  and cfg.query_pre_attn_scalar in (None, kv)
-                 and _flash_available(seq_full, kv))
+                 and layout is not None)
         # Where the heads fill whole 128-lane columns the kernels take
         # q, k, v and give the context as [b, s, n*d], the projections'
         # own layout: each of q, k, v then comes from a matmul of its
         # own over its columns of the stored weight, so that no
         # activation is sliced, transposed or lane-padded on the way.
-        from apex_tpu.contrib import fmha
-
         batch_major = (flash and not self.decode
                        and not cfg.context_parallel
                        and cfg.indexer_heads is None
-                       and fmha.fits_batch_major(np_local, kv))
+                       and layout == "bsnd")
 
         def heads_of(t):   # [s, b, n * kv] -> [s, b, n, kv]
             return t.reshape(*t.shape[:-1], -1, kv)
@@ -1227,13 +1228,6 @@ class ParallelAttention(nn.Module):
                          preferred_element_type=jnp.float32)
         ctx = ctx.reshape(s, b, np_local * kv)
         return self._output_proj(cfg, ctx)
-
-
-def _flash_available(seq, head_dim):
-    from apex_tpu.contrib.fmha import GATE
-
-    return (GATE.enabled() and seq % 128 == 0
-            and head_dim in (64, 128, 256))
 
 
 class ParallelMLP(nn.Module):

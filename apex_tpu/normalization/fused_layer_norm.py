@@ -7,10 +7,9 @@ Parity: reference apex/normalization/fused_layer_norm.py —
 ``manual_rms_norm`` (16-29).
 
 TPU design: modules are flax.linen Modules; the math lives in
-:mod:`apex_tpu.ops.layer_norm` — Pallas kernels from
-:mod:`apex_tpu.kernels.norm` behind the kernel registry's
-``layernorm``/``rmsnorm`` gates (docs/kernels.md), the jnp oracle
-everywhere else.
+:mod:`apex_tpu.ops.layer_norm`: the jnp forward and backward under a
+``custom_vjp``, which XLA fuses into their neighbours (no hand-written
+kernel).
 "Mixed" variants compute in fp32 but return the *parameter* dtype, matching
 the reference's mixed-dtype kernels (layer_norm_cuda.cpp
 ``forward_affine_mixed_dtypes``).

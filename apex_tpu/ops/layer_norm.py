@@ -1,25 +1,18 @@
-"""Fused LayerNorm / RMSNorm — Pallas TPU kernels with custom VJP.
+"""Fused LayerNorm / RMSNorm — the jnp formulation under a custom VJP.
 
 Parity: reference csrc/layer_norm_cuda.cpp (442) + layer_norm_cuda_kernel.cu
 (1,170) exporting ``forward[_affine]``, ``backward[_affine]``,
 ``rms_forward*``, ``rms_backward*`` — consumed by
 apex/normalization/fused_layer_norm.py:32-165.
 
-TPU design: the kernel bodies live in :mod:`apex_tpu.kernels.norm`
-(one Pallas kernel per (fwd, bwd-dx) pass, row-blocked, fp32 row stats
-on the VPU; backward recomputes stats from the stashed input instead of
-round-tripping them through HBM) behind the ``layernorm`` / ``rmsnorm``
-gates of the kernel registry (:mod:`apex_tpu.kernels.registry` —
-``APEX_TPU_KERNELS`` master switch, per-kernel overrides, legacy
-``APEX_TPU_PALLAS_LN=1`` still honored). This module keeps the public
-entry points, the custom VJP wiring, and the pure-jnp oracle — the
-math XLA fuses itself, which is both the non-TPU fallback (CPU tests;
-the reference's own CPU path exists "mainly for unittest sake",
-fused_layer_norm.py:411-413) and the kernels' parity reference. The
-kernels default OFF even on TPU: measured on a real chip (BERT-large,
-hidden 1024) the jnp path is ~14% faster end-to-end because XLA's own
-LN fusion matches the kernel's bandwidth and the custom-call is a
-fusion barrier.
+TPU design: the row statistics, the normalization and the affine are a
+chain XLA fuses into its neighbours by itself, so this module is the
+public entry points, the shape handling and a ``custom_vjp`` around the
+jnp forward and backward (fp32 statistics; the backward recomputes them
+from the stashed input instead of keeping them). There is no
+hand-written kernel: the one there was lost on the chip (BERT-large,
+hidden 1024: 14% of a step, the custom call being a fusion barrier with
+layout copies behind it) and was taken out.
 """
 
 import functools
@@ -27,37 +20,12 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.kernels import norm as _kernels
-from apex_tpu.kernels.registry import PallasGate, get_kernel_registry
-
-_INTERPRET = False  # flipped by tests to debug kernels
-
-
-def _record(name, use, gate):
-    """kernels/dispatch telemetry (trace-time; no-op when the metrics
-    registry is disabled)."""
-    path = ("interpret" if (use and _interp(gate))
-            else "pallas" if use else "oracle")
-    get_kernel_registry().dispatch(name, path)
-
-
-def _use_pallas(*arrays_and_gate) -> bool:
-    """Whether to run the hand-written Pallas kernel instead of the jnp
-    lowering XLA fuses itself — the registry gate's decision (tests
-    monkeypatch this to force the kernel on CPU). An optional
-    :class:`PallasGate` positional selects the rmsnorm gate; default is
-    the layernorm gate."""
-    gate = next((a for a in arrays_and_gate if isinstance(a, PallasGate)),
-                _kernels.GATE_LN)
-    return gate.enabled()
-
-
-def _interp(gate):
-    return _INTERPRET or gate.interpret
-
 
 def _ln_stats(x):
-    return _kernels._ln_stats(x)
+    mean = jnp.mean(x, axis=-1, keepdims=True)
+    xc = x - mean
+    var = jnp.mean(xc * xc, axis=-1, keepdims=True)
+    return mean, var
 
 
 # ---------------------------------------------------------------------------
@@ -65,11 +33,6 @@ def _ln_stats(x):
 # ---------------------------------------------------------------------------
 
 def _ln_fwd(x2d, weight, bias, eps):
-    use = _use_pallas(x2d)
-    _record("layernorm", use, _kernels.GATE_LN)
-    if use:
-        return _kernels.ln_fwd(x2d, weight, bias, eps,
-                               interpret=_interp(_kernels.GATE_LN))
     x = x2d.astype(jnp.float32)
     mean, var = _ln_stats(x)
     y = (x - mean) * jax.lax.rsqrt(var + eps)
@@ -81,9 +44,6 @@ def _ln_fwd(x2d, weight, bias, eps):
 
 
 def _ln_bwd_dx(dy2d, x2d, weight, eps):
-    if _use_pallas(x2d):
-        return _kernels.ln_bwd_dx(dy2d, x2d, weight, eps,
-                                  interpret=_interp(_kernels.GATE_LN))
     dy = dy2d.astype(jnp.float32)
     x = x2d.astype(jnp.float32)
     mean, var = _ln_stats(x)
@@ -153,11 +113,6 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, eps=1e-5,
 # ---------------------------------------------------------------------------
 
 def _rms_fwd(x2d, weight, eps):
-    use = _use_pallas(x2d, _kernels.GATE_RMS)
-    _record("rmsnorm", use, _kernels.GATE_RMS)
-    if use:
-        return _kernels.rms_fwd(x2d, weight, eps,
-                                interpret=_interp(_kernels.GATE_RMS))
     x = x2d.astype(jnp.float32)
     ms = jnp.mean(x * x, axis=-1, keepdims=True)
     y = x * jax.lax.rsqrt(ms + eps)
@@ -167,9 +122,6 @@ def _rms_fwd(x2d, weight, eps):
 
 
 def _rms_bwd_dx(dy2d, x2d, weight, eps):
-    if _use_pallas(x2d, _kernels.GATE_RMS):
-        return _kernels.rms_bwd_dx(dy2d, x2d, weight, eps,
-                                   interpret=_interp(_kernels.GATE_RMS))
     dy = dy2d.astype(jnp.float32)
     x = x2d.astype(jnp.float32)
     ms = jnp.mean(x * x, axis=-1, keepdims=True)

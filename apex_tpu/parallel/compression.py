@@ -38,8 +38,7 @@ convergence test holds the same 2% bound).
 
 The quantize/dequantize kernels ride the kernel registry
 (:mod:`apex_tpu.kernels.registry`): gates ``quant`` (int8) and
-``quant4``, master switch ``APEX_TPU_KERNELS``, the legacy
-``APEX_TPU_COMPRESS_PALLAS`` still honored with a DeprecationWarning;
+``quant4``, the one switch ``APEX_TPU_KERNELS=0``;
 :func:`force_interpret` runs them in interpreter mode for CPU tests.
 Off TPU the pure-``jnp`` formulations below are both the fallback and
 the kernels' parity oracles.
@@ -50,7 +49,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from apex_tpu.kernels import quant4 as _quant4
-from apex_tpu.kernels.registry import kernel_gate, record_dispatch
+from apex_tpu.kernels.registry import kernel_gate
 from apex_tpu.telemetry import comm as _telemetry_comm
 
 # ~256 lanes per scale: 2 TPU lane-groups wide, 0.4% scale overhead.
@@ -63,17 +62,13 @@ _QMAX = 127.0
 # compression modes whose collectives return an error-feedback residual
 RESIDUAL_MODES = ("int8", "int4")
 
-_GATE = kernel_gate("quant", legacy_env="APEX_TPU_COMPRESS_PALLAS")
+_GATE = kernel_gate("quant")
 
 
 def needs_residual(mode) -> bool:
     """Whether ``mode`` makes the compressed collectives stateful —
     returning ``(result, new_residual)`` for error feedback."""
     return mode in RESIDUAL_MODES
-
-
-def _gate():
-    return _GATE
 
 
 def force_interpret(on: bool):
@@ -152,7 +147,7 @@ def _quantize_pallas(x2d, scales):
                   pl.BlockSpec((_ROWS_PER_CELL, 1), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((_ROWS_PER_CELL, bs), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x2d.shape, jnp.int8),
-        interpret=_gate().interpret,
+        interpret=_GATE.interpret,
         name="quant_quantize",
     )(x2d, s)
     return q[:nb]
@@ -171,7 +166,7 @@ def _dequantize_pallas(q2d, scales):
                   pl.BlockSpec((_ROWS_PER_CELL, 1), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((_ROWS_PER_CELL, bs), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(q2d.shape, jnp.float32),
-        interpret=_gate().interpret,
+        interpret=_GATE.interpret,
         name="quant_dequantize",
     )(q2d, s)
     return out[:nb]
@@ -186,14 +181,14 @@ def quantize_blockwise(flat, block_size: int = BLOCK_SIZE, scales=None):
     x2d = pad_to_blocks(flat, block_size)
     if scales is None:
         scales = block_scales(x2d)
-    if record_dispatch("quant", _gate()):
+    if _GATE.path() != "oracle":
         return _quantize_pallas(x2d, scales), scales
     return _quantize_jnp(x2d, scales), scales
 
 
 def dequantize_blockwise(q2d, scales, n=None):
     """(q [nblocks, b] int8/int32, scales [nblocks, 1]) -> [n] fp32."""
-    if record_dispatch("quant", _gate()):
+    if _GATE.path() != "oracle":
         out = _dequantize_pallas(q2d, scales)
     else:
         out = _dequantize_jnp(q2d, scales)
@@ -217,7 +212,7 @@ def quantize_rows_blockwise(x, block_size: int = BLOCK_SIZE):
                    ((0, 0), (0, nb * block_size - n)))
     flat = flat.reshape(-1, block_size)
     scales = block_scales(flat)
-    q = (_quantize_pallas(flat, scales) if record_dispatch("quant", _gate())
+    q = (_quantize_pallas(flat, scales) if _GATE.path() != "oracle"
          else _quantize_jnp(flat, scales))
     return (q.reshape(*lead, nb, block_size),
             scales.reshape(*lead, nb, 1))
@@ -232,7 +227,7 @@ def dequantize_rows_blockwise(q, scales, n=None):
     block_size = q.shape[-1]
     flat = q.reshape(-1, block_size)
     s = scales.reshape(-1, 1)
-    out = (_dequantize_pallas(flat, s) if record_dispatch("quant", _gate())
+    out = (_dequantize_pallas(flat, s) if _GATE.path() != "oracle"
            else _dequantize_jnp(flat, s))
     out = out.reshape(*lead, q.shape[-2] * block_size)
     return out if n is None else out[..., :n]
@@ -362,7 +357,7 @@ def psum_compressed_blocks(x2d, axis_name, *, scale_mult=None):
     ``err2d`` is the local quantization error in the SAME 2-D block
     layout (the next step's residual, zero pad tail included)."""
     scales = _shared_scales(x2d, axis_name)
-    q = (_quantize_pallas(x2d, scales) if _gate().enabled()
+    q = (_quantize_pallas(x2d, scales) if _GATE.path() != "oracle"
          else _quantize_jnp(x2d, scales))
     _telemetry_comm.record_collective(
         "psum", elements=q.size, dtype=jnp.int8, axis_name=axis_name,
@@ -409,7 +404,7 @@ def psum_scatter_compressed(flat, axis_name, *, mode="int8", residual=None,
         dq = _quant4._dequantize_jnp(q, scales)
     else:
         scales = _shared_scales(x2d, axis_name)
-        q = _quantize_pallas(x2d, scales) if _gate().enabled() \
+        q = _quantize_pallas(x2d, scales) if _GATE.path() != "oracle" \
             else _quantize_jnp(x2d, scales)
         _telemetry_comm.record_collective(
             "psum_scatter", elements=q.size, dtype=jnp.int8,
@@ -477,7 +472,7 @@ def _all_gather_int4(shard, axis_name, *, block_size=BLOCK_SIZE):
                          1e-12)
     sq, gmax = _quant4.int4_block_scales(absmax)
     scales = _quant4.effective_scales(sq, gmax)
-    fused = _fused_cc.GATE.enabled()
+    fused = _fused_cc.GATE.path(record=False) != "oracle"
     if fused:
         packed = _fused_cc.quantize_pack_int4(x2d, scales)
     else:

@@ -109,8 +109,7 @@ _MOVED_WARNED = False
 def _warn_moved(old_module):
     """One DeprecationWarning per process across BOTH compat shims —
     the first of ``schedules`` / ``p2p_communication`` to be imported
-    warns, the second stays silent (same contract as the
-    ``contrib._pallas_gate`` retirement pattern)."""
+    warns, the second stays silent."""
     global _MOVED_WARNED
     if _MOVED_WARNED:
         return

@@ -32,11 +32,8 @@ from apex_tpu.transformer.enums import AttnMaskType
 def scaled_upper_triang_masked_softmax(x, scale):
     """Causal-masked scaled softmax over [b, sq, sk] or [b, np, sq, sk]
     (reference scaled_upper_triang_masked_softmax_cuda)."""
-    if _kernels.usable(scale) and x.ndim == 3:
-        _kernels.record("interpret" if _kernels.GATE.interpret
-                        else "pallas")
+    if _kernels.usable(scale, fits=x.ndim == 3):
         return _kernels.scaled_upper_triang_masked_softmax(x, float(scale))
-    _kernels.record("oracle")
     xf = x.astype(jnp.float32) * scale
     sq, sk = x.shape[-2], x.shape[-1]
     causal = jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)
@@ -53,12 +50,9 @@ def scaled_masked_softmax(x, mask, scale):
     if mask is None:
         return scaled_softmax(x, scale)
     if _kernels.usable(scale):
-        _kernels.record("interpret" if _kernels.GATE.interpret
-                        else "pallas")
         maskf = jnp.broadcast_to(mask.astype(bool), x.shape) \
             .astype(jnp.float32)
         return _kernels.scaled_masked_softmax(x, maskf, float(scale))
-    _kernels.record("oracle")
     xf = x.astype(jnp.float32) * scale
     xf = jnp.where(mask.astype(bool), -10000.0, xf)
     xf = xf - jnp.max(xf, axis=-1, keepdims=True)
@@ -70,10 +64,7 @@ def scaled_masked_softmax(x, mask, scale):
 def scaled_softmax(x, scale):
     """No-mask scaled softmax (reference scaled_softmax_cuda)."""
     if _kernels.usable(scale):
-        _kernels.record("interpret" if _kernels.GATE.interpret
-                        else "pallas")
         return _kernels.scaled_softmax(x, float(scale))
-    _kernels.record("oracle")
     xf = x.astype(jnp.float32) * scale
     xf = xf - jnp.max(xf, axis=-1, keepdims=True)
     e = jnp.exp(xf)

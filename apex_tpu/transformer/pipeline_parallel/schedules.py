@@ -3,8 +3,7 @@
 the reference-parity schedule machinery unchanged — this module
 re-exports it so the ``apex.transformer.pipeline_parallel.schedules``
 API surface keeps resolving here (one DeprecationWarning per process,
-shared with the ``p2p_communication`` shim; the ``contrib._pallas_gate``
-retirement pattern)."""
+shared with the ``p2p_communication`` shim)."""
 
 from apex_tpu.parallel.pipeline import (  # noqa: F401
     PIPELINE_PARALLEL_AXIS,

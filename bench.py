@@ -1032,7 +1032,10 @@ def bench_mla_decode(prefix, steps):
     def run_variant(flash):
         # the kernel/einsum choice is a trace-time branch: fresh jitted
         # callables per variant get their own cache entries
-        os.environ["APEX_TPU_MLA_FLASH"] = "1" if flash else "0"
+        if flash:
+            os.environ.pop("APEX_TPU_KERNELS", None)
+        else:
+            os.environ["APEX_TPU_KERNELS"] = "0"
 
         @jax.jit
         def prefill(params, prompt):
@@ -1064,7 +1067,7 @@ def bench_mla_decode(prefix, steps):
 
     dt_einsum = run_variant(False)
     dt_flash = run_variant(True)
-    os.environ.pop("APEX_TPU_MLA_FLASH", None)
+    os.environ.pop("APEX_TPU_KERNELS", None)
 
     # fwd flops/token: projections + absorbed attention over the mean
     # live prefix + swiglu + head (rough; the roofline here is HBM —
@@ -1216,7 +1219,6 @@ def bench_kernels(size, steps):
     from apex_tpu.kernels import optim as _koptim
     from apex_tpu.kernels import quant4 as _quant4
     from apex_tpu.kernels.registry import get_kernel_registry
-    from apex_tpu.ops import layer_norm as _ln_ops
     from apex_tpu.parallel import compression
     from apex_tpu.transformer.functional import fused_softmax as _fsm
 
@@ -1224,9 +1226,6 @@ def bench_kernels(size, steps):
     rng = np.random.RandomState(0)
     h = 512
     rows = int(size)
-    x2d = jnp.asarray(rng.randn(rows, h).astype(np.float32))
-    w = jnp.asarray(rng.randn(h).astype(np.float32))
-    b = jnp.asarray(rng.randn(h).astype(np.float32))
     x3d = jnp.asarray(rng.randn(8, 128, 128).astype(np.float32))
     nflat = rows * h
     g, p, m, v = (jnp.asarray(rng.randn(nflat).astype(np.float32))
@@ -1237,12 +1236,11 @@ def bench_kernels(size, steps):
     on_tpu = _backend_verdict() == "tpu"
 
     def time_leg(make_fn, args, names, kernel_on):
-        env_keys = [f"APEX_TPU_KERNEL_{n.upper()}" for n in names]
-        old = {k: os.environ.get(k) for k in env_keys}
+        old = {"APEX_TPU_KERNELS": os.environ.get("APEX_TPU_KERNELS")}
         try:
-            for k in env_keys:
-                os.environ[k] = "1" if kernel_on else "0"
-            if kernel_on and not on_tpu:
+            if not kernel_on:
+                os.environ["APEX_TPU_KERNELS"] = "0"
+            elif not on_tpu:
                 kreg.force_interpret(True, names)
             fn = jax.jit(make_fn())
             out = fn(*args)
@@ -1259,19 +1257,6 @@ def bench_kernels(size, steps):
                 else:
                     os.environ[k] = val
             kreg.force_interpret(False, names)
-
-    def rms_make():
-        def f(x, wv):
-            return jax.value_and_grad(
-                lambda xx: jnp.sum(_ln_ops.rms_norm(xx, h, wv) ** 2))(x)
-        return f
-
-    def ln_make():
-        def f(x, wv, bv):
-            return jax.value_and_grad(
-                lambda xx: jnp.sum(
-                    _ln_ops.layer_norm(xx, h, wv, bv) ** 2))(x)
-        return f
 
     def sm_make():
         def f(x):
@@ -1308,8 +1293,6 @@ def bench_kernels(size, steps):
         return f
 
     families = [
-        ("rmsnorm", rms_make, (x2d, w), ["rmsnorm"]),
-        ("layernorm", ln_make, (x2d, w, b), ["layernorm"]),
         ("softmax", sm_make, (x3d,), ["softmax"]),
         ("adam", adam_make, (g, p, m, v), ["adam"]),
         ("lamb", lamb_make, (g, p, m, v), ["lamb"]),
@@ -1424,15 +1407,16 @@ def bench_fused_cc(size, steps):
     ring_args = (gather_full,)
 
     def leg_env(fused_on):
-        key = "APEX_TPU_KERNEL_FUSED_CC"
+        key = "APEX_TPU_KERNELS"
         old = os.environ.get(key)
-        os.environ[key] = "1" if fused_on else "0"
-        if fused_on and not on_tpu:
+        if not fused_on:
+            os.environ[key] = "0"
+        elif not on_tpu:
             kreg.force_interpret(True, ["fused_cc"])
         return old
 
     def leg_restore(old):
-        key = "APEX_TPU_KERNEL_FUSED_CC"
+        key = "APEX_TPU_KERNELS"
         if old is None:
             os.environ.pop(key, None)
         else:

@@ -87,9 +87,8 @@ def rel_err(got, want):
 def counted_dispatches(expect):
     """Run a leg under a live metrics registry and hold its kernel
     dispatch counters to the smoke's rule: every name in ``expect`` took
-    the compiled Pallas path at least once, and no default-on kernel
-    took the interpreter or its oracle."""
-    from apex_tpu.kernels.registry import get_kernel_registry
+    the compiled Pallas path at least once, and no kernel took the
+    interpreter or its oracle."""
     from apex_tpu.telemetry.registry import MetricsRegistry, use_registry
 
     with use_registry(MetricsRegistry(enabled=True)) as reg:
@@ -98,12 +97,7 @@ def counted_dispatches(expect):
                 for k, v in reg.snapshot()["counters"].items()
                 if k.startswith("kernels/dispatch/")}
     say("  kernel dispatches", json.dumps(counters, sort_keys=True))
-    # kernels registered default-off (the norms) take their oracle
-    # inside the model; that is their default, not a stray
-    kernels = get_kernel_registry()
-    default_off = {n for n in kernels.names() if not kernels.gate(n).default}
-    stray = [k for k in counters if not k.endswith("_pallas")
-             and k.rpartition("_")[0] not in default_off]
+    stray = [k for k in counters if not k.endswith("_pallas")]
     assert not stray, f"dispatch left the compiled Pallas path: {stray}"
     missing = [n for n in expect if not counters.get(f"{n}_pallas")]
     assert not missing, f"no Pallas dispatch recorded for {missing}"
@@ -307,27 +301,18 @@ class KernelCase(NamedTuple):
     bound: object
 
 
-def gates_off(fn, *gates):
+def gates_off(fn):
     """The jnp oracle of a registry kernel IS its gate-off path: trace
-    the same entry point with the kernel's own env var at 0, with the
-    dispatch uncounted (an oracle run on purpose is not a stray one)."""
+    the same entry point under ``APEX_TPU_KERNELS=0``, with the dispatch
+    uncounted (an oracle run on purpose is not a stray one)."""
     from apex_tpu.telemetry.registry import MetricsRegistry, use_registry
 
     def oracle(*args):
-        with mock.patch.dict(os.environ, {g.env_var: "0" for g in gates}), \
+        with mock.patch.dict(os.environ, {"APEX_TPU_KERNELS": "0"}), \
                 use_registry(MetricsRegistry(enabled=False)):
             return fn(*args)
 
     return oracle
-
-
-def opted_in(fn, gate):
-    """The default-off norm kernels run through their explicit gate."""
-    def kernel(*args):
-        with mock.patch.dict(os.environ, {gate.env_var: "1"}):
-            return fn(*args)
-
-    return kernel
 
 
 def randn(key, shape, dtype=jnp.bfloat16):
@@ -340,9 +325,7 @@ def kernel_cases():
     16 heads of 64, cache 1024) and the attention family also at a GQA
     layout (g=4, rep=4, d=64, cache 2048)."""
     from apex_tpu.contrib import fmha, gqa_decode, mla_decode
-    from apex_tpu.kernels import fused_cc, norm, optim, quant4
-    from apex_tpu.kernels import softmax as ksoftmax
-    from apex_tpu.normalization import FusedLayerNorm, FusedRMSNorm
+    from apex_tpu.kernels import fused_cc, optim, quant4
     from apex_tpu.parallel import compression
     from apex_tpu.transformer.functional import fused_softmax
 
@@ -456,8 +439,7 @@ def kernel_cases():
 
         def int8_cache(g=g, rep=rep, T=T):
             # quantized on the jnp path: the verify case stands alone
-            quant = gates_off(compression.quantize_rows_blockwise,
-                              compression._GATE)
+            quant = gates_off(compression.quantize_rows_blockwise)
             kq, ks = quant(randn(9, (T, g * 64)))
             vq, vs = quant(randn(10, (T, g * 64)))
             return (randn(11, (5, g, rep, 64)), kq, ks, vq, vs,
@@ -485,36 +467,34 @@ def kernel_cases():
 
     # -- quant4 -------------------------------------------------------------
     add("quant4 quantize", quant4.quantize_int4,
-        gates_off(quant4.quantize_int4, quant4.GATE), int4_args, "codes")
+        gates_off(quant4.quantize_int4), int4_args, "codes")
 
     def int4_pack_unpack(x, s):
         return quant4.unpack_int4(quant4.pack_int4(
             quant4._quantize_jnp(x, s)))
 
     add("quant4 pack/unpack", int4_pack_unpack,
-        gates_off(int4_pack_unpack, quant4.GATE), int4_args, "exact")
+        gates_off(int4_pack_unpack), int4_args, "exact")
 
     def int4_dequantize(x, s):
         return quant4.dequantize_int4(quant4._quantize_jnp(x, s), s)
 
     add("quant4 dequantize", int4_dequantize,
-        gates_off(int4_dequantize, quant4.GATE), int4_args, TOL_F32)
+        gates_off(int4_dequantize), int4_args, TOL_F32)
 
     # -- quant (int8: the KV-cache and gradient grid) -----------------------
     add("quant quantize_rows_blockwise",
         lambda x: compression.quantize_rows_blockwise(x)[0],
-        gates_off(lambda x: compression.quantize_rows_blockwise(x)[0],
-                  compression._GATE),
+        gates_off(lambda x: compression.quantize_rows_blockwise(x)[0]),
         lambda: (randn(13, (SEQ, 1024)),), "codes")
 
     def int8_args():
-        return gates_off(compression.quantize_rows_blockwise,
-                         compression._GATE)(randn(13, (SEQ, 1024)))
+        return gates_off(compression.quantize_rows_blockwise)(
+            randn(13, (SEQ, 1024)))
 
     add("quant dequantize_rows_blockwise",
         compression.dequantize_rows_blockwise,
-        gates_off(compression.dequantize_rows_blockwise,
-                  compression._GATE), int8_args, TOL_F32)
+        gates_off(compression.dequantize_rows_blockwise), int8_args, TOL_F32)
 
     # -- softmax: causal forward + backward, masked forward -----------------
     # The backward works from the SAVED bf16 probabilities (as the
@@ -528,7 +508,7 @@ def kernel_cases():
                 fused_softmax.scaled_masked_softmax(x[None], mask, sm))
 
     add("softmax causal fwd+bwd, masked fwd", softmaxes,
-        gates_off(softmaxes, ksoftmax.GATE),
+        gates_off(softmaxes),
         lambda: (randn(14, (16, SEQ, SEQ)), jax.random.bernoulli(
             jax.random.PRNGKey(15), 0.3, (1, 1, SEQ, SEQ)),
             randn(24, (16, SEQ, SEQ))),
@@ -545,27 +525,8 @@ def kernel_cases():
                              bc2=0.001, **hyper)
     lamb = functools.partial(optim.fused_lamb_mvu, bc1=0.1, bc2=0.001,
                              beta3=0.1, **hyper)
-    add("adam", adam, gates_off(adam, optim.GATE_ADAM), shard, TOL_F32)
-    add("lamb", lamb, gates_off(lamb, optim.GATE_LAMB), shard, TOL_F32)
-
-    # -- the default-off norm kernels, through their explicit gate ----------
-    for name, mod, gate in (
-            ("layernorm", FusedLayerNorm(normalized_shape=1024),
-             norm.GATE_LN),
-            ("rmsnorm", FusedRMSNorm(normalized_shape=1024), norm.GATE_RMS)):
-        # an independent cotangent: dy = y makes a norm's dx vanish
-        # analytically and the comparison one of rounding noise
-        def norm_fwd_bwd(p, x, dy, mod=mod):
-            y, vjp = jax.vjp(lambda t: mod.apply(p, t), x)
-            return y, vjp(dy)[0]
-
-        def norm_args(mod=mod):
-            x = randn(20, (BATCH * SEQ, 1024))
-            return (mod.init(jax.random.PRNGKey(0), x), x,
-                    randn(25, (BATCH * SEQ, 1024)))
-
-        add(f"{name} fwd+bwd", opted_in(norm_fwd_bwd, gate),
-            gates_off(norm_fwd_bwd, gate), norm_args, TOL_BF16)
+    add("adam", adam, gates_off(adam), shard, TOL_F32)
+    add("lamb", lamb, gates_off(lamb), shard, TOL_F32)
     return cases
 
 
@@ -630,7 +591,7 @@ def matmul_collectives_check(devices):
             randn(23, (4096, 1024)))
     with counted_dispatches(["fused_cc"]):
         got = sharded(body)(*args)
-    want = sharded(gates_off(body, fused_cc.GATE))(*args)
+    want = sharded(gates_off(body))(*args)
     name = "fused_cc matmul collectives tp=4"
     say(f"  {name}", compare(name, TOL_MXU, got, want))
 

@@ -10,7 +10,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import apex_tpu.models.transformer_lm as tlm
 from apex_tpu.contrib import fmha
 from apex_tpu.kernels import registry as kreg
 from apex_tpu.models import TransformerConfig
@@ -24,7 +23,9 @@ def interpret(monkeypatch):
     """The flash kernels in interpret mode, and the model's gate open at
     widths it would refuse on a chip."""
     monkeypatch.setattr(fmha.GATE, "interpret", True)
-    monkeypatch.setattr(tlm, "_flash_available", lambda s, d: True)
+    monkeypatch.setattr(
+        fmha, "dense_layout", lambda s, n, d: (
+            "bnsd" if fmha._heads_per_cell(n, d) is None else "bsnd"))
     return monkeypatch
 
 
@@ -103,7 +104,7 @@ def test_off_the_kernel_path_the_entry_is_the_oracle():
 def test_heads_that_fill_no_128_lane_column_are_refused(heads, width):
     x = jnp.zeros((1, 128, width))
     assert not (width % heads == 0
-                and fmha.fits_batch_major(heads, width // heads))
+                and fmha._heads_per_cell(heads, width // heads))
     with pytest.raises(ValueError, match="128-lane columns"):
         fmha.flash_attention_bsnd(x, x, x, heads)
 
@@ -112,7 +113,67 @@ def test_heads_that_fill_no_128_lane_column_are_refused(heads, width):
     (16, 64, True), (1, 64, False), (3, 128, True), (2, 256, True),
     (4, 32, True), (2, 32, False), (2, 96, False)])
 def test_which_heads_fit(heads, head_dim, fits):
-    assert fmha.fits_batch_major(heads, head_dim) is fits
+    assert (fmha._heads_per_cell(heads, head_dim) is not None) is fits
+
+
+# (sequence, local heads, head size) -> (flash?, batch-major?): what
+# ``transformer_lm._flash_available(seq, head_dim)`` and
+# ``fmha.fits_batch_major(heads, head_dim)`` gave on the commit before
+# the model's rule moved into ``fmha.dense_layout`` (PR 31), written down
+# from that code: flash at whole 128-row tiles and head sizes 64, 128,
+# 256; batch-major where the heads fill whole 128-lane columns.
+MODEL_RULE = [
+    (96, 16, 64, False, False),       # fmha itself would take one block of 96
+    (128, 16, 64, True, True),
+    (1024, 16, 64, True, True),       # GPT-2 345M
+    (1024, 15, 64, True, False),      # an odd number of heads of 64
+    (1024, 1, 64, True, False),
+    (1024, 4, 128, True, True),
+    (8192, 3, 128, True, True),
+    (8192, 32, 128, True, True),      # Keye's heads (the indexer keeps it
+                                      # head-major; that is the model's)
+    (256, 2, 256, True, True),
+    (1024, 8, 96, False, False),
+    (1024, 4, 32, False, False),      # bsnd takes 4 heads of 32; no model does
+    (1024, 16, 512, False, False),
+    (640, 16, 64, True, True),        # 128 divides it, 512 and 256 do not
+    (200, 16, 64, False, False),
+    (64, 2, 64, False, False),
+]
+
+
+@pytest.mark.parametrize("seq,heads,head_dim,flash,batch_major", MODEL_RULE)
+def test_the_models_question_has_the_answers_it_had(
+        monkeypatch, seq, heads, head_dim, flash, batch_major):
+    want = (None if not flash else "bsnd" if batch_major else "bnsd")
+    # on a CPU no kernel runs: the model keeps its softmax path
+    assert fmha.dense_layout(seq, heads, head_dim) is None
+    monkeypatch.setattr(kreg, "_on_tpu", lambda: True)
+    assert fmha.dense_layout(seq, heads, head_dim) == want
+    monkeypatch.setenv("APEX_TPU_KERNELS", "0")
+    assert fmha.dense_layout(seq, heads, head_dim) is None
+    monkeypatch.delenv("APEX_TPU_KERNELS")
+    monkeypatch.setattr(kreg, "_on_tpu", lambda: False)
+    monkeypatch.setattr(fmha.GATE, "interpret", True)
+    assert fmha.dense_layout(seq, heads, head_dim) == want
+
+
+def test_the_models_question_is_not_counted():
+    with use_registry(MetricsRegistry(enabled=True)) as reg:
+        fmha.dense_layout(1024, 16, 64)
+    assert reg.snapshot()["counters"] == {}
+
+
+def test_a_direct_call_keeps_the_wider_rule(monkeypatch):
+    """96 rows: one block of 96 for ``flash_attention`` itself, the
+    softmax path for a model."""
+    monkeypatch.setattr(fmha.GATE, "interpret", True)
+    assert fmha.dense_layout(96, 2, 64) is None
+    q = jnp.ones((1, 2, 96, 64), jnp.float32)
+    with use_registry(MetricsRegistry(enabled=True)) as reg:
+        jax.eval_shape(lambda: fmha.flash_attention(q, q, q))
+    assert reg.counter_value(
+        "kernels/dispatch/flash_attention_interpret") == 1
 
 
 def _stack(checkpointing=True, **kw):
@@ -151,7 +212,7 @@ def test_the_model_takes_the_entry_from_its_heads_and_nothing_else_moves(
     head-major call, and the counter of its own says which ran."""
     def run(batch_major):
         if not batch_major:
-            interpret.setattr(fmha, "fits_batch_major", lambda n, d: False)
+            interpret.setattr(fmha, "dense_layout", lambda s, n, d: "bnsd")
         params, hidden, loss = _stack(**MODELS[model])
         with use_registry(MetricsRegistry(enabled=True)) as reg:
             value, grads = jax.jit(jax.value_and_grad(

@@ -144,7 +144,7 @@ class TestMatmulCollectiveFusion:
             return np.asarray(shard_map(body, **specs)(x, w))
 
         got = run()
-        monkeypatch.setenv("APEX_TPU_KERNEL_FUSED_CC", "0")
+        KREG.force_interpret(False, ["fused_cc"])
         want = run()
         np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
@@ -162,7 +162,7 @@ class TestMatmulCollectiveFusion:
             return np.asarray(shard_map(body, **specs)(xfull, w))
 
         got = run()
-        monkeypatch.setenv("APEX_TPU_KERNEL_FUSED_CC", "0")
+        KREG.force_interpret(False, ["fused_cc"])
         want = run()
         np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
@@ -209,7 +209,7 @@ class TestMatmulCollectiveFusion:
             return reg.snapshot()["counters"].get("comm/bytes", 0.0)
 
         fused_bytes = leg()
-        monkeypatch.setenv("APEX_TPU_KERNEL_FUSED_CC", "0")
+        KREG.force_interpret(False, ["fused_cc"])
         unfused_bytes = leg()
         assert fused_bytes == unfused_bytes > 0
 
@@ -343,7 +343,6 @@ class TestModelWindowWiring:
             fused_logits = run()
         finally:
             KREG.force_interpret(False, ["fused_cc"])
-        monkeypatch.setenv("APEX_TPU_KERNEL_FUSED_CC", "0")
         einsum_logits = run()
         np.testing.assert_allclose(fused_logits, einsum_logits,
                                    rtol=2e-5, atol=2e-5)
@@ -415,7 +414,7 @@ class TestQuantizeIntoRing:
                 out_specs=P())(full))
 
         got = run()
-        monkeypatch.setenv("APEX_TPU_KERNEL_FUSED_CC", "0")
+        KREG.force_interpret(False, ["fused_cc"])
         want = run()
         np.testing.assert_array_equal(got, want)
 
@@ -605,7 +604,7 @@ class TestStaticParityLowered:
             return jax.jit(fn).lower(*args).as_text()
 
         fused_bytes = asharding.static_comm_bytes(lowered())
-        monkeypatch.setenv("APEX_TPU_KERNEL_FUSED_CC", "0")
+        KREG.force_interpret(False, ["fused_cc"])
         unfused_bytes = asharding.static_comm_bytes(lowered())
         assert fused_bytes == unfused_bytes > 0
 

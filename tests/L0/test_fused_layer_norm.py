@@ -146,65 +146,3 @@ class TestModules:
         x = jnp.asarray(rng.randn(8, 64).astype(np.float32)).astype(jnp.bfloat16)
         y = fused_layer_norm(x, 64)
         assert y.dtype == jnp.bfloat16
-
-
-class TestPallasKernels:
-    """Exercise the hand-written Pallas LN/RMS kernels in interpreter mode
-    on CPU (the dispatch default routes to the jnp lowering — measured
-    faster end-to-end — so without this the kernel code would be dead in
-    CI). Mirrors the fmha interpret-mode pattern in test_contrib.py."""
-
-    @pytest.fixture(autouse=True)
-    def _interpret_pallas(self, monkeypatch):
-        from apex_tpu.ops import layer_norm as ln_mod
-
-        monkeypatch.setattr(ln_mod, "_INTERPRET", True)
-        monkeypatch.setattr(ln_mod, "_use_pallas", lambda *a: True)
-
-    def test_ln_fwd_bwd_vs_oracle(self, rng):
-        x = jnp.asarray(rng.randn(64, 128).astype(np.float32))
-        w = jnp.asarray(rng.randn(128).astype(np.float32))
-        b = jnp.asarray(rng.randn(128).astype(np.float32))
-
-        def ours(x, w, b):
-            return jnp.sum(fused_layer_norm_affine(x, w, b, 128, eps=1e-5) ** 2)
-
-        def oracle(x, w, b):
-            mu = x.mean(-1, keepdims=True)
-            var = x.var(-1, keepdims=True)
-            return jnp.sum((((x - mu) / jnp.sqrt(var + 1e-5)) * w + b) ** 2)
-
-        np.testing.assert_allclose(float(ours(x, w, b)),
-                                   float(oracle(x, w, b)), rtol=1e-5)
-        g_ours = jax.grad(ours, argnums=(0, 1, 2))(x, w, b)
-        g_ref = jax.grad(oracle, argnums=(0, 1, 2))(x, w, b)
-        for a, r in zip(g_ours, g_ref):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(r),
-                                       rtol=1e-3, atol=5e-4)
-
-    def test_rms_fwd_bwd_vs_oracle(self, rng):
-        x = jnp.asarray(rng.randn(32, 256).astype(np.float32))
-        w = jnp.asarray(rng.randn(256).astype(np.float32))
-
-        def ours(x, w):
-            return jnp.sum(fused_rms_norm_affine(x, w, 256, eps=1e-5) ** 2)
-
-        def oracle(x, w):
-            ms = jnp.mean(x * x, -1, keepdims=True)
-            return jnp.sum((x / jnp.sqrt(ms + 1e-5) * w) ** 2)
-
-        np.testing.assert_allclose(float(ours(x, w)), float(oracle(x, w)),
-                                   rtol=1e-5)
-        g_ours = jax.grad(ours, argnums=(0, 1))(x, w)
-        g_ref = jax.grad(oracle, argnums=(0, 1))(x, w)
-        for a, r in zip(g_ours, g_ref):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(r),
-                                       rtol=1e-3, atol=5e-4)
-
-    def test_bf16_kernel_path(self, rng):
-        x = jnp.asarray(rng.randn(16, 128).astype(np.float32),
-                        dtype=jnp.bfloat16)
-        w = jnp.ones((128,), jnp.float32)
-        b = jnp.zeros((128,), jnp.float32)
-        out = fused_layer_norm_affine(x, w, b, 128)
-        assert bool(jnp.isfinite(out.astype(jnp.float32)).all())

@@ -75,7 +75,6 @@ def test_generate_token_exact_kernel_vs_einsum(case, monkeypatch):
 
     out_kernel = generate(model, params, prompt, 10)
 
-    monkeypatch.setenv("APEX_TPU_DECODE_FLASH", "0")
     gqa_decode.force_interpret(False)
     # fresh jit cache entries: the flag is read at trace time
     from apex_tpu.models import generation as gen_mod
@@ -104,7 +103,6 @@ def test_alibi_stays_on_einsum(monkeypatch):
 
     from apex_tpu.models import generation as gen_mod
 
-    monkeypatch.setenv("APEX_TPU_DECODE_FLASH", "0")
     gqa_decode.force_interpret(False)
     gen_mod._compiled.cache_clear()
     out_einsum = generate(model, params, prompt, 6)
@@ -116,7 +114,7 @@ def test_block_ladder_nondivisible_buffers():
     """A 1280-long buffer is not a 512-multiple but IS a 256-multiple:
     the ladder must pick 256 and keep the kernel (review finding) —
     parity at a length crossing several 256-tiles."""
-    from apex_tpu.contrib._pallas_gate import choose_block
+    from apex_tpu.kernels.registry import choose_block
 
     assert choose_block(1280, 512) == 256
     assert choose_block(1536, 512) == 512

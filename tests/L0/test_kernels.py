@@ -1,8 +1,8 @@
 """apex_tpu.kernels — the Pallas fused-kernel layer (ISSUE 14).
 
 Covers the tentpole acceptance on the CPU container: registry
-semantics (APEX_TPU_KERNELS master switch, per-kernel overrides,
-legacy-env deprecation, zero-overhead-off dispatch telemetry);
+semantics (the one rule and its truth table, the APEX_TPU_KERNELS=0
+switch, zero-overhead-off dispatch telemetry);
 interpret-mode parity for all four kernel families against their jnp
 oracles (bit-exact for the RMSNorm forward and the int4 quantize
 codes / nibble packing; the documented few-ulp FMA-association bound
@@ -19,7 +19,6 @@ Pallas binary) per the tier-1 budget rules.
 """
 
 import functools
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -36,12 +35,12 @@ from apex_tpu.kernels.registry import (
     get_kernel_registry,
     kernel_gate,
 )
-from apex_tpu.ops import layer_norm as ln_ops
 from apex_tpu.parallel import (
     DistributedDataParallel,
     compression,
     init_residual,
 )
+from apex_tpu.telemetry.registry import MetricsRegistry, use_registry
 from apex_tpu.testing import shard_map
 from apex_tpu.transformer.functional import fused_softmax as fsm
 
@@ -83,108 +82,170 @@ def _opt_inputs(rng, n=700):
 # registry
 # ---------------------------------------------------------------------------
 
+# what the one rule gives: (APEX_TPU_KERNELS, interpret, on a TPU, fits)
+def _expected_path(switch, interpret, on_tpu, fits):
+    if switch == "0" or not fits:
+        return "oracle"
+    if interpret:
+        return "interpret"
+    return "pallas" if on_tpu else "oracle"
+
+
+REGISTERED = ("adam", "flash_attention", "fused_cc", "gqa_decode", "lamb",
+              "mla_decode", "quant", "quant4", "softmax")
+
+
+def _tiny_entries():
+    """Every registered kernel's public entry at a tiny shape."""
+    from apex_tpu.contrib import fmha, gqa_decode, mla_decode
+    from apex_tpu.kernels import fused_cc
+
+    f32 = jnp.float32
+    x = jnp.ones((8, 256), f32)
+    flat = jnp.ones((700,), f32)
+    q = jnp.ones((1, 2, 128, 64), f32)
+    return {
+        "adam": lambda: koptim.fused_adam_update(flat, flat, flat, flat,
+                                                 **ADAM_KW),
+        "lamb": lambda: koptim.fused_lamb_mvu(flat, flat, flat, flat,
+                                              **LAMB_KW),
+        "flash_attention": lambda: fmha.flash_attention(q, q, q),
+        "fused_cc": lambda: fused_cc.quantize_pack_int4(
+            x, jnp.ones((8, 1), f32)),
+        "gqa_decode": lambda: gqa_decode.gqa_flash_decode(
+            jnp.ones((1, 2, 2, 64), f32), jnp.ones((128, 1, 2, 64), f32),
+            jnp.ones((128, 1, 2, 64), f32), jnp.asarray(5), 0.125),
+        "mla_decode": lambda: mla_decode.mla_flash_decode(
+            jnp.ones((1, 2, 160), f32), jnp.ones((128, 1, 160), f32),
+            jnp.asarray(5), 128, 0.1),
+        "quant": lambda: compression.quantize_blockwise(flat),
+        "quant4": lambda: quant4.quantize_int4(x, jnp.ones((8, 1), f32)),
+        "softmax": lambda: fsm.scaled_softmax(x, 0.5),
+    }
+
+
+# the environment options this decision had before there was one rule
+DELETED_OPTIONS = (
+    r"APEX_TPU_(KERNEL_[A-Z0-9_]+|DISABLE_PALLAS|PALLAS_LN|COMPRESS_PALLAS"
+    r"|DECODE_FLASH|MLA_FLASH)\b")
+
+
 class TestRegistry:
+    @pytest.mark.parametrize("fits", [True, False])
+    @pytest.mark.parametrize("on_tpu", [False, True])
+    @pytest.mark.parametrize("interpret", [False, True])
+    @pytest.mark.parametrize("switch", [None, "0", "1"])
+    def test_the_one_rule(self, monkeypatch, switch, interpret, on_tpu,
+                          fits):
+        """APEX_TPU_KERNELS x interpret x backend x fits -> path; ``0``
+        beats a forced interpreter, and the path returned is the path
+        counted."""
+        if switch is None:
+            monkeypatch.delenv("APEX_TPU_KERNELS", raising=False)
+        else:
+            monkeypatch.setenv("APEX_TPU_KERNELS", switch)
+        monkeypatch.setattr(kreg_mod, "_on_tpu", lambda: on_tpu)
+        gate = PallasGate("truth_table")      # not registered
+        gate.force_interpret(interpret)
+        want = _expected_path(switch, interpret, on_tpu, fits)
+        with use_registry(MetricsRegistry(enabled=True)) as reg:
+            assert gate.path(fits=fits, record=False) == want
+            assert reg.snapshot()["counters"] == {}
+            assert gate.path(fits=fits) == want
+        assert reg.snapshot()["counters"] == {
+            "kernels/dispatch": 1, f"kernels/truth_table/{want}": 1,
+            f"kernels/dispatch/truth_table_{want}": 1}
+
     def test_master_switch_kills_every_kernel(self, monkeypatch):
         """APEX_TPU_KERNELS=0 is the oracle everywhere — it wins even
         over a forced interpreter (the bit-identity escape hatch)."""
         monkeypatch.setenv("APEX_TPU_KERNELS", "0")
         KREG.force_interpret(True)
         try:
-            assert not any(KREG.enabled(n) for n in KREG.names())
-        finally:
-            KREG.force_interpret(False)
-
-    def test_per_kernel_override_wins_over_master(self, monkeypatch):
-        monkeypatch.setenv("APEX_TPU_KERNELS", "0")
-        monkeypatch.setenv("APEX_TPU_KERNEL_RMSNORM", "1")
-        KREG.force_interpret(True, ["rmsnorm", "layernorm"])
-        try:
-            assert KREG.enabled("rmsnorm")
-            assert not KREG.enabled("layernorm")
-        finally:
-            KREG.force_interpret(False)
-
-    def test_global_pallas_kill_wins_over_everything(self, monkeypatch):
-        monkeypatch.setenv("APEX_TPU_DISABLE_PALLAS", "1")
-        monkeypatch.setenv("APEX_TPU_KERNEL_RMSNORM", "1")
-        KREG.force_interpret(True, ["rmsnorm"])
-        try:
-            assert not KREG.enabled("rmsnorm")
+            assert {KREG.gate(n).path(record=False)
+                    for n in KREG.names()} == {"oracle"}
         finally:
             KREG.force_interpret(False)
 
     def test_cpu_backend_without_interpret_is_oracle(self):
         # no env, no interpret: CPU container -> every gate off
-        assert not any(KREG.enabled(n) for n in KREG.names())
+        assert {KREG.gate(n).path(record=False)
+                for n in KREG.names()} == {"oracle"}
 
-    def test_legacy_compress_pallas_warns_once(self, monkeypatch):
-        monkeypatch.setattr(kreg_mod, "_warned_legacy", set())
-        monkeypatch.setenv("APEX_TPU_COMPRESS_PALLAS", "1")
-        gate = KREG.gate("quant")
-        with warnings.catch_warnings(record=True) as w:
-            warnings.simplefilter("always")
-            gate.enabled()
-            gate.enabled()
-        deps = [x for x in w if issubclass(x.category, DeprecationWarning)]
-        assert len(deps) == 1
-        assert "APEX_TPU_COMPRESS_PALLAS" in str(deps[0].message)
+    def test_every_kernel_registers_here_and_the_norms_are_gone(self):
+        entries = _tiny_entries()       # imports every kernel module
+        assert list(REGISTERED) == KREG.names() == sorted(entries)
+        assert {"gqa_decode", "mla_decode"} <= set(REGISTERED)
+        assert not {"layernorm", "rmsnorm"} & set(REGISTERED)
 
-    def test_legacy_pallas_ln_still_opts_in(self, monkeypatch):
-        """The documented LN alias keeps working (no deprecation —
-        only COMPRESS_PALLAS is deprecated)."""
-        monkeypatch.setenv("APEX_TPU_PALLAS_LN", "1")
-        gate = KREG.gate("layernorm")
-        with warnings.catch_warnings(record=True) as w:
-            warnings.simplefilter("always")
-            vote = gate._env_vote()
-        assert vote is True
-        assert not [x for x in w
-                    if issubclass(x.category, DeprecationWarning)]
+    @pytest.mark.parametrize("name", REGISTERED)
+    def test_on_a_cpu_the_public_entry_takes_and_counts_the_oracle(
+            self, name):
+        with use_registry(MetricsRegistry(enabled=True)) as reg:
+            jax.eval_shape(_tiny_entries()[name])
+        counters = reg.snapshot()["counters"]
+        assert counters[f"kernels/dispatch/{name}_oracle"] >= 1
+        assert counters[f"kernels/{name}/oracle"] >= 1
+        assert not [k for k in counters if k.startswith("kernels/")
+                    and k.endswith(("pallas", "interpret"))]
 
-    def test_contrib_shim_reexports(self):
-        from apex_tpu.contrib._pallas_gate import (
-            PallasGate as ShimGate,
-            choose_block,
-        )
+    @pytest.mark.parametrize("name", REGISTERED)
+    def test_forced_into_the_interpreter_the_entry_counts_it(self, name):
+        KREG.force_interpret(True, [name])
+        try:
+            with use_registry(MetricsRegistry(enabled=True)) as reg:
+                jax.eval_shape(_tiny_entries()[name])
+        finally:
+            KREG.force_interpret(False, [name])
+        assert reg.counter_value(
+            f"kernels/dispatch/{name}_interpret") >= 1
+        assert reg.counter_value(f"kernels/dispatch/{name}_oracle") == 0
 
-        assert ShimGate is PallasGate
-        assert choose_block(1280, 512) == 256
+    def test_the_deleted_options_are_neither_read_nor_documented(self):
+        import pathlib
+        import re
+
+        root = pathlib.Path(__file__).resolve().parents[2]
+        files = [root / "chip_smoke.py", root / "README.md",
+                 *(root / "apex_tpu").rglob("*.py"),
+                 *(root / "docs").rglob("*.md")]
+        assert len(files) > 100
+        found = [f"{f.relative_to(root)}: {m.group(0)}" for f in files
+                 for m in re.finditer(DELETED_OPTIONS, f.read_text())]
+        assert not found
+        assert not (root / "apex_tpu/kernels/norm.py").exists()
+        assert not (root / "apex_tpu/contrib/_pallas_gate.py").exists()
+
+    def test_kernel_gate_takes_a_name_and_nothing_else(self):
+        import inspect
+
+        assert list(inspect.signature(kernel_gate).parameters) == ["name"]
+        assert list(inspect.signature(KREG.register).parameters) == ["name"]
 
     def test_register_is_idempotent(self):
-        g1 = kernel_gate("rmsnorm")
-        g2 = kernel_gate("rmsnorm", default=True)
-        assert g1 is g2 is KREG.gate("rmsnorm")
+        g1 = kernel_gate("softmax")
+        g2 = kernel_gate("softmax")
+        assert g1 is g2 is KREG.gate("softmax") is ksm.GATE
 
     def test_dispatch_records_only_when_enabled(self):
-        from apex_tpu.telemetry.registry import (
-            MetricsRegistry,
-            use_registry,
-        )
-
         off = MetricsRegistry(enabled=False)
         with use_registry(off):
-            KREG.dispatch("rmsnorm", "oracle")
+            KREG.dispatch("softmax", "oracle")
+            ksm.GATE.path()
         assert off.snapshot()["counters"] == {}
         on = MetricsRegistry(enabled=True)
         with use_registry(on):
-            KREG.dispatch("rmsnorm", "oracle")
-            KREG.dispatch("rmsnorm", "interpret")
+            KREG.dispatch("softmax", "oracle")
+            KREG.dispatch("softmax", "interpret")
         snap = on.snapshot()["counters"]
         assert snap["kernels/dispatch"] == 2
-        assert snap["kernels/rmsnorm/oracle"] == 1
-        assert snap["kernels/rmsnorm/interpret"] == 1
+        assert snap["kernels/softmax/oracle"] == 1
+        assert snap["kernels/softmax/interpret"] == 1
 
     def test_dispatch_event_lands_in_jsonl(self, tmp_path):
-        from apex_tpu.telemetry.registry import (
-            MetricsRegistry,
-            use_registry,
-        )
-
         reg = MetricsRegistry(enabled=True, jsonl_dir=str(tmp_path))
         with use_registry(reg):
-            x = jnp.ones((4, 128), jnp.float32)
-            w = jnp.ones((128,), jnp.float32)
-            ln_ops.rms_norm(x, 128, w)
+            fsm.scaled_softmax(jnp.ones((4, 128), jnp.float32), 1.0)
             reg.flush()
         import json
 
@@ -192,7 +253,7 @@ class TestRegistry:
         for f in tmp_path.glob("*.jsonl"):
             events += [json.loads(l) for l in f.read_text().splitlines()]
         k = [e for e in events if e.get("kind") == "kernel"]
-        assert k and k[0]["kernel"] == "rmsnorm" \
+        assert k and k[0]["kernel"] == "softmax" \
             and k[0]["path"] == "oracle"
 
 
@@ -232,31 +293,6 @@ class TestTelemetryReportKernelKind:
 # ---------------------------------------------------------------------------
 
 class TestNormParity:
-    def test_rms_fwd_bit_exact(self, rng, interpret):
-        x = jnp.asarray(rng.randn(32, 128).astype(np.float32))
-        w = jnp.asarray(rng.randn(128).astype(np.float32))
-        KREG.force_interpret(False)
-        oracle = np.asarray(ln_ops.rms_norm(x, 128, w))
-        KREG.force_interpret(True)
-        kernel = np.asarray(ln_ops.rms_norm(x, 128, w))
-        np.testing.assert_array_equal(kernel, oracle)
-
-    def test_ln_fwd_bwd_within_bound(self, rng, interpret):
-        x = jnp.asarray(rng.randn(32, 128).astype(np.float32))
-        w = jnp.asarray(rng.randn(128).astype(np.float32))
-        b = jnp.asarray(rng.randn(128).astype(np.float32))
-
-        def f(xx):
-            return jnp.sum(ln_ops.layer_norm(xx, 128, w, b) ** 2)
-
-        KREG.force_interpret(False)
-        v0, g0 = jax.value_and_grad(f)(x)
-        KREG.force_interpret(True)
-        v1, g1 = jax.value_and_grad(f)(x)
-        np.testing.assert_allclose(float(v1), float(v0), rtol=FMA_RTOL)
-        np.testing.assert_allclose(np.asarray(g1), np.asarray(g0),
-                                   rtol=FMA_RTOL, atol=FMA_ATOL)
-
     def test_gate_off_is_todays_path(self, rng, monkeypatch):
         """APEX_TPU_KERNELS=0 through the public normalization entry
         point is bit-identical to the default (oracle) path."""
@@ -335,7 +371,12 @@ class TestSoftmaxParity:
         """A non-static scale cannot be baked into a kernel — usable()
         refuses and the entry point stays on the oracle."""
         assert not ksm.usable(jnp.float32(1.0))
-        assert ksm.usable(1.0) == ksm.GATE.enabled()
+        assert not ksm.usable(1.0)        # a CPU: the oracle all the same
+        ksm.GATE.force_interpret(True)
+        try:
+            assert ksm.usable(1.0) and not ksm.usable(jnp.float32(1.0))
+        finally:
+            ksm.GATE.force_interpret(False)
 
     def test_fully_masked_rows_match_oracle(self, rng, interpret):
         """An all-masked row follows the oracle's convention exactly

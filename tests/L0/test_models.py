@@ -100,16 +100,24 @@ def test_dcgan_one_amp_step_finite(rng):
         assert bool(jnp.isfinite(leaf.astype(jnp.float32)).all())
 
 
+def _any_width(fmha_mod):
+    """``fmha.dense_layout`` without its rule of sequence and head size:
+    the model takes the flash kernels at widths it would refuse on a
+    chip, in the layout the heads allow."""
+    return lambda seq, heads, head_dim: (
+        "bnsd" if fmha_mod._heads_per_cell(heads, head_dim) is None
+        else "bsnd")
+
+
 def test_gpt_flash_attention_path_jits(monkeypatch, rng):
     """The model-level flash path must survive jit+grad (regression: the
     attention layer once passed a traced jnp scale into the flash
     custom_vjp's static nondiff argument, blowing up only when
     use_flash_attention was actually enabled on TPU)."""
     import apex_tpu.contrib.fmha as fmha_mod
-    import apex_tpu.models.transformer_lm as tlm
 
     monkeypatch.setattr(fmha_mod.GATE, "interpret", True)
-    monkeypatch.setattr(tlm, "_flash_available", lambda s, d: True)
+    monkeypatch.setattr(fmha_mod, "dense_layout", _any_width(fmha_mod))
 
     from apex_tpu.models import GPTModel, TransformerConfig
 
@@ -139,7 +147,6 @@ def test_gpt_sliding_window_flash_matches_masked_path(monkeypatch, rng):
     """Model-level SWA through the flash kernel (window band block-skip)
     must match the masked-softmax fold of the same config."""
     import apex_tpu.contrib.fmha as fmha_mod
-    import apex_tpu.models.transformer_lm as tlm
 
     from apex_tpu.models import GPTModel, TransformerConfig
 
@@ -157,7 +164,7 @@ def test_gpt_sliding_window_flash_matches_masked_path(monkeypatch, rng):
 
     masked = logits(use_flash=False)
     monkeypatch.setattr(fmha_mod.GATE, "interpret", True)
-    monkeypatch.setattr(tlm, "_flash_available", lambda s, d: True)
+    monkeypatch.setattr(fmha_mod, "dense_layout", _any_width(fmha_mod))
     flash = logits(use_flash=True)
     np.testing.assert_allclose(flash, masked, rtol=2e-4, atol=2e-4)
 
@@ -166,7 +173,6 @@ def test_gpt_alibi_flash_matches_masked_path(monkeypatch, rng):
     """Model-level ALiBi through the flash kernel (in-kernel key-position
     bias) must match the masked-softmax score-bias path."""
     import apex_tpu.contrib.fmha as fmha_mod
-    import apex_tpu.models.transformer_lm as tlm
 
     from apex_tpu.models import GPTModel, TransformerConfig
 
@@ -184,7 +190,7 @@ def test_gpt_alibi_flash_matches_masked_path(monkeypatch, rng):
 
     masked = logits(use_flash=False)
     monkeypatch.setattr(fmha_mod.GATE, "interpret", True)
-    monkeypatch.setattr(tlm, "_flash_available", lambda s, d: True)
+    monkeypatch.setattr(fmha_mod, "dense_layout", _any_width(fmha_mod))
     flash = logits(use_flash=True)
     np.testing.assert_allclose(flash, masked, rtol=2e-4, atol=2e-4)
 
@@ -204,10 +210,9 @@ def flash_interpreted(monkeypatch):
     """The flash kernels in interpret mode, at widths the gate of the
     model would refuse on a chip."""
     import apex_tpu.contrib.fmha as fmha_mod
-    import apex_tpu.models.transformer_lm as tlm
 
     monkeypatch.setattr(fmha_mod.GATE, "interpret", True)
-    monkeypatch.setattr(tlm, "_flash_available", lambda s, d: True)
+    monkeypatch.setattr(fmha_mod, "dense_layout", _any_width(fmha_mod))
     return monkeypatch
 
 

@@ -85,7 +85,6 @@ def test_every_registered_kernel_is_covered():
     covered = {c.name.split()[0] for c in CASES}
     missing = set(kreg.get_kernel_registry().names()) - covered
     assert not missing, f"no TPU lowering case for {sorted(missing)}"
-    assert {"gqa_decode", "mla_decode"} <= covered
 
 
 # The names the kernels give themselves (``pl.pallas_call(name=)``), by
@@ -116,8 +115,6 @@ KERNEL_NAMES = {
     "softmax": ("softmax_fwd", "softmax_bwd"),
     "adam": ("fused_adam",),
     "lamb": ("fused_lamb",),
-    "layernorm": ("layer_norm_fwd", "layer_norm_bwd"),
-    "rmsnorm": ("rms_norm_fwd", "rms_norm_bwd"),
 }
 _TEXTS = {}
 

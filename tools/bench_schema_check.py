@@ -224,8 +224,6 @@ STATIC_COMM_FIELDS_SINCE_ROUND = 18
 KERNELS_FIELDS_SINCE_ROUND = 19
 KERNELS_METRIC_PREFIX = "kernels_"
 KERNELS_REQUIRED_FIELDS = (
-    "rmsnorm_kernel_ms", "rmsnorm_xla_ms",
-    "layernorm_kernel_ms", "layernorm_xla_ms",
     "softmax_kernel_ms", "softmax_xla_ms",
     "adam_kernel_ms", "adam_xla_ms",
     "lamb_kernel_ms", "lamb_xla_ms",
