@@ -18,6 +18,9 @@ existing Python entry points:
   int8 ``quant`` pair lives in ``compression`` itself)
 - :mod:`apex_tpu.kernels.fused_cc` — matmul + collective, the verify
   window's flash attention, int4 quantize + pack around a collective
+- :mod:`apex_tpu.kernels.topk_select` — the sparse-attention indexer's
+  top-k selection with its rows of scores held in VMEM (entry:
+  ``apex_tpu.models.transformer_lm.topk_selection``)
 - ``apex_tpu.contrib.fmha`` (``flash_attention``),
   ``apex_tpu.contrib.gqa_decode`` and ``apex_tpu.contrib.mla_decode``
   register their gates here too.
@@ -25,7 +28,7 @@ existing Python entry points:
 See docs/kernels.md for the rule, parity bounds, and wire formats.
 """
 
-from apex_tpu.kernels import optim, quant4, softmax  # noqa: F401
+from apex_tpu.kernels import optim, quant4, softmax, topk_select  # noqa: F401
 from apex_tpu.kernels.registry import (  # noqa: F401
     KernelRegistry,
     PallasGate,

@@ -584,10 +584,30 @@ INDEXER_CHUNK = 512   # queries scored at a time (DSA's q_chunk_size)
 
 def topk_selection(scores, topk: int):
     """``[b, s, s]`` int8: for each query ``t`` the ``min(t + 1, topk)``
-    keys ``u <= t`` of largest ``scores[b, t, u]``. By a threshold a row,
-    the ``topk``-th largest score found by bisection over the float32's
-    bits (32 counting passes, no sort, no gather); scores that tie with
-    the threshold are all kept."""
+    keys ``u <= t`` of largest float32 ``scores[b, t, u]``. By a threshold
+    a row, the ``topk``-th largest score found by bisection over the
+    float32's bits (32 counting passes, no sort, no gather); scores that
+    tie with the threshold are all kept.
+
+    The kernel registry's one rule picks who computes it (counted as
+    ``kernels/dispatch/topk_select_<path>``): the Pallas kernel
+    ``indexer_topk_select`` (``kernels/topk_select.py``), which holds a
+    block of query rows in VMEM through all 32 passes and reads the
+    scores from HBM once, or the jnp oracle below, one sequence at a time
+    so that its temporaries stay a sequence's, where the shape does not
+    fit (``topk_select.fits``), off the TPU and under
+    ``APEX_TPU_KERNELS=0``. The two agree in every element."""
+    from apex_tpu.kernels import topk_select
+
+    if topk_select.GATE.path(topk_select.fits(scores.shape)) != "oracle":
+        return topk_select.topk_select(scores, topk)
+    return jax.lax.map(
+        lambda row: _topk_selection_oracle(row[None], topk)[0], scores)
+
+
+def _topk_selection_oracle(scores, topk: int):
+    """:func:`topk_selection` in jnp: the kernel's oracle. Every pass
+    builds the keys from ``scores`` again, so it reads them 33 times."""
     b, s, _ = scores.shape
     t = jnp.arange(s)
     causal = t[None, :] <= t[:, None]
@@ -621,10 +641,12 @@ class SparseIndexer(nn.Module):
     ``I[t, u] = sum_j w[t, j] * heads^-1/2 * head_dim^-1/2 *
     relu(qI[t, j] . kI[u])``. ``__call__`` -> ``(I [b, s, s] float32,
     selection [b, s, s] int8)``, the selection being each query's
-    ``indexer_topk`` best causal keys (:func:`topk_selection`). Scores
-    are computed one sequence and ``INDEXER_CHUNK`` queries at a time, so
-    ``[heads, s, s]`` is never live; selection and loss run a sequence at
-    a time too.
+    ``indexer_topk`` best causal keys (:func:`topk_selection`, under the
+    scope ``indexer/select``: one ``indexer_topk_select`` kernel over the
+    whole ``[b, s, s]`` where the kernel registry gives it, the jnp
+    bisection a sequence at a time elsewhere). Scores are computed one
+    sequence and ``INDEXER_CHUNK`` queries at a time, so ``[heads, s, s]``
+    is never live; the loss runs a sequence at a time too.
 
     ``loss`` sows ``indexer_loss`` into ``moe_losses``:
     ``mean_t KL(p_t || softmax_{selected u} I[t, u])`` with ``p_t`` the
@@ -691,9 +713,8 @@ class SparseIndexer(nn.Module):
                                               w.transpose(1, 0, 2),
                                               k.transpose(1, 0, 2)))
         with jax.named_scope("indexer/select"):
-            selection = jax.lax.map(
-                lambda row: topk_selection(row[None], cfg.indexer_topk)[0],
-                jax.lax.stop_gradient(scores))
+            selection = topk_selection(jax.lax.stop_gradient(scores),
+                                       cfg.indexer_topk)
         get_registry().gauge("attention/selected_keys_max").set(
             min(cfg.indexer_topk, s))
         return scores, selection

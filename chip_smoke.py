@@ -299,6 +299,7 @@ class KernelCase(NamedTuple):
     oracle: Callable
     make_args: Callable
     bound: object
+    timed: bool = False     # also print what a call of each takes
 
 
 def gates_off(fn):
@@ -326,6 +327,7 @@ def kernel_cases():
     layout (g=4, rep=4, d=64, cache 2048)."""
     from apex_tpu.contrib import fmha, gqa_decode, mla_decode
     from apex_tpu.kernels import fused_cc, optim, quant4
+    from apex_tpu.models import transformer_lm
     from apex_tpu.parallel import compression
     from apex_tpu.transformer.functional import fused_softmax
 
@@ -333,8 +335,9 @@ def kernel_cases():
     layouts = ((16, 1, SEQ), (4, 4, 2048))   # (g, rep, cache length)
     cases = []
 
-    def add(name, kernel, oracle, make_args, bound):
-        cases.append(KernelCase(name, kernel, oracle, make_args, bound))
+    def add(name, kernel, oracle, make_args, bound, timed=False):
+        cases.append(KernelCase(name, kernel, oracle, make_args, bound,
+                                timed))
 
     # -- flash attention, forward and backward ----------------------------
     def fwd_bwd(attn):
@@ -399,6 +402,16 @@ def kernel_cases():
         sparse(lambda q, k, v, sel: fmha.sparse_attention(q, k, v, sel,
                                                           True)),
         sparse(sparse_oracle), selection_args, TOL_MXU)
+
+    # -- the indexer's top-k selection at the shape the Keye cell runs it
+    # (a layer's float32 index scores, 2 x 8192 x 8192, top-2048): the
+    # kernel's int8 selection is the oracle's in every element
+    def select(scores):
+        return transformer_lm.topk_selection(scores, 2048)
+
+    add("topk_select [2, 8192, 8192] top-2048", select, gates_off(select),
+        lambda: (randn(25, (2, 8192, 8192), jnp.float32),), "exact",
+        timed=True)
 
     # -- gqa_decode at three fill levels; GQA with window + soft cap -------
     for (g, rep, T), kw in zip(layouts,
@@ -551,15 +564,29 @@ def compare(name, bound, got, want):
     return f"max rel err {err:.1e} (bound {bound:.0e})"
 
 
+def ms_a_call(fn, args, calls=5):
+    """Device time of one call of a compiled ``fn``: the host's clock
+    around ``calls`` dispatches in flight, waited for at the end (after
+    one call that wakes the device from the host's turn before it)."""
+    jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    for _ in range(calls):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return round((time.perf_counter() - t0) / calls * 1e3, 2)
+
+
 def kernels_leg():
     cases = kernel_cases()
     with counted_dispatches({c.name.split()[0] for c in cases}):
         for case in cases:
             args = case.make_args()
-            got = jax.jit(case.kernel)(*args)
-            want = jax.jit(case.oracle)(*args)
+            kernel, oracle = jax.jit(case.kernel), jax.jit(case.oracle)
             say(f"  {case.name}",
-                compare(case.name, case.bound, got, want))
+                compare(case.name, case.bound, kernel(*args), oracle(*args)))
+            if case.timed:
+                say(f"  {case.name}, device ms a call, kernel / oracle",
+                    f"{ms_a_call(kernel, args)} / {ms_a_call(oracle, args)}")
 
 
 # ---------------------------------------------------------------------------

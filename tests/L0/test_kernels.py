@@ -92,13 +92,14 @@ def _expected_path(switch, interpret, on_tpu, fits):
 
 
 REGISTERED = ("adam", "flash_attention", "fused_cc", "gqa_decode", "lamb",
-              "mla_decode", "quant", "quant4", "softmax")
+              "mla_decode", "quant", "quant4", "softmax", "topk_select")
 
 
 def _tiny_entries():
     """Every registered kernel's public entry at a tiny shape."""
     from apex_tpu.contrib import fmha, gqa_decode, mla_decode
     from apex_tpu.kernels import fused_cc
+    from apex_tpu.models import transformer_lm
 
     f32 = jnp.float32
     x = jnp.ones((8, 256), f32)
@@ -121,6 +122,8 @@ def _tiny_entries():
         "quant": lambda: compression.quantize_blockwise(flat),
         "quant4": lambda: quant4.quantize_int4(x, jnp.ones((8, 1), f32)),
         "softmax": lambda: fsm.scaled_softmax(x, 0.5),
+        "topk_select": lambda: transformer_lm.topk_selection(
+            jnp.ones((1, 128, 128), f32), 8),
     }
 
 

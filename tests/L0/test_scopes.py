@@ -155,6 +155,11 @@ def test_update_and_loss_are_not_charged_to_the_model(tables, model,
      ("attention/kernel", "recompute")),
     ("jit(f)/transpose(jvp(BertModel))/transformer/layer_0/self_attention/"
      "transpose", ("attention", "backward")),
+    # the indexer's selection kernel is the indexer's, not attention/kernel
+    ("jit(f)/transpose(jvp(GPTModel))/transformer/checkpoint/"
+     "rematted_computation/layer_1/self_attention/indexer/indexer/select/"
+     "jit(_select)/indexer_topk_select/pallas_call",
+     ("indexer", "recompute")),
     ("jit(f)/jvp(BertModel)/head/lm_layernorm/reduce_sum",
      ("head", "forward")),
     ("jit(f)/jvp(GPTModel)/transformer/layer_0/add",

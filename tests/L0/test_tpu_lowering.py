@@ -115,6 +115,7 @@ KERNEL_NAMES = {
     "softmax": ("softmax_fwd", "softmax_bwd"),
     "adam": ("fused_adam",),
     "lamb": ("fused_lamb",),
+    "topk_select": ("indexer_topk_select",),
 }
 _TEXTS = {}
 
