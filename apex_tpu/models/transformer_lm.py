@@ -136,7 +136,10 @@ class TransformerConfig:
     # GPT-2/BERT-era: grouped-query attention (fewer K/V head groups),
     # rotary position embeddings, SwiGLU MLPs, RMSNorm blocks.
     num_query_groups: Optional[int] = None  # None -> MHA (groups == heads)
-    position_embedding_type: str = "learned"  # or "rope"
+    # "learned", "rope", "alibi", or "none": no positional encoding at
+    # all (Nemotron-H's attention layers, which sit between state-space
+    # layers that see the order)
+    position_embedding_type: str = "learned"
     rotary_base: float = 10000.0
     # Long-context RoPE frequency rescaling (Llama-3.1 "llama3" or
     # position-interpolation "linear"); None -> unscaled frequencies.
@@ -238,6 +241,33 @@ class TransformerConfig:
     # layer, without the exchange (SwitchMLP). None -> all of them.
     moe_local_experts: Optional[int] = None
     moe_expert_offset: int = 0
+    # How the router scores: "softmax" (Switch / Mixtral), or
+    # "sigmoid_bias" (DeepSeek-V3, Nemotron-H): sigmoid scores, the top k
+    # chosen by score + a per-expert bias that no gradient reaches, the
+    # gates the unbiased scores of the chosen (normalised if
+    # moe_normalize_topk) times moe_routed_scaling_factor, no auxiliary
+    # loss. Sorted routing only (moe/router.py).
+    moe_router_score: str = "softmax"
+    moe_routed_scaling_factor: float = 1.0
+    # One sub-block a layer (Nemotron-H "hybrid_override_pattern"): a
+    # string of num_layers letters, each layer x + f(norm(x)) with f a
+    # Mamba-2 mixer ("M", transformer/ssm.py), attention ("*") or the
+    # expert layer ("E"). None -> every layer is attention then MLP, as
+    # everywhere else in this file.
+    layer_pattern: Optional[str] = None
+    # Mamba-2 ("M" layers): heads of mamba_head_dim channels in
+    # mamba_n_groups groups that share B and C, mamba_state_size states a
+    # channel, a causal depthwise conv of mamba_conv_kernel, the scan in
+    # chunks of mamba_chunk_size; dt_min/max/floor shape dt_bias's init.
+    mamba_num_heads: int = 64
+    mamba_head_dim: int = 64
+    mamba_n_groups: int = 8
+    mamba_state_size: int = 128
+    mamba_conv_kernel: int = 4
+    mamba_chunk_size: int = 128
+    mamba_dt_min: float = 0.001
+    mamba_dt_max: float = 0.1
+    mamba_dt_floor: float = 1e-4
     # Tie the LM head to the word-embedding table (reference
     # parallel_lm_logits ties by default). Off here because the SPMD
     # pipeline harness needs untied heads (first/last stages run the same
@@ -361,11 +391,11 @@ class TransformerConfig:
                 # producers can pass head_dim through unconditionally
                 object.__setattr__(self, "head_dim", None)
         if self.position_embedding_type not in ("learned", "rope",
-                                                "alibi"):
+                                                "alibi", "none"):
             raise ValueError(
                 f"unknown position_embedding_type "
                 f"{self.position_embedding_type!r}; expected 'learned', "
-                f"'rope' or 'alibi'")
+                f"'rope', 'alibi' or 'none'")
         if self.position_embedding_type == "alibi" and self.context_parallel:
             raise ValueError("alibi does not compose with context "
                              "parallelism (ring/ulysses kernels carry no "
@@ -413,6 +443,32 @@ class TransformerConfig:
                     f"moe_local_experts ({self.moe_local_experts}) from "
                     f"moe_expert_offset ({self.moe_expert_offset}) must lie "
                     f"within num_moe_experts ({self.num_moe_experts})")
+        if self.moe_router_score not in ("softmax", "sigmoid_bias"):
+            raise ValueError(
+                f"unknown moe_router_score {self.moe_router_score!r}; "
+                f"expected 'softmax' or 'sigmoid_bias'")
+        if self.layer_pattern is not None:
+            if (len(self.layer_pattern) != self.num_layers
+                    or set(self.layer_pattern) - set("ME*")):
+                raise ValueError(
+                    f"layer_pattern {self.layer_pattern!r} must be "
+                    f"num_layers ({self.num_layers}) letters of 'M' "
+                    f"(Mamba-2), 'E' (experts), '*' (attention)")
+            if (self.scan_layers or self.parallel_residual
+                    or self.sandwich_norm or not self.pre_norm):
+                raise ValueError(
+                    "layer_pattern layers are x + f(norm(x)), unrolled: "
+                    "no scan_layers, parallel_residual, sandwich_norm or "
+                    "pre_norm=False")
+            if "E" in self.layer_pattern and self.num_moe_experts is None:
+                raise ValueError("an 'E' layer needs num_moe_experts")
+            if "M" in self.layer_pattern and (
+                    self.mamba_num_heads % self.mamba_n_groups
+                    or (self.mamba_num_heads * self.mamba_head_dim)
+                    % self.mamba_n_groups):
+                raise ValueError(
+                    f"mamba_num_heads ({self.mamba_num_heads}) must be a "
+                    f"multiple of mamba_n_groups ({self.mamba_n_groups})")
         if self.num_query_groups is not None:
             if (self.num_query_groups < 1
                     or self.num_attention_heads % self.num_query_groups):
@@ -1302,6 +1358,36 @@ class ParallelMLP(nn.Module):
         return x
 
 
+def _make_mlp(cfg, moe: bool):
+    """The feed-forward module of a layer, named ``mlp``: the expert layer
+    (with its shared expert where ``moe_shared_expert_size`` is set) or
+    the dense MLP."""
+    if not moe:
+        return ParallelMLP(cfg, name="mlp")
+    routed = dict(
+        hidden_size=cfg.hidden_size, ffn_hidden_size=cfg.ffn_size,
+        num_experts=cfg.num_moe_experts, top_k=cfg.moe_top_k,
+        capacity_factor=cfg.moe_capacity_factor,
+        jitter_eps=cfg.moe_jitter_eps, router_type=cfg.moe_router_type,
+        dispatch_mode=cfg.moe_dispatch_mode,
+        normalize_topk=cfg.moe_normalize_topk, activation=cfg.activation,
+        params_dtype=cfg.params_dtype, compute_dtype=cfg.compute_dtype,
+        local_experts=cfg.moe_local_experts,
+        expert_offset=cfg.moe_expert_offset,
+        router_score=cfg.moe_router_score,
+        routed_scaling_factor=cfg.moe_routed_scaling_factor,
+        sequence_parallel_enabled=cfg.sequence_parallel, name="mlp")
+    if cfg.moe_shared_expert_size:
+        from apex_tpu.transformer.moe.layer import SharedExpertMoE
+
+        return SharedExpertMoE(
+            shared_expert_size=cfg.moe_shared_expert_size,
+            shared_expert_gated=cfg.moe_shared_expert_gated, **routed)
+    from apex_tpu.transformer.moe import SwitchMLP
+
+    return SwitchMLP(**routed)
+
+
 class ParallelTransformerLayer(nn.Module):
     """Pre-LN transformer block (reference ParallelTransformerLayer)."""
 
@@ -1314,9 +1400,34 @@ class ParallelTransformerLayer(nn.Module):
         return (cfg.num_moe_experts is not None
                 and self.layer_number % cfg.moe_layer_freq == 0)
 
+    def _one_sub_block(self, hidden_states, attention_mask, position_ids):
+        """A ``layer_pattern`` layer: ``x + f(norm(x))``, ``f`` by this
+        layer's letter."""
+        cfg = self.config
+        kind = cfg.layer_pattern[self.layer_number]
+        if self.decode and kind == "M":
+            raise ValueError("the Mamba-2 mixer has no decode path")
+        x = _make_norm(cfg, "input_layernorm")(
+            hidden_states.astype(jnp.float32)).astype(cfg.compute_dtype)
+        if kind == "M":
+            from apex_tpu.transformer.ssm import Mamba2Mixer
+
+            out = Mamba2Mixer(cfg, name="mixer")(x)
+        elif kind == "*":
+            out = ParallelAttention(cfg, decode=self.decode,
+                                    layer_number=self.layer_number,
+                                    name="self_attention")(
+                x, attention_mask, position_ids)
+        else:
+            out = _make_mlp(cfg, True)(x)
+        return hidden_states + out.astype(hidden_states.dtype)
+
     @nn.compact
     def __call__(self, hidden_states, attention_mask=None, position_ids=None):
         cfg = self.config
+        if cfg.layer_pattern is not None:
+            return self._one_sub_block(hidden_states, attention_mask,
+                                       position_ids)
         if cfg.pre_norm:
             ln1 = _make_norm(cfg, "input_layernorm")
             ln1_out = ln1(hidden_states.astype(jnp.float32)).astype(
@@ -1344,44 +1455,7 @@ class ParallelTransformerLayer(nn.Module):
         ln2 = (None if (cfg.parallel_residual_shared_ln
                         or not cfg.pre_norm)
                else _make_norm(cfg, "post_attention_layernorm"))
-        if self._is_moe_layer() and cfg.moe_shared_expert_size:
-            from apex_tpu.transformer.moe.layer import SharedExpertMoE
-
-            mlp = SharedExpertMoE(
-                hidden_size=cfg.hidden_size,
-                ffn_hidden_size=cfg.ffn_size,
-                shared_expert_size=cfg.moe_shared_expert_size,
-                num_experts=cfg.num_moe_experts, top_k=cfg.moe_top_k,
-                capacity_factor=cfg.moe_capacity_factor,
-                jitter_eps=cfg.moe_jitter_eps,
-                router_type=cfg.moe_router_type,
-                normalize_topk=cfg.moe_normalize_topk,
-                dispatch_mode=cfg.moe_dispatch_mode,
-                activation=cfg.activation,
-                shared_expert_gated=cfg.moe_shared_expert_gated,
-                params_dtype=cfg.params_dtype,
-                compute_dtype=cfg.compute_dtype,
-                sequence_parallel_enabled=cfg.sequence_parallel, name="mlp")
-        elif self._is_moe_layer():
-            from apex_tpu.transformer.moe import SwitchMLP
-
-            mlp = SwitchMLP(
-                hidden_size=cfg.hidden_size,
-                ffn_hidden_size=cfg.ffn_size,
-                num_experts=cfg.num_moe_experts, top_k=cfg.moe_top_k,
-                capacity_factor=cfg.moe_capacity_factor,
-                jitter_eps=cfg.moe_jitter_eps,
-                router_type=cfg.moe_router_type,
-                dispatch_mode=cfg.moe_dispatch_mode,
-                normalize_topk=cfg.moe_normalize_topk,
-                activation=cfg.activation,
-                params_dtype=cfg.params_dtype,
-                compute_dtype=cfg.compute_dtype,
-                local_experts=cfg.moe_local_experts,
-                expert_offset=cfg.moe_expert_offset,
-                sequence_parallel_enabled=cfg.sequence_parallel, name="mlp")
-        else:
-            mlp = ParallelMLP(cfg, name="mlp")
+        mlp = _make_mlp(cfg, self._is_moe_layer())
         if ln2 is not None:
             mlp_in = ln2(hidden_states.astype(jnp.float32)).astype(
                 cfg.compute_dtype)

@@ -44,9 +44,14 @@ _BLOCKS = {
     # inside attention and the mlp, and named for themselves: the
     # sparse-attention indexer (flax module ``indexer``, scopes
     # ``indexer/{project,scores,select,loss}``) and the expert layer's
-    # routed path (scopes ``moe/{router,dispatch,experts,combine}``)
+    # routed path and shared expert (scopes ``moe/{router,dispatch,
+    # experts,combine,shared}``)
     "indexer": ("indexer",),
     "moe": ("moe",),
+    # the Mamba-2 mixer (transformer/ssm.py; scopes ``ssm/{in_proj,conv,
+    # scan,gate_norm,out_proj}`` in the flax module ``mixer`` of a
+    # ``layer_pattern`` layer)
+    "ssm": ("ssm",),
     "head": ("head", "word_embeddings.attend", "lm_dense", "lm_layernorm",
              "lm_head", "lm_head_bias", "pooler", "binary_head"),
     "loss": ("loss",),
@@ -60,7 +65,7 @@ _BLOCKS = {
 _BLOCK_OF = {name: block for block, names in _BLOCKS.items()
              for name in names}
 # blocks that sit inside another block's module and take its time out of it
-_INNER = ("indexer", "moe")
+_INNER = ("indexer", "moe", "ssm")
 # XLA replaces ``lax.ragged_dot`` by a grouped-matmul kernel of its own and
 # names it, and the call that prepares its group metadata, by what it is
 # and not by the scope it was traced under (``op_name="ragged-dot-none"``):
@@ -77,6 +82,8 @@ _MODEL = re.compile(r"layer_\d+|layers?|transformer|\w+Model")
 # theirs; an unnamed one ends in ``pallas_call``)
 _ATTENTION_PARTS = {"query_key_value": "qkv", "dense": "dense",
                     "pallas_call": "kernel"}
+# sub-blocks of the Mamba-2 mixer: the scopes it opens under ``ssm/``
+_SSM_PARTS = ("in_proj", "conv", "scan", "gate_norm", "out_proj")
 # ``jvp(GPTModel)`` / ``transpose(jvp(loss))`` / ``jit(_where)`` -> the name
 _WRAPPED = re.compile(r"^(?:\w+\()+([^()]*)\)+$")
 
@@ -102,9 +109,12 @@ def classify(scope: str) -> tuple:
     ``attention/kernel``, ``attention/dense`` where a later component
     says which part; ``indexer`` where a later component is the
     sparse-attention indexer), ``mlp`` (``moe`` where a later component
-    is one of the expert layer's scopes), ``head``, ``loss``, ``amp``,
-    ``optimizer``, ``collective``; ``residual`` for a scope inside the
-    model that names none of them; ``None`` for any other. ``phase`` is
+    is one of the expert layer's scopes), ``ssm`` (``ssm/in_proj``,
+    ``ssm/conv``, ``ssm/scan``, ``ssm/gate_norm``, ``ssm/out_proj`` where
+    the next component says which part of the Mamba-2 mixer), ``head``,
+    ``loss``, ``amp``, ``optimizer``, ``collective``; ``residual`` for a
+    scope inside the model that names none of them; ``None`` for any
+    other. ``phase`` is
     ``recompute`` under ``jax.checkpoint``'s ``rematted_computation``,
     else ``backward`` under a transposed jvp, ``forward`` under a jvp,
     and ``update`` outside differentiation."""
@@ -124,9 +134,13 @@ def classify(scope: str) -> tuple:
             return "moe", phase
         block = _BLOCK_OF.get(part)
         if block in ("attention", "mlp"):
-            for sub in parts[i + 1:]:
+            for j, sub in enumerate(parts[i + 1:], i + 1):
                 if _BLOCK_OF.get(sub) in _INNER:
-                    return _BLOCK_OF[sub], phase
+                    block, i = _BLOCK_OF[sub], j
+                    break
+        if block == "ssm":
+            part = parts[i + 1] if i + 1 < len(parts) else None
+            return (f"ssm/{part}" if part in _SSM_PARTS else block), phase
         if block == "attention":
             for sub in parts[i + 1:]:
                 if sub in _ATTENTION_PARTS:

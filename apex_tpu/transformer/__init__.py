@@ -2,7 +2,7 @@
 
 Parity: reference apex/transformer/__init__.py (parallel_state,
 tensor_parallel, pipeline_parallel, amp, functional, layers, enums,
-microbatches, testing).
+microbatches, testing); beyond it: context_parallel, moe, ssm.
 """
 
 from apex_tpu.transformer import parallel_state  # noqa: F401
@@ -15,3 +15,4 @@ from apex_tpu.transformer.microbatches import build_num_microbatches_calculator 
 from apex_tpu.transformer import amp  # noqa: F401
 from apex_tpu.transformer import context_parallel  # noqa: F401
 from apex_tpu.transformer import moe  # noqa: F401
+from apex_tpu.transformer import ssm  # noqa: F401

@@ -114,7 +114,8 @@ class ExpertMLP(nn.Module):
     ``activation="swiglu"`` makes w1 a fused per-rank [gate | up]
     projection (2 * ffn/tp local columns, bias-free — the Llama/Mixtral
     expert shape); "gelu" is the Switch-Transformer shape with biases
-    (ragged layout gathers per-row biases via ``expert_idx``).
+    (ragged layout gathers per-row biases via ``expert_idx``); "relu2"
+    is the ungated ``w2 relu(w1 x)^2`` without biases (Nemotron-H).
     """
 
     hidden_size: int
@@ -130,10 +131,12 @@ class ExpertMLP(nn.Module):
         ffn_local = divide(self.ffn_hidden_size, tp)
         init = nn.initializers.lecun_normal(batch_axis=(0,))
         swiglu = self.activation == "swiglu"
-        if not swiglu and self.activation != "gelu":
+        relu2 = self.activation == "relu2"
+        biased = not (swiglu or relu2)
+        if biased and self.activation != "gelu":
             raise ValueError(f"unknown activation {self.activation!r}")
         ragged = group_sizes is not None
-        if not swiglu and ragged and expert_idx is None:
+        if biased and ragged and expert_idx is None:
             raise ValueError("ragged gelu experts need expert_idx for "
                              "per-row bias gathers")
 
@@ -147,7 +150,7 @@ class ExpertMLP(nn.Module):
         w2 = self.param("w2", shard_init,
                         (self.num_local_experts, ffn_local, self.hidden_size),
                         self.params_dtype)
-        if not swiglu:
+        if biased:
             b1 = self.param("b1", nn.initializers.zeros,
                             (self.num_local_experts, ffn_local),
                             self.params_dtype)
@@ -168,6 +171,8 @@ class ExpertMLP(nn.Module):
         if swiglu:
             gate, up = jnp.split(h1, 2, axis=-1)
             a = (jax.nn.silu(gate) * up).astype(self.compute_dtype)
+        elif relu2:
+            a = jnp.square(jax.nn.relu(h1)).astype(self.compute_dtype)
         else:
             bias1 = (b1[expert_idx] if ragged else b1[:, None, :])
             h1 = h1 + bias1.astype(jnp.float32)
@@ -180,7 +185,7 @@ class ExpertMLP(nn.Module):
             y = jnp.einsum("ecf,efh->ech", a, w2.astype(self.compute_dtype),
                            preferred_element_type=jnp.float32)
         y = reduce_from_tensor_model_parallel_region(y)
-        if swiglu:
+        if not biased:
             return y
         bias2 = (b2[expert_idx] if ragged else b2[:, None, :])
         return y + bias2.astype(jnp.float32)
@@ -192,8 +197,15 @@ class SharedExpertMoE(nn.Module):
     scalar sigmoid gate optional. The shared expert is a dense SwiGLU
     MLP (column-parallel fused [gate | up], row-parallel down) of its
     own width — distinct from DeepSeek's ungated shared expert, which
-    lives in models/mla.py. Aux losses sow through the nested SwitchMLP
-    as usual."""
+    lives in models/mla.py. With ``activation="relu2"`` routed and
+    shared experts are both the ungated ``down(relu(up x)^2)``
+    (Nemotron-H; params ``shared_up`` / ``shared_down``, scope
+    ``moe/shared``), the shared one added unweighted where
+    ``shared_expert_gated`` is off. ``local_experts``,
+    ``expert_offset``, ``router_score`` and ``routed_scaling_factor`` go
+    to the routed SwitchMLP: in its held-share mode the shared expert is
+    whole on every rank. Aux losses sow through the nested SwitchMLP as
+    usual."""
 
     hidden_size: int
     ffn_hidden_size: int            # routed expert width
@@ -215,6 +227,10 @@ class SharedExpertMoE(nn.Module):
     compute_dtype: Any = jnp.bfloat16
     sequence_parallel_enabled: bool = False
     warn_on_dropped_losses: bool = True
+    local_experts: Optional[int] = None
+    expert_offset: int = 0
+    router_score: str = "softmax"
+    routed_scaling_factor: float = 1.0
 
     @nn.compact
     def __call__(self, hidden_states):
@@ -227,10 +243,11 @@ class SharedExpertMoE(nn.Module):
             raise ValueError(
                 f"SharedExpertMoE supports top_k routing only, got "
                 f"{self.router_type!r}")
-        if self.activation != "swiglu":
+        if self.activation not in ("swiglu", "relu2"):
             raise ValueError(
                 f"SharedExpertMoE experts are SwiGLU (the Qwen2-MoE "
-                f"shape), got activation {self.activation!r}")
+                f"shape) or relu2 (Nemotron-H), got activation "
+                f"{self.activation!r}")
         routed = SwitchMLP(
             hidden_size=self.hidden_size,
             ffn_hidden_size=self.ffn_hidden_size,
@@ -238,14 +255,35 @@ class SharedExpertMoE(nn.Module):
             capacity_factor=self.capacity_factor,
             jitter_eps=self.jitter_eps,
             normalize_topk=self.normalize_topk,
-            dispatch_mode=self.dispatch_mode, activation="swiglu",
+            dispatch_mode=self.dispatch_mode, activation=self.activation,
             params_dtype=self.params_dtype,
             compute_dtype=self.compute_dtype,
             sequence_parallel_enabled=self.sequence_parallel_enabled,
             warn_on_dropped_losses=self.warn_on_dropped_losses,
+            local_experts=self.local_experts,
+            expert_offset=self.expert_offset,
+            router_score=self.router_score,
+            routed_scaling_factor=self.routed_scaling_factor,
             name="routed")(hidden_states)
 
         x = hidden_states.astype(self.compute_dtype)
+        if self.activation == "relu2":
+            with jax.named_scope("moe/shared"):
+                up = ColumnParallelLinear(
+                    input_size=self.hidden_size,
+                    output_size=self.shared_expert_size,
+                    gather_output=False, bias=False,
+                    sequence_parallel_enabled=self.sequence_parallel_enabled,
+                    params_dtype=self.params_dtype, name="shared_up")(x)
+                h = jnp.square(jax.nn.relu(up.astype(jnp.float32))).astype(
+                    self.compute_dtype)
+                shared = RowParallelLinear(
+                    input_size=self.shared_expert_size,
+                    output_size=self.hidden_size, input_is_parallel=True,
+                    bias=False,
+                    sequence_parallel_enabled=self.sequence_parallel_enabled,
+                    params_dtype=self.params_dtype, name="shared_down")(h)
+            return routed + self._gated(shared, x).astype(routed.dtype)
         gate_up = ColumnParallelLinear(
             input_size=self.hidden_size,
             output_size=2 * self.shared_expert_size,
@@ -260,14 +298,16 @@ class SharedExpertMoE(nn.Module):
             bias=False,
             sequence_parallel_enabled=self.sequence_parallel_enabled,
             params_dtype=self.params_dtype, name="shared_down")(h)
-        if self.shared_expert_gated:
-            gate_w = self.param("shared_expert_gate",
-                                nn.initializers.zeros,
-                                (self.hidden_size, 1), self.params_dtype)
-            scale = jax.nn.sigmoid(
-                (x.astype(jnp.float32) @ gate_w.astype(jnp.float32)))
-            shared = shared * scale.astype(shared.dtype)
-        return routed + shared.astype(routed.dtype)
+        return routed + self._gated(shared, x).astype(routed.dtype)
+
+    def _gated(self, shared, x):
+        if not self.shared_expert_gated:
+            return shared
+        gate_w = self.param("shared_expert_gate", nn.initializers.zeros,
+                            (self.hidden_size, 1), self.params_dtype)
+        scale = jax.nn.sigmoid(
+            (x.astype(jnp.float32) @ gate_w.astype(jnp.float32)))
+        return shared * scale.astype(shared.dtype)
 
 
 class SwitchMLP(nn.Module):
@@ -332,6 +372,10 @@ class SwitchMLP(nn.Module):
     warn_on_dropped_losses: bool = True
     local_experts: Optional[int] = None
     expert_offset: int = 0
+    # the router's scoring ("softmax" or "sigmoid_bias") and the factor
+    # on its gates: TopKRouter.score, compute_routing_sorted
+    router_score: str = "softmax"
+    routed_scaling_factor: float = 1.0
 
     def _resolve_dispatch(self, ep: int, capacity: int, num_tokens: int):
         mode = self.dispatch_mode
@@ -390,6 +434,8 @@ class SwitchMLP(nn.Module):
             normalize_topk=self.normalize_topk,
             routing_format={"einsum": "dense", "scatter": "sorted",
                             "ragged": "sorted_dropless"}[mode],
+            score=self.router_score,
+            routed_scaling_factor=self.routed_scaling_factor,
             params_dtype=self.params_dtype, name="router")
         with jax.named_scope("moe/router"):
             routing = router(tokens)

@@ -128,8 +128,19 @@ class SortedRouting:
 
 
 def compute_routing_sorted(logits, top_k: int, capacity: Optional[int],
-                           normalize_topk: bool = True) -> SortedRouting:
+                           normalize_topk: bool = True, *, score_bias=None,
+                           routed_scaling_factor: float = 1.0
+                           ) -> SortedRouting:
     """Sort-based routing from fp32 ``logits`` [T, E].
+
+    With ``score_bias`` [E] the scores are sigmoids, not a softmax
+    (DeepSeek-V3's auxiliary-loss-free balancing, Nemotron-H's router):
+    the ``top_k`` experts are those of largest ``score + score_bias``, the
+    gates are the chosen experts' *unbiased* scores, over their sum if
+    ``normalize_topk``, times ``routed_scaling_factor``. The bias steers
+    the choice alone and no gradient reaches it; there is no auxiliary
+    loss (``aux_loss`` and ``z_loss`` are 0) and ``probs`` are the
+    sigmoids.
 
     ``capacity=None`` is truly dropless (every assignment kept, no slot
     layout — feed ``ExpertMLP`` via ragged grouping). With a capacity,
@@ -141,15 +152,24 @@ def compute_routing_sorted(logits, top_k: int, capacity: Optional[int],
     logits = logits.astype(jnp.float32)
     T, E = logits.shape
     N = top_k * T
-    probs = jax.nn.softmax(logits, axis=-1)
+    if score_bias is None:
+        probs = jax.nn.softmax(logits, axis=-1)
 
-    # lax.top_k returns descending values, ties broken toward the lower
-    # index — the same choice sequence as compute_routing's iterative
-    # argmax-and-mask.
-    topv, topi = lax.top_k(probs, top_k)  # [T, k], [T, k]
-    gates = topv
-    if normalize_topk and top_k > 1:
-        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+        # lax.top_k returns descending values, ties broken toward the lower
+        # index — the same choice sequence as compute_routing's iterative
+        # argmax-and-mask.
+        topv, topi = lax.top_k(probs, top_k)  # [T, k], [T, k]
+        gates = topv
+        if normalize_topk and top_k > 1:
+            gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    else:
+        probs = jax.nn.sigmoid(logits)
+        _, topi = lax.top_k(
+            probs + lax.stop_gradient(score_bias.astype(jnp.float32)), top_k)
+        gates = jnp.take_along_axis(probs, topi, axis=-1)
+        if normalize_topk:
+            gates = gates / (gates.sum(-1, keepdims=True) + 1e-20)
+        gates = gates * routed_scaling_factor
 
     # Choice-rank-major flatten, then a stable sort by expert: within an
     # expert, rows appear in (rank, token) order — compute_routing's fill
@@ -177,7 +197,10 @@ def compute_routing_sorted(logits, top_k: int, capacity: Optional[int],
         dropped = 1.0 - jnp.sum(kept) / N
 
     f = counts.astype(jnp.float32) / N  # pre-drop fraction, as compute_routing
-    aux_loss, z_loss = _router_losses(logits, probs, f)
+    if score_bias is None:
+        aux_loss, z_loss = _router_losses(logits, probs, f)
+    else:
+        aux_loss = z_loss = jnp.zeros((), jnp.float32)
     return SortedRouting(token_sorted, expert_sorted, gate_sorted, counts,
                          slot, aux_loss, z_loss, probs,
                          lax.stop_gradient(dropped))
@@ -246,6 +269,14 @@ class TopKRouter(nn.Module):
     jitter_eps: float = 0.0
     normalize_topk: bool = True
     router_type: str = "top_k"
+    # "softmax", or "sigmoid_bias": sigmoid scores with the choice steered
+    # by the ``e_score_correction_bias`` parameter (a buffer in the
+    # published models: zeros at init, moved by the trainer's balancing
+    # rule and by no gradient), gates times ``routed_scaling_factor``
+    # (compute_routing_sorted). Counted at trace time as
+    # ``moe/router/sigmoid_bias``.
+    score: str = "softmax"
+    routed_scaling_factor: float = 1.0
     params_dtype: Any = jnp.float32
     capacity: Optional[int] = None  # override for tests
     # "dense" -> RoutingResult ([T,E,C] one-hots for the einsum path);
@@ -283,12 +314,27 @@ class TopKRouter(nn.Module):
         if self.router_type != "top_k":
             raise ValueError(f"unknown router_type {self.router_type!r}; "
                              "expected 'top_k' or 'expert_choice'")
+        how = {}
+        if self.score == "sigmoid_bias":
+            from apex_tpu.telemetry.registry import get_registry
+
+            if self.routing_format == "dense":
+                raise ValueError("the sigmoid_bias router has the sorted "
+                                 "routing formats only")
+            get_registry().counter("moe/router/sigmoid_bias").inc()
+            how = dict(
+                score_bias=self.param(
+                    "e_score_correction_bias", nn.initializers.zeros,
+                    (self.num_experts,), jnp.float32),
+                routed_scaling_factor=self.routed_scaling_factor)
+        elif self.score != "softmax":
+            raise ValueError(f"unknown router score {self.score!r}")
         if self.routing_format == "sorted":
             return compute_routing_sorted(logits, self.top_k, cap,
-                                          self.normalize_topk)
+                                          self.normalize_topk, **how)
         if self.routing_format == "sorted_dropless":
             return compute_routing_sorted(logits, self.top_k, None,
-                                          self.normalize_topk)
+                                          self.normalize_topk, **how)
         if self.routing_format != "dense":
             raise ValueError(
                 f"unknown routing_format {self.routing_format!r}")

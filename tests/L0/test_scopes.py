@@ -160,6 +160,27 @@ def test_update_and_loss_are_not_charged_to_the_model(tables, model,
      "rematted_computation/layer_1/self_attention/indexer/indexer/select/"
      "jit(_select)/indexer_topk_select/pallas_call",
      ("indexer", "recompute")),
+    # the Mamba-2 mixer names its parts; the shared expert is the expert
+    # layer's
+    ("jit(f)/jvp(GPTModel)/transformer/layer_0/mixer/ssm/scan/dot_general",
+     ("ssm/scan", "forward")),
+    ("jit(f)/transpose(jvp(GPTModel))/transformer/checkpoint/"
+     "rematted_computation/layer_2/mixer/ssm/scan/while/body/mul",
+     ("ssm/scan", "recompute")),
+    ("jit(f)/transpose(jvp(GPTModel))/transformer/layer_4/mixer/ssm/"
+     "in_proj/dot_general", ("ssm/in_proj", "backward")),
+    ("jit(f)/jvp(GPTModel)/transformer/layer_0/mixer/ssm/conv/mul",
+     ("ssm/conv", "forward")),
+    ("jit(f)/jvp(GPTModel)/transformer/layer_0/mixer/ssm/gate_norm/rsqrt",
+     ("ssm/gate_norm", "forward")),
+    ("jit(f)/jvp(GPTModel)/transformer/layer_0/mixer/ssm/out_proj/"
+     "dot_general", ("ssm/out_proj", "forward")),
+    ("jit(f)/jvp(GPTModel)/transformer/layer_0/mixer/ssm/add",
+     ("ssm", "forward")),
+    ("jit(f)/jvp(GPTModel)/transformer/layer_1/mlp/moe/shared/shared_up/"
+     "dot_general", ("moe", "forward")),
+    ("jit(f)/jvp(GPTModel)/transformer/layer_1/mlp/routed/moe/router/"
+     "router/logistic", ("moe", "forward")),
     ("jit(f)/jvp(BertModel)/head/lm_layernorm/reduce_sum",
      ("head", "forward")),
     ("jit(f)/jvp(GPTModel)/transformer/layer_0/add",
@@ -175,6 +196,50 @@ def test_update_and_loss_are_not_charged_to_the_model(tables, model,
 ])
 def test_classify(scope, want):
     assert classify(scope) == want
+
+
+@pytest.fixture(scope="module")
+def hybrid_blocks():
+    """Blocks of a compiled three-layer ``layer_pattern`` step (a Mamba-2
+    mixer, an expert layer with its shared expert, attention)."""
+    cfg = TransformerConfig(
+        hidden_size=32, num_layers=3, num_attention_heads=2, head_dim=16,
+        num_query_groups=1, ffn_hidden_size=16, vocab_size=64,
+        max_position_embeddings=SEQ, compute_dtype=jnp.bfloat16,
+        use_flash_attention=False, normalization="rmsnorm",
+        activation="relu2", attention_bias=False,
+        position_embedding_type="none", layer_pattern="ME*",
+        mamba_num_heads=4, mamba_head_dim=8, mamba_n_groups=2,
+        mamba_state_size=8, mamba_chunk_size=8, num_moe_experts=8,
+        moe_top_k=2, moe_local_experts=4, moe_capacity_factor=2.0,
+        moe_router_score="sigmoid_bias", moe_shared_expert_size=24,
+        moe_shared_expert_gated=False, activation_checkpointing=True)
+    model = GPTModel(cfg)
+    tokens = jnp.zeros((BATCH, SEQ), jnp.int32)
+
+    def loss(p, b):
+        logits, _ = model.apply({"params": p}, b["tokens"],
+                                mutable=["moe_losses"])
+        return gpt_loss_fn(logits, b["labels"])
+
+    table = scope_table(_compiled(model, FusedAdam(lr=1e-4), loss,
+                                  {"tokens": tokens, "labels": tokens},
+                                  tokens))
+    return collections.Counter(classify(s) for s in table.values())
+
+
+@pytest.mark.parametrize("block", [
+    "ssm/in_proj", "ssm/conv", "ssm/scan", "ssm/gate_norm", "ssm/out_proj",
+    "moe", "attention/qkv", "layernorm"])
+@pytest.mark.parametrize("phase", ["forward", "backward", "recompute"])
+def test_a_hybrid_step_gives_the_mixer_s_parts_their_time(hybrid_blocks,
+                                                          block, phase):
+    if (block, phase) == ("ssm/out_proj", "recompute"):
+        # a layer's last product: its backward needs its inputs alone
+        assert hybrid_blocks[(block, phase)] == 0
+        return
+    assert hybrid_blocks[(block, phase)] > 0, sorted(
+        hybrid_blocks, key=str)
 
 
 # what a fusion answers with: the scope of the matrix product or Mosaic
