@@ -21,6 +21,10 @@ existing Python entry points:
 - :mod:`apex_tpu.kernels.topk_select` — the sparse-attention indexer's
   top-k selection with its rows of scores held in VMEM (entry:
   ``apex_tpu.models.transformer_lm.topk_selection``)
+- :mod:`apex_tpu.kernels.grouped_matmul` — the experts' grouped matmul
+  over the row tiles that hold assignments, with its two backward
+  products (entry: ``apex_tpu.transformer.moe.layer.ExpertMLP``'s ragged
+  layout)
 - ``apex_tpu.contrib.fmha`` (``flash_attention``),
   ``apex_tpu.contrib.gqa_decode`` and ``apex_tpu.contrib.mla_decode``
   register their gates here too.
@@ -28,7 +32,13 @@ existing Python entry points:
 See docs/kernels.md for the rule, parity bounds, and wire formats.
 """
 
-from apex_tpu.kernels import optim, quant4, softmax, topk_select  # noqa: F401
+from apex_tpu.kernels import (  # noqa: F401
+    grouped_matmul,
+    optim,
+    quant4,
+    softmax,
+    topk_select,
+)
 from apex_tpu.kernels.registry import (  # noqa: F401
     KernelRegistry,
     PallasGate,

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from apex_tpu.kernels.grouped_matmul import grouped_matmul
 from apex_tpu.transformer.moe.router import TopKRouter, expert_capacity
 from apex_tpu.transformer.parallel_state import (
     EXPERT_PARALLEL_AXIS,
@@ -106,10 +107,12 @@ class ExpertMLP(nn.Module):
     - slotted [E_local, S, h] (default): per-expert einsum over the
       leading dim — the all_to_all-compatible layout.
     - ragged [N, h] with ``group_sizes`` [E_local] (rows grouped by
-      expert, consecutively): ``lax.ragged_dot`` grouped matmul — zero
-      capacity padding, the dropless serving layout. XLA lowers this to
-      the TPU grouped-matmul kernel (the MegaBlocks dMoE idea without
-      hand-written block-sparsity: the "blocks" are the ragged groups).
+      expert, consecutively; rows past their sum belong to no expert and
+      give zeros): ``kernels.grouped_matmul`` — zero capacity padding,
+      the dropless serving layout. On a TPU, from one row tile of rows
+      up, a Pallas grouped matmul over the row tiles that hold rows;
+      ``lax.ragged_dot`` (XLA's TPU grouped-matmul kernel), its oracle,
+      elsewhere.
 
     ``activation="swiglu"`` makes w1 a fused per-rank [gate | up]
     projection (2 * ffn/tp local columns, bias-free — the Llama/Mixtral
@@ -162,9 +165,8 @@ class ExpertMLP(nn.Module):
         x = copy_to_tensor_model_parallel_region(x)
         x = x.astype(self.compute_dtype)
         if ragged:
-            h1 = lax.ragged_dot(x, w1.astype(self.compute_dtype),
-                                group_sizes,
-                                preferred_element_type=jnp.float32)
+            h1 = grouped_matmul(x, w1.astype(self.compute_dtype),
+                                group_sizes)
         else:
             h1 = jnp.einsum("ech,ehf->ecf", x, w1.astype(self.compute_dtype),
                             preferred_element_type=jnp.float32)
@@ -178,9 +180,8 @@ class ExpertMLP(nn.Module):
             h1 = h1 + bias1.astype(jnp.float32)
             a = jax.nn.gelu(h1).astype(self.compute_dtype)
         if ragged:
-            y = lax.ragged_dot(a, w2.astype(self.compute_dtype),
-                               group_sizes,
-                               preferred_element_type=jnp.float32)
+            y = grouped_matmul(a, w2.astype(self.compute_dtype),
+                               group_sizes)
         else:
             y = jnp.einsum("ecf,efh->ech", a, w2.astype(self.compute_dtype),
                            preferred_element_type=jnp.float32)
@@ -551,15 +552,19 @@ class SwitchMLP(nn.Module):
         """The held experts' rows of a dropless sorted routing, in a
         static number of rows: -> (token_idx, expert_idx local, gate,
         counts), each over ``rows`` rows but ``counts`` ``[local_experts]``.
-        The sorted order puts the held experts' assignments in one run;
-        rows past its end carry gate 0 and join the last group, where a
-        zeroed input adds nothing to output or gradients."""
+        The sorted order puts the held experts' assignments in one run,
+        and ``counts`` are its groups: they sum to the rows kept, not to
+        ``rows``. Rows past the run's end are in no group (the grouped
+        matmul gives them zeros and visits none of their tiles); they
+        carry gate 0 and a zeroed input, so the combine's scatter-add
+        and the activation's backward see zeros there too."""
         from apex_tpu.telemetry.registry import get_registry
 
         n, off, E = self.local_experts, self.expert_offset, self.num_experts
         N = self.top_k * num_tokens
         rows = min(N, -(-int(N * n / E * self.capacity_factor) // 8) * 8)
         get_registry().gauge("moe/held_experts").set(n)
+        get_registry().gauge("moe/held_rows").set(rows)
         get_registry().gauge("moe/published_experts").set(E)
         ends = jnp.cumsum(routing.counts)
         start = ends[off] - routing.counts[off]
@@ -568,7 +573,6 @@ class SwitchMLP(nn.Module):
         local_ends = jnp.minimum(ends[off:off + n] - start, rows)
         counts = jnp.diff(local_ends, prepend=0)
         kept = local_ends[-1]
-        counts = counts.at[n - 1].add(rows - kept)
         row = jnp.arange(rows, dtype=jnp.int32)
         source = jnp.minimum(start + row, N - 1)
         valid = row < kept

@@ -321,6 +321,77 @@ def randn(key, shape, dtype=jnp.bfloat16):
                              jnp.float32).astype(dtype)
 
 
+# (gathered rows, hidden, expert width, held experts, activation) of the
+# expert layer in the Nemotron cell and in the Keye cell, and the (m, k, n,
+# g) of its two grouped matmuls (swiglu's first has [gate | up] columns)
+EXPERT_LAYERS = ((98304, 2688, 1856, 8, "relu2"),
+                 (131072, 2048, 768, 16, "swiglu"))
+GROUPED_SHAPES = tuple(
+    shape for m, h, f, g, act in EXPERT_LAYERS
+    for shape in ((m, h, f * (2 if act == "swiglu" else 1), g),
+                  (m, f, h, g)))
+GROUPED_SHARES = (0.06, 0.125, 0.28)    # of the rows that hold assignments
+
+
+def group_sizes(m, g, share):
+    """``share`` of ``m`` rows in uneven groups of which the second is
+    empty; the rest is the tail, in no group."""
+    weights = np.random.default_rng(g).dirichlet(np.ones(g))
+    weights[1] = 0.0
+    return jnp.asarray(np.floor(weights / weights.sum() * share * m),
+                       jnp.int32)
+
+
+def grouped_args(shape, share):
+    m, k, n, g = shape
+    return (randn(26, (m, k)), randn(27, (g, k, n)),
+            group_sizes(m, g, share), randn(28, (m, n), jnp.float32))
+
+
+def grouped_fwd_bwd(lhs, rhs, sizes, dout):
+    from apex_tpu.kernels.grouped_matmul import grouped_matmul
+
+    out, vjp = jax.vjp(lambda a, b: grouped_matmul(a, b, sizes), lhs, rhs)
+    return (out,) + vjp(dout)
+
+
+def expert_ffn_fwd_bwd(act, x, w1, w2, sizes, dout):
+    """``ExpertMLP``'s ragged layout, forward and backward: the two
+    grouped matmuls with the activation between them."""
+    from apex_tpu.kernels.grouped_matmul import grouped_matmul
+
+    def ffn(x, w1, w2):
+        h = grouped_matmul(x, w1, sizes)
+        if act == "swiglu":
+            gate, up = jnp.split(h, 2, axis=-1)
+            h = jax.nn.silu(gate) * up
+        else:
+            h = jnp.square(jax.nn.relu(h))
+        return grouped_matmul(h.astype(x.dtype), w2, sizes)
+
+    out, vjp = jax.vjp(ffn, x, w1, w2)
+    return (out,) + vjp(dout)
+
+
+def grouped_matmul_times():
+    """The kernel against what it replaced, XLA's ``ragged-dot`` over the
+    same unpadded counts: each cell's expert layer (its two matmuls'
+    shapes are ``GROUPED_SHAPES``) forward and backward at three real
+    shares, device ms kernel / oracle."""
+    for m, h, f, g, act in EXPERT_LAYERS:
+        fn = functools.partial(expert_ffn_fwd_bwd, act)
+        kernel, oracle = jax.jit(fn), jax.jit(gates_off(fn))
+        cols = f * (2 if act == "swiglu" else 1)
+        for share in GROUPED_SHARES:
+            args = (randn(26, (m, h)), randn(27, (g, h, cols)) * 0.02,
+                    randn(28, (g, f, h)) * 0.02, group_sizes(m, g, share),
+                    randn(29, (m, h), jnp.float32))
+            say(f"  grouped_matmul: {g} {act} experts {h} -> {f} -> {h} "
+                f"over {m} rows, {share:.1%} real, fwd+bwd device ms, "
+                "kernel / ragged-dot",
+                f"{ms_a_call(kernel, args)} / {ms_a_call(oracle, args)}")
+
+
 def kernel_cases():
     """Every kernel once, at a shape from the legs above (GPT-2 345M:
     16 heads of 64, cache 1024) and the attention family also at a GQA
@@ -412,6 +483,15 @@ def kernel_cases():
     add("topk_select [2, 8192, 8192] top-2048", select, gates_off(select),
         lambda: (randn(25, (2, 8192, 8192), jnp.float32),), "exact",
         timed=True)
+
+    # -- the experts' grouped matmul, forward and both gradients, at the
+    # four shapes the two MoE cells run it, an eighth of the rows real (a
+    # balanced router's share of Keye's; the rest is the tail)
+    for shape in GROUPED_SHAPES:
+        m, k, n, g = shape
+        add(f"grouped_matmul fwd+dlhs+drhs [{m}, {k}] x [{g}, {k}, {n}]",
+            grouped_fwd_bwd, gates_off(grouped_fwd_bwd),
+            functools.partial(grouped_args, shape, 0.125), TOL_MXU)
 
     # -- gqa_decode at three fill levels; GQA with window + soft cap -------
     for (g, rep, T), kw in zip(layouts,
@@ -587,6 +667,7 @@ def kernels_leg():
             if case.timed:
                 say(f"  {case.name}, device ms a call, kernel / oracle",
                     f"{ms_a_call(kernel, args)} / {ms_a_call(oracle, args)}")
+    grouped_matmul_times()
 
 
 # ---------------------------------------------------------------------------

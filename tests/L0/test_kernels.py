@@ -91,14 +91,15 @@ def _expected_path(switch, interpret, on_tpu, fits):
     return "pallas" if on_tpu else "oracle"
 
 
-REGISTERED = ("adam", "flash_attention", "fused_cc", "gqa_decode", "lamb",
-              "mla_decode", "quant", "quant4", "softmax", "topk_select")
+REGISTERED = ("adam", "flash_attention", "fused_cc", "gqa_decode",
+              "grouped_matmul", "lamb", "mla_decode", "quant", "quant4",
+              "softmax", "topk_select")
 
 
 def _tiny_entries():
     """Every registered kernel's public entry at a tiny shape."""
     from apex_tpu.contrib import fmha, gqa_decode, mla_decode
-    from apex_tpu.kernels import fused_cc
+    from apex_tpu.kernels import fused_cc, grouped_matmul
     from apex_tpu.models import transformer_lm
 
     f32 = jnp.float32
@@ -113,6 +114,9 @@ def _tiny_entries():
         "flash_attention": lambda: fmha.flash_attention(q, q, q),
         "fused_cc": lambda: fused_cc.quantize_pack_int4(
             x, jnp.ones((8, 1), f32)),
+        "grouped_matmul": lambda: grouped_matmul.grouped_matmul(
+            jnp.ones((grouped_matmul.ROWS, 128), f32),
+            jnp.ones((2, 128, 128), f32), jnp.asarray([5, 9])),
         "gqa_decode": lambda: gqa_decode.gqa_flash_decode(
             jnp.ones((1, 2, 2, 64), f32), jnp.ones((128, 1, 2, 64), f32),
             jnp.ones((128, 1, 2, 64), f32), jnp.asarray(5), 0.125),

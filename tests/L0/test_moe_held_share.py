@@ -130,3 +130,147 @@ def test_held_share_gradients_reach_router_and_held_experts():
             {"params": p}, x) ** 2))(share)
     for leaf in jax.tree_util.tree_leaves(grads):
         assert float(jnp.abs(leaf).max()) > 0
+
+
+# -- the grouped matmul under the held share (PR 34): the rows past the
+# held run are in no group -------------------------------------------------
+
+from apex_tpu.kernels import registry as kreg  # noqa: E402
+from apex_tpu.telemetry.registry import (  # noqa: E402
+    MetricsRegistry,
+    use_registry,
+)
+from apex_tpu.transformer.moe import layer as layer_mod  # noqa: E402
+
+KREG = kreg.get_kernel_registry()
+# wide enough for the kernel's tiles: 512 gathered rows of 128
+HW, FW, TW = 128, 128, 256
+
+
+def _wide_layer(activation, **kw):
+    return SwitchMLP(hidden_size=HW, ffn_hidden_size=FW, num_experts=E,
+                     top_k=K, activation=activation,
+                     compute_dtype=jnp.float32,
+                     warn_on_dropped_losses=False, **kw)
+
+
+def _wide_inputs(activation, seed=0):
+    rng = np.random.default_rng(seed)
+    cols = 2 * FW if activation == "swiglu" else FW
+    x = jnp.asarray(rng.normal(size=(TW // 2, 2, HW)), jnp.float32)
+    return x, {
+        "router": {"gate_weight": jnp.asarray(
+            rng.normal(size=(HW, E)) * 0.2, jnp.float32)},
+        "experts": {
+            "w1": jnp.asarray(rng.normal(size=(E, HW, cols)) * 0.1,
+                              jnp.float32),
+            "w2": jnp.asarray(rng.normal(size=(E, FW, HW)) * 0.1,
+                              jnp.float32)}}
+
+
+def _tail_in_the_last_group(lhs, rhs, group_sizes):
+    """The parent's formulation: every row past the held run joins the
+    last expert's group and is multiplied by its matrices."""
+    tail = lhs.shape[0] - jnp.sum(group_sizes)
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes.at[-1].add(tail),
+                              preferred_element_type=jnp.float32)
+
+
+@pytest.fixture(params=["oracle", "interpret"])
+def path(request):
+    KREG.force_interpret(request.param == "interpret", ["grouped_matmul"])
+    yield request.param
+    KREG.force_interpret(False, ["grouped_matmul"])
+
+
+@pytest.mark.parametrize("off,n,factor", [(0, 4, 2.0), (2, 2, 4.0),
+                                          (4, 4, 1.0)])
+def test_counts_are_the_kept_rows_and_leave_the_tail_out(
+        monkeypatch, off, n, factor):
+    x, params = _wide_inputs("swiglu")
+    seen = []
+
+    def spy(lhs, rhs, group_sizes):
+        seen.append((lhs.shape[0], np.asarray(group_sizes)))
+        return jax.lax.ragged_dot(lhs, rhs, group_sizes,
+                                  preferred_element_type=jnp.float32)
+
+    monkeypatch.setattr(layer_mod, "grouped_matmul", spy)
+    _, sown = _wide_layer("swiglu", local_experts=n, expert_offset=off,
+                          capacity_factor=factor).apply(
+        {"params": _share(params, off, n)}, x, mutable=["moe_losses"])
+    tokens = x.reshape(-1, HW)
+    _, idx = jax.lax.top_k(tokens @ params["router"]["gate_weight"], K)
+    held = np.bincount(np.asarray(idx).ravel(), minlength=E)[off:off + n]
+    assert len(seen) == 2 and seen[0][0] == seen[1][0]
+    rows, counts = seen[0]
+    assert (counts == seen[1][1]).all() and counts.shape == (n,)
+    kept = min(int(held.sum()), rows)
+    assert counts.sum() == kept
+    if held.sum() < rows:       # assignments fewer than the static rows
+        assert counts.sum() < rows and (counts == held).all()
+    else:                       # the overflow is cut off the last groups
+        assert (counts <= held).all()
+    dropped = float(flax.traverse_util.flatten_dict(
+        sown["moe_losses"])[("held_dropped_fraction",)][0])
+    assert dropped == pytest.approx(1.0 - kept / max(held.sum(), 1))
+
+
+@pytest.mark.parametrize("activation", ["swiglu", "relu2"])
+def test_the_layer_is_the_parent_s_with_the_tail_in_the_last_group(
+        monkeypatch, path, activation):
+    """Output, input gradient and every parameter gradient of the
+    held-share layer against the same layer with the tail multiplied, as
+    before PR 34: on the oracle path and through the kernels."""
+    x, params = _wide_inputs(activation)
+    share = _share(params, 2, 4)
+    layer = _wide_layer(activation, local_experts=4, expert_offset=2,
+                        capacity_factor=2.0)
+
+    def out_and_grads():
+        def loss(p, inp):
+            out = layer.apply({"params": p}, inp)
+            return jnp.sum(out * jnp.cos(out)), out
+
+        (_, out), grads = jax.value_and_grad(loss, (0, 1), has_aux=True)(
+            share, x)
+        return out, grads
+
+    with use_registry(MetricsRegistry(enabled=True)) as reg:
+        got = out_and_grads()
+    assert reg.counter_value(
+        f"kernels/dispatch/grouped_matmul_{path}") == 2
+    assert reg.snapshot()["gauges"]["moe/held_rows"] == 512
+    monkeypatch.setattr(layer_mod, "grouped_matmul", _tail_in_the_last_group)
+    want = out_and_grads()
+    flat_got = jax.tree_util.tree_leaves_with_path(got)
+    flat_want = jax.tree_util.tree_leaves(want)
+    assert len(flat_got) == 5       # out, router, w1, w2, input
+    for (where, a), b in zip(flat_got, flat_want):
+        assert float(jnp.abs(b).max()) > 0, where
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-6,
+                                   err_msg=str(where))
+
+
+@pytest.mark.parametrize("activation", ["swiglu", "relu2", "gelu"])
+def test_the_dropless_ragged_mode_is_unchanged(monkeypatch, path,
+                                               activation):
+    """``dispatch_mode="ragged"`` (no tail: the counts sum to every row)
+    gives what ``lax.ragged_dot`` in the expert layer gave."""
+    x, params = _wide_inputs(activation)
+    if activation == "gelu":
+        rng = np.random.default_rng(5)
+        params["experts"]["b1"] = jnp.asarray(
+            rng.normal(size=(E, FW)) * 0.1, jnp.float32)
+        params["experts"]["b2"] = jnp.asarray(
+            rng.normal(size=(E, HW)) * 0.1, jnp.float32)
+    layer = _wide_layer(activation, dispatch_mode="ragged")
+    got = layer.apply({"params": params}, x)
+    monkeypatch.setattr(
+        layer_mod, "grouped_matmul",
+        lambda a, b, s: jax.lax.ragged_dot(
+            a, b, s, preferred_element_type=jnp.float32))
+    want = layer.apply({"params": params}, x)
+    if path == "oracle":
+        assert (np.asarray(got) == np.asarray(want)).all()
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)

@@ -181,6 +181,18 @@ def test_update_and_loss_are_not_charged_to_the_model(tables, model,
      "dot_general", ("moe", "forward")),
     ("jit(f)/jvp(GPTModel)/transformer/layer_1/mlp/routed/moe/router/"
      "router/logistic", ("moe", "forward")),
+    # the experts' grouped matmul keeps its scope and so its phase, which
+    # XLA's own ragged-dot kernel (the oracle's, below) does not
+    ("jit(f)/jvp(GPTModel)/transformer/layer_1/mlp/routed/moe/experts/"
+     "experts/jit(_rows)/moe_grouped_matmul_fwd/pallas_call",
+     ("moe", "forward")),
+    ("jit(f)/transpose(jvp(GPTModel))/transformer/checkpoint/"
+     "rematted_computation/layer_1/mlp/routed/moe/experts/experts/"
+     "jit(_rows)/moe_grouped_matmul_fwd/pallas_call", ("moe", "recompute")),
+    ("jit(f)/transpose(jvp(GPTModel))/transformer/layer_1/mlp/routed/moe/"
+     "experts/experts/jit(_drhs)/moe_grouped_matmul_drhs/pallas_call",
+     ("moe", "backward")),
+    ("ragged-dot-none", ("moe", "update")),
     ("jit(f)/jvp(BertModel)/head/lm_layernorm/reduce_sum",
      ("head", "forward")),
     ("jit(f)/jvp(GPTModel)/transformer/layer_0/add",

@@ -116,6 +116,8 @@ KERNEL_NAMES = {
     "adam": ("fused_adam",),
     "lamb": ("fused_lamb",),
     "topk_select": ("indexer_topk_select",),
+    "grouped_matmul": ("moe_grouped_matmul_fwd", "moe_grouped_matmul_dlhs",
+                       "moe_grouped_matmul_drhs"),
 }
 _TEXTS = {}
 
