@@ -6,10 +6,13 @@ apex/contrib/multihead_attn (CUTLASS-based fused attention). The TPU
 version is a general flash-attention: online-softmax over KV blocks, fp32
 accumulators, causal or full, any seq multiple of the block size.
 
-Two entries to one set of kernel bodies: ``flash_attention`` over
-``[b, n, s, d]`` and ``flash_attention_bsnd`` over ``[b, s, n * d]``, the
+Three entries to one set of kernel bodies: ``flash_attention`` over
+``[b, n, s, d]``, ``flash_attention_bsnd`` over ``[b, s, n * d]``, the
 layout the projections around attention write and read (heads folded into
-the lanes; a grid cell takes the heads of one 128-lane column).
+the lanes; a grid cell takes the heads of one 128-lane column), and
+``mla_flash_attention``, batch-major too, for multi-head latent attention
+(queries and keys of a positionless and a rotary part beside narrower
+values, the rotary key one vector a token shared by the heads).
 
 Forward and backward are Pallas kernels over 3-D grids (batch*heads x
 outer-blocks x streamed-blocks, innermost/"arbitrary"): K/V (forward, dq)
@@ -173,7 +176,7 @@ def _and_run(run, tile_selected):
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, slopes_ref, o_ref, lse_ref,
                       acc_ref, m_ref, l_ref, *, scale, causal, block_q,
                       block_k, num_kv, window, alibi, sel_ref=None,
-                      tile_selected=None):
+                      tile_selected=None, rope=None):
     """One (head, q-block, kv-block) grid cell of online-softmax attention.
 
     K/V arrive as [1, block_k, d] VMEM tiles streamed by the grid — VMEM
@@ -204,6 +207,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, slopes_ref, o_ref, lse_ref,
         k = k_ref[0].astype(jnp.float32)
         v = v_ref[0].astype(jnp.float32)
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
+        if rope is not None:
+            s = s + rope.scores(scale)
         if alibi:
             s = s + _alibi_bias(slopes_ref, kj, block_q, block_k)
         if causal:
@@ -306,7 +311,7 @@ def _flash_fwd_pallas(q, k, v, scale, causal, block_q, block_k,
 def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                      slopes_ref, dq_ref, dq_acc, *, scale, causal,
                      block_q, block_k, num_kv, window, alibi, sel_ref=None,
-                     tile_selected=None):
+                     tile_selected=None, rope=None):
     """dq for one q block, streaming kv blocks (innermost grid dim):
     p = exp(q k^T scale - lse); ds = p * (do v^T - delta); dq += ds k scale.
     """
@@ -330,7 +335,10 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         do = do_ref[0].astype(jnp.float32)
         lse = lse_ref[0]
         delta = delta_ref[0]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
+        if rope is not None:
+            s = s + rope.scores()
+        s = s * scale
         if alibi:
             s = s + _alibi_bias(slopes_ref, kj, block_q, block_k)
         if causal:
@@ -342,16 +350,20 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         ds = p * (dp - delta)
         dq_acc[...] += jnp.dot(ds, k,
                                preferred_element_type=jnp.float32) * scale
+        if rope is not None:
+            rope.add_dq(ds, scale)
 
     @pl.when(kj == num_kv - 1)
     def _finish():
         dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
+        if rope is not None:
+            rope.write_dq()
 
 
 def _flash_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
                       slopes_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
                       scale, causal, block_q, block_k, num_q, window,
-                      alibi, sel_ref=None, tile_selected=None):
+                      alibi, sel_ref=None, tile_selected=None, rope=None):
     """dk/dv for one kv block, streaming q blocks (innermost grid dim):
     dv += p^T do;  dk += ds^T q scale."""
     from jax.experimental import pallas as pl
@@ -377,7 +389,10 @@ def _flash_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
         do = do_ref[0].astype(jnp.float32)
         lse = lse_ref[0]
         delta = delta_ref[0]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
+        if rope is not None:
+            s = s + rope.scores()
+        s = s * scale
         if alibi:
             s = s + _alibi_bias(slopes_ref, kj, block_q, block_k)
         if causal:
@@ -391,6 +406,8 @@ def _flash_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
         ds = p * (dp - delta)
         dk_acc[...] += jnp.dot(ds.T, q,
                                preferred_element_type=jnp.float32) * scale
+        if rope is not None:
+            rope.add_dk(ds, scale)
 
     @pl.when(qi == num_q - 1)
     def _finish():
@@ -1275,6 +1292,381 @@ def _bsnd_bwd_rule(heads, causal, scale, block_q, block_k, window, res, g):
 
 
 flash_attention_bsnd.defvjp(_bsnd_fwd_rule, _bsnd_bwd_rule)
+
+
+# ----------------------------------------------------- latent attention
+#
+# Multi-head latent attention (DeepSeek-V2/V3, Moonlight) scores a pair as
+# ``q^C . k^C + q^R . k^R``: a positionless part a head (``d_nope`` wide)
+# and a rotary part (``d_rope`` wide) whose key is ONE vector a token,
+# shared by the heads; values are ``d_v`` wide. The same three kernel
+# bodies take the rotary part as a second product into the same scores
+# (``rope=``: :class:`_RopePart`), batch-major as the ``bsnd`` entry, a
+# grid cell over the heads of whole 128-lane columns of every operand
+# (two heads at 128 + 64 beside 128). The shared rotary key is read as it
+# is, ``[b, s, d_rope]``, once a tile: no ``[b, s, n, d_rope]`` broadcast
+# and no concatenated key is written, and its gradient is summed over a
+# cell's heads in the kernel's scratch (over the cells by XLA, from
+# float32). These calls are named ``mla_attention_*``.
+
+class _RopePart:
+    """A head's rotary operands beside the body's own q and k: ``q``
+    (``[1, block_q, d_rope]`` as a body reads it) and the shared ``k``
+    block, and where the gradient that the body forms goes."""
+
+    def __init__(self, q, k, dq_ref=None, acc=None):
+        self.q, self.k, self.dq_ref, self.acc = q, k, dq_ref, acc
+
+    def scores(self, scale=None):
+        q = self.q[0].astype(jnp.float32)
+        if scale is not None:       # the forward scales q, not the scores
+            q = q * scale
+        return jnp.dot(q, self.k[0].astype(jnp.float32).T,
+                       preferred_element_type=jnp.float32)
+
+    def add_dq(self, ds, scale):
+        self.acc[...] += jnp.dot(ds, self.k[0].astype(jnp.float32),
+                                 preferred_element_type=jnp.float32) * scale
+
+    def write_dq(self):
+        self.dq_ref[0] = self.acc[...].astype(self.dq_ref.dtype)
+
+    def add_dk(self, ds, scale):
+        # every head of the cell adds to the one shared key's gradient
+        self.acc[...] += jnp.dot(ds.T, self.q[0].astype(jnp.float32),
+                                 preferred_element_type=jnp.float32) * scale
+
+
+def _mla_heads_per_cell(heads, widths):
+    """Heads a latent-attention cell takes so that its block of every
+    per-head operand is whole 128-lane columns; ``None`` where there is
+    no such cut."""
+    for c in (1, 2, 4, 8):
+        if heads % c == 0 and all(c * w % 128 == 0 for w in widths):
+            return c
+    return None
+
+
+def _mla_fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref,
+                    *scratch, heads, dims, num_kv, **kw):
+    """As :func:`_bsnd_fwd_kernel`, with the rotary part."""
+    from jax.experimental import pallas as pl
+
+    dn, dr, dv = dims
+    for h in range(heads):
+        acc_ref, m_ref, l_ref, lse_col = scratch[4 * h:4 * h + 4]
+        _flash_fwd_kernel(
+            _HeadLanes(qn_ref, h, dn), _HeadLanes(kn_ref, h, dn),
+            _HeadLanes(v_ref, h, dv), None, _HeadLanes(o_ref, h, dv),
+            lse_col, acc_ref, m_ref, l_ref, num_kv=num_kv, window=None,
+            alibi=False, rope=_RopePart(_HeadLanes(qr_ref, h, dr), kr_ref),
+            **kw)
+
+    @pl.when(pl.program_id(2) == num_kv - 1)
+    def _lse_rows():
+        for h in range(heads):
+            lse_ref[0, 0, h:h + 1, :] = _turned(scratch[4 * h + 3][0])
+
+
+def _mla_dq_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, o_ref,
+                   lse_ref, dqn_ref, dqr_ref, delta_ref, *scratch, heads,
+                   dims, **kw):
+    """As :func:`_bsnd_dq_kernel`; ``scratch`` holds (dq acc, rotary dq
+    acc, lse column, delta column) a head."""
+    from jax.experimental import pallas as pl
+
+    dn, dr, dv = dims
+    for h in range(heads):
+        dq_acc, dqr_acc, lse_col, delta_col = scratch[4 * h:4 * h + 4]
+        do_h = _HeadLanes(do_ref, h, dv)
+
+        @pl.when(pl.program_id(2) == 0)
+        def _columns():
+            lse_col[0] = _turned(lse_ref[0, 0, h:h + 1, :])
+            delta = jnp.sum(
+                do_h[0].astype(jnp.float32)
+                * _HeadLanes(o_ref, h, dv)[0].astype(jnp.float32),
+                axis=-1, keepdims=True)
+            delta_col[0] = delta
+            delta_ref[0, 0, h:h + 1, :] = _turned(delta)
+            dqr_acc[...] = jnp.zeros_like(dqr_acc)
+
+        _flash_dq_kernel(
+            _HeadLanes(qn_ref, h, dn), _HeadLanes(kn_ref, h, dn),
+            _HeadLanes(v_ref, h, dv), do_h, lse_col, delta_col, None,
+            _HeadLanes(dqn_ref, h, dn), dq_acc, window=None, alibi=False,
+            rope=_RopePart(_HeadLanes(qr_ref, h, dr), kr_ref,
+                           _HeadLanes(dqr_ref, h, dr), dqr_acc), **kw)
+
+
+def _mla_dkv_kernel(kn_ref, kr_ref, v_ref, qn_ref, qr_ref, do_ref, lse_ref,
+                    delta_ref, dkn_ref, dkr_ref, dv_ref, *scratch, heads,
+                    dims, num_q, **kw):
+    """As :func:`_bsnd_dkv_kernel`; ``scratch`` holds (dk acc, dv acc,
+    lse column, delta column) a head and, last, the shared rotary key's
+    gradient, which all the cell's heads add to."""
+    from jax.experimental import pallas as pl
+
+    dn, dr, dv = dims
+    dkr_acc = scratch[-1]
+    qi = pl.program_id(2)
+
+    @pl.when(qi == 0)
+    def _init():
+        dkr_acc[...] = jnp.zeros_like(dkr_acc)
+
+    run = _stream_q_run(qi, pl.program_id(1), kw["block_q"], kw["block_k"],
+                        kw["causal"], None)
+    for h in range(heads):
+        dk_acc, dv_acc, lse_col, delta_col = scratch[4 * h:4 * h + 4]
+
+        @pl.when(run)
+        def _columns():
+            lse_col[0] = _turned(lse_ref[0, 0, h:h + 1, :])
+            delta_col[0] = _turned(delta_ref[0, 0, h:h + 1, :])
+
+        _flash_dkv_kernel(
+            _HeadLanes(kn_ref, h, dn), _HeadLanes(v_ref, h, dv),
+            _HeadLanes(qn_ref, h, dn), _HeadLanes(do_ref, h, dv), lse_col,
+            delta_col, None, _HeadLanes(dkn_ref, h, dn),
+            _HeadLanes(dv_ref, h, dv), dk_acc, dv_acc, num_q=num_q,
+            window=None, alibi=False,
+            rope=_RopePart(_HeadLanes(qr_ref, h, dr), kr_ref, acc=dkr_acc),
+            **kw)
+
+    @pl.when(qi == num_q - 1)
+    def _finish():
+        dkr_ref[0, 0] = dkr_acc[...]
+
+
+def _mla_specs(per_cell, cells, dims, block_q, block_k, q_of, kv_of):
+    """BlockSpecs over a ``(b * cells, x, y)`` grid, as
+    :func:`_bsnd_specs`: ``qn``/``qr``/``o`` follow the q block, ``kn``/
+    ``kr``/``v`` the kv block; ``dkr`` is the cell's part of the shared
+    rotary key's gradient, ``[b, cells, s, d_rope]`` float32."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    dn, dr, dv = dims
+
+    def spec(shape, index):
+        return pl.BlockSpec(shape, index, memory_space=pltpu.VMEM)
+
+    def q_side(width):
+        return spec((1, block_q, per_cell * width),
+                    lambda g, x, y: (g // cells, q_of(x, y), g % cells))
+
+    def kv_side(width):
+        return spec((1, block_k, per_cell * width),
+                    lambda g, x, y: (g // cells, kv_of(x, y), g % cells))
+
+    return {
+        "qn": q_side(dn), "qr": q_side(dr), "o": q_side(dv),
+        "kn": kv_side(dn), "v": kv_side(dv),
+        "kr": spec((1, block_k, dr),
+                   lambda g, x, y: (g // cells, kv_of(x, y), 0)),
+        "row": spec((1, 1, per_cell, block_q),
+                    lambda g, x, y: (g // cells, g % cells, 0, q_of(x, y))),
+        "dkr": spec((1, 1, block_k, dr),
+                    lambda g, x, y: (g // cells, g % cells, kv_of(x, y), 0)),
+    }
+
+
+def _mla_dims(q_nope, q_rope, v, heads):
+    return tuple(x.shape[-1] // heads for x in (q_nope, q_rope, v))
+
+
+_mla_jit = functools.partial(jax.jit, static_argnames=(
+    "heads", "scale", "causal", "block_q", "block_k", "interpret"))
+
+
+@_mla_jit
+def _mla_fwd_pallas(q_nope, q_rope, k_nope, k_rope, v, *, heads, scale,
+                    causal, block_q, block_k, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, s, _ = q_nope.shape
+    dims = _mla_dims(q_nope, q_rope, v, heads)
+    per_cell = _mla_heads_per_cell(heads, dims)
+    cells = heads // per_cell
+    num_kv = s // block_k
+    sp = _mla_specs(
+        per_cell, cells, dims, block_q, block_k, lambda i, j: i,
+        lambda i, j: _fetched_kv_block(i, j, block_q, block_k, causal,
+                                       None))
+    return pl.pallas_call(
+        functools.partial(
+            _mla_fwd_kernel, heads=per_cell, dims=dims, scale=scale,
+            causal=causal, block_q=block_q, block_k=block_k, num_kv=num_kv),
+        grid=(b * cells, s // block_q, num_kv),
+        in_specs=[sp["qn"], sp["qr"], sp["kn"], sp["kr"], sp["v"]],
+        out_specs=[sp["o"], sp["row"]],
+        out_shape=[
+            jax.ShapeDtypeStruct(v.shape, q_nope.dtype),
+            jax.ShapeDtypeStruct((b, cells, per_cell, s), jnp.float32),
+        ],
+        scratch_shapes=per_cell * [
+            pltpu.VMEM((block_q, dims[2]), jnp.float32),    # acc
+            pltpu.VMEM((block_q, 1), jnp.float32),          # running max
+            pltpu.VMEM((block_q, 1), jnp.float32),          # running sum
+            pltpu.VMEM((1, block_q, 1), jnp.float32),       # lse column
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name="mla_attention_flash_fwd",
+    )(q_nope, q_rope, k_nope, k_rope, v)
+
+
+@_mla_jit
+def _mla_bwd_pallas(q_nope, q_rope, k_nope, k_rope, v, o, lse, do, *, heads,
+                    scale, causal, block_q, block_k, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, s, _ = q_nope.shape
+    dims = dn, dr, dv = _mla_dims(q_nope, q_rope, v, heads)
+    per_cell = _mla_heads_per_cell(heads, dims)
+    cells = heads // per_cell
+    num_q, num_kv = s // block_q, s // block_k
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
+    static = dict(heads=per_cell, dims=dims, scale=scale, causal=causal,
+                  block_q=block_q, block_k=block_k)
+    column = pltpu.VMEM((1, block_q, 1), jnp.float32)
+
+    sp = _mla_specs(
+        per_cell, cells, dims, block_q, block_k, lambda i, j: i,
+        lambda i, j: _fetched_kv_block(i, j, block_q, block_k, causal,
+                                       None))
+    dq_nope, dq_rope, delta = pl.pallas_call(
+        functools.partial(_mla_dq_kernel, num_kv=num_kv, **static),
+        grid=(b * cells, num_q, num_kv),
+        in_specs=[sp["qn"], sp["qr"], sp["kn"], sp["kr"], sp["v"], sp["o"],
+                  sp["o"], sp["row"]],
+        out_specs=[sp["qn"], sp["qr"], sp["row"]],
+        out_shape=[jax.ShapeDtypeStruct(q_nope.shape, q_nope.dtype),
+                   jax.ShapeDtypeStruct(q_rope.shape, q_rope.dtype),
+                   jax.ShapeDtypeStruct(lse.shape, jnp.float32)],
+        scratch_shapes=per_cell * [
+            pltpu.VMEM((block_q, dn), jnp.float32),
+            pltpu.VMEM((block_q, dr), jnp.float32), column, column],
+        compiler_params=params, interpret=interpret,
+        name="mla_attention_flash_dq",
+    )(q_nope, q_rope, k_nope, k_rope, v, do, o, lse)
+
+    sp = _mla_specs(
+        per_cell, cells, dims, block_q, block_k,
+        lambda j, i: _fetched_q_block(j, i, block_q, block_k, causal, None),
+        lambda j, i: j)
+    dk_nope, dk_rope, dv_ = pl.pallas_call(
+        functools.partial(_mla_dkv_kernel, num_q=num_q, **static),
+        grid=(b * cells, num_kv, num_q),
+        in_specs=[sp["kn"], sp["kr"], sp["v"], sp["qn"], sp["qr"], sp["o"],
+                  sp["row"], sp["row"]],
+        out_specs=[sp["kn"], sp["dkr"], sp["v"]],
+        out_shape=[jax.ShapeDtypeStruct(k_nope.shape, k_nope.dtype),
+                   jax.ShapeDtypeStruct((b, cells, s, dr), jnp.float32),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=per_cell * [
+            pltpu.VMEM((block_k, dn), jnp.float32),
+            pltpu.VMEM((block_k, dv), jnp.float32), column, column]
+        + [pltpu.VMEM((block_k, dr), jnp.float32)],
+        compiler_params=params, interpret=interpret,
+        name="mla_attention_flash_dkv",
+    )(k_nope, k_rope, v, q_nope, q_rope, do, lse, delta)
+    dk_rope = jnp.sum(dk_rope, axis=1).astype(k_rope.dtype)
+    return dq_nope, dq_rope, dk_nope, dk_rope, dv_
+
+
+def mla_attention_reference(q_nope, q_rope, k_nope, k_rope, v, heads,
+                            causal=True):
+    """The latent attention's oracle: the rotary key broadcast to the
+    heads and concatenated to each head's positionless key, then
+    :func:`_attention_reference` (float32 softmax over ``[b, n, s, s]``
+    scores). Operands and result as :func:`mla_flash_attention`."""
+    b, s, _ = q_nope.shape
+    dn, dr, _ = _mla_dims(q_nope, q_rope, v, heads)
+    scale = 1.0 / ((dn + dr) ** 0.5)
+
+    def per_head(x):
+        return x.reshape(b, s, heads, -1)
+
+    q = jnp.concatenate([per_head(q_nope), per_head(q_rope)], axis=-1)
+    k = jnp.concatenate(
+        [per_head(k_nope),
+         jnp.broadcast_to(k_rope[:, :, None, :], (b, s, heads, dr))],
+        axis=-1)
+    out = _attention_reference(
+        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+        per_head(v).transpose(0, 2, 1, 3), scale, causal)
+    return _to_batch_major(out)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def mla_flash_attention(q_nope, q_rope, k_nope, k_rope, v, heads,
+                        causal=True, block_q=DEFAULT_BLOCK_Q,
+                        block_k=DEFAULT_BLOCK_K):
+    """Flash attention for multi-head latent attention, batch-major:
+    ``q_nope``, ``k_nope`` ``[batch, seq, heads * d_nope]``, ``q_rope``
+    ``[batch, seq, heads * d_rope]`` (rotated), ``k_rope`` ``[batch, seq,
+    d_rope]`` (rotated; one key a token, shared by the heads), ``v``
+    ``[batch, seq, heads * d_v]`` -> ``[batch, seq, heads * d_v]``.
+    Scores are ``(q_nope . k_nope + q_rope . k_rope) * (d_nope + d_rope)
+    ** -0.5``. The kernels run where a block divides the sequence and a
+    cell's heads fill whole 128-lane columns of every operand
+    (:func:`_mla_heads_per_cell`); their oracle
+    (:func:`mla_attention_reference`) elsewhere. Residuals as
+    :func:`flash_attention_bsnd` keeps them (``FLASH_RESIDUAL_NAMES``).
+    Counted as ``kernels/dispatch/flash_attention_mla_<path>`` beside
+    ``flash_attention``'s own counter."""
+    return _mla_fwd_rule(q_nope, q_rope, k_nope, k_rope, v, heads, causal,
+                         block_q, block_k)[0]
+
+
+def _mla_resolve(q_nope, q_rope, v, heads, block_q, block_k):
+    dims = _mla_dims(q_nope, q_rope, v, heads)
+    scale, bq, bk = _resolve_sizes(dims[0] + dims[1], q_nope.shape[1],
+                                   None, block_q, block_k)
+    fits = (bq is not None and bk is not None
+            and _mla_heads_per_cell(heads, dims) is not None)
+    return scale, bq, bk, fits
+
+
+def _mla_fwd_rule(q_nope, q_rope, k_nope, k_rope, v, heads, causal,
+                  block_q, block_k):
+    scale, bq, bk, fits = _mla_resolve(q_nope, q_rope, v, heads, block_q,
+                                       block_k)
+    path = GATE.path(fits=fits)
+    get_kernel_registry().dispatch("flash_attention_mla", path)
+    operands = (q_nope, q_rope, k_nope, k_rope, v)
+    if path != "oracle":
+        out, lse = _mla_fwd_pallas(
+            *operands, heads=heads, scale=scale, causal=causal,
+            block_q=bq, block_k=bk, interpret=GATE.interpret)
+        out = checkpoint_name(out, FLASH_RESIDUAL_NAMES[0])
+        lse = checkpoint_name(lse, FLASH_RESIDUAL_NAMES[1])
+        return out, operands + (out, lse)
+    return (mla_attention_reference(*operands, heads, causal),
+            operands + (None, None))
+
+
+def _mla_bwd_rule(heads, causal, block_q, block_k, res, g):
+    *operands, out, lse = res
+    scale, bq, bk, _ = _mla_resolve(operands[0], operands[1], operands[4],
+                                    heads, block_q, block_k)
+    if lse is not None:
+        return _mla_bwd_pallas(
+            *operands, out, lse, g, heads=heads, scale=scale,
+            causal=causal, block_q=bq, block_k=bk,
+            interpret=GATE.interpret)
+    _, vjp = jax.vjp(
+        lambda *xs: mla_attention_reference(*xs, heads, causal), *operands)
+    return vjp(g)
+
+
+mla_flash_attention.defvjp(_mla_fwd_rule, _mla_bwd_rule)
 
 
 def head_summed_probs(q, k, selection, causal=True, scale=None,

@@ -30,7 +30,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.models.transformer_lm import _rope_core
+from apex_tpu.models.transformer_lm import _rope_core, latent_attention
 from apex_tpu.normalization import FusedRMSNorm
 from apex_tpu.transformer.parallel_state import (
     get_tensor_model_parallel_world_size,
@@ -162,29 +162,15 @@ class MLAAttention(nn.Module):
         kv = kv.reshape(s, b, n_local, nope + vd)
         k_nope, value = kv[..., :nope], kv[..., nope:]
 
-        # rope on the decoupled sub-vectors (interleaved convention; the
-        # key rope part is one shared "head" broadcast after rotation)
-        q_pe = _rope_core(q_pe, cfg.rotary_base, position_ids, rope,
-                          interleaved=True)
-        k_pe = _rope_core(k_pe[:, :, None, :], cfg.rotary_base,
-                          position_ids, rope, interleaved=True)
-        k_pe = jnp.broadcast_to(k_pe, (s, b, n_local, rope))
-
-        scale = jnp.asarray(cfg.qk_head_dim ** -0.5, jnp.float32)
-        scores = (jnp.einsum("qbnd,kbnd->bnqk",
-                             jnp.concatenate([q_nope, q_pe], -1).astype(
-                                 cfg.compute_dtype),
-                             jnp.concatenate([k_nope, k_pe], -1).astype(
-                                 cfg.compute_dtype),
-                             preferred_element_type=jnp.float32) * scale)
-        i = jnp.arange(s)[:, None]
-        j = jnp.arange(s)[None, :]
-        scores = jnp.where(j > i, -1e9, scores)  # causal
-        probs = jax.nn.softmax(scores, axis=-1)
-        ctx = jnp.einsum("bnqk,kbnd->qbnd",
-                         probs.astype(cfg.compute_dtype),
-                         value.astype(cfg.compute_dtype),
-                         preferred_element_type=jnp.float32)
+        # rotary on the decoupled sub-vectors (interleaved convention; the
+        # key's rotary part is one shared "head") and causal attention:
+        # the package's one spelling of it, the flash kernels where they
+        # run (models/transformer_lm.py latent_attention)
+        ctx = latent_attention(
+            q_nope.astype(cfg.compute_dtype), q_pe.astype(cfg.compute_dtype),
+            k_nope.astype(cfg.compute_dtype), k_pe.astype(cfg.compute_dtype),
+            value.astype(cfg.compute_dtype), rotary_base=cfg.rotary_base,
+            position_ids=position_ids)
         ctx = ctx.reshape(s, b, n_local * vd).astype(cfg.compute_dtype)
         return RowParallelLinear(
             input_size=cfg.num_heads * vd, output_size=cfg.hidden_size,

@@ -117,6 +117,13 @@ class TransformerConfig:
     moe_top_k: int = 1
     moe_capacity_factor: float = 1.25
     moe_layer_freq: int = 1
+    # The first ``moe_first_dense_layers`` layers keep the dense MLP
+    # (DeepSeek's ``first_k_dense_replace``); ``moe_layer_freq`` counts
+    # from the first layer after them.
+    moe_first_dense_layers: int = 0
+    # An expert's width where it is not the dense MLP's (DeepSeek's
+    # ``moe_intermediate_size``); None -> ``ffn_size``.
+    moe_ffn_hidden_size: Optional[int] = None
     moe_jitter_eps: float = 0.0
     moe_router_type: str = "top_k"  # or "expert_choice"
     moe_aux_loss_coeff: float = 1e-2
@@ -249,12 +256,34 @@ class TransformerConfig:
     # loss. Sorted routing only (moe/router.py).
     moe_router_score: str = "softmax"
     moe_routed_scaling_factor: float = 1.0
+    # DeepSeek-V3's complementary sequence-wise balance loss on the
+    # sigmoid_bias router (``seq_aux``): each expert layer sows
+    # ``seq_aux_loss`` (moe/router.py ``sequence_balance_loss``) and the
+    # training loss adds this coefficient times their sum
+    # (``moe.seq_aux_loss_from_variables``). 0.0 -> nothing is traced.
+    moe_seq_aux_loss_coeff: float = 0.0
     # One sub-block a layer (Nemotron-H "hybrid_override_pattern"): a
     # string of num_layers letters, each layer x + f(norm(x)) with f a
     # Mamba-2 mixer ("M", transformer/ssm.py), attention ("*") or the
     # expert layer ("E"). None -> every layer is attention then MLP, as
     # everywhere else in this file.
     layer_pattern: Optional[str] = None
+    # Multi-head latent attention (DeepSeek-V2/V3, Moonlight): keys and
+    # values come from a ``kv_lora_rank``-wide latent of the token, RMS
+    # normed and projected up to ``qk_nope_head_dim`` positionless key
+    # channels and ``v_head_dim`` value channels a head; ``qk_rope_head_dim``
+    # rotary channels ride beside them, the key's as ONE vector a token
+    # that all heads share; queries come straight from the hidden state
+    # (``q_lora_rank`` None: a query latent is models/mla.py's alone so
+    # far, and any other value is refused here).
+    # Scores scale by (nope + rope) ** -0.5; ``rotary_base`` and
+    # ``rotary_interleaved`` say how the rotary channels turn.
+    # ``kv_lora_rank`` None -> attention as everywhere else in this file.
+    kv_lora_rank: Optional[int] = None
+    q_lora_rank: Optional[int] = None
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
     # Mamba-2 ("M" layers): heads of mamba_head_dim channels in
     # mamba_n_groups groups that share B and C, mamba_state_size states a
     # channel, a causal depthwise conv of mamba_conv_kernel, the scan in
@@ -443,6 +472,64 @@ class TransformerConfig:
                     f"moe_local_experts ({self.moe_local_experts}) from "
                     f"moe_expert_offset ({self.moe_expert_offset}) must lie "
                     f"within num_moe_experts ({self.num_moe_experts})")
+        if self.moe_first_dense_layers < 0 or (
+                self.moe_first_dense_layers and (
+                    self.num_moe_experts is None or self.scan_layers
+                    or self.layer_pattern is not None)):
+            raise ValueError(
+                f"moe_first_dense_layers ({self.moe_first_dense_layers}) "
+                f"needs num_moe_experts and an unrolled stack without a "
+                f"layer_pattern")
+        if self.moe_ffn_hidden_size is not None and (
+                self.moe_ffn_hidden_size < 1 or self.num_moe_experts is None):
+            raise ValueError(
+                f"moe_ffn_hidden_size ({self.moe_ffn_hidden_size}) must be "
+                f">= 1 and needs num_moe_experts")
+        if self.moe_seq_aux_loss_coeff < 0 or (
+                self.moe_seq_aux_loss_coeff
+                and self.moe_router_score != "sigmoid_bias"):
+            raise ValueError(
+                f"moe_seq_aux_loss_coeff ({self.moe_seq_aux_loss_coeff}) "
+                f"must be >= 0 and belongs to moe_router_score "
+                f"'sigmoid_bias'")
+        if self.kv_lora_rank is not None:
+            widths = (self.kv_lora_rank, self.qk_nope_head_dim,
+                      self.qk_rope_head_dim, self.v_head_dim)
+            if min(widths) < 1 or self.qk_rope_head_dim % 2:
+                raise ValueError(
+                    f"latent attention needs kv_lora_rank, qk_nope_head_dim,"
+                    f" qk_rope_head_dim (even) and v_head_dim >= 1, got "
+                    f"{widths}")
+            if self.q_lora_rank is not None:
+                raise ValueError(
+                    f"q_lora_rank ({self.q_lora_rank}): this path projects "
+                    f"queries straight from the hidden state (None); "
+                    f"models/mla.py has the query latent")
+            if (self.attn_mask_type != AttnMaskType.causal
+                    or self.sliding_window is not None
+                    or self.attn_logit_softcapping is not None
+                    or self.query_pre_attn_scalar is not None
+                    or self.qk_norm is not None
+                    or self.qkv_clip is not None
+                    or self.indexer_heads is not None
+                    or self.num_query_groups not in (
+                        None, self.num_attention_heads)
+                    or self.attention_bias
+                    or self.position_embedding_type != "rope"
+                    or self.rope_scaling is not None
+                    or self.rope_sections is not None
+                    or self.rotary_percent != 1.0
+                    or self.context_parallel or self.sequence_parallel):
+                raise ValueError(
+                    "latent attention (kv_lora_rank) is causal rope "
+                    "attention over all the heads, without biases, a "
+                    "window, a soft cap, a custom softmax scale, QK norm, "
+                    "a clip, the sparse indexer, grouped queries, rope "
+                    "scaling / sections / percent or context / sequence "
+                    "parallelism")
+        elif self.q_lora_rank is not None:
+            raise ValueError("q_lora_rank needs kv_lora_rank (latent "
+                             "attention)")
         if self.moe_router_score not in ("softmax", "sigmoid_bias"):
             raise ValueError(
                 f"unknown moe_router_score {self.moe_router_score!r}; "
@@ -805,15 +892,48 @@ def indexer_loss_from_variables(variables):
     the ``moe_losses`` collection of ``model.apply(...,
     mutable=["moe_losses"])`` (beside
     ``transformer.moe.moe_loss_from_variables``)."""
-    import flax
+    from apex_tpu.transformer.moe import sown_total
 
-    losses = variables.get("moe_losses", variables)
-    total = jnp.zeros((), jnp.float32)
-    for path, val in flax.traverse_util.flatten_dict(dict(losses)).items():
-        if path[-1] == "indexer_loss":
-            total = total + jnp.sum(
-                sum(val) if isinstance(val, (tuple, list)) else val)
-    return total
+    return sown_total(variables, "indexer_loss")
+
+
+def latent_attention(q_nope, q_rope, k_nope, k_rope, v, *, rotary_base,
+                     position_ids=None, interleaved=True, flash=True):
+    """Multi-head latent attention from the projected operands to the
+    context: the one spelling of it for training in the package
+    (``ParallelAttention``'s latent path and ``models/mla.py``).
+
+    ``q_nope``, ``k_nope`` ``[s, b, n, d_nope]``, ``q_rope`` ``[s, b, n,
+    d_rope]`` and ``k_rope`` ``[s, b, d_rope]`` (the one rotary key a
+    token, shared by the heads) not yet rotated, ``v`` ``[s, b, n, d_v]``
+    -> ``[s, b, n * d_v]``. Rotary on ``q_rope`` of every head and on
+    ``k_rope`` (scope ``mla/rope``), then causal attention over
+    ``(q_nope . k_nope + q_rope . k_rope) * (d_nope + d_rope) ** -0.5``
+    (scope ``mla/kernel``): ``contrib.fmha.mla_flash_attention``, whose
+    one rule runs the kernels or their oracle; with ``flash`` off, the
+    oracle. Nothing is broadcast or concatenated on the kernel path."""
+    from apex_tpu.contrib import fmha
+
+    s, b, n, _ = q_nope.shape
+
+    def batch_major(t):     # [s, b, ...] -> [b, s, the rest folded]
+        return t.reshape(s, b, -1).transpose(1, 0, 2)
+
+    with jax.named_scope("mla"):    # the layout changes between the parts
+        with jax.named_scope("rope"):
+            rope = q_rope.shape[-1]
+            q_rope = _rope_core(q_rope, rotary_base, position_ids, rope,
+                                interleaved)
+            k_rope = _rope_core(k_rope[:, :, None, :], rotary_base,
+                                position_ids, rope, interleaved)[:, :, 0, :]
+        operands = tuple(map(batch_major,
+                             (q_nope, q_rope, k_nope, k_rope, v)))
+        with jax.named_scope("kernel"):
+            if flash:
+                ctx = fmha.mla_flash_attention(*operands, n, True)
+            else:
+                ctx = fmha.mla_attention_reference(*operands, n, True)
+        return ctx.transpose(1, 0, 2)
 
 
 class ParallelAttention(nn.Module):
@@ -868,6 +988,10 @@ class ParallelAttention(nn.Module):
         cfg = self.config
         tp = get_tensor_model_parallel_world_size()
         np_local = cfg.num_attention_heads // tp
+        if cfg.kv_lora_rank is not None:
+            return self._latent_attention(cfg, hidden_states,
+                                          attention_mask, position_ids,
+                                          np_local)
         kv = cfg.kv_channels
         s, b, h = hidden_states.shape[-3:]
         x = hidden_states.astype(cfg.compute_dtype)
@@ -1103,6 +1227,74 @@ class ParallelAttention(nn.Module):
 
         ctx = ctx.reshape(ctx.shape[0], b, np_local * kv)
         return self._output_proj(cfg, ctx)
+
+    def _latent_attention(self, cfg, hidden_states, attention_mask,
+                          position_ids, n):
+        """Multi-head latent attention (``kv_lora_rank`` set), training:
+        the projections, each under its scope ``mla/{q_proj,kv_down,
+        kv_up,out_proj}``, around :func:`latent_attention`. The q and
+        kv_up weights' columns are ``[every head's positionless part |
+        every head's rotary part]`` and ``[every head's key part | every
+        head's value]``: each part comes from a matmul of its own over its
+        columns, in the kernels' layout, so that no activation is sliced
+        per head. Counted at trace time as ``mla/layers``."""
+        from apex_tpu.normalization import FusedRMSNorm
+        from apex_tpu.telemetry.registry import get_registry
+
+        if self.decode or attention_mask is not None:
+            raise ValueError(
+                "latent attention trains without an explicit "
+                "attention_mask; its cache row does not exist in this "
+                "path (models/mla.py decodes)")
+        get_registry().counter("mla/layers").inc()
+        dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                      cfg.v_head_dim)
+        lat = cfg.kv_lora_rank
+        s, b, _ = hidden_states.shape
+        x = hidden_states.astype(cfg.compute_dtype)
+
+        def linear(name, fan_in, fan_out):
+            # the latents ride every rank whole; the heads shard
+            return ColumnParallelLinear(
+                input_size=fan_in, output_size=fan_out, gather_output=False,
+                bias=False, params_dtype=cfg.params_dtype, name=name)
+
+        def norm(t, name):
+            return FusedRMSNorm(
+                normalized_shape=t.shape[-1], eps=cfg.layernorm_epsilon,
+                param_dtype=jnp.float32, name=name)(
+                    t.astype(jnp.float32)).astype(cfg.compute_dtype)
+
+        def cut_at(width):
+            return lambda w: (w[..., :width], w[..., width:])
+
+        with jax.named_scope("mla/q_proj"):
+            q_nope, q_rope = linear(
+                "q_proj", cfg.hidden_size,
+                cfg.num_attention_heads * (dn + dr))(
+                    x, column_groups=cut_at(n * dn))
+        with jax.named_scope("mla/kv_down"):
+            down = nn.Dense(lat + dr, use_bias=False,
+                            dtype=cfg.compute_dtype,
+                            param_dtype=cfg.params_dtype, name="kv_down")(x)
+            latent = norm(down[..., :lat], "kv_norm")
+            k_rope = down[..., lat:]
+        with jax.named_scope("mla/kv_up"):
+            k_nope, v = linear(
+                "kv_up", lat, cfg.num_attention_heads * (dn + dv))(
+                    latent, column_groups=cut_at(n * dn))
+        ctx = latent_attention(
+            q_nope.reshape(s, b, n, dn), q_rope.reshape(s, b, n, dr),
+            k_nope.reshape(s, b, n, dn), k_rope, v.reshape(s, b, n, dv),
+            rotary_base=cfg.rotary_base, position_ids=position_ids,
+            interleaved=cfg.rotary_interleaved,
+            flash=cfg.use_flash_attention)
+        with jax.named_scope("mla/out_proj"):
+            return RowParallelLinear(
+                input_size=cfg.num_attention_heads * dv,
+                output_size=cfg.hidden_size, input_is_parallel=True,
+                bias=False, params_dtype=cfg.params_dtype,
+                name="dense")(ctx.astype(cfg.compute_dtype))
 
     def _apply_qk_norm(self, cfg, q, k, tp):
         """Query/key RMSNorm before rope (fp32, cast back).
@@ -1365,7 +1557,8 @@ def _make_mlp(cfg, moe: bool):
     if not moe:
         return ParallelMLP(cfg, name="mlp")
     routed = dict(
-        hidden_size=cfg.hidden_size, ffn_hidden_size=cfg.ffn_size,
+        hidden_size=cfg.hidden_size,
+        ffn_hidden_size=cfg.moe_ffn_hidden_size or cfg.ffn_size,
         num_experts=cfg.num_moe_experts, top_k=cfg.moe_top_k,
         capacity_factor=cfg.moe_capacity_factor,
         jitter_eps=cfg.moe_jitter_eps, router_type=cfg.moe_router_type,
@@ -1376,6 +1569,7 @@ def _make_mlp(cfg, moe: bool):
         expert_offset=cfg.moe_expert_offset,
         router_score=cfg.moe_router_score,
         routed_scaling_factor=cfg.moe_routed_scaling_factor,
+        seq_aux_loss=cfg.moe_seq_aux_loss_coeff > 0,
         sequence_parallel_enabled=cfg.sequence_parallel, name="mlp")
     if cfg.moe_shared_expert_size:
         from apex_tpu.transformer.moe.layer import SharedExpertMoE
@@ -1397,8 +1591,9 @@ class ParallelTransformerLayer(nn.Module):
 
     def _is_moe_layer(self) -> bool:
         cfg = self.config
-        return (cfg.num_moe_experts is not None
-                and self.layer_number % cfg.moe_layer_freq == 0)
+        after = self.layer_number - cfg.moe_first_dense_layers
+        return (cfg.num_moe_experts is not None and after >= 0
+                and after % cfg.moe_layer_freq == 0)
 
     def _one_sub_block(self, hidden_states, attention_mask, position_ids):
         """A ``layer_pattern`` layer: ``x + f(norm(x))``, ``f`` by this

@@ -52,6 +52,10 @@ _BLOCKS = {
     # scan,gate_norm,out_proj}`` in the flax module ``mixer`` of a
     # ``layer_pattern`` layer)
     "ssm": ("ssm",),
+    # latent attention (models/transformer_lm.py ``ParallelAttention``'s
+    # latent path and ``latent_attention``; scopes ``mla/{q_proj,kv_down,
+    # kv_up,rope,kernel,out_proj}`` in the flax module ``self_attention``)
+    "mla": ("mla",),
     "head": ("head", "word_embeddings.attend", "lm_dense", "lm_layernorm",
              "lm_head", "lm_head_bias", "pooler", "binary_head"),
     "loss": ("loss",),
@@ -65,7 +69,7 @@ _BLOCKS = {
 _BLOCK_OF = {name: block for block, names in _BLOCKS.items()
              for name in names}
 # blocks that sit inside another block's module and take its time out of it
-_INNER = ("indexer", "moe", "ssm")
+_INNER = ("indexer", "moe", "ssm", "mla")
 # XLA replaces ``lax.ragged_dot`` by a grouped-matmul kernel of its own and
 # names it, and the call that prepares its group metadata, by what it is
 # and not by the scope it was traced under (``op_name="ragged-dot-none"``):
@@ -84,6 +88,9 @@ _ATTENTION_PARTS = {"query_key_value": "qkv", "dense": "dense",
                     "pallas_call": "kernel"}
 # sub-blocks of the Mamba-2 mixer: the scopes it opens under ``ssm/``
 _SSM_PARTS = ("in_proj", "conv", "scan", "gate_norm", "out_proj")
+# sub-blocks of latent attention: the scopes it opens under ``mla/``
+_MLA_PARTS = ("q_proj", "kv_down", "kv_up", "rope", "kernel", "out_proj")
+_PARTS = {"ssm": _SSM_PARTS, "mla": _MLA_PARTS}
 # ``jvp(GPTModel)`` / ``transpose(jvp(loss))`` / ``jit(_where)`` -> the name
 _WRAPPED = re.compile(r"^(?:\w+\()+([^()]*)\)+$")
 
@@ -111,7 +118,10 @@ def classify(scope: str) -> tuple:
     sparse-attention indexer), ``mlp`` (``moe`` where a later component
     is one of the expert layer's scopes), ``ssm`` (``ssm/in_proj``,
     ``ssm/conv``, ``ssm/scan``, ``ssm/gate_norm``, ``ssm/out_proj`` where
-    the next component says which part of the Mamba-2 mixer), ``head``,
+    the next component says which part of the Mamba-2 mixer), ``mla``
+    (``mla/q_proj``, ``mla/kv_down``, ``mla/kv_up``, ``mla/rope``,
+    ``mla/kernel``, ``mla/out_proj``: latent attention, inside the
+    ``self_attention`` module as the indexer is), ``head``,
     ``loss``, ``amp``, ``optimizer``, ``collective``; ``residual`` for a
     scope inside the model that names none of them; ``None`` for any
     other. ``phase`` is
@@ -138,9 +148,10 @@ def classify(scope: str) -> tuple:
                 if _BLOCK_OF.get(sub) in _INNER:
                     block, i = _BLOCK_OF[sub], j
                     break
-        if block == "ssm":
+        if block in _PARTS:
             part = parts[i + 1] if i + 1 < len(parts) else None
-            return (f"ssm/{part}" if part in _SSM_PARTS else block), phase
+            return (f"{block}/{part}" if part in _PARTS[block]
+                    else block), phase
         if block == "attention":
             for sub in parts[i + 1:]:
                 if sub in _ATTENTION_PARTS:

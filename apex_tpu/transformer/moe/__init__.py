@@ -16,6 +16,8 @@ from apex_tpu.transformer.moe.layer import (
     SwitchMLP,
     is_expert_param,
     moe_loss_from_variables,
+    seq_aux_loss_from_variables,
+    sown_total,
 )
 from apex_tpu.transformer.moe.router import (
     SortedRouting,
@@ -23,6 +25,7 @@ from apex_tpu.transformer.moe.router import (
     compute_expert_choice_routing,
     compute_routing,
     compute_routing_sorted,
+    sequence_balance_loss,
 )
 
 __all__ = [
@@ -36,4 +39,7 @@ __all__ = [
     "compute_routing_sorted",
     "is_expert_param",
     "moe_loss_from_variables",
+    "seq_aux_loss_from_variables",
+    "sequence_balance_loss",
+    "sown_total",
 ]

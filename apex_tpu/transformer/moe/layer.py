@@ -26,7 +26,11 @@ import jax.numpy as jnp
 from jax import lax
 
 from apex_tpu.kernels.grouped_matmul import grouped_matmul
-from apex_tpu.transformer.moe.router import TopKRouter, expert_capacity
+from apex_tpu.transformer.moe.router import (
+    TopKRouter,
+    expert_capacity,
+    sequence_balance_loss,
+)
 from apex_tpu.transformer.parallel_state import (
     EXPERT_PARALLEL_AXIS,
     TENSOR_PARALLEL_AXIS,
@@ -60,6 +64,30 @@ def moe_loss_from_variables(variables, aux_loss_coeff: float = 1e-2,
         elif path[-1] == "z_loss":
             z = z + total
     return aux_loss_coeff * aux + z_loss_coeff * z
+
+
+def sown_total(variables, name: str):
+    """The sum over layers of the scalars sown under ``name`` into the
+    ``moe_losses`` collection of ``model.apply(...,
+    mutable=["moe_losses"])`` (the mutated-variables dict or the
+    collection itself; scan-stacked layers sow ``[L]``-shaped entries)."""
+    import flax
+
+    losses = variables.get("moe_losses", variables)
+    total = jnp.zeros((), jnp.float32)
+    for path, val in flax.traverse_util.flatten_dict(dict(losses)).items():
+        if path[-1] == name:
+            total = total + jnp.sum(
+                sum(val) if isinstance(val, (tuple, list)) else val)
+    return total
+
+
+def seq_aux_loss_from_variables(variables):
+    """The sum over layers of the sequence-wise balance losses
+    (``seq_aux_loss``, sown where ``SwitchMLP.seq_aux_loss`` is on); the
+    caller multiplies by its coefficient
+    (``TransformerConfig.moe_seq_aux_loss_coeff``)."""
+    return sown_total(variables, "seq_aux_loss")
 
 
 _WARNED_DROPPED_LOSSES = False
@@ -232,6 +260,7 @@ class SharedExpertMoE(nn.Module):
     expert_offset: int = 0
     router_score: str = "softmax"
     routed_scaling_factor: float = 1.0
+    seq_aux_loss: bool = False
 
     @nn.compact
     def __call__(self, hidden_states):
@@ -265,6 +294,7 @@ class SharedExpertMoE(nn.Module):
             expert_offset=self.expert_offset,
             router_score=self.router_score,
             routed_scaling_factor=self.routed_scaling_factor,
+            seq_aux_loss=self.seq_aux_loss,
             name="routed")(hidden_states)
 
         x = hidden_states.astype(self.compute_dtype)
@@ -285,20 +315,21 @@ class SharedExpertMoE(nn.Module):
                     sequence_parallel_enabled=self.sequence_parallel_enabled,
                     params_dtype=self.params_dtype, name="shared_down")(h)
             return routed + self._gated(shared, x).astype(routed.dtype)
-        gate_up = ColumnParallelLinear(
-            input_size=self.hidden_size,
-            output_size=2 * self.shared_expert_size,
-            gather_output=False, bias=False,
-            sequence_parallel_enabled=self.sequence_parallel_enabled,
-            params_dtype=self.params_dtype, name="shared_gate_up")(x)
-        g, up = jnp.split(gate_up.astype(jnp.float32), 2, axis=-1)
-        h = (jax.nn.silu(g) * up).astype(self.compute_dtype)
-        shared = RowParallelLinear(
-            input_size=self.shared_expert_size,
-            output_size=self.hidden_size, input_is_parallel=True,
-            bias=False,
-            sequence_parallel_enabled=self.sequence_parallel_enabled,
-            params_dtype=self.params_dtype, name="shared_down")(h)
+        with jax.named_scope("moe/shared"):
+            gate_up = ColumnParallelLinear(
+                input_size=self.hidden_size,
+                output_size=2 * self.shared_expert_size,
+                gather_output=False, bias=False,
+                sequence_parallel_enabled=self.sequence_parallel_enabled,
+                params_dtype=self.params_dtype, name="shared_gate_up")(x)
+            g, up = jnp.split(gate_up.astype(jnp.float32), 2, axis=-1)
+            h = (jax.nn.silu(g) * up).astype(self.compute_dtype)
+            shared = RowParallelLinear(
+                input_size=self.shared_expert_size,
+                output_size=self.hidden_size, input_is_parallel=True,
+                bias=False,
+                sequence_parallel_enabled=self.sequence_parallel_enabled,
+                params_dtype=self.params_dtype, name="shared_down")(h)
         return routed + self._gated(shared, x).astype(routed.dtype)
 
     def _gated(self, shared, x):
@@ -377,6 +408,10 @@ class SwitchMLP(nn.Module):
     # on its gates: TopKRouter.score, compute_routing_sorted
     router_score: str = "softmax"
     routed_scaling_factor: float = 1.0
+    # sow ``seq_aux_loss``, the sequence-wise balance loss of the
+    # sigmoid_bias router (router.py ``sequence_balance_loss``), beside
+    # the other losses; off -> nothing of it is traced
+    seq_aux_loss: bool = False
 
     def _resolve_dispatch(self, ep: int, capacity: int, num_tokens: int):
         mode = self.dispatch_mode
@@ -445,6 +480,14 @@ class SwitchMLP(nn.Module):
         # observability, not a loss: moe_loss_from_variables sums only the
         # *_loss keys; watch this to tune capacity_factor
         self.sow("moe_losses", "dropped_fraction", routing.dropped_fraction)
+        if self.seq_aux_loss:
+            if self.router_score != "sigmoid_bias" or len(orig_shape) != 3:
+                raise ValueError(
+                    "seq_aux_loss is the sigmoid_bias router's, over "
+                    "[s, b, h] hidden states")
+            with jax.named_scope("moe/router"):
+                self.sow("moe_losses", "seq_aux_loss", sequence_balance_loss(
+                    routing.probs, routing.chosen, orig_shape[1]))
         if (not sown and not self.is_initializing()
                 and self.warn_on_dropped_losses):
             # sow() into a non-mutable collection is a silent no-op; a

@@ -125,6 +125,7 @@ class SortedRouting:
     z_loss: jnp.ndarray      # scalar router z-loss
     probs: jnp.ndarray       # [T, E] softmax router probabilities
     dropped_fraction: jnp.ndarray = None
+    chosen: jnp.ndarray = None   # [T, k] int32 — each token's experts
 
 
 def compute_routing_sorted(logits, top_k: int, capacity: Optional[int],
@@ -203,7 +204,28 @@ def compute_routing_sorted(logits, top_k: int, capacity: Optional[int],
         aux_loss = z_loss = jnp.zeros((), jnp.float32)
     return SortedRouting(token_sorted, expert_sorted, gate_sorted, counts,
                          slot, aux_loss, z_loss, probs,
-                         lax.stop_gradient(dropped))
+                         lax.stop_gradient(dropped), topi)
+
+
+def sequence_balance_loss(probs, chosen, num_sequences: int):
+    """DeepSeek-V3's complementary sequence-wise balance loss (its
+    technical report, eq. 17-20; ``seq_aux`` in the published configs),
+    before its coefficient: for a sequence of ``T`` tokens
+    ``sum_i f_i P_i`` with ``f_i = E / (k T) * #{t : expert i chosen at
+    t}`` (no gradient) and ``P_i = (1 / T) sum_t s_it / sum_j s_jt``,
+    averaged over the sequences. ``probs`` ``[T_all, E]`` are the sigmoid
+    scores and ``chosen`` ``[T_all, k]`` each token's experts
+    (``SortedRouting.chosen``); tokens are laid out ``[s, b]`` flattened (a
+    sequence is every ``num_sequences``-th row), as ``SwitchMLP`` flattens
+    them. Over all ``E`` experts the router sees, held here or not."""
+    T_all, E = probs.shape
+    T, k = T_all // num_sequences, chosen.shape[-1]
+    share = probs / jnp.sum(probs, axis=-1, keepdims=True)
+    P = jnp.mean(share.reshape(T, num_sequences, E), axis=0)
+    picked = jnp.zeros((T_all, E), jnp.float32).at[
+        jnp.arange(T_all)[:, None], chosen].set(1.0)
+    f = jnp.sum(picked.reshape(T, num_sequences, E), axis=0) * (E / (k * T))
+    return jnp.mean(jnp.sum(f * P, axis=-1))
 
 
 def compute_expert_choice_routing(logits, capacity: int) -> RoutingResult:

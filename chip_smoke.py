@@ -325,7 +325,8 @@ def randn(key, shape, dtype=jnp.bfloat16):
 # expert layer in the Nemotron cell and in the Keye cell, and the (m, k, n,
 # g) of its two grouped matmuls (swiglu's first has [gate | up] columns)
 EXPERT_LAYERS = ((98304, 2688, 1856, 8, "relu2"),
-                 (131072, 2048, 768, 16, "swiglu"))
+                 (131072, 2048, 768, 16, "swiglu"),
+                 (98304, 2048, 1408, 8, "swiglu"))
 GROUPED_SHAPES = tuple(
     shape for m, h, f, g, act in EXPERT_LAYERS
     for shape in ((m, h, f * (2 if act == "swiglu" else 1), g),
@@ -412,8 +413,8 @@ def kernel_cases():
 
     # -- flash attention, forward and backward ----------------------------
     def fwd_bwd(attn):
-        def run(q, k, v):
-            out, vjp = jax.vjp(attn, q, k, v)
+        def run(*operands):
+            out, vjp = jax.vjp(attn, *operands)
             return out, vjp(out)
         return run
 
@@ -438,6 +439,33 @@ def kernel_cases():
             lambda heads=heads, d=d, T=T: tuple(
                 randn(i, (2, T, heads * d)) for i in range(3)),
             TOL_MXU)
+
+    # -- the same kernel bodies with the latent attention's rotary part
+    # (Moonlight: 16 heads of 128 + 64 beside values of 128, one shared
+    # rotary key a token) at the cell's shape, 2 x 8192; the oracle a head
+    # at a time, since its [2, 16, 8192, 8192] scores do not fit
+    def mla_by_head(qn, qr, kn, kr, v, heads=16):
+        b, s, _ = qn.shape
+
+        def heads_of(x):    # [b, s, n * d] -> [b * n, s, d]
+            return x.reshape(b, s, heads, -1).transpose(0, 2, 1, 3).reshape(
+                b * heads, s, -1)
+
+        q = jnp.concatenate([heads_of(qn), heads_of(qr)], -1)
+        k = jnp.concatenate([heads_of(kn), heads_of(jnp.tile(
+            kr, (1, 1, heads)))], -1)
+        one = jax.checkpoint(lambda t: fmha._attention_reference(
+            *(x[None, None] for x in t), q.shape[-1] ** -0.5, True)[0, 0])
+        out = jax.lax.map(one, (q, k, heads_of(v)))
+        return out.reshape(b, heads, s, -1).transpose(0, 2, 1, 3).reshape(
+            b, s, -1)
+
+    add("flash_attention mla fwd+bwd heads=16 128+64|128 seq=8192",
+        fwd_bwd(lambda *a: fmha.mla_flash_attention(*a, 16, True)),
+        fwd_bwd(mla_by_head),
+        lambda: tuple(randn(30 + i, (2, 8192, width)) for i, width in
+                      enumerate((2048, 1024, 2048, 64, 2048))),
+        TOL_MXU, timed=True)
 
     # -- the same kernels with a selection operand (sparse attention: each
     # query its 512 best of the causal keys by a seeded score, one tile
@@ -485,7 +513,7 @@ def kernel_cases():
         timed=True)
 
     # -- the experts' grouped matmul, forward and both gradients, at the
-    # four shapes the two MoE cells run it, an eighth of the rows real (a
+    # six shapes the three MoE cells run it, an eighth of the rows real (a
     # balanced router's share of Keye's; the rest is the tail)
     for shape in GROUPED_SHAPES:
         m, k, n, g = shape

@@ -193,6 +193,29 @@ def test_update_and_loss_are_not_charged_to_the_model(tables, model,
      "experts/experts/jit(_drhs)/moe_grouped_matmul_drhs/pallas_call",
      ("moe", "backward")),
     ("ragged-dot-none", ("moe", "update")),
+    # latent attention names its parts inside self_attention, the kernels
+    # among them; what it does outside its scopes stays attention's
+    ("jit(f)/jvp(GPTModel)/transformer/layer_0/self_attention/mla/q_proj/"
+     "q_proj/dot_general", ("mla/q_proj", "forward")),
+    ("jit(f)/jvp(GPTModel)/transformer/layer_0/self_attention/mla/kv_down/"
+     "kv_norm/rsqrt", ("mla/kv_down", "forward")),
+    ("jit(f)/transpose(jvp(GPTModel))/transformer/layer_2/self_attention/"
+     "mla/kv_up/kv_up/dot_general", ("mla/kv_up", "backward")),
+    ("jit(f)/jvp(GPTModel)/transformer/layer_0/self_attention/mla/rope/cos",
+     ("mla/rope", "forward")),
+    ("jit(f)/transpose(jvp(GPTModel))/transformer/checkpoint/"
+     "rematted_computation/layer_1/self_attention/mla/out_proj/dense/"
+     "dot_general", ("mla/out_proj", "recompute")),
+    ("jit(f)/jvp(GPTModel)/transformer/layer_1/self_attention/mla/kernel/"
+     "jit(_mla_fwd_pallas)/mla_attention_flash_fwd/pallas_call",
+     ("mla/kernel", "forward")),
+    ("jit(f)/transpose(jvp(GPTModel))/transformer/layer_1/self_attention/"
+     "mla/kernel/jit(_mla_bwd_pallas)/mla_attention_flash_dkv/pallas_call",
+     ("mla/kernel", "backward")),
+    ("jit(f)/jvp(GPTModel)/transformer/layer_1/self_attention/mla/transpose",
+     ("mla", "forward")),
+    ("jit(f)/jvp(GPTModel)/transformer/layer_1/self_attention/transpose",
+     ("attention", "forward")),
     ("jit(f)/jvp(BertModel)/head/lm_layernorm/reduce_sum",
      ("head", "forward")),
     ("jit(f)/jvp(GPTModel)/transformer/layer_0/add",
@@ -252,6 +275,49 @@ def test_a_hybrid_step_gives_the_mixer_s_parts_their_time(hybrid_blocks,
         return
     assert hybrid_blocks[(block, phase)] > 0, sorted(
         hybrid_blocks, key=str)
+
+
+@pytest.fixture(scope="module")
+def latent_blocks():
+    """Blocks of a compiled two-layer latent-attention step (a leading
+    dense layer, then gated experts with a shared expert)."""
+    cfg = TransformerConfig(
+        hidden_size=32, num_layers=2, num_attention_heads=2,
+        ffn_hidden_size=48, vocab_size=64, max_position_embeddings=SEQ,
+        compute_dtype=jnp.bfloat16, normalization="rmsnorm",
+        activation="swiglu", attention_bias=False,
+        position_embedding_type="rope", rotary_interleaved=True,
+        kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16, num_moe_experts=8, moe_top_k=2,
+        moe_first_dense_layers=1, moe_ffn_hidden_size=16,
+        moe_local_experts=4, moe_capacity_factor=2.0,
+        moe_router_score="sigmoid_bias", moe_seq_aux_loss_coeff=0.001,
+        moe_shared_expert_size=32, moe_shared_expert_gated=False,
+        activation_checkpointing=True)
+    model = GPTModel(cfg)
+    tokens = jnp.zeros((BATCH, SEQ), jnp.int32)
+
+    def loss(p, b):
+        logits, _ = model.apply({"params": p}, b["tokens"],
+                                mutable=["moe_losses"])
+        return gpt_loss_fn(logits, b["labels"])
+
+    table = scope_table(_compiled(model, FusedAdam(lr=1e-4), loss,
+                                  {"tokens": tokens, "labels": tokens},
+                                  tokens))
+    return collections.Counter(classify(s) for s in table.values())
+
+
+@pytest.mark.parametrize("block", [
+    "mla/q_proj", "mla/kv_down", "mla/kv_up", "mla/rope", "mla/kernel",
+    "mla/out_proj", "moe", "mlp", "layernorm"])
+@pytest.mark.parametrize("phase", ["forward", "backward"])
+def test_a_latent_step_gives_the_attention_s_parts_their_time(latent_blocks,
+                                                              block, phase):
+    assert latent_blocks[(block, phase)] > 0, sorted(latent_blocks, key=str)
+    # the plain attention block's projection and kernel are not there
+    assert latent_blocks[("attention/qkv", phase)] == 0
+    assert latent_blocks[("attention/kernel", phase)] == 0
 
 
 # what a fusion answers with: the scope of the matrix product or Mosaic

@@ -95,6 +95,9 @@ KERNEL_NAMES = {
                                   "sparse_attention_flash_dq",
                                   "sparse_attention_flash_dkv",
                                   "sparse_attention_head_probs"),
+    "flash_attention mla": ("mla_attention_flash_fwd",
+                            "mla_attention_flash_dq",
+                            "mla_attention_flash_dkv"),
     "flash_attention bsnd": ("self_attention_flash_fwd",
                              "self_attention_flash_dq",
                              "self_attention_flash_dkv"),
