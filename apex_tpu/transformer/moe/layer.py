@@ -26,6 +26,11 @@ import jax.numpy as jnp
 from jax import lax
 
 from apex_tpu.kernels.grouped_matmul import grouped_matmul
+from apex_tpu.kernels.row_gather import (
+    gather_rows,
+    row_tile,
+    scatter_add_rows,
+)
 from apex_tpu.transformer.moe.router import (
     TopKRouter,
     expert_capacity,
@@ -378,9 +383,14 @@ class SwitchMLP(nn.Module):
     output). Ragged path only. The held assignments are gathered into
     ``capacity_factor`` times their expected number of rows (a static
     shape); what would not fit is dropped and shows in
-    ``dropped_fraction``. Sows, beside the losses, ``held_assignments``
-    (the share of the k*T assignments that fell on held experts) and
-    ``held_load_max_over_mean``.
+    ``dropped_fraction``. Of those rows only the ones that hold an
+    assignment are moved: dispatch and combine
+    (``kernels.row_gather``) and the experts' matmuls
+    (``kernels.grouped_matmul``) walk the row tiles below the count.
+    Sows, beside the losses, ``held_assignments`` (the share of the k*T
+    assignments that fell on held experts), ``held_load_max_over_mean``,
+    ``held_dropped_fraction`` and ``held_row_tiles`` (the share of the
+    row tiles walked).
     """
 
     hidden_size: int
@@ -505,17 +515,18 @@ class SwitchMLP(nn.Module):
         hidden = orig_shape[-1]
 
         if self.local_experts is not None:
-            token_idx, expert_idx, gate, counts = self._held_share(
+            # every pass over the rows walks the row tiles below ``kept``,
+            # the rows that hold an assignment, as the grouped matmuls do
+            token_idx, expert_idx, gate, counts, kept = self._held_share(
                 routing, num_tokens)
             with jax.named_scope("moe/dispatch"):
-                sorted_x = x[token_idx] * (gate > 0)[:, None].astype(x.dtype)
+                sorted_x = gather_rows(x, token_idx, kept)
             with jax.named_scope("moe/experts"):
                 y = experts(sorted_x, group_sizes=counts,
                             expert_idx=expert_idx)
             with jax.named_scope("moe/combine"):
-                contrib = y.astype(jnp.float32) * gate[:, None]
-                out = jnp.zeros((num_tokens, hidden), jnp.float32)
-                out = out.at[token_idx].add(contrib)
+                out = scatter_add_rows(y, token_idx, kept, num_tokens,
+                                       weights=gate)
         elif mode == "ragged":
             # Zero-padding dropless path: gather rows into expert-sorted
             # order (grad = scatter-add, the gather's XLA transpose), run
@@ -594,13 +605,16 @@ class SwitchMLP(nn.Module):
     def _held_share(self, routing, num_tokens):
         """The held experts' rows of a dropless sorted routing, in a
         static number of rows: -> (token_idx, expert_idx local, gate,
-        counts), each over ``rows`` rows but ``counts`` ``[local_experts]``.
-        The sorted order puts the held experts' assignments in one run,
-        and ``counts`` are its groups: they sum to the rows kept, not to
-        ``rows``. Rows past the run's end are in no group (the grouped
-        matmul gives them zeros and visits none of their tiles); they
-        carry gate 0 and a zeroed input, so the combine's scatter-add
-        and the activation's backward see zeros there too."""
+        counts, kept), each over ``rows`` rows but ``counts``
+        ``[local_experts]`` and ``kept`` a scalar. The sorted order puts
+        the held experts' assignments in one run, so the three are one
+        window of it from the run's start on (a slice of the sorted
+        arrays, padded where the window runs off their end), and
+        ``counts`` are the run's groups: they sum to ``kept``, the rows
+        that hold an assignment, not to ``rows``. Rows from ``kept`` on
+        are in no group: the grouped matmul gives them zeros and visits
+        none of their tiles, ``gather_rows`` gives them a zero input and
+        ``scatter_add_rows`` does not read them; they carry gate 0."""
         from apex_tpu.telemetry.registry import get_registry
 
         n, off, E = self.local_experts, self.expert_offset, self.num_experts
@@ -609,6 +623,8 @@ class SwitchMLP(nn.Module):
         get_registry().gauge("moe/held_experts").set(n)
         get_registry().gauge("moe/held_rows").set(rows)
         get_registry().gauge("moe/published_experts").set(E)
+        tile = row_tile(rows)
+        get_registry().gauge("moe/row_tile").set(tile)
         ends = jnp.cumsum(routing.counts)
         start = ends[off] - routing.counts[off]
         held = routing.counts[off:off + n]
@@ -616,15 +632,22 @@ class SwitchMLP(nn.Module):
         local_ends = jnp.minimum(ends[off:off + n] - start, rows)
         counts = jnp.diff(local_ends, prepend=0)
         kept = local_ends[-1]
-        row = jnp.arange(rows, dtype=jnp.int32)
-        source = jnp.minimum(start + row, N - 1)
-        valid = row < kept
+        valid = jnp.arange(rows, dtype=jnp.int32) < kept
         total = jnp.sum(held)
         self.sow("moe_losses", "held_assignments", total / N)
         self.sow("moe_losses", "held_load_max_over_mean",
                  jnp.max(held) * n / jnp.maximum(total, 1))
         self.sow("moe_losses", "held_dropped_fraction",
                  jax.lax.stop_gradient(1.0 - kept / jnp.maximum(total, 1)))
-        return (routing.token_idx[source],
-                jnp.clip(routing.expert_idx[source] - off, 0, n - 1),
-                jnp.where(valid, routing.gate[source], 0.0), counts)
+        # the share of the row tiles that the walks over the rows take
+        self.sow("moe_losses", "held_row_tiles",
+                 jax.lax.stop_gradient(
+                     (-(-kept // tile)) / (-(-rows // tile))))
+
+        def window(sorted_array):
+            return lax.dynamic_slice_in_dim(
+                jnp.pad(sorted_array, (0, rows)), start, rows)
+
+        return (window(routing.token_idx),
+                jnp.clip(window(routing.expert_idx) - off, 0, n - 1),
+                jnp.where(valid, window(routing.gate), 0.0), counts, kept)

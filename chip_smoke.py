@@ -356,9 +356,9 @@ def grouped_fwd_bwd(lhs, rhs, sizes, dout):
     return (out,) + vjp(dout)
 
 
-def expert_ffn_fwd_bwd(act, x, w1, w2, sizes, dout):
-    """``ExpertMLP``'s ragged layout, forward and backward: the two
-    grouped matmuls with the activation between them."""
+def expert_ffn(act, sizes):
+    """``ExpertMLP``'s ragged layout: the two grouped matmuls with the
+    activation between them, ``(x, w1, w2) -> y``."""
     from apex_tpu.kernels.grouped_matmul import grouped_matmul
 
     def ffn(x, w1, w2):
@@ -370,7 +370,12 @@ def expert_ffn_fwd_bwd(act, x, w1, w2, sizes, dout):
             h = jnp.square(jax.nn.relu(h))
         return grouped_matmul(h.astype(x.dtype), w2, sizes)
 
-    out, vjp = jax.vjp(ffn, x, w1, w2)
+    return ffn
+
+
+def expert_ffn_fwd_bwd(act, x, w1, w2, sizes, dout):
+    """:func:`expert_ffn`, forward and backward."""
+    out, vjp = jax.vjp(expert_ffn(act, sizes), x, w1, w2)
     return (out,) + vjp(dout)
 
 
@@ -391,6 +396,98 @@ def grouped_matmul_times():
                 f"over {m} rows, {share:.1%} real, fwd+bwd device ms, "
                 "kernel / ragged-dot",
                 f"{ms_a_call(kernel, args)} / {ms_a_call(oracle, args)}")
+
+
+TOKENS = 16384      # 2 x 8192 packed tokens, what the three cells route
+
+
+def held_rows(m, h, g, share):
+    """A held share's rows as ``SwitchMLP._held_share`` hands them out:
+    ``(x [TOKENS, h], token_idx [m], gate [m], sizes [g], kept)``; a
+    group's tokens distinct, gate 0 from ``kept`` on."""
+    sizes = group_sizes(m, g, share)
+    kept = int(sizes.sum())
+    rng = np.random.default_rng(m + g)
+    idx = np.concatenate(
+        [rng.permutation(TOKENS)[:n] for n in np.asarray(sizes)]
+        + [rng.integers(0, TOKENS, m - kept)])
+    gate = np.where(np.arange(m) < kept, rng.uniform(0.05, 0.5, m), 0.0)
+    return (randn(30, (TOKENS, h)), jnp.asarray(idx, jnp.int32),
+            jnp.asarray(gate, jnp.float32), sizes, jnp.int32(kept))
+
+
+def whole_array(fn):
+    """``fn`` traced with the rows' walk as one tile: XLA's gather and
+    scatter-add over every row under the ``r < kept`` mask, the oracle of
+    ``kernels/row_gather.py``."""
+    from apex_tpu.kernels import row_gather
+
+    def oracle(*args):
+        with mock.patch.object(row_gather, "ROW_TILE", 1 << 30), \
+                mock.patch.object(row_gather, "SCATTER_TILE", 1 << 30):
+            return fn(*args)
+
+    return oracle
+
+
+def held_layer_fwd_bwd(act, walk, x, w1, w2, idx, gate, sizes, kept, dout):
+    """The held-share branch of ``SwitchMLP``, forward and backward:
+    dispatch, the expert FFN, combine. ``walk``: the rows' passes follow
+    ``kept`` (``gather_rows`` / ``scatter_add_rows``); else the parent's
+    formulation, XLA's gather and scatter-add over every row."""
+    from apex_tpu.kernels.row_gather import gather_rows, scatter_add_rows
+
+    ffn = expert_ffn(act, sizes)
+
+    def layer(x, w1, w2, gate):
+        if walk:
+            y = ffn(gather_rows(x, idx, kept), w1, w2)
+            out = scatter_add_rows(y, idx, kept, x.shape[0], weights=gate)
+        else:
+            y = ffn(x[idx] * (gate > 0)[:, None].astype(x.dtype), w1, w2)
+            out = jnp.zeros(x.shape, jnp.float32).at[idx].add(
+                y * gate[:, None])
+        return out.astype(x.dtype)
+
+    out, vjp = jax.vjp(layer, x, w1, w2, gate)
+    return (out,) + vjp(dout)
+
+
+def row_gather_times():
+    """``gather_rows`` and ``scatter_add_rows`` at the three cells' shapes
+    and three real shares against their whole-array oracle, and each
+    cell's expert layer forward + backward, the rows' passes following the
+    count against the parent's formulation: parity, then device ms."""
+    from apex_tpu.kernels.row_gather import gather_rows, scatter_add_rows
+
+    def scatter(vals, idx, kept, gate):
+        return scatter_add_rows(vals, idx, kept, TOKENS, weights=gate)
+
+    for m, h, f, g, act in EXPERT_LAYERS:
+        cols = f * (2 if act == "swiglu" else 1)
+        w1, w2 = randn(27, (g, h, cols)) * 0.02, randn(28, (g, f, h)) * 0.02
+        vals, dout = randn(31, (m, h), jnp.float32), randn(29, (TOKENS, h))
+        for share in GROUPED_SHARES:
+            x, idx, gate, sizes, kept = held_rows(m, h, g, share)
+            where = f"{m} rows of {h}, {share:.1%} real"
+            for name, fn, args, bound in (
+                    ("gather_rows", gather_rows, (x, idx, kept), "exact"),
+                    ("scatter_add_rows", scatter, (vals, idx, kept, gate),
+                     TOL_F32)):
+                walk, oracle = jax.jit(fn), jax.jit(whole_array(fn))
+                say(f"  {name}: {where}",
+                    compare(name, bound, walk(*args), oracle(*args)))
+                say(f"  {name}: {where}, device ms, walk / whole array",
+                    f"{ms_a_call(walk, args)} / {ms_a_call(oracle, args)}")
+            args = (x, w1, w2, idx, gate, sizes, kept, dout)
+            change = jax.jit(functools.partial(held_layer_fwd_bwd, act, True))
+            parent = jax.jit(functools.partial(held_layer_fwd_bwd, act,
+                                               False))
+            say(f"  held layer: {g} {act} experts {h} -> {f} -> {h}, {where}",
+                compare("held layer", TOL_MXU, change(*args), parent(*args)))
+            say(f"  held layer: {where}, fwd+bwd device ms, rows walked to "
+                "the count / every row",
+                f"{ms_a_call(change, args)} / {ms_a_call(parent, args)}")
 
 
 def kernel_cases():
@@ -696,6 +793,7 @@ def kernels_leg():
                 say(f"  {case.name}, device ms a call, kernel / oracle",
                     f"{ms_a_call(kernel, args)} / {ms_a_call(oracle, args)}")
     grouped_matmul_times()
+    row_gather_times()
 
 
 # ---------------------------------------------------------------------------

@@ -274,3 +274,229 @@ def test_the_dropless_ragged_mode_is_unchanged(monkeypatch, path,
     if path == "oracle":
         assert (np.asarray(got) == np.asarray(want)).all()
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+
+
+# -- the rows' passes follow the count (PR 36): dispatch, combine and the
+# window of ``_held_share`` against the parent's formulation ---------------
+
+from apex_tpu.kernels import row_gather  # noqa: E402
+
+
+def _parent_held_share(self, routing, num_tokens):
+    """``SwitchMLP._held_share`` as it was before PR 36: the window of
+    the sorted order by three gathers through a clipped ``source``."""
+    n, off, E_ = self.local_experts, self.expert_offset, self.num_experts
+    N = self.top_k * num_tokens
+    rows = min(N, -(-int(N * n / E_ * self.capacity_factor) // 8) * 8)
+    ends = jnp.cumsum(routing.counts)
+    start = ends[off] - routing.counts[off]
+    held = routing.counts[off:off + n]
+    local_ends = jnp.minimum(ends[off:off + n] - start, rows)
+    counts = jnp.diff(local_ends, prepend=0)
+    kept = local_ends[-1]
+    row = jnp.arange(rows, dtype=jnp.int32)
+    source = jnp.minimum(start + row, N - 1)
+    valid = row < kept
+    total = jnp.sum(held)
+    self.sow("moe_losses", "held_assignments", total / N)
+    self.sow("moe_losses", "held_load_max_over_mean",
+             jnp.max(held) * n / jnp.maximum(total, 1))
+    self.sow("moe_losses", "held_dropped_fraction",
+             jax.lax.stop_gradient(1.0 - kept / jnp.maximum(total, 1)))
+    return (routing.token_idx[source],
+            jnp.clip(routing.expert_idx[source] - off, 0, n - 1),
+            jnp.where(valid, routing.gate[source], 0.0), counts, kept)
+
+
+def _parent_dispatch(x, token_idx, kept):
+    """Every row gathered, the tail zeroed (the parent's mask was ``gate
+    > 0``, and the gate is 0 from ``kept`` on)."""
+    real = jnp.arange(token_idx.shape[0]) < kept
+    return x[token_idx] * real[:, None].astype(x.dtype)
+
+
+def _parent_combine(y, token_idx, kept, num_tokens, weights):
+    """Every row multiplied by its gate, 0 in the tail, and added."""
+    del kept
+    contrib = y.astype(jnp.float32) * weights[:, None]
+    return jnp.zeros((num_tokens, y.shape[1]), jnp.float32).at[
+        token_idx].add(contrib)
+
+
+def _as_the_parent(monkeypatch):
+    monkeypatch.setattr(SwitchMLP, "_held_share", _parent_held_share)
+    monkeypatch.setattr(layer_mod, "gather_rows", _parent_dispatch)
+    monkeypatch.setattr(layer_mod, "scatter_add_rows", _parent_combine)
+
+
+def _steered(params, x, experts, strength=40.0):
+    """The router sends every token to ``experts`` (its top-2 among
+    them): one input channel held constant and a router row on it."""
+    x = x.at[..., 0].set(1.0)
+    row = jnp.zeros((E,), jnp.float32).at[jnp.asarray(experts)].set(
+        strength) + jnp.arange(E) * 0.1
+    params = dict(params, router={"gate_weight": params["router"][
+        "gate_weight"].at[0].set(row)})
+    return params, x
+
+
+def _with_biases(params, seed=5):
+    rng = np.random.default_rng(seed)
+    experts = dict(params["experts"],
+                   b1=jnp.asarray(rng.normal(size=(E, FW)) * 0.1,
+                                  jnp.float32),
+                   b2=jnp.asarray(rng.normal(size=(E, HW)) * 0.5,
+                                  jnp.float32))
+    return dict(params, experts=experts)
+
+
+def _out_grads_sown(layer, share, x):
+    def loss(p, inp):
+        out, sown = layer.apply({"params": p}, inp, mutable=["moe_losses"])
+        return jnp.sum(out * jnp.cos(out)), (out, sown["moe_losses"])
+
+    (_, (out, sown)), grads = jax.value_and_grad(
+        loss, (0, 1), has_aux=True)(share, x)
+    return out, grads, sown
+
+
+# (expert_offset, local_experts, capacity_factor, the experts the router
+# is steered to or None): rows == N where the factor is large (the window
+# runs off the end of the sorted order for every offset but 0)
+SHARES = {
+    "rows_are_every_assignment": (2, 4, 8.0, None),
+    "offset_share": (5, 3, 2.0, None),
+    "first_share": (0, 2, 3.0, None),
+    "collapsed_onto_the_share": (2, 4, 8.0, (3, 4)),
+    "holds_none": (2, 4, 8.0, (0, 7)),
+    "overflow_cut": (0, 4, 0.5, (1, 2)),
+}
+
+
+@pytest.fixture(params=["walk", "one_tile"])
+def tiles(request, monkeypatch):
+    """``walk``: row tiles of 64 (128 for the scatter-add where there are
+    more rows than that), so the layer's 128-512 rows take a loop of
+    several trips; ``one_tile``: the module's 2048, no loop."""
+    if request.param == "walk":
+        monkeypatch.setattr(row_gather, "ROW_TILE", 64)
+        monkeypatch.setattr(row_gather, "SCATTER_TILE", 128)
+    return request.param
+
+
+@pytest.mark.parametrize("activation", ["swiglu", "relu2", "gelu"])
+@pytest.mark.parametrize("share_name", sorted(SHARES))
+def test_the_rows_passes_follow_the_count_and_change_nothing(
+        monkeypatch, tiles, activation, share_name):
+    """Output, input gradient, every parameter's gradient (the router's
+    among them, through the gate) and everything sown: the parent's
+    formulation written out above. Biased gelu experts leave a bias in
+    every tail row, which must not reach the output."""
+    off, n, factor, steer = SHARES[share_name]
+    x, params = _wide_inputs(activation)
+    if activation == "gelu":
+        params = _with_biases(params)
+    if steer is not None:
+        params, x = _steered(params, x, steer)
+    share = _share(params, off, n)
+    layer = _wide_layer(activation, local_experts=n, expert_offset=off,
+                        capacity_factor=factor)
+    with use_registry(MetricsRegistry(enabled=True)) as reg:
+        out, grads, sown = _out_grads_sown(layer, share, x)
+    gauges = reg.snapshot()["gauges"]
+    rows = gauges["moe/held_rows"]
+    tile = gauges["moe/row_tile"]
+    assert tile == (64 if tiles == "walk" else rows)
+    sown = {k[-1]: v[0] for k, v in
+            flax.traverse_util.flatten_dict(sown).items()}
+    held = float(sown["held_assignments"]) * 2 * TW
+    kept = min(round(held), rows)
+    assert float(sown["held_row_tiles"]) == pytest.approx(
+        -(-kept // tile) / -(-rows // tile))
+    if share_name == "rows_are_every_assignment":
+        assert rows == 2 * TW
+    if share_name == "collapsed_onto_the_share":
+        assert round(held) == 2 * TW
+    if share_name == "holds_none":
+        assert held == 0 and float(jnp.abs(out).max()) == 0
+        assert float(sown["held_row_tiles"]) == 0
+
+    _as_the_parent(monkeypatch)
+    want_out, want_grads, want_sown = _out_grads_sown(layer, share, x)
+    want_sown = {k[-1]: v[0] for k, v in
+                 flax.traverse_util.flatten_dict(want_sown).items()}
+    assert set(sown) == set(want_sown) | {"held_row_tiles"}
+    for key, value in want_sown.items():
+        assert float(sown[key]) == float(value), key
+    np.testing.assert_allclose(out, want_out, rtol=2e-5, atol=2e-6)
+    flat = jax.tree_util.tree_leaves_with_path(grads)
+    flat_want = jax.tree_util.tree_leaves(want_grads)
+    assert len(flat) == (6 if activation == "gelu" else 4)
+    for (where, a), b in zip(flat, flat_want):
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-6,
+                                   err_msg=str(where))
+
+
+@pytest.mark.parametrize("off", [0, 3, 6])
+def test_the_window_is_a_slice_of_the_sorted_order(monkeypatch, off):
+    """``_held_share`` hands out the parent's rows below ``kept`` to the
+    bit: token, local expert and gate; from ``kept`` on the gate is 0 and
+    the window is padded, not wrapped into other experts' rows."""
+    x, params = _wide_inputs("swiglu")
+    seen = {}
+
+    def spy(name, fn):
+        def wrapped(*args, **kw):
+            seen.setdefault(name, []).append(
+                [np.asarray(a) for a in args if hasattr(a, "shape")]
+                + [np.asarray(w) for w in kw.values()])
+            return fn(*args, **kw)
+        return wrapped
+
+    def run():
+        seen.clear()
+        monkeypatch.setattr(layer_mod, "gather_rows",
+                            spy("dispatch", _parent_dispatch))
+        monkeypatch.setattr(layer_mod, "scatter_add_rows",
+                            spy("combine", _parent_combine))
+        _wide_layer("swiglu", local_experts=2, expert_offset=off,
+                    capacity_factor=8.0).apply(
+            {"params": _share(params, off, 2)}, x)
+        (_, idx, kept), = seen["dispatch"]
+        (_, idx2, kept2, gate), = seen["combine"]
+        assert (idx == idx2).all() and kept == kept2
+        return idx, int(kept), gate
+
+    idx, kept, gate = run()
+    monkeypatch.setattr(SwitchMLP, "_held_share", _parent_held_share)
+    want_idx, want_kept, want_gate = run()
+    assert kept == want_kept and 0 < kept < idx.shape[0] == 2 * TW
+    assert (idx[:kept] == want_idx[:kept]).all()
+    assert (gate == want_gate).all() and (gate[kept:] == 0).all()
+    if off == 6:    # the window ran off the end: the padding, not a wrap
+        assert (idx[-(2 * TW - kept) // 2:] == 0).all()
+
+
+def test_the_dropless_ragged_mode_makes_no_walk(monkeypatch):
+    """``dispatch_mode="ragged"`` has every row real: it keeps XLA's
+    whole-array gather and scatter-add and calls neither primitive."""
+    x, params = _wide_inputs("swiglu")
+    monkeypatch.setattr(row_gather, "ROW_TILE", 64)
+    layer = _wide_layer("swiglu", dispatch_mode="ragged")
+
+    def jaxpr():
+        return str(jax.make_jaxpr(
+            lambda p, inp: layer.apply({"params": p}, inp))(params, x))
+
+    before = jaxpr()
+    assert "while[" not in before and "scatter-add" in before
+
+    def refuse(*args, **kw):
+        raise AssertionError("the dropless path walks no tiles")
+
+    monkeypatch.setattr(layer_mod, "gather_rows", refuse)
+    monkeypatch.setattr(layer_mod, "scatter_add_rows", refuse)
+    assert jaxpr() == before
+    held = _wide_layer("swiglu", local_experts=4, capacity_factor=8.0)
+    with pytest.raises(AssertionError, match="walks no tiles"):
+        held.apply({"params": _share(params, 0, 4)}, x)

@@ -158,6 +158,30 @@ def test_kernel_is_named_in_the_compiled_program(tpu, case):
                    for s in called), called
 
 
+@pytest.mark.parametrize("shape", chip_smoke.EXPERT_LAYERS,
+                         ids=lambda s: "x".join(str(n) for n in s[:3]))
+def test_the_rows_walk_compiles_for_v5e_at_the_cells_shapes(tpu, shape):
+    """The held-share layer of each MoE cell, forward and backward, the
+    rows' passes following the count (``kernels/row_gather.py``): a
+    loop each for dispatch, combine and their two transposes, with the
+    grouped-matmul kernels between them."""
+    import functools
+
+    m, h, f, g, act = shape
+    cols = f * (2 if act == "swiglu" else 1)
+    text = _compile(
+        functools.partial(chip_smoke.held_layer_fwd_bwd, act, True),
+        tpu, _sds((chip_smoke.TOKENS, h), BF16), _sds((g, h, cols), BF16),
+        _sds((g, f, h), BF16), _sds((m,), I32), _sds((m,), F32),
+        _sds((g,), I32), _sds((), I32), _sds((chip_smoke.TOKENS, h), BF16))
+    for walk in ("_gather_walk", "_scatter_walk"):
+        for phase in ("jvp", "transpose\\(jvp"):
+            assert re.search(rf"{phase}\(jit\({walk}\)+/while/body", text), (
+                walk, phase)
+    for kernel in KERNEL_NAMES["grouped_matmul"]:
+        assert kernel in text
+
+
 def test_matmul_collectives_compile_under_tp4(tpu):
     """fused_cc family (a) inside shard_map over the four topology
     devices: tiled GEMM + psum, ring reduce-scatter, ring all-gather."""
