@@ -33,6 +33,14 @@ Design notes (TPU-first, not a port):
   for explicit fp16 use).
 """
 
+import sys as _sys
+import time as _time
+
+# the package's own import is a span of set-up (telemetry.compile_watch
+# record_import, at the bottom); whoever imports jax first pays for it
+_IMPORT_START = _time.perf_counter()
+_JAX_PRELOADED = "jax" in _sys.modules
+
 import logging as _pylogging
 
 __version__ = "0.1.0"
@@ -54,3 +62,6 @@ from apex_tpu import resilience  # noqa: F401
 from apex_tpu import transformer  # noqa: F401
 
 _pylogging.getLogger(__name__).addHandler(_pylogging.NullHandler())
+
+telemetry.compile_watch.record_import(
+    __name__, _IMPORT_START, _time.perf_counter(), _JAX_PRELOADED)

@@ -12,63 +12,31 @@ Placement rule:
   of nothing's identity but must be STABLE: a directory built from a
   temporary name, a pid or the time is a cache that never hits.
 
-Enabling also installs ``jax.monitoring`` listeners for the persistent
-cache's hit/miss events, so :func:`cache_stats` (and the
-``compile_cache/hits`` / ``compile_cache/misses`` telemetry counters)
-answer "is the cache actually warm?" — a cache that silently misses
-every compile (key drift across jax versions, an evicted dir) costs the
-full compile time while looking enabled.
+Enabling also installs the program's one ``jax.monitoring`` listener
+(``telemetry.compile_watch.install_monitoring``; jax 0.9's compile-phase
+spans and the persistent cache's events behind one callback), so the
+record of compile phases starts before the entry point's first jit, and
+:func:`cache_stats` (with the ``compile_cache/hits`` /
+``compile_cache/misses`` telemetry counters) answers "is the cache
+actually warm?" — a cache that silently misses every compile (key drift
+across jax versions, an evicted dir) costs the full compile time while
+looking enabled.
 """
 
 import os
-import threading
 
 ENV_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
-
-_HIT_EVENT = "/jax/compilation_cache/cache_hits"
-_MISS_EVENT = "/jax/compilation_cache/cache_misses"
-
-_STATS_LOCK = threading.Lock()
-_STATS = {"hits": 0, "misses": 0}
-_LISTENER_INSTALLED = False
-
-
-def _on_cache_event(event, **kwargs):
-    if event == _HIT_EVENT:
-        key = "hits"
-    elif event == _MISS_EVENT:
-        key = "misses"
-    else:
-        return
-    with _STATS_LOCK:
-        _STATS[key] += 1
-    from apex_tpu.telemetry.registry import get_registry
-
-    reg = get_registry()
-    if reg.enabled:
-        reg.counter(f"compile_cache/{key}").inc()
-
-
-def install_cache_counters() -> None:
-    """Register the (one, idempotent) monitoring listener feeding
-    :func:`cache_stats`. jax offers no per-listener removal, so this
-    registers once per process; the listener is a counter bump."""
-    global _LISTENER_INSTALLED
-    with _STATS_LOCK:
-        if _LISTENER_INSTALLED:
-            return
-        _LISTENER_INSTALLED = True
-    import jax.monitoring
-
-    jax.monitoring.register_event_listener(_on_cache_event)
 
 
 def cache_stats() -> dict:
     """``{"hits", "misses"}`` persistent-cache lookups observed since
-    :func:`install_cache_counters` ran (0/0 before — counting starts
-    when the cache is enabled)."""
-    with _STATS_LOCK:
-        return dict(_STATS)
+    :func:`enable_compile_cache` ran (0/0 before — counting starts when
+    the cache is enabled): a view of ``compile_watch``'s record, whose
+    ``cache_totals()`` also has the seconds a load took and saved."""
+    from apex_tpu.telemetry import compile_watch
+
+    totals = compile_watch.cache_totals()
+    return {"hits": totals["hits"], "misses": totals["misses"]}
 
 
 def default_cache_dir() -> str:
@@ -111,5 +79,7 @@ def enable_compile_cache(min_compile_secs: float = 0.5) -> str:
     from jax._src import compilation_cache as _jax_cc
 
     _jax_cc.reset_cache()
-    install_cache_counters()
+    from apex_tpu.telemetry import compile_watch
+
+    compile_watch.install_monitoring()
     return jax.config.jax_compilation_cache_dir

@@ -43,12 +43,22 @@ The observability layer under the parallel/optimizer/bench stack:
   (:class:`~apex_tpu.telemetry.attribution.PipelineAttributor`):
   exposure-difference straggler detection over ``pp_tick_<t>`` spans,
   measured vs analytic bubble fraction, per-axis exposed-comm split.
-- :mod:`compile_watch` — trace/compile accounting per jitted function
-  (:class:`~apex_tpu.telemetry.compile_watch.CompileWatcher`):
+- :mod:`compile_watch` — the compile path. Always on: one
+  ``jax.monitoring`` listener (jax 0.9) behind a bounded record of every
+  trace, lowering and backend compile or cache load, and of the
+  package's own import, on the ``perf_counter`` clock
+  (:func:`~apex_tpu.telemetry.compile_watch.phase_records`,
+  :func:`~apex_tpu.telemetry.compile_watch.phase_table`,
+  :func:`~apex_tpu.telemetry.compile_watch.process_start_perf`), with
+  ``compile/*`` and ``compile_cache/*`` counters and ``compile/trace``
+  / ``compile/lower`` / ``compile/backend`` spans when the registry is
+  enabled: where set-up's seconds go. Opt-in via
+  ``APEX_TPU_COMPILE_WATCH=1``: trace/compile accounting per jitted
+  function (:class:`~apex_tpu.telemetry.compile_watch.CompileWatcher`),
   ``compile`` events that name exactly which argument changed on a
-  recompile, ``compile/count``/``compile/seconds`` counters, and the
+  recompile. And the
   :func:`~apex_tpu.telemetry.compile_watch.assert_no_recompiles`
-  test primitive. Opt-in via ``APEX_TPU_COMPILE_WATCH=1``.
+  test primitive.
 - :mod:`memory`    — HBM budget accounting:
   :func:`~apex_tpu.telemetry.memory.step_memory` (XLA
   ``memory_analysis()`` -> peak bytes + ``memory/hbm_headroom``

@@ -95,3 +95,41 @@ def test_leaves_are_summed_and_a_loop_is_counted_once():
     assert total == pytest.approx((24 - 4) / 2)
     text = block_parts.render(out)
     assert "moe/dispatch" in text and "moe_grouped_matmul_fwd" in text
+
+
+def test_the_set_up_table_under_the_device_table():
+    """The result line's ``setup_*`` readings and the compile path's
+    largest rows before the window opened, from the program's record."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    from apex_tpu.telemetry import compile_watch
+
+    compile_watch.install_monitoring()
+    jax.jit(lambda x: jnp.tanh(x) + 5)(jnp.ones((3, 17)))
+    setup_s = time.perf_counter() - block_parts._T0
+    jax.jit(lambda x: jnp.tanh(x) + 6)(jnp.ones((3, 19)))   # after it
+    result = {"metrics": {"setup_trace_s": {"value": 1.5, "unit": "s"},
+                          "setup_cache_misses": {"value": 2.0,
+                                                 "unit": "count"},
+                          "step_ms_p50": {"value": 9.0, "unit": "ms"}}}
+    out = block_parts.setup_table(result, setup_s)
+    assert out["readings"] == {"setup_trace_s": 1.5,
+                               "setup_cache_misses": 2.0}
+    assert 0 < len(out["rows"]) <= 20
+    assert out["records_before_window"] < out["record_stats"]["kept"] \
+        or out["record_stats"]["dropped"] > 0
+    assert out["record_stats"]["listener_seconds"] > 0
+    assert [r["self_s"] for r in out["rows"]] == sorted(
+        (r["self_s"] for r in out["rows"]), reverse=True)
+    starts = [row[0] for row in out["timeline"]]
+    assert starts == sorted(starts) and 0 < len(starts) <= 16
+    ahead = out["clock_start_after_process_s"]
+    assert ahead > 0
+    assert all(0 <= b <= e <= ahead + setup_s
+               for b, e, *_ in out["timeline"])
+    text = block_parts.render_setup(out)
+    assert "setup_trace_s 1.500" in text and "records before" in text
+    assert out["rows"][0]["fun_name"][:43] in text

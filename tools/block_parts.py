@@ -26,8 +26,14 @@ of some and drops the program's scopes) is the loop's. Beside the sum
 of a block's leaves stands the union of all its operations' intervals,
 which is what ``benchmark/block_time.py`` reads (``moe_ms_per_step``).
 
+Under the device table stands where the run's set-up went, from the
+program's record of compile phases (``apex_tpu.telemetry.compile_watch``):
+the six ``setup_*`` readings of the result line and the twenty rows of
+``phase_table`` with the most self seconds before the window opened, by
+function and phase, with the record's size and what its listener took.
+
 Writes ``chiprun_out/block_parts_<workload>.json`` beside the printed
-table.
+tables.
 """
 
 import argparse
@@ -146,6 +152,61 @@ def render(result) -> str:
     return "\n".join(lines)
 
 
+def setup_table(result, setup_s) -> dict:
+    """Where set-up went: the result line's ``setup_*`` readings and the
+    compile path's phases before the window opened, largest first."""
+    from apex_tpu.telemetry import compile_watch
+
+    start = compile_watch.process_start_perf()
+    opening = _T0 + setup_s       # main's Clock counts from _T0
+    longest = sorted(compile_watch.phase_records(opening),
+                     key=lambda r: r.start - r.end)[:16]
+    return {
+        "setup_s": setup_s,
+        # the sixteen longest records in time order, seconds after the
+        # process's start: the gaps between them are what no record names
+        "timeline": [[r.start - (start or 0.0), r.end - (start or 0.0),
+                      r.phase, r.fun_name]
+                     for r in sorted(longest, key=lambda r: r.start)],
+        "readings": {name: m["value"]
+                     for name, m in result["metrics"].items()
+                     if name.startswith("setup_")},
+        "rows": compile_watch.phase_table(until=opening)[:20],
+        "records_before_window": len(compile_watch.phase_records(opening)),
+        "record_stats": compile_watch.record_stats(),
+        "jax_preloaded": compile_watch.jax_preloaded(),
+        # the readers count from this script's _T0, as its Clock does
+        "clock_start_after_process_s": None if start is None
+        else _T0 - start}
+
+
+def render_setup(setup) -> str:
+    lines = [f"set-up {setup['setup_s']:.3f} s: " + ", ".join(
+        f"{name} {value:.3f}"
+        for name, value in setup["readings"].items())]
+    lines.append(f"{'function':<44}{'phase':<9}{'calls':>7}{'self s':>9}"
+                 f"{'total s':>9}{'hit/miss':>10}")
+    for row in setup["rows"]:
+        lines.append(
+            f"{row['fun_name'][:43]:<44}{row['phase']:<9}{row['calls']:>7}"
+            f"{row['self_s']:>9.3f}{row['total_s']:>9.3f}"
+            f"{row['cache_hits']:>6}/{row['cache_misses']}")
+    lines.append("the longest records, seconds after the process's start:")
+    for begin, end, phase, fun_name in setup["timeline"]:
+        lines.append(f"{begin:>10.3f} -{end:>9.3f}  {phase:<9}{fun_name}")
+    lines.append(
+        f"{setup['records_before_window']} records before the window, "
+        f"{setup['record_stats']['kept']} in all, "
+        f"{setup['record_stats']['folded']} folded, "
+        f"{setup['record_stats']['dropped']} dropped; the listener "
+        f"took {setup['record_stats']['listener_seconds']:.4f} s; jax "
+        f"imported "
+        f"before apex_tpu: {setup['jax_preloaded']}; the run's clock "
+        f"started {setup['clock_start_after_process_s']} s after the "
+        f"process")
+    return "\n".join(lines)
+
+
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument("--workload", required=True)
@@ -162,7 +223,8 @@ def main(argv=None) -> int:
     line = harness.result_line
 
     def keep(cell, outcome, values, window, device, peak, traced):
-        kept.update(window=window, trace=(traced or {}).get("trace"))
+        kept.update(window=window, trace=(traced or {}).get("trace"),
+                    setup_s=values["setup_s"])
         return line(cell, outcome, values, window, device, peak, traced)
 
     scope_table = program_scopes.scope_table
@@ -184,10 +246,12 @@ def main(argv=None) -> int:
     out = table(trace, scope_of, steps)
     out.update(workload=args.workload, seed=args.seed, steps=steps,
                window_s=trace.window_s, busy_s=trace.busy_s())
-    text = render(out)
+    out["setup"] = setup_table(result, kept["setup_s"])
     print(f"{args.workload} seed {args.seed}: {steps:.2f} steps in a "
           f"{trace.window_s:.3f} s window, busy {trace.busy_s():.3f} s")
-    print(text, flush=True)
+    print(render(out))
+    print()
+    print(render_setup(out["setup"]), flush=True)
     out_dir = _ROOT / "chiprun_out"
     out_dir.mkdir(exist_ok=True)
     (out_dir / f"block_parts_{args.workload}.json").write_text(
