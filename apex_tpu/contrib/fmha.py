@@ -21,6 +21,19 @@ accumulators, so VMEM use is independent of sequence length (validated to
 seq 65536 on-chip; see PERF.md). The forward emits the per-row
 log-sum-exp; the backward recomputes p = exp(q k^T scale - lse) per tile
 (flash-attention v2 style) instead of materializing the [s, s] matrix.
+
+Causal, a tile above the diagonal (or outside a window's band) is skipped
+and never fetched, and every tile that runs is masked by position. One
+class of tile does less than that: with square blocks and no window (what
+every default call has) the forward and dq run a tile *on the diagonal*
+in strips of ``STRIP`` rows, each against the keys its queries can see
+and masked on its own square of the diagonal alone, so the three eighths
+of the tile that no query of a strip sees are never multiplied,
+exponentiated or reduced. The same mathematics: what is left out would
+have added exact zeros. dkv runs whole tiles everywhere. Every entry's
+dispatch record counts a head's tiles by what runs them
+(:func:`_tile_classes`).
+
 Off-TPU both passes take the reference einsum path; on TPU, sequence
 lengths that no block fits (not a multiple of any of 512/256/128 and
 larger than 512) take it too, while short sequences use the whole
@@ -34,6 +47,7 @@ import numbers
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from apex_tpu.kernels.registry import get_kernel_registry, kernel_gate
@@ -41,7 +55,11 @@ from apex_tpu.kernels.registry import get_kernel_registry, kernel_gate
 GATE = kernel_gate("flash_attention")
 
 # 512x512 measured fastest on-chip at seq 8192 (8.0 TFLOP/s vs 3.8 at
-# 128x128); both are min()'d down for shorter sequences.
+# 128x128); both are min()'d down for shorter sequences. Causal, a tile of
+# these blocks is skipped (above the diagonal) or runs whole under the
+# mask; the forward and dq run a tile on the diagonal in strips that leave
+# out the keys no row of the strip sees: at 1024 positions two of the
+# three tiles that run, at 8192 16 of 136.
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 NEG_INF = -1e30
@@ -50,10 +68,20 @@ NEG_INF = -1e30
 FLASH_RESIDUAL_NAMES = ("flash_out", "flash_lse")
 
 
-def _takes(bq, bk) -> str:
+def _takes(bq, bk, seq=None, causal=None, window=None, entry=None) -> str:
     """This call's path, counted: the kernels run where a block divides
-    the sequence (:func:`_fit_block` found ``bq`` and ``bk``)."""
-    return GATE.path(fits=bq is not None and bk is not None)
+    the sequence (:func:`_fit_block` found ``bq`` and ``bk``). A flash
+    entry gives its ``seq``, and its record then says what the bodies do
+    over a head's tiles (:func:`_tile_classes`), under the entry's own
+    name: ``flash_attention``'s, or the ``entry`` counted beside it."""
+    fits = bq is not None and bk is not None
+    tiles = _tile_classes(seq, bq, bk, causal, window) \
+        if fits and seq is not None else {}
+    if entry is None:
+        return GATE.path(fits=fits, **tiles)
+    path = GATE.path(fits=fits)
+    get_kernel_registry().dispatch(entry, path, **tiles)
+    return path
 
 
 def dense_layout(seq, heads, head_dim):
@@ -91,12 +119,12 @@ def _causal_mask(scores, qi, kj, block_q, block_k, window=None):
     return jnp.where(visible, scores, NEG_INF)
 
 
-def _alibi_bias(slopes_ref, kj, block_q, block_k):
-    """Key-position-only alibi bias for a (qi, kj) block pair: row
-    constants cancel in softmax, so slope * absolute-key-index is the
-    whole bias (HF build_alibi_tensor form)."""
-    k_ids = kj * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)    # Mosaic's iota is integer
+def _alibi_bias(slopes_ref, k0, shape):
+    """Key-position-only alibi bias for scores of ``shape`` whose first
+    column is key ``k0``: row constants cancel in softmax, so slope *
+    absolute-key-index is the whole bias (HF build_alibi_tensor form)."""
+    k_ids = k0 + jax.lax.broadcasted_iota(
+        jnp.int32, shape, 1)                 # Mosaic's iota is integer
     return slopes_ref[0, 0, 0] * k_ids.astype(jnp.float32)
 
 
@@ -160,9 +188,96 @@ def _fetched_q_block(kj, qi, block_q, block_k, causal, window):
     return qi
 
 
-def _selected(sel_ref):
-    """The selection tile ``[block_q, block_k]`` as booleans."""
-    return sel_ref[0].astype(jnp.int32) != 0
+# ------------------------------------------- a tile on the diagonal in strips
+#
+# Causal, with square blocks and no window (what every default call has),
+# the tiles that run are those under the diagonal and those on it
+# (``qi == kj``). The forward and dq run a tile on the diagonal in strips
+# of ``STRIP`` rows, each against the keys its queries can see and masked
+# on its own square of the diagonal alone: the three eighths of a 512-tile
+# that no query of a strip sees are never multiplied, exponentiated or
+# reduced, and what they would have added is exact zeros. Every other tile
+# that runs, and every tile of dkv, runs whole under the mask.
+
+# Rows of a strip. Measured on the chip at GPT-2's shape (16 x 16 heads of
+# 64 at 1024, device ms a call; PERF.md section 6, PR 38): forward 1.698
+# whole, 1.722 in strips of 256, 1.650 in strips of 128; dq 1.421, 1.328,
+# 1.320. dkv in strips of columns lost (1.855 whole, 1.878 at 256, 2.234 at
+# 128: each strip of keys turns its own p and ds), so it has none.
+STRIP = 128
+
+
+def _diagonal_strips(block_q, block_k, causal, window):
+    """``[(rows, cols)]``, static slices: the strips in which the forward
+    and dq run a tile on the diagonal, strip by strip its queries and the
+    keys they can see; ``None`` where the call's tiles all run whole
+    (not causal, a window, blocks that are not square or hold no two
+    strips)."""
+    if (not causal or window is not None or block_q != block_k
+            or block_q % STRIP or block_q == STRIP):
+        return None
+    return [(slice(r, r + STRIP), slice(0, r + STRIP))
+            for r in range(0, block_q, STRIP)]
+
+
+def _run_tile(step, run, qi, kj, block_q, block_k, causal, window):
+    """Emit ``step(parts, in_strips)`` under ``run``: for a tile on the
+    diagonal over its strips, where the call has them, and for every
+    other tile (every tile, where it has none) over the whole of it."""
+    from jax.experimental import pallas as pl
+
+    whole = [(slice(0, block_q), slice(0, block_k))]
+    strips = _diagonal_strips(block_q, block_k, causal, window)
+    if strips is None:
+        pl.when(run)(functools.partial(step, whole, False))
+        return
+    pl.when(run & (qi == kj))(functools.partial(step, strips, True))
+    pl.when(run & (qi != kj))(functools.partial(step, whole, False))
+
+
+def _mask_strip(s):
+    """A strip's scores with its square on the diagonal, the last columns,
+    masked. The rest of the strip lies under the diagonal."""
+    n = s.shape[0]
+    square = _causal_mask(s[:, -n:], 0, 0, n, n)
+    if s.shape[1] == n:
+        return square
+    return jnp.concatenate([s[:, :-n], square], axis=1)
+
+
+def _tile_classes(seq, block_q, block_k, causal, window=None):
+    """What the bodies do over one head's ``seq x seq`` scores, from
+    shapes alone: the tiles the forward and dq run in strips
+    (``tiles_diagonal``; dkv runs them whole), the tiles all three run
+    whole (``tiles_whole``) and the tiles none runs or fetches
+    (``tiles_skipped``), the (query, key) pairs the forward's and dq's
+    steps compute and the pairs a query sees (``pairs_computed``,
+    ``pairs_visible``). The fields of the entries' dispatch records."""
+    nq, nk = seq // block_q, seq // block_k
+    qi, kj = np.ogrid[:nq, :nk]
+    run = np.broadcast_to(
+        _stream_kv_run(qi, kj, block_q, block_k, causal, window), (nq, nk))
+    strips = _diagonal_strips(block_q, block_k, causal, window)
+    diagonal = int((run & (qi == kj)).sum()) if strips else 0
+    whole = int(run.sum()) - diagonal
+    visible = seq * seq
+    if causal:
+        band = min(seq, window or seq)
+        visible = band * (band + 1) // 2 + (seq - band) * band
+    return {
+        "tiles_diagonal": diagonal,
+        "tiles_whole": whole,
+        "tiles_skipped": nq * nk - diagonal - whole,
+        "pairs_computed": whole * block_q * block_k + diagonal * sum(
+            STRIP * cols.stop for _, cols in strips or ()),
+        "pairs_visible": visible,
+    }
+
+
+def _selected(sel_ref, rows=slice(None), cols=slice(None)):
+    """The selection tile ``[block_q, block_k]``, or a strip's ``rows``
+    and ``cols`` of it, as booleans."""
+    return sel_ref[0, rows, cols].astype(jnp.int32) != 0
 
 
 def _and_run(run, tile_selected):
@@ -201,35 +316,41 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, slopes_ref, o_ref, lse_ref,
     run = _and_run(_stream_kv_run(qi, kj, block_q, block_k, causal, window),
                    tile_selected)
 
-    @pl.when(run)
-    def _step():
+    def _step(parts, in_strips):
         q = q_ref[0].astype(jnp.float32) * scale
         k = k_ref[0].astype(jnp.float32)
         v = v_ref[0].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-        if rope is not None:
-            s = s + rope.scores(scale)
-        if alibi:
-            s = s + _alibi_bias(slopes_ref, kj, block_q, block_k)
-        if causal:
-            s = _causal_mask(s, qi, kj, block_q, block_k, window)
-        if sel_ref is not None:
-            sel = _selected(sel_ref)
-            s = jnp.where(sel, s, NEG_INF)
-        m_prev = m_ref[...]
-        l_prev = l_ref[...]
-        m_cur = jnp.max(s, axis=-1)[:, None]
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
-        if sel_ref is not None:
-            # a row with nothing selected so far has m_new == NEG_INF,
-            # where exp(s - m_new) is 1 on its masked entries
-            p = jnp.where(sel, p, 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = alpha * l_prev + jnp.sum(p, axis=-1)[:, None]
-        m_ref[...] = m_new
-        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
+        for rows, cols in parts:
+            s = jnp.dot(q[rows], k[cols].T,
+                        preferred_element_type=jnp.float32)
+            if rope is not None:
+                s = s + rope.scores(scale, rows, cols)
+            if alibi:
+                s = s + _alibi_bias(
+                    slopes_ref, kj * block_k + cols.start, s.shape)
+            if in_strips:
+                s = _mask_strip(s)
+            elif causal:
+                s = _causal_mask(s, qi, kj, block_q, block_k, window)
+            if sel_ref is not None:
+                sel = _selected(sel_ref, rows, cols)
+                s = jnp.where(sel, s, NEG_INF)
+            m_prev = m_ref[rows]
+            l_prev = l_ref[rows]
+            m_cur = jnp.max(s, axis=-1)[:, None]
+            m_new = jnp.maximum(m_prev, m_cur)
+            p = jnp.exp(s - m_new)
+            if sel_ref is not None:
+                # a row with nothing selected so far has m_new == NEG_INF,
+                # where exp(s - m_new) is 1 on its masked entries
+                p = jnp.where(sel, p, 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[rows] = alpha * l_prev + jnp.sum(p, axis=-1)[:, None]
+            m_ref[rows] = m_new
+            acc_ref[rows] = acc_ref[rows] * alpha + jnp.dot(
+                p, v[cols], preferred_element_type=jnp.float32)
+
+    _run_tile(_step, run, qi, kj, block_q, block_k, causal, window)
 
     @pl.when(kj == num_kv - 1)
     def _finish():
@@ -327,31 +448,38 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     run = _and_run(_stream_kv_run(qi, kj, block_q, block_k, causal, window),
                    tile_selected)
 
-    @pl.when(run)
-    def _step():
+    def _step(parts, in_strips):
         q = q_ref[0].astype(jnp.float32)
         k = k_ref[0].astype(jnp.float32)
         v = v_ref[0].astype(jnp.float32)
         do = do_ref[0].astype(jnp.float32)
         lse = lse_ref[0]
         delta = delta_ref[0]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-        if rope is not None:
-            s = s + rope.scores()
-        s = s * scale
-        if alibi:
-            s = s + _alibi_bias(slopes_ref, kj, block_q, block_k)
-        if causal:
-            s = _causal_mask(s, qi, kj, block_q, block_k, window)
-        if sel_ref is not None:
-            s = jnp.where(_selected(sel_ref), s, NEG_INF)
-        p = jnp.exp(s - lse)
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        dq_acc[...] += jnp.dot(ds, k,
-                               preferred_element_type=jnp.float32) * scale
-        if rope is not None:
-            rope.add_dq(ds, scale)
+        for rows, cols in parts:
+            s = jnp.dot(q[rows], k[cols].T,
+                        preferred_element_type=jnp.float32)
+            if rope is not None:
+                s = s + rope.scores(None, rows, cols)
+            s = s * scale
+            if alibi:
+                s = s + _alibi_bias(
+                    slopes_ref, kj * block_k + cols.start, s.shape)
+            if in_strips:
+                s = _mask_strip(s)
+            elif causal:
+                s = _causal_mask(s, qi, kj, block_q, block_k, window)
+            if sel_ref is not None:
+                s = jnp.where(_selected(sel_ref, rows, cols), s, NEG_INF)
+            p = jnp.exp(s - lse[rows])
+            dp = jnp.dot(do[rows], v[cols].T,
+                         preferred_element_type=jnp.float32)
+            ds = p * (dp - delta[rows])
+            dq_acc[rows] += jnp.dot(
+                ds, k[cols], preferred_element_type=jnp.float32) * scale
+            if rope is not None:
+                rope.add_dq(ds, scale, rows, cols)
+
+    _run_tile(_step, run, qi, kj, block_q, block_k, causal, window)
 
     @pl.when(kj == num_kv - 1)
     def _finish():
@@ -394,7 +522,7 @@ def _flash_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
             s = s + rope.scores()
         s = s * scale
         if alibi:
-            s = s + _alibi_bias(slopes_ref, kj, block_q, block_k)
+            s = s + _alibi_bias(slopes_ref, kj * block_k, s.shape)
         if causal:
             s = _causal_mask(s, qi, kj, block_q, block_k, window)
         if sel_ref is not None:
@@ -1126,7 +1254,7 @@ def flash_attention(q, k, v, causal=True, scale=None,
     _check_window(window, causal)
     _check_selection(selection, q, window, alibi_slopes)
     scale, bq, bk = _resolve(q, scale, block_q, block_k)
-    if _takes(bq, bk) != "oracle":
+    if _takes(bq, bk, q.shape[2], causal, window) != "oracle":
         if selection is not None:
             return _sparse_fwd_pallas(q, k, v, selection, scale, causal,
                                       bq, bk)[0]
@@ -1153,7 +1281,7 @@ def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k,
     _check_window(window, causal)
     _check_selection(selection, q, window, alibi_slopes)
     scale_, bq, bk = _resolve(q, scale, block_q, block_k)
-    if _takes(bq, bk) != "oracle":
+    if _takes(bq, bk, q.shape[2], causal, window) != "oracle":
         if selection is not None:
             out, lse = _sparse_fwd_pallas(q, k, v, selection, scale_, causal,
                                           bq, bk)
@@ -1234,7 +1362,7 @@ def flash_attention_bsnd(q, k, v, heads, causal=True, scale=None,
                           window, alibi_slopes)[0]
 
 
-def _bsnd_resolve(q, heads, scale, block_q, block_k):
+def _bsnd_resolve(q, heads, scale, block_q, block_k, causal, window):
     """(scale, block_q, block_k, does the kernel run), recording the
     call under both counters."""
     width = q.shape[-1]
@@ -1244,8 +1372,8 @@ def _bsnd_resolve(q, heads, scale, block_q, block_k):
             "lanes into 128-lane columns; use flash_attention")
     scale, bq, bk = _resolve_sizes(width // heads, q.shape[1], scale,
                                    block_q, block_k)
-    path = _takes(bq, bk)
-    get_kernel_registry().dispatch("flash_attention_bsnd", path)
+    path = _takes(bq, bk, q.shape[1], causal, window,
+                  entry="flash_attention_bsnd")
     return scale, bq, bk, path != "oracle"
 
 
@@ -1259,7 +1387,7 @@ def _bsnd_fwd_rule(q, k, v, heads, causal, scale, block_q, block_k,
                    window=None, alibi_slopes=None):
     _check_window(window, causal)
     scale_, bq, bk, kernel = _bsnd_resolve(q, heads, scale, block_q,
-                                           block_k)
+                                           block_k, causal, window)
     if kernel:
         out, lse = _bsnd_fwd_pallas(
             q, k, v, alibi_slopes, heads=heads, scale=scale_, causal=causal,
@@ -1317,16 +1445,19 @@ class _RopePart:
     def __init__(self, q, k, dq_ref=None, acc=None):
         self.q, self.k, self.dq_ref, self.acc = q, k, dq_ref, acc
 
-    def scores(self, scale=None):
-        q = self.q[0].astype(jnp.float32)
+    def scores(self, scale=None, rows=slice(None), cols=slice(None)):
+        """The rotary product of the tile's ``rows`` against its ``cols``
+        (a strip's; dkv takes the whole tile)."""
+        q = self.q[0].astype(jnp.float32)[rows]
         if scale is not None:       # the forward scales q, not the scores
             q = q * scale
-        return jnp.dot(q, self.k[0].astype(jnp.float32).T,
+        return jnp.dot(q, self.k[0].astype(jnp.float32)[cols].T,
                        preferred_element_type=jnp.float32)
 
-    def add_dq(self, ds, scale):
-        self.acc[...] += jnp.dot(ds, self.k[0].astype(jnp.float32),
-                                 preferred_element_type=jnp.float32) * scale
+    def add_dq(self, ds, scale, rows, cols):
+        self.acc[rows] += jnp.dot(
+            ds, self.k[0].astype(jnp.float32)[cols],
+            preferred_element_type=jnp.float32) * scale
 
     def write_dq(self):
         self.dq_ref[0] = self.acc[...].astype(self.dq_ref.dtype)
@@ -1629,17 +1760,16 @@ def _mla_resolve(q_nope, q_rope, v, heads, block_q, block_k):
     dims = _mla_dims(q_nope, q_rope, v, heads)
     scale, bq, bk = _resolve_sizes(dims[0] + dims[1], q_nope.shape[1],
                                    None, block_q, block_k)
-    fits = (bq is not None and bk is not None
-            and _mla_heads_per_cell(heads, dims) is not None)
-    return scale, bq, bk, fits
+    if _mla_heads_per_cell(heads, dims) is None:
+        bq = bk = None      # no cut of the heads into 128-lane columns
+    return scale, bq, bk
 
 
 def _mla_fwd_rule(q_nope, q_rope, k_nope, k_rope, v, heads, causal,
                   block_q, block_k):
-    scale, bq, bk, fits = _mla_resolve(q_nope, q_rope, v, heads, block_q,
-                                       block_k)
-    path = GATE.path(fits=fits)
-    get_kernel_registry().dispatch("flash_attention_mla", path)
+    scale, bq, bk = _mla_resolve(q_nope, q_rope, v, heads, block_q, block_k)
+    path = _takes(bq, bk, q_nope.shape[1], causal,
+                  entry="flash_attention_mla")
     operands = (q_nope, q_rope, k_nope, k_rope, v)
     if path != "oracle":
         out, lse = _mla_fwd_pallas(
@@ -1654,8 +1784,8 @@ def _mla_fwd_rule(q_nope, q_rope, k_nope, k_rope, v, heads, causal,
 
 def _mla_bwd_rule(heads, causal, block_q, block_k, res, g):
     *operands, out, lse = res
-    scale, bq, bk, _ = _mla_resolve(operands[0], operands[1], operands[4],
-                                    heads, block_q, block_k)
+    scale, bq, bk = _mla_resolve(operands[0], operands[1], operands[4],
+                                 heads, block_q, block_k)
     if lse is not None:
         return _mla_bwd_pallas(
             *operands, out, lse, g, heads=heads, scale=scale,

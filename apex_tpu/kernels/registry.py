@@ -19,8 +19,10 @@ kernel uses ``force_interpret(False, [name])``.
 
 The same call records the dispatch (trace time): the counters
 ``kernels/dispatch``, ``kernels/<name>/<path>`` and
-``kernels/dispatch/<name>_<path>`` and a ``kernel`` JSONL event, only
-when the process-wide metrics registry is enabled.
+``kernels/dispatch/<name>_<path>``, a ``kernel`` JSONL event and, for the
+numbers a kernel module adds about the call's shape, the gauges
+``kernels/<name>/<field>``, only when the process-wide metrics registry
+is enabled.
 """
 
 import os
@@ -45,11 +47,13 @@ class PallasGate:
     def force_interpret(self, on: bool):
         self.interpret = bool(on)
 
-    def path(self, fits: bool = True, *, record: bool = True) -> str:
+    def path(self, fits: bool = True, *, record: bool = True,
+             **fields) -> str:
         """``"pallas"``, ``"interpret"`` or ``"oracle"`` for one call
         (the rule in the module docstring), recorded as this kernel's
-        dispatch. A predicate that only asks on a caller's behalf, before
-        the entry that will count the call, passes ``record=False``."""
+        dispatch with the kernel module's own ``fields``. A predicate
+        that only asks on a caller's behalf, before the entry that will
+        count the call, passes ``record=False``."""
         if os.environ.get(_SWITCH) == "0" or not fits:
             path = "oracle"
         elif self.interpret:
@@ -57,7 +61,7 @@ class PallasGate:
         else:
             path = "pallas" if _on_tpu() else "oracle"
         if record:
-            _REGISTRY.dispatch(self.name, path)
+            _REGISTRY.dispatch(self.name, path, **fields)
         return path
 
 
@@ -108,8 +112,11 @@ class KernelRegistry:
     def dispatch(self, name: str, path: str, **fields):
         """Record one kernel dispatch (trace-time; what
         :meth:`PallasGate.path` calls): ``path`` is ``"pallas"``,
-        ``"interpret"`` or ``"oracle"``. No-op when telemetry is
-        disabled — zero overhead off."""
+        ``"interpret"`` or ``"oracle"``; ``fields`` are numbers a
+        kernel module says about the call's shape (the flash entries: the
+        tiles a head runs by class), kept on the event and, the last
+        call's, as gauges ``kernels/<name>/<field>``. No-op when
+        telemetry is disabled — zero overhead off."""
         from apex_tpu.telemetry.registry import get_registry
 
         reg = get_registry()
@@ -123,6 +130,8 @@ class KernelRegistry:
         # ``kernels/dispatch/<name>_oracle`` instead of vanishing
         reg.counter(f"kernels/dispatch/{name}_{path}").inc()
         reg.event("kernel", "dispatch", kernel=name, path=path, **fields)
+        for field, value in fields.items():
+            reg.gauge(f"kernels/{name}/{field}").set(value)
 
 
 _REGISTRY = KernelRegistry()

@@ -537,6 +537,16 @@ def kernel_cases():
                 randn(i, (2, T, heads * d)) for i in range(3)),
             TOL_MXU)
 
+    # -- the alibi bias inside the strips of a tile on the diagonal
+    slopes = 0.01 * jnp.arange(1, 17, dtype=jnp.float32)
+    add("flash_attention bsnd fwd+bwd heads=16 d=64 seq=2048 alibi",
+        fwd_bwd(lambda q, k, v: fmha.flash_attention_bsnd(
+            q, k, v, 16, True, None, 512, 512, None, slopes)),
+        fwd_bwd(lambda q, k, v: fmha._bsnd_reference(
+            q, k, v, 16, 0.125, True, None, slopes)),
+        lambda: tuple(randn(i, (2, 2048, 1024)) for i in range(3)),
+        TOL_MXU)
+
     # -- the same kernel bodies with the latent attention's rotary part
     # (Moonlight: 16 heads of 128 + 64 beside values of 128, one shared
     # rotary key a token) at the cell's shape, 2 x 8192; the oracle a head

@@ -122,10 +122,14 @@ def test_threshold_selection_is_an_exact_top_k(seq, topk):
 
 
 # sha256 of GPT-2 345M's three flash calls (16 x 16 heads of 64 at 1024,
-# forward + backward) as a jaxpr, source lines and addresses stripped,
-# taken on the commit before the selection operand existed (PR 27)
+# forward + backward) as a jaxpr, source lines and addresses stripped.
+# Through PR 37 the hash of the commit before the selection operand
+# existed (PR 27); taken anew at PR 38, whose forward and dq run a tile on
+# the diagonal in strips (dkv's call is what it was). It holds that an
+# optional operand (the selection, the rotary part, alibi, a window)
+# leaves GPT-2's program byte for byte alone.
 GPT2_FLASH_JAXPR = \
-    "c2dae6728830e5c727d4c712260631aeefdc582e039b4bfa9de9780260d5ab76"
+    "cda857fa352bfbcf8459063361d650965e777009002efa47502b41807fb4c518"
 
 
 def test_without_a_selection_the_kernels_are_what_they_were(monkeypatch):
@@ -139,6 +143,10 @@ def test_without_a_selection_the_kernels_are_what_they_were(monkeypatch):
 
     text = str(jax.make_jaxpr(step)(q, q, q))
     assert text.count("pallas_call") == 3 and "sparse_attention" not in text
+    assert sorted(re.findall(r"name=(self_attention_\w+)", text)) == [
+        "self_attention_flash_dkv", "self_attention_flash_dq",
+        "self_attention_flash_fwd"]
+    assert "i8[" not in text
     text = re.sub(r" at [^\s:]+:\d+", "", text)
     text = re.sub(r"0x[0-9a-f]+", "0x", text)
     assert hashlib.sha256(text.encode()).hexdigest() == GPT2_FLASH_JAXPR
