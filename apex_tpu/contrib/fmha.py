@@ -44,6 +44,7 @@ up as ``kernels/dispatch/flash_attention_oracle``.
 
 import functools
 import numbers
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -68,14 +69,18 @@ NEG_INF = -1e30
 FLASH_RESIDUAL_NAMES = ("flash_out", "flash_lse")
 
 
-def _takes(bq, bk, seq=None, causal=None, window=None, entry=None) -> str:
+def _takes(bq, bk, seq=None, causal=None, window=None, entry=None,
+           rule=None) -> str:
     """This call's path, counted: the kernels run where a block divides
     the sequence (:func:`_fit_block` found ``bq`` and ``bk``). A flash
     entry gives its ``seq``, and its record then says what the bodies do
     over a head's tiles (:func:`_tile_classes`), under the entry's own
-    name: ``flash_attention``'s, or the ``entry`` counted beside it."""
+    name: ``flash_attention``'s, or the ``entry`` counted beside it (under
+    a ``rule``, either layout's calls: ``BLOCKDIFF_ENTRY``)."""
+    if rule is not None:
+        entry = BLOCKDIFF_ENTRY
     fits = bq is not None and bk is not None
-    tiles = _tile_classes(seq, bq, bk, causal, window) \
+    tiles = _tile_classes(seq, bq, bk, causal, window, rule) \
         if fits and seq is not None else {}
     if entry is None:
         return GATE.path(fits=fits, **tiles)
@@ -128,8 +133,10 @@ def _alibi_bias(slopes_ref, k0, shape):
     return slopes_ref[0, 0, 0] * k_ids.astype(jnp.float32)
 
 
-def _stream_kv_run(qi, kj, block_q, block_k, causal, window):
+def _stream_kv_run(qi, kj, block_q, block_k, causal, window, rule=None):
     """Does kv block kj contribute to q block qi? (fwd / dq kernels)"""
+    if rule is not None:
+        return rule.tile_runs(qi, kj, block_q, block_k)
     if not causal:
         return True
     run = kj * block_k <= (qi + 1) * block_q - 1
@@ -138,8 +145,10 @@ def _stream_kv_run(qi, kj, block_q, block_k, causal, window):
     return run
 
 
-def _stream_q_run(qi, kj, block_q, block_k, causal, window):
+def _stream_q_run(qi, kj, block_q, block_k, causal, window, rule=None):
     """Does q block qi contribute to kv block kj? (dkv kernel)"""
+    if rule is not None:
+        return rule.tile_runs(qi, kj, block_q, block_k)
     if not causal:
         return True
     run = (qi + 1) * block_q - 1 >= kj * block_k
@@ -160,11 +169,14 @@ def _window_last_q_pos(kj, block_k, window):
     return (kj + 1) * block_k - 1 + window - 1
 
 
-def _fetched_kv_block(qi, kj, block_q, block_k, causal, window):
+def _fetched_kv_block(qi, kj, block_q, block_k, causal, window, rule=None):
     """The kv block cell (qi, kj) of the forward / dq grid fetches. Causal:
     masked blocks are clamped into the contributing range; Pallas skips
     the DMA when a block index repeats, so fully-above-diagonal (and,
-    windowed, fully-below-band) K/V tiles are never fetched."""
+    windowed, fully-below-band) K/V tiles are never fetched. Under a
+    ``rule`` (:class:`_BlockDiffusion`) the same, into its two ranges."""
+    if rule is not None:
+        return rule.fetched_kv_block(qi, kj, block_q, block_k)
     if not causal:
         return kj
     last = ((qi + 1) * block_q - 1) // block_k
@@ -175,9 +187,11 @@ def _fetched_kv_block(qi, kj, block_q, block_k, causal, window):
     return kj
 
 
-def _fetched_q_block(kj, qi, block_q, block_k, causal, window):
+def _fetched_q_block(kj, qi, block_q, block_k, causal, window, rule=None):
     """The q block cell (kj, qi) of the dkv grid fetches (as
     :func:`_fetched_kv_block`, for the streamed q side)."""
+    if rule is not None:
+        return rule.fetched_q_block(kj, qi, block_q, block_k)
     if not causal:
         return qi
     first = (kj * block_k) // block_q
@@ -186,6 +200,151 @@ def _fetched_q_block(kj, qi, block_q, block_k, causal, window):
         qi = jnp.minimum(
             qi, _window_last_q_pos(kj, block_k, window) // block_q)
     return qi
+
+
+# ------------------------------------------------- the block-diffusion rule
+#
+# A third attention rule beside ``causal`` and ``window``: training by
+# masked diffusion inside blocks of ``block`` tokens, autoregressive across
+# blocks (Block Diffusion, arXiv:2503.09573, "efficient training"; SDAR,
+# arXiv:2510.06303). A row of ``2 * length`` holds ``length`` clean tokens
+# and then their ``length`` noised copies, token ``i`` of either half in
+# block ``(i mod length) // block``. Query ``i`` sees key ``j`` iff
+#
+#     clean -> clean   and block(j) <= block(i)      (block-causal)
+#     noisy -> clean   and block(j) <  block(i)      (every earlier block)
+#     noisy -> noisy   and block(j) == block(i)      (its own block, both ways)
+#
+# and a clean query never sees a noisy key. The kernels work the rule out
+# from positions, as they do ``causal``: no ``[2L, 2L]`` operand. Blocks
+# divide ``length``, so a tile lies in one half on either side, and
+# ``block`` divides them, so the tiles that run are whole ranges: for a
+# clean q tile the clean kv tiles up to its own; for a noisy one the clean
+# kv tiles that hold an earlier block and the noisy ones that overlap it.
+# The index maps clamp a skipped tile into those ranges, so it is neither
+# run nor fetched. Every tile that runs runs whole under the mask (no
+# strips: the rule's diagonal is not a triangle's). These calls are named
+# ``blockdiff_attention_*``.
+
+BLOCKDIFF_ENTRY = "flash_attention_blockdiff"   # the calls' dispatch record
+
+
+class _BlockDiffusion(NamedTuple):
+    """The rule of a call: ``length`` (``L``, half the row) and ``block``
+    (the diffusion block). Hashable: a static argument of the jitted
+    entries."""
+
+    length: int
+    block: int
+
+    def _half(self, i, block):
+        """Tile ``i``: (is it in the noisy half, its first position inside
+        its half)."""
+        n = self.length // block
+        noisy = i >= n
+        return noisy, (i - n * noisy) * block
+
+    def tile_runs(self, qi, kj, block_q, block_k):
+        """Does any query of q tile ``qi`` see a key of kv tile ``kj``?"""
+        noisy_q, q0 = self._half(qi, block_q)
+        noisy_k, k0 = self._half(kj, block_k)
+        q_last = q0 + block_q - 1
+        clean_clean = k0 <= q_last
+        noisy_clean = k0 + self.block <= q_last
+        noisy_noisy = (k0 <= q_last) & (q0 <= k0 + block_k - 1)
+        return ((~noisy_q & ~noisy_k & clean_clean)
+                | (noisy_q & ~noisy_k & noisy_clean)
+                | (noisy_q & noisy_k & noisy_noisy))
+
+    def fetched_kv_block(self, qi, kj, block_q, block_k):
+        """The kv tile cell (qi, kj) of the forward / dq grid fetches: a
+        tile that runs for ``qi``, the same as its neighbour's wherever
+        ``kj`` itself does not run."""
+        nk = self.length // block_k
+        noisy_q, q0 = self._half(qi, block_q)
+        q_last = q0 + block_q - 1
+        # clean keys [0, last_clean] (none at -1), then, for a noisy q
+        # tile, the noisy keys [first_noisy, last_noisy]
+        last_clean = jnp.where(noisy_q, (q_last - self.block) // block_k,
+                               q_last // block_k)
+        first_noisy = jnp.where(noisy_q, nk + q0 // block_k, last_clean)
+        last_noisy = jnp.where(noisy_q, nk + q_last // block_k, last_clean)
+        return jnp.where(kj <= last_clean, kj,
+                         jnp.clip(kj, first_noisy, last_noisy))
+
+    def fetched_q_block(self, kj, qi, block_q, block_k):
+        """The q tile cell (kj, qi) of the dkv grid fetches (as
+        :meth:`fetched_kv_block`, for the streamed q side)."""
+        nq = self.length // block_q
+        noisy_k, k0 = self._half(kj, block_k)
+        # a clean kv tile: the clean q tiles from its own on, then the
+        # noisy ones that hold a later block (none where first_noisy
+        # reaches 2 * nq); a noisy kv tile: the noisy q tiles over it
+        first_clean = k0 // block_q
+        first_noisy = nq + (k0 + self.block) // block_q
+        seen_by_clean = jnp.where(
+            qi < nq, jnp.maximum(qi, first_clean),
+            jnp.where(first_noisy < 2 * nq, jnp.maximum(qi, first_noisy),
+                      nq - 1))
+        seen_by_noisy = jnp.clip(qi, nq + k0 // block_q,
+                                 nq + (k0 + block_k - 1) // block_q)
+        return jnp.where(noisy_k, seen_by_noisy, seen_by_clean)
+
+    def _block_of(self, positions):
+        # the kernels take a power of two (:func:`_resolve_sizes`): a shift
+        return positions >> (self.block.bit_length() - 1)
+
+    def mask(self, scores, qi, kj, block_q, block_k):
+        """The tile's scores under the rule, from positions: the queries'
+        blocks down a column, the keys' along a row."""
+        noisy_q, q0 = self._half(qi, block_q)
+        noisy_k, k0 = self._half(kj, block_k)
+        qb = self._block_of(q0 + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, 1), 0))
+        kb = self._block_of(k0 + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_k), 1))
+        # clean -> clean kb <= qb; noisy -> clean kb + 1 <= qb; noisy ->
+        # noisy kb <= qb and kb >= qb. (clean -> noisy tiles never run.)
+        earlier = (noisy_q & ~noisy_k).astype(jnp.int32)
+        own = noisy_k.astype(jnp.int32)
+        visible = (kb + earlier <= qb) & (kb >= qb * own)
+        return jnp.where(visible, scores, NEG_INF)
+
+
+def block_diffusion_mask(length, block):
+    """The rule as booleans ``[2 * length, 2 * length]``, ``True`` where a
+    query (row) sees a key (column): the oracle's mask, and a model's
+    where it runs the rule off the kernels."""
+    i = np.arange(2 * length)
+    clean, blk = i < length, (i % length) // block
+    q_clean, k_clean = clean[:, None], clean[None, :]
+    qb, kb = blk[:, None], blk[None, :]
+    return ((q_clean & k_clean & (kb <= qb))
+            | (~q_clean & k_clean & (kb < qb))
+            | (~q_clean & ~k_clean & (kb == qb)))
+
+
+def _rule_of(block_diffusion, seq, causal=False, window=None,
+             alibi_slopes=None, selection=None):
+    """The call's :class:`_BlockDiffusion` (``None`` without
+    ``block_diffusion``), checked against what it does not compose with."""
+    if block_diffusion is None:
+        return None
+    if (isinstance(block_diffusion, bool)
+            or not isinstance(block_diffusion, numbers.Integral)
+            or block_diffusion < 1 or seq % 2
+            or (seq // 2) % block_diffusion):
+        raise ValueError(
+            f"flash_attention block_diffusion must be a positive static int "
+            f"that divides half the sequence ({seq} / 2), got "
+            f"{block_diffusion!r}")
+    if (causal or window is not None or alibi_slopes is not None
+            or selection is not None):
+        raise ValueError(
+            "flash_attention block_diffusion is a rule of its own: it "
+            "composes with neither causal, window, alibi_slopes nor "
+            "selection")
+    return _BlockDiffusion(seq // 2, int(block_diffusion))
 
 
 # ------------------------------------------- a tile on the diagonal in strips
@@ -245,23 +404,29 @@ def _mask_strip(s):
     return jnp.concatenate([s[:, :-n], square], axis=1)
 
 
-def _tile_classes(seq, block_q, block_k, causal, window=None):
+def _tile_classes(seq, block_q, block_k, causal, window=None, rule=None):
     """What the bodies do over one head's ``seq x seq`` scores, from
     shapes alone: the tiles the forward and dq run in strips
     (``tiles_diagonal``; dkv runs them whole), the tiles all three run
     whole (``tiles_whole``) and the tiles none runs or fetches
     (``tiles_skipped``), the (query, key) pairs the forward's and dq's
     steps compute and the pairs a query sees (``pairs_computed``,
-    ``pairs_visible``). The fields of the entries' dispatch records."""
+    ``pairs_visible``). The fields of the entries' dispatch records.
+    Under a ``rule`` (block diffusion over ``seq`` = ``2L``) every tile
+    that runs runs whole, and a query sees ``L + block`` keys on
+    average."""
     nq, nk = seq // block_q, seq // block_k
     qi, kj = np.ogrid[:nq, :nk]
     run = np.broadcast_to(
-        _stream_kv_run(qi, kj, block_q, block_k, causal, window), (nq, nk))
+        _stream_kv_run(qi, kj, block_q, block_k, causal, window, rule),
+        (nq, nk))
     strips = _diagonal_strips(block_q, block_k, causal, window)
     diagonal = int((run & (qi == kj)).sum()) if strips else 0
     whole = int(run.sum()) - diagonal
     visible = seq * seq
-    if causal:
+    if rule is not None:
+        visible = rule.length * (rule.length + rule.block)
+    elif causal:
         band = min(seq, window or seq)
         visible = band * (band + 1) // 2 + (seq - band) * band
     return {
@@ -291,7 +456,7 @@ def _and_run(run, tile_selected):
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, slopes_ref, o_ref, lse_ref,
                       acc_ref, m_ref, l_ref, *, scale, causal, block_q,
                       block_k, num_kv, window, alibi, sel_ref=None,
-                      tile_selected=None, rope=None):
+                      tile_selected=None, rope=None, rule=None):
     """One (head, q-block, kv-block) grid cell of online-softmax attention.
 
     K/V arrive as [1, block_k, d] VMEM tiles streamed by the grid — VMEM
@@ -313,8 +478,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, slopes_ref, o_ref, lse_ref,
 
     # Causal: kv blocks entirely above the diagonal (or, windowed, fully
     # below the band) contribute nothing.
-    run = _and_run(_stream_kv_run(qi, kj, block_q, block_k, causal, window),
-                   tile_selected)
+    run = _and_run(_stream_kv_run(qi, kj, block_q, block_k, causal, window,
+                                  rule), tile_selected)
 
     def _step(parts, in_strips):
         q = q_ref[0].astype(jnp.float32) * scale
@@ -330,6 +495,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, slopes_ref, o_ref, lse_ref,
                     slopes_ref, kj * block_k + cols.start, s.shape)
             if in_strips:
                 s = _mask_strip(s)
+            elif rule is not None:
+                s = rule.mask(s, qi, kj, block_q, block_k)
             elif causal:
                 s = _causal_mask(s, qi, kj, block_q, block_k, window)
             if sel_ref is not None:
@@ -370,8 +537,14 @@ def _slopes_input(alibi_slopes, b, n):
     ).reshape(b * n, 1, 1)
 
 
+def _kernel_name(rule, which):
+    """``self_attention_flash_<which>``, or the rule's own name."""
+    return ("blockdiff_attention" if rule is not None
+            else "self_attention") + "_flash_" + which
+
+
 def _flash_fwd_pallas(q, k, v, scale, causal, block_q, block_k,
-                      window=None, alibi_slopes=None):
+                      window=None, alibi_slopes=None, rule=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -387,11 +560,11 @@ def _flash_fwd_pallas(q, k, v, scale, causal, block_q, block_k,
     kernel = functools.partial(
         _flash_fwd_kernel, scale=scale, causal=causal, block_q=block_q,
         block_k=block_k, num_kv=num_kv, window=window,
-        alibi=alibi_slopes is not None)
+        alibi=alibi_slopes is not None, rule=rule)
 
     def kv_index(h, i, j):
         return (h, _fetched_kv_block(i, j, block_q, block_k, causal,
-                                     window), 0)
+                                     window, rule), 0)
 
     out, lse = pl.pallas_call(
         kernel,
@@ -424,7 +597,7 @@ def _flash_fwd_pallas(q, k, v, scale, causal, block_q, block_k,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=GATE.interpret,
-        name="self_attention_flash_fwd",
+        name=_kernel_name(rule, "fwd"),
     )(q3, k3, v3, slopes3)
     return out.reshape(b, n, s, d), lse.reshape(b, n, s)
 
@@ -432,7 +605,7 @@ def _flash_fwd_pallas(q, k, v, scale, causal, block_q, block_k,
 def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                      slopes_ref, dq_ref, dq_acc, *, scale, causal,
                      block_q, block_k, num_kv, window, alibi, sel_ref=None,
-                     tile_selected=None, rope=None):
+                     tile_selected=None, rope=None, rule=None):
     """dq for one q block, streaming kv blocks (innermost grid dim):
     p = exp(q k^T scale - lse); ds = p * (do v^T - delta); dq += ds k scale.
     """
@@ -445,8 +618,8 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    run = _and_run(_stream_kv_run(qi, kj, block_q, block_k, causal, window),
-                   tile_selected)
+    run = _and_run(_stream_kv_run(qi, kj, block_q, block_k, causal, window,
+                                  rule), tile_selected)
 
     def _step(parts, in_strips):
         q = q_ref[0].astype(jnp.float32)
@@ -466,6 +639,8 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     slopes_ref, kj * block_k + cols.start, s.shape)
             if in_strips:
                 s = _mask_strip(s)
+            elif rule is not None:
+                s = rule.mask(s, qi, kj, block_q, block_k)
             elif causal:
                 s = _causal_mask(s, qi, kj, block_q, block_k, window)
             if sel_ref is not None:
@@ -491,7 +666,8 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _flash_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
                       slopes_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
                       scale, causal, block_q, block_k, num_q, window,
-                      alibi, sel_ref=None, tile_selected=None, rope=None):
+                      alibi, sel_ref=None, tile_selected=None, rope=None,
+                      rule=None):
     """dk/dv for one kv block, streaming q blocks (innermost grid dim):
     dv += p^T do;  dk += ds^T q scale."""
     from jax.experimental import pallas as pl
@@ -506,8 +682,8 @@ def _flash_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
 
     # Causal: q blocks entirely above this kv block (or, windowed, beyond
     # the band) contribute nothing.
-    run = _and_run(_stream_q_run(qi, kj, block_q, block_k, causal, window),
-                   tile_selected)
+    run = _and_run(_stream_q_run(qi, kj, block_q, block_k, causal, window,
+                                 rule), tile_selected)
 
     @pl.when(run)
     def _step():
@@ -523,7 +699,9 @@ def _flash_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
         s = s * scale
         if alibi:
             s = s + _alibi_bias(slopes_ref, kj * block_k, s.shape)
-        if causal:
+        if rule is not None:
+            s = rule.mask(s, qi, kj, block_q, block_k)
+        elif causal:
             s = _causal_mask(s, qi, kj, block_q, block_k, window)
         if sel_ref is not None:
             s = jnp.where(_selected(sel_ref), s, NEG_INF)
@@ -544,7 +722,7 @@ def _flash_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_bwd_pallas(q, k, v, o, lse, do, scale, causal, block_q,
-                      block_k, window=None, alibi_slopes=None):
+                      block_k, window=None, alibi_slopes=None, rule=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -562,16 +740,16 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, scale, causal, block_q,
 
     def kv_index(h, i, j):
         return (h, _fetched_kv_block(i, j, block_q, block_k, causal,
-                                     window), 0)
+                                     window, rule), 0)
 
     def q_index_for_kv(h, j, i):
         return (h, _fetched_q_block(j, i, block_q, block_k, causal,
-                                    window), 0)
+                                    window, rule), 0)
 
     dq = pl.pallas_call(
         functools.partial(_flash_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, num_kv=num_kv,
-                          window=window, alibi=alibi),
+                          window=window, alibi=alibi, rule=rule),
         grid=(b * n, num_q, num_kv),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda h, i, j: (h, i, 0),
@@ -596,13 +774,13 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, scale, causal, block_q,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=GATE.interpret,
-        name="self_attention_flash_dq",
+        name=_kernel_name(rule, "dq"),
     )(q3, k3, v3, do3, lse3, delta, slopes3)
 
     dk, dv = pl.pallas_call(
         functools.partial(_flash_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, num_q=num_q,
-                          window=window, alibi=alibi),
+                          window=window, alibi=alibi, rule=rule),
         grid=(b * n, num_kv, num_q),
         in_specs=[
             pl.BlockSpec((1, block_k, d), lambda h, j, i: (h, j, 0),
@@ -637,7 +815,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, scale, causal, block_q,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=GATE.interpret,
-        name="self_attention_flash_dkv",
+        name=_kernel_name(rule, "dkv"),
     )(k3, v3, q3, do3, lse3, delta, slopes3)
 
     rs = lambda x: x.reshape(b, n, s, d)  # noqa: E731
@@ -763,7 +941,8 @@ def _bsnd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
     from jax.experimental import pallas as pl
 
     run = _stream_q_run(pl.program_id(2), pl.program_id(1), kw["block_q"],
-                        kw["block_k"], kw["causal"], kw["window"])
+                        kw["block_k"], kw["causal"], kw["window"],
+                        kw.get("rule"))
     for h in range(heads):
         dk_acc, dv_acc, lse_col, delta_col = scratch[4 * h:4 * h + 4]
 
@@ -814,12 +993,13 @@ def _bsnd_slopes(alibi_slopes, heads):
 # gate's interpreter switch among them), where each bare ``pallas_call``
 # is traced and lowered to Mosaic again, two heads' worth a cell here.
 _bsnd_jit = functools.partial(jax.jit, static_argnames=(
-    "heads", "scale", "causal", "block_q", "block_k", "window", "interpret"))
+    "heads", "scale", "causal", "block_q", "block_k", "window", "interpret",
+    "rule"))
 
 
 @_bsnd_jit
 def _bsnd_fwd_pallas(q, k, v, alibi_slopes, *, heads, scale, causal,
-                     block_q, block_k, window, interpret):
+                     block_q, block_k, window, interpret, rule=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -831,12 +1011,12 @@ def _bsnd_fwd_pallas(q, k, v, alibi_slopes, *, heads, scale, causal,
     sp = _bsnd_specs(
         heads, d, block_q, block_k, lambda i, j: i,
         lambda i, j: _fetched_kv_block(i, j, block_q, block_k, causal,
-                                       window))
+                                       window, rule))
     return pl.pallas_call(
         functools.partial(
             _bsnd_fwd_kernel, heads=per_cell, d=d, scale=scale,
             causal=causal, block_q=block_q, block_k=block_k, num_kv=num_kv,
-            window=window, alibi=alibi_slopes is not None),
+            window=window, alibi=alibi_slopes is not None, rule=rule),
         grid=(b * cells, s // block_q, num_kv),
         in_specs=[sp["q"], sp["kv"], sp["kv"], sp["slopes"]],
         out_specs=[sp["q"], sp["row"]],
@@ -853,13 +1033,13 @@ def _bsnd_fwd_pallas(q, k, v, alibi_slopes, *, heads, scale, causal,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        name="self_attention_flash_fwd",
+        name=_kernel_name(rule, "fwd"),
     )(q, k, v, _bsnd_slopes(alibi_slopes, heads))
 
 
 @_bsnd_jit
 def _bsnd_bwd_pallas(q, k, v, o, lse, do, alibi_slopes, *, heads, scale,
-                     causal, block_q, block_k, window, interpret):
+                     causal, block_q, block_k, window, interpret, rule=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -873,13 +1053,13 @@ def _bsnd_bwd_pallas(q, k, v, o, lse, do, alibi_slopes, *, heads, scale,
         dimension_semantics=("parallel", "parallel", "arbitrary"))
     static = dict(heads=per_cell, d=d, scale=scale, causal=causal,
                   block_q=block_q, block_k=block_k, window=window,
-                  alibi=alibi_slopes is not None)
+                  alibi=alibi_slopes is not None, rule=rule)
     column = pltpu.VMEM((1, block_q, 1), jnp.float32)
 
     sp = _bsnd_specs(
         heads, d, block_q, block_k, lambda i, j: i,
         lambda i, j: _fetched_kv_block(i, j, block_q, block_k, causal,
-                                       window))
+                                       window, rule))
     dq, delta = pl.pallas_call(
         functools.partial(_bsnd_dq_kernel, num_kv=num_kv, **static),
         grid=(b * cells, num_q, num_kv),
@@ -891,13 +1071,13 @@ def _bsnd_bwd_pallas(q, k, v, o, lse, do, alibi_slopes, *, heads, scale,
         scratch_shapes=per_cell * [
             pltpu.VMEM((block_q, d), jnp.float32), column, column],
         compiler_params=params, interpret=interpret,
-        name="self_attention_flash_dq",
+        name=_kernel_name(rule, "dq"),
     )(q, k, v, do, o, lse, slopes)
 
     sp = _bsnd_specs(
         heads, d, block_q, block_k,
         lambda j, i: _fetched_q_block(j, i, block_q, block_k, causal,
-                                      window),
+                                      window, rule),
         lambda j, i: j)
     dk, dv = pl.pallas_call(
         functools.partial(_bsnd_dkv_kernel, num_q=num_q, **static),
@@ -911,7 +1091,7 @@ def _bsnd_bwd_pallas(q, k, v, o, lse, do, alibi_slopes, *, heads, scale,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32), column, column],
         compiler_params=params, interpret=interpret,
-        name="self_attention_flash_dkv",
+        name=_kernel_name(rule, "dkv"),
     )(k, v, q, do, lse, delta, slopes)
     return dq, dk, dv
 
@@ -1152,7 +1332,7 @@ def _head_probs_pallas(q, k, lse, selection, scale, causal, block_q,
 
 
 def _reference_scores(q, k, scale, causal, window=None, alibi_slopes=None,
-                      selection=None):
+                      selection=None, rule=None):
     """Masked float32 scores ``[b, n, s, s]`` of the reference path."""
     s = jnp.einsum("bnqd,bnkd->bnqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
@@ -1169,15 +1349,18 @@ def _reference_scores(q, k, scale, causal, window=None, alibi_slopes=None,
         s = jnp.where(mask, s, NEG_INF)
     if selection is not None:
         s = jnp.where(selection[:, None] != 0, s, NEG_INF)
+    if rule is not None:
+        s = jnp.where(block_diffusion_mask(*rule), s, NEG_INF)
     return s
 
 
 def _attention_reference(q, k, v, scale, causal, window=None,
-                         alibi_slopes=None, selection=None):
+                         alibi_slopes=None, selection=None, rule=None):
     """Reference einsum attention (fp32 softmax), used for the backward
     rematerialization and the non-TPU fallback."""
     p = jax.nn.softmax(_reference_scores(q, k, scale, causal, window,
-                                         alibi_slopes, selection), axis=-1)
+                                         alibi_slopes, selection, rule),
+                       axis=-1)
     return jnp.einsum("bnqk,bnkd->bnqd", p, v.astype(jnp.float32)).astype(q.dtype)
 
 
@@ -1192,12 +1375,13 @@ def _fit_block(block, s):
     return None
 
 
-def _resolve(q, scale, block_q, block_k):
+def _resolve(q, scale, block_q, block_k, rule=None):
     """(scale, block_q, block_k) for ``[.., seq, head_dim]`` operands."""
-    return _resolve_sizes(q.shape[-1], q.shape[-2], scale, block_q, block_k)
+    return _resolve_sizes(q.shape[-1], q.shape[-2], scale, block_q, block_k,
+                          rule)
 
 
-def _resolve_sizes(head_dim, s, scale, block_q, block_k):
+def _resolve_sizes(head_dim, s, scale, block_q, block_k, rule=None):
     if scale is None:
         scale = 1.0 / (head_dim ** 0.5)
     elif not isinstance(scale, numbers.Number):
@@ -1208,6 +1392,15 @@ def _resolve_sizes(head_dim, s, scale, block_q, block_k):
             "flash_attention scale must be a python number (it is a "
             f"static argument of the custom_vjp), got {type(scale)}; "
             "pass scale=None for the 1/sqrt(head_dim) default")
+    if rule is not None:
+        # tiles lie in one half of the row and hold whole diffusion blocks
+        # of a power of two (every block that divides a 128-tile is one)
+        bq, bk = _fit_block(block_q, rule.length), _fit_block(block_k,
+                                                              rule.length)
+        if (bq is None or bk is None or bq % rule.block or bk % rule.block
+                or rule.block & (rule.block - 1)):
+            bq = bk = None
+        return scale, bq, bk
     return scale, _fit_block(block_q, s), _fit_block(block_k, s)
 
 
@@ -1224,10 +1417,11 @@ def _check_window(window, causal):
                          f"static int, got {window!r}")
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 10))
 def flash_attention(q, k, v, causal=True, scale=None,
                     block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
-                    window=None, alibi_slopes=None, selection=None):
+                    window=None, alibi_slopes=None, selection=None,
+                    block_diffusion=None):
     """Flash attention over [batch, heads, seq, head_dim] inputs (head
     major: for callers that hold their heads so; on a TPU a head_dim
     under 128 pads to 128 lanes in every operand. A caller that holds
@@ -1250,18 +1444,31 @@ def flash_attention(q, k, v, causal=True, scale=None,
     alibi bias inside the kernel. Treated as NON-DIFFERENTIABLE (the
     returned cotangent is zero, matching the CUDA flash-attention
     convention) — trained-ALiBi variants must not route slope gradients
-    through this op."""
+    through this op.
+    ``block_diffusion``: the diffusion block's length, a static int: the
+    row holds ``seq / 2`` clean tokens and then their noised copies, and
+    the rule is block diffusion's (:class:`_BlockDiffusion`), worked out
+    from positions inside the kernels (named ``blockdiff_attention_*``;
+    tiles no query of which sees a key are neither run nor fetched).
+    A rule of its own: ``causal`` has to be ``False``, and it composes
+    with neither ``window``, ``alibi_slopes`` nor ``selection``. Counted
+    as ``kernels/dispatch/flash_attention_blockdiff_<path>`` beside
+    ``flash_attention``'s own counter; where no block fits both halves
+    and whole diffusion blocks of a power of two, the oracle under
+    :func:`block_diffusion_mask`."""
     _check_window(window, causal)
     _check_selection(selection, q, window, alibi_slopes)
-    scale, bq, bk = _resolve(q, scale, block_q, block_k)
-    if _takes(bq, bk, q.shape[2], causal, window) != "oracle":
+    rule = _rule_of(block_diffusion, q.shape[2], causal, window,
+                    alibi_slopes, selection)
+    scale, bq, bk = _resolve(q, scale, block_q, block_k, rule)
+    if _takes(bq, bk, q.shape[2], causal, window, rule=rule) != "oracle":
         if selection is not None:
             return _sparse_fwd_pallas(q, k, v, selection, scale, causal,
                                       bq, bk)[0]
         return _flash_fwd_pallas(q, k, v, scale, causal, bq, bk,
-                                 window, alibi_slopes)[0]
+                                 window, alibi_slopes, rule)[0]
     return _attention_reference(q, k, v, scale, causal, window,
-                                alibi_slopes, selection)
+                                alibi_slopes, selection, rule)
 
 
 def _check_selection(selection, q, window, alibi_slopes):
@@ -1277,17 +1484,20 @@ def _check_selection(selection, q, window, alibi_slopes):
 
 
 def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k,
-                    window=None, alibi_slopes=None, selection=None):
+                    window=None, alibi_slopes=None, selection=None,
+                    block_diffusion=None):
     _check_window(window, causal)
     _check_selection(selection, q, window, alibi_slopes)
-    scale_, bq, bk = _resolve(q, scale, block_q, block_k)
-    if _takes(bq, bk, q.shape[2], causal, window) != "oracle":
+    rule = _rule_of(block_diffusion, q.shape[2], causal, window,
+                    alibi_slopes, selection)
+    scale_, bq, bk = _resolve(q, scale, block_q, block_k, rule)
+    if _takes(bq, bk, q.shape[2], causal, window, rule=rule) != "oracle":
         if selection is not None:
             out, lse = _sparse_fwd_pallas(q, k, v, selection, scale_, causal,
                                           bq, bk)
         else:
             out, lse = _flash_fwd_pallas(q, k, v, scale_, causal, bq, bk,
-                                         window, alibi_slopes)
+                                         window, alibi_slopes, rule)
         # The two residuals only another run of the kernel can rebuild,
         # named so that a checkpointed layer can keep them (``lse`` in
         # its [b, n, s] form: the kernel's [b*n, s, 1] pads its last
@@ -1299,13 +1509,15 @@ def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k,
         lse = checkpoint_name(lse, FLASH_RESIDUAL_NAMES[1])
         return out, (q, k, v, out, lse, alibi_slopes, selection)
     return (_attention_reference(q, k, v, scale_, causal, window,
-                                 alibi_slopes, selection),
+                                 alibi_slopes, selection, rule),
             (q, k, v, None, None, alibi_slopes, selection))
 
 
-def _flash_bwd_rule(causal, scale, block_q, block_k, window, res, g):
+def _flash_bwd_rule(causal, scale, block_q, block_k, window,
+                    block_diffusion, res, g):
     q, k, v, out, lse, alibi_slopes, selection = res
-    scale_, bq, bk = _resolve(q, scale, block_q, block_k)
+    rule = _rule_of(block_diffusion, q.shape[2])
+    scale_, bq, bk = _resolve(q, scale, block_q, block_k, rule)
     none_slope_grad = (None if alibi_slopes is None
                        else jnp.zeros_like(alibi_slopes))
     if lse is not None and selection is not None:
@@ -1315,12 +1527,13 @@ def _flash_bwd_rule(causal, scale, block_q, block_k, window, res, g):
     if lse is not None:
         dq, dk, dv = _flash_bwd_pallas(q, k, v, out, lse, g, scale_,
                                        causal, bq, bk, window,
-                                       alibi_slopes)
+                                       alibi_slopes, rule)
         return dq, dk, dv, none_slope_grad, None
     _, vjp = jax.vjp(
         lambda q_, k_, v_: _attention_reference(q_, k_, v_, scale_,
                                                 causal, window,
-                                                alibi_slopes, selection),
+                                                alibi_slopes, selection,
+                                                rule),
         q, k, v)
     return (*vjp(g), none_slope_grad, None)
 
@@ -1340,10 +1553,11 @@ def _to_batch_major(x):
     return x.transpose(0, 2, 1, 3).reshape(b, s, n * d)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 10))
 def flash_attention_bsnd(q, k, v, heads, causal=True, scale=None,
                          block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
-                         window=None, alibi_slopes=None):
+                         window=None, alibi_slopes=None,
+                         block_diffusion=None):
     """:func:`flash_attention` over ``[batch, seq, heads * head_dim]``
     inputs: the layout a qkv projection writes and an output projection
     reads, so that nothing is transposed around the kernels and a head
@@ -1351,18 +1565,20 @@ def flash_attention_bsnd(q, k, v, heads, causal=True, scale=None,
     under other index maps; a grid cell takes the heads of one 128-lane
     column, so ``head_dim`` has to be a multiple of 128, or divide 128
     with ``heads`` a multiple of ``128 // head_dim``. ``causal``,
-    ``scale``, ``window`` and ``alibi_slopes`` as :func:`flash_attention`
-    has them. The residuals a checkpointed layer can keep
+    ``scale``, ``window``, ``alibi_slopes`` and ``block_diffusion`` as
+    :func:`flash_attention` has them. The residuals a checkpointed layer can keep
     (``FLASH_RESIDUAL_NAMES``) are the kernel's own: ``out`` ``[b, s,
     n * d]`` in the operands' dtype and the log-sum-exp ``[b, n / c, c,
     s]`` float32, ``c`` heads a cell; the backward kernels read both as
     they are. Counted as ``kernels/dispatch/flash_attention_bsnd_<path>``
+    (under ``block_diffusion`` as ``flash_attention_blockdiff_<path>``)
     beside ``flash_attention``'s own counter."""
     return _bsnd_fwd_rule(q, k, v, heads, causal, scale, block_q, block_k,
-                          window, alibi_slopes)[0]
+                          window, alibi_slopes, block_diffusion)[0]
 
 
-def _bsnd_resolve(q, heads, scale, block_q, block_k, causal, window):
+def _bsnd_resolve(q, heads, scale, block_q, block_k, causal, window,
+                  rule=None):
     """(scale, block_q, block_k, does the kernel run), recording the
     call under both counters."""
     width = q.shape[-1]
@@ -1371,50 +1587,57 @@ def _bsnd_resolve(q, heads, scale, block_q, block_k, causal, window):
             f"flash_attention_bsnd cannot cut {heads} heads over {width} "
             "lanes into 128-lane columns; use flash_attention")
     scale, bq, bk = _resolve_sizes(width // heads, q.shape[1], scale,
-                                   block_q, block_k)
+                                   block_q, block_k, rule)
     path = _takes(bq, bk, q.shape[1], causal, window,
-                  entry="flash_attention_bsnd")
+                  entry="flash_attention_bsnd", rule=rule)
     return scale, bq, bk, path != "oracle"
 
 
-def _bsnd_reference(q, k, v, heads, scale, causal, window, alibi_slopes):
+def _bsnd_reference(q, k, v, heads, scale, causal, window, alibi_slopes,
+                    rule=None):
     return _to_batch_major(_attention_reference(
         *(_to_head_major(x, heads) for x in (q, k, v)), scale, causal,
-        window, alibi_slopes))
+        window, alibi_slopes, rule=rule))
 
 
 def _bsnd_fwd_rule(q, k, v, heads, causal, scale, block_q, block_k,
-                   window=None, alibi_slopes=None):
+                   window=None, alibi_slopes=None, block_diffusion=None):
     _check_window(window, causal)
+    rule = _rule_of(block_diffusion, q.shape[1], causal, window,
+                    alibi_slopes)
     scale_, bq, bk, kernel = _bsnd_resolve(q, heads, scale, block_q,
-                                           block_k, causal, window)
+                                           block_k, causal, window, rule)
     if kernel:
         out, lse = _bsnd_fwd_pallas(
             q, k, v, alibi_slopes, heads=heads, scale=scale_, causal=causal,
-            block_q=bq, block_k=bk, window=window, interpret=GATE.interpret)
+            block_q=bq, block_k=bk, window=window, interpret=GATE.interpret,
+            rule=rule)
         # as _flash_fwd_rule; both are kept as the kernel wrote them
         out = checkpoint_name(out, FLASH_RESIDUAL_NAMES[0])
         lse = checkpoint_name(lse, FLASH_RESIDUAL_NAMES[1])
         return out, (q, k, v, out, lse, alibi_slopes)
     return (_bsnd_reference(q, k, v, heads, scale_, causal, window,
-                            alibi_slopes),
+                            alibi_slopes, rule),
             (q, k, v, None, None, alibi_slopes))
 
 
-def _bsnd_bwd_rule(heads, causal, scale, block_q, block_k, window, res, g):
+def _bsnd_bwd_rule(heads, causal, scale, block_q, block_k, window,
+                   block_diffusion, res, g):
     q, k, v, out, lse, alibi_slopes = res
+    rule = _rule_of(block_diffusion, q.shape[1])
     scale_, bq, bk = _resolve_sizes(q.shape[-1] // heads, q.shape[1], scale,
-                                    block_q, block_k)
+                                    block_q, block_k, rule)
     none_slope_grad = (None if alibi_slopes is None
                        else jnp.zeros_like(alibi_slopes))
     if lse is not None:
         return (*_bsnd_bwd_pallas(
             q, k, v, out, lse, g, alibi_slopes, heads=heads, scale=scale_,
             causal=causal, block_q=bq, block_k=bk, window=window,
-            interpret=GATE.interpret), none_slope_grad)
+            interpret=GATE.interpret, rule=rule), none_slope_grad)
     _, vjp = jax.vjp(
         lambda q_, k_, v_: _bsnd_reference(q_, k_, v_, heads, scale_,
-                                           causal, window, alibi_slopes),
+                                           causal, window, alibi_slopes,
+                                           rule),
         q, k, v)
     return (*vjp(g), none_slope_grad)
 
@@ -1846,7 +2069,7 @@ def _sparse_fwd_rule(q, k, v, selection, causal, scale, block_q, block_k):
 
 
 def _sparse_bwd_rule(causal, scale, block_q, block_k, res, g):
-    return _flash_bwd_rule(causal, scale, block_q, block_k, None, res,
+    return _flash_bwd_rule(causal, scale, block_q, block_k, None, None, res,
                            g[0])[:3] + (None,)
 
 

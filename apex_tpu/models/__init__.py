@@ -12,7 +12,11 @@ from apex_tpu.models.transformer_lm import (  # noqa: F401
     ParallelTransformer,
     TransformerConfig,
 )
-from apex_tpu.models.gpt import GPTModel, gpt_loss_fn  # noqa: F401
+from apex_tpu.models.gpt import (  # noqa: F401
+    GPTModel,
+    block_diffusion_loss_fn,
+    gpt_loss_fn,
+)
 from apex_tpu.models.generation import (  # noqa: F401
     beam_search,
     generate,

@@ -17,6 +17,7 @@ from apex_tpu.models.transformer_lm import (
     TransformerConfig,
     _make_norm,
 )
+from apex_tpu.telemetry.registry import get_registry
 from apex_tpu.transformer.parallel_state import (
     get_tensor_model_parallel_world_size,
 )
@@ -30,7 +31,14 @@ from apex_tpu.transformer.tensor_parallel.utils import divide
 
 class GPTModel(nn.Module):
     """Causal LM. Input token ids [b, s] -> vocab-parallel logits
-    [b, s, vocab/tp] (pre-loss; use ``gpt_loss_fn``)."""
+    [b, s, vocab/tp] (pre-loss; use ``gpt_loss_fn``).
+
+    With ``config.diffusion_block_length`` (training by diffusion over
+    blocks) a row is ``[x0 ; xt]``, ``L`` clean tokens and their ``L``
+    noised copies: both copies of a token take its position (``position_ids``
+    default to ``0..L-1`` twice), and the final norm and the head run on the
+    noisy half alone: logits ``[b, L, vocab/tp]``, for
+    ``block_diffusion_loss_fn``."""
 
     config: TransformerConfig
     num_layers: Optional[int] = None
@@ -48,6 +56,9 @@ class GPTModel(nn.Module):
         cfg = self.config
         tp = get_tensor_model_parallel_world_size()
 
+        if cfg.diffusion_block_length is not None and position_ids is None:
+            half = tokens.shape[-1] // 2
+            position_ids = jnp.tile(jnp.arange(half), 2)[None, :]
         if self.pre_process:
             with jax.named_scope("embedding"):
                 emb = VocabParallelEmbedding(
@@ -89,8 +100,18 @@ class GPTModel(nn.Module):
         if not self.post_process:
             return h
 
+        if cfg.diffusion_block_length is not None:
+            # the clean half was context: it reaches neither the final
+            # norm, the head nor the loss
+            with jax.named_scope("diffusion/select_noisy"):
+                h = h[h.shape[0] // 2:]
+            reg = get_registry()
+            reg.gauge("diffusion/block_length").set(
+                cfg.diffusion_block_length)
+            reg.gauge("diffusion/head_rows").set(h.shape[0] * h.shape[1])
         h = _make_norm(cfg, "final_layernorm")(h.astype(jnp.float32))
-        with jax.named_scope("head"):
+        with jax.named_scope("head" if cfg.diffusion_block_length is None
+                             else "diffusion/head"):
             h = copy_to_tensor_model_parallel_region(
                 h.astype(cfg.compute_dtype))
             if cfg.tie_word_embeddings:
@@ -139,6 +160,22 @@ def _fold_tp(key):
     except Exception:
         rank = 0
     return jax.random.fold_in(key, rank)
+
+
+def block_diffusion_loss_fn(vocab_parallel_logits, labels, weights):
+    """The block-diffusion training loss: cross-entropy of the noisy
+    half's logits ``[b, L, vocab/tp]`` against the data tokens ``labels``
+    ``[b, L]`` at the masked positions, each weighted by its block's
+    ``1 / t`` (``weights`` ``[b, L]``: ``m / t``, zero where the token was
+    not masked), over all ``L`` data tokens of every sequence (not over
+    the masked ones: the weights carry the schedule's normalisation). No
+    shift: the logit at a masked position predicts that position's
+    token."""
+    # two scopes, not one name with a slash: a transformation wraps the
+    # outermost name whole (``jvp(diffusion)/loss``)
+    with jax.named_scope("diffusion"), jax.named_scope("loss"):
+        losses = vocab_parallel_cross_entropy(vocab_parallel_logits, labels)
+        return jnp.sum(losses * weights) / losses.size
 
 
 @jax.named_scope("loss")

@@ -297,6 +297,16 @@ class TransformerConfig:
     mamba_dt_min: float = 0.001
     mamba_dt_max: float = 0.1
     mamba_dt_floor: float = 1e-4
+    # Training by diffusion over blocks (Block Diffusion, arXiv:2503.09573;
+    # SDAR): with ``attn_mask_type`` ``AttnMaskType.block_diffusion``, a row
+    # holds ``L`` clean tokens and then their ``L`` noised copies, cut into
+    # blocks of this many tokens; attention follows the block-diffusion
+    # rule (the flash kernels work it out from positions; off them the
+    # softmax path takes it as a boolean mask), both copies of a token
+    # share its position, and the final norm and the head run on the noisy
+    # half alone (models/gpt.py; the loss is ``block_diffusion_loss_fn``).
+    # Training only. None -> rows are what they are everywhere else.
+    diffusion_block_length: Optional[int] = None
     # Tie the LM head to the word-embedding table (reference
     # parallel_lm_logits ties by default). Off here because the SPMD
     # pipeline harness needs untied heads (first/last stages run the same
@@ -305,6 +315,17 @@ class TransformerConfig:
     tie_word_embeddings: bool = False
 
     def __post_init__(self):
+        ruled = self.attn_mask_type == AttnMaskType.block_diffusion
+        if ruled != (self.diffusion_block_length is not None) or (
+                ruled and self.diffusion_block_length < 1):
+            raise ValueError(
+                f"AttnMaskType.block_diffusion and diffusion_block_length "
+                f"({self.diffusion_block_length}, >= 1) come together")
+        if ruled and (self.context_parallel or self.sequence_parallel
+                      or self.position_embedding_type == "alibi"):
+            raise ValueError(
+                "block diffusion runs on one sequence shard, without alibi: "
+                "no context or sequence parallelism")
         if self.sliding_window is not None:
             if self.sliding_window < 1:
                 raise ValueError(
@@ -998,6 +1019,24 @@ class ParallelAttention(nn.Module):
         if self.decode and cfg.sequence_parallel:
             raise ValueError("decode mode does not compose with "
                              "sequence parallelism")
+        block_length = cfg.diffusion_block_length
+        if block_length is not None:
+            if self.decode:
+                raise ValueError(
+                    "block diffusion (diffusion_block_length) is a training "
+                    "rule over [clean; noisy] rows; there is no decode path")
+            if s % (2 * block_length):
+                raise ValueError(
+                    f"a block-diffusion row holds L clean tokens and their L "
+                    f"noised copies in blocks of {block_length}; got {s}")
+            from apex_tpu.telemetry.registry import get_registry
+
+            get_registry().counter("diffusion/layers").inc()
+        # the rule of this call: the block length where attention follows
+        # block diffusion (a planted ``causal`` over the same rows has none)
+        rule = (block_length
+                if cfg.attn_mask_type == AttnMaskType.block_diffusion
+                else None)
         if cfg.indexer_heads is not None and (
                 self.decode or attention_mask is not None or tp > 1):
             raise ValueError(
@@ -1166,7 +1205,7 @@ class ParallelAttention(nn.Module):
                            .transpose(1, 0, 2) for t in (q, k, v))
                 ctx = fmha.flash_attention_bsnd(
                     q, k, v, np_local, causal, window=win,
-                    alibi_slopes=slopes)
+                    alibi_slopes=slopes, block_diffusion=rule)
                 ctx = ctx.transpose(1, 0, 2)  # [s, b, n*d]
             else:
                 # [s, b, n, d] -> [b, n, s, d]
@@ -1174,7 +1213,8 @@ class ParallelAttention(nn.Module):
                 kt = k.transpose(1, 2, 0, 3)
                 vt = v.transpose(1, 2, 0, 3)
                 ctx = fmha.flash_attention(qt, kt, vt, causal=causal,
-                                           window=win, alibi_slopes=slopes)
+                                           window=win, alibi_slopes=slopes,
+                                           block_diffusion=rule)
                 ctx = ctx.transpose(2, 0, 1, 3)  # [s, b, n, d]
         else:
             if win is not None:
@@ -1188,6 +1228,16 @@ class ParallelAttention(nn.Module):
                 band = (j > i) | (i - j >= win)
                 attention_mask = (band if attention_mask is None
                                   else band | attention_mask.astype(bool))
+            if rule is not None:
+                # the kernels' oracle: the rule as a boolean mask (True
+                # where a query does not see a key), counted as theirs
+                from apex_tpu.kernels.registry import get_kernel_registry
+
+                get_kernel_registry().dispatch(fmha.BLOCKDIFF_ENTRY, "oracle")
+                unseen = jnp.asarray(~fmha.block_diffusion_mask(
+                    seq_full // 2, rule))
+                attention_mask = (unseen if attention_mask is None
+                                  else unseen | attention_mask.astype(bool))
             # core attention (reference CoreAttention): [b, n, s, s] scores
             qt = q.transpose(1, 2, 0, 3).astype(cfg.compute_dtype)
             kt = k.transpose(1, 2, 0, 3).astype(cfg.compute_dtype)

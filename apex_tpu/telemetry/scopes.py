@@ -56,6 +56,11 @@ _BLOCKS = {
     # latent path and ``latent_attention``; scopes ``mla/{q_proj,kv_down,
     # kv_up,rope,kernel,out_proj}`` in the flax module ``self_attention``)
     "mla": ("mla",),
+    # training by diffusion over blocks (models/gpt.py under
+    # ``diffusion_block_length``): what runs on the noisy half alone, scopes
+    # ``diffusion/{select_noisy,head,loss}``: the slice of rows L..2L-1, the
+    # head on them and the weighted cross-entropy
+    "diffusion_head": ("diffusion",),
     "head": ("head", "word_embeddings.attend", "lm_dense", "lm_layernorm",
              "lm_head", "lm_head_bias", "pooler", "binary_head"),
     "loss": ("loss",),
@@ -121,8 +126,9 @@ def classify(scope: str) -> tuple:
     the next component says which part of the Mamba-2 mixer), ``mla``
     (``mla/q_proj``, ``mla/kv_down``, ``mla/kv_up``, ``mla/rope``,
     ``mla/kernel``, ``mla/out_proj``: latent attention, inside the
-    ``self_attention`` module as the indexer is), ``head``,
-    ``loss``, ``amp``, ``optimizer``, ``collective``; ``residual`` for a
+    ``self_attention`` module as the indexer is), ``diffusion_head``
+    (the noisy half's slice, head and loss of a block-diffusion model),
+    ``head``, ``loss``, ``amp``, ``optimizer``, ``collective``; ``residual`` for a
     scope inside the model that names none of them; ``None`` for any
     other. ``phase`` is
     ``recompute`` under ``jax.checkpoint``'s ``rematted_computation``,

@@ -16,6 +16,9 @@ class AttnType(enum.Enum):
 class AttnMaskType(enum.Enum):
     padding = 1
     causal = 2
+    # beyond the reference: block diffusion over a row of clean tokens and
+    # their noised copies (contrib/fmha.py ``_BlockDiffusion``)
+    block_diffusion = 3
 
 
 class ModelType(enum.Enum):

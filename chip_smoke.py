@@ -547,6 +547,20 @@ def kernel_cases():
         lambda: tuple(randn(i, (2, 2048, 1024)) for i in range(3)),
         TOL_MXU)
 
+    # -- the same kernel bodies under the block-diffusion rule (a row of
+    # 1024 clean tokens and their 1024 noised copies in blocks of 4; the
+    # calls named blockdiff_attention_flash_*), batch-major at head size
+    # 128: tiles of all three parts run, and tiles of all three are skipped
+    add("flash_attention bsnd blockdiff fwd+bwd heads=8 d=128 seq=2x1024 "
+        "block=4",
+        fwd_bwd(lambda q, k, v: fmha.flash_attention_bsnd(
+            q, k, v, 8, False, block_diffusion=4)),
+        fwd_bwd(lambda q, k, v: fmha._bsnd_reference(
+            q, k, v, 8, 128 ** -0.5, False, None, None,
+            fmha._BlockDiffusion(1024, 4))),
+        lambda: tuple(randn(40 + i, (2, 2048, 1024)) for i in range(3)),
+        TOL_MXU)
+
     # -- the same kernel bodies with the latent attention's rotary part
     # (Moonlight: 16 heads of 128 + 64 beside values of 128, one shared
     # rotary key a token) at the cell's shape, 2 x 8192; the oracle a head

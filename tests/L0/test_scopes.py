@@ -218,6 +218,21 @@ def test_update_and_loss_are_not_charged_to_the_model(tables, model,
      ("attention", "forward")),
     ("jit(f)/jvp(BertModel)/head/lm_layernorm/reduce_sum",
      ("head", "forward")),
+    # training by diffusion over blocks: the noisy half's slice, head and
+    # loss are one block; the rule's kernels stay in block attention
+    ("jit(f)/jvp(GPTModel)/diffusion/select_noisy/slice",
+     ("diffusion_head", "forward")),
+    ("jit(f)/transpose(jvp(GPTModel))/diffusion/head/dot_general",
+     ("diffusion_head", "backward")),
+    ("jit(f)/jvp(diffusion)/loss/reduce_sum", ("diffusion_head", "forward")),
+    ("jit(f)/transpose(jvp(diffusion))/loss/jit(_where)/select_n",
+     ("diffusion_head", "backward")),
+    ("jit(f)/jvp(GPTModel)/transformer/layer_1/self_attention/"
+     "jit(_bsnd_fwd_pallas)/blockdiff_attention_flash_fwd/pallas_call",
+     ("attention/kernel", "forward")),
+    ("jit(f)/transpose(jvp(GPTModel))/transformer/layer_1/self_attention/"
+     "jit(_bsnd_bwd_pallas)/blockdiff_attention_flash_dkv/pallas_call",
+     ("attention/kernel", "backward")),
     ("jit(f)/jvp(GPTModel)/transformer/layer_0/add",
      ("residual", "forward")),
     ("params[\\'transformer\\'][\\'layer_0\\'][\\'mlp\\']"
@@ -318,6 +333,52 @@ def test_a_latent_step_gives_the_attention_s_parts_their_time(latent_blocks,
     # the plain attention block's projection and kernel are not there
     assert latent_blocks[("attention/qkv", phase)] == 0
     assert latent_blocks[("attention/kernel", phase)] == 0
+
+
+@pytest.fixture(scope="module")
+def diffusion_blocks():
+    """Blocks of a compiled two-layer block-diffusion step (rows
+    ``[x0 ; xt]`` under the rule, held experts, the head and the weighted
+    loss on the noisy half)."""
+    from apex_tpu.models.gpt import block_diffusion_loss_fn
+
+    cfg = TransformerConfig(
+        hidden_size=32, num_layers=2, num_attention_heads=2, head_dim=16,
+        num_query_groups=1, ffn_hidden_size=16, vocab_size=64,
+        max_position_embeddings=SEQ, compute_dtype=jnp.bfloat16,
+        normalization="rmsnorm", activation="swiglu", attention_bias=False,
+        qk_norm="head", position_embedding_type="rope",
+        attn_mask_type=AttnMaskType.block_diffusion,
+        diffusion_block_length=4, num_moe_experts=8, moe_top_k=2,
+        moe_local_experts=4, moe_capacity_factor=2.0,
+        activation_checkpointing=True)
+    model = GPTModel(cfg)
+    tokens = jnp.zeros((BATCH, SEQ), jnp.int32)
+    rows = jnp.zeros((BATCH, 2 * SEQ), jnp.int32)
+
+    def loss(p, b):
+        logits, _ = model.apply(
+            {"params": p}, jnp.concatenate([b["tokens"], b["noisy"]], 1),
+            mutable=["moe_losses"])
+        return block_diffusion_loss_fn(logits, b["tokens"], b["weights"])
+
+    table = scope_table(_compiled(
+        model, FusedAdam(lr=1e-4), loss,
+        {"tokens": tokens, "noisy": tokens,
+         "weights": jnp.ones((BATCH, SEQ), jnp.float32)}, rows))
+    return collections.Counter(classify(s) for s in table.values())
+
+
+@pytest.mark.parametrize("block", ["diffusion_head", "attention/qkv",
+                                   "attention/dense", "moe", "layernorm"])
+@pytest.mark.parametrize("phase", ["forward", "backward"])
+def test_a_diffusion_step_gives_the_noisy_half_s_head_its_block(
+        diffusion_blocks, block, phase):
+    assert diffusion_blocks[(block, phase)] > 0, sorted(diffusion_blocks,
+                                                        key=str)
+    # the head and the loss are the block's, not blocks of their own
+    assert diffusion_blocks[("head", phase)] == 0
+    assert diffusion_blocks[("loss", phase)] == 0
 
 
 # what a fusion answers with: the scope of the matrix product or Mosaic

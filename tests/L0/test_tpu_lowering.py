@@ -98,6 +98,9 @@ KERNEL_NAMES = {
     "flash_attention mla": ("mla_attention_flash_fwd",
                             "mla_attention_flash_dq",
                             "mla_attention_flash_dkv"),
+    "flash_attention bsnd blockdiff": ("blockdiff_attention_flash_fwd",
+                                       "blockdiff_attention_flash_dq",
+                                       "blockdiff_attention_flash_dkv"),
     "flash_attention bsnd": ("self_attention_flash_fwd",
                              "self_attention_flash_dq",
                              "self_attention_flash_dkv"),
