@@ -23,7 +23,9 @@ log-sum-exp; the backward recomputes p = exp(q k^T scale - lse) per tile
 (flash-attention v2 style) instead of materializing the [s, s] matrix.
 
 Causal, a tile above the diagonal (or outside a window's band) is skipped
-and never fetched, and every tile that runs is masked by position. One
+and never fetched, and every tile that runs is masked by position. A call
+with a window names its kernels ``window_attention_*`` (the others are
+``self_attention_*``), so that a trace reads the band apart. One
 class of tile does less than that: with square blocks and no window (what
 every default call has) the forward and dq run a tile *on the diagonal*
 in strips of ``STRIP`` rows, each against the keys its queries can see
@@ -76,16 +78,20 @@ def _takes(bq, bk, seq=None, causal=None, window=None, entry=None,
     entry gives its ``seq``, and its record then says what the bodies do
     over a head's tiles (:func:`_tile_classes`), under the entry's own
     name: ``flash_attention``'s, or the ``entry`` counted beside it (under
-    a ``rule``, either layout's calls: ``BLOCKDIFF_ENTRY``)."""
+    a ``rule``, either layout's calls: ``BLOCKDIFF_ENTRY``); a call with a
+    ``window`` is counted once more, as ``WINDOW_ENTRY``."""
     if rule is not None:
         entry = BLOCKDIFF_ENTRY
     fits = bq is not None and bk is not None
     tiles = _tile_classes(seq, bq, bk, causal, window, rule) \
         if fits and seq is not None else {}
     if entry is None:
-        return GATE.path(fits=fits, **tiles)
-    path = GATE.path(fits=fits)
-    get_kernel_registry().dispatch(entry, path, **tiles)
+        path = GATE.path(fits=fits, **tiles)
+    else:
+        path = GATE.path(fits=fits)
+        get_kernel_registry().dispatch(entry, path, **tiles)
+    if window is not None:
+        get_kernel_registry().dispatch(WINDOW_ENTRY, path, **tiles)
     return path
 
 
@@ -227,6 +233,9 @@ def _fetched_q_block(kj, qi, block_q, block_k, causal, window, rule=None):
 # ``blockdiff_attention_*``.
 
 BLOCKDIFF_ENTRY = "flash_attention_blockdiff"   # the calls' dispatch record
+# a windowed call's record, beside its entry's own: the band's tiles read
+# apart from full causal ones (the kernels are named window_attention_*)
+WINDOW_ENTRY = "flash_attention_window"
 
 
 class _BlockDiffusion(NamedTuple):
@@ -537,9 +546,11 @@ def _slopes_input(alibi_slopes, b, n):
     ).reshape(b * n, 1, 1)
 
 
-def _kernel_name(rule, which):
-    """``self_attention_flash_<which>``, or the rule's own name."""
+def _kernel_name(rule, which, window=None):
+    """``self_attention_flash_<which>``, or the rule's or the window
+    band's own name."""
     return ("blockdiff_attention" if rule is not None
+            else "window_attention" if window is not None
             else "self_attention") + "_flash_" + which
 
 
@@ -597,7 +608,7 @@ def _flash_fwd_pallas(q, k, v, scale, causal, block_q, block_k,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=GATE.interpret,
-        name=_kernel_name(rule, "fwd"),
+        name=_kernel_name(rule, "fwd", window),
     )(q3, k3, v3, slopes3)
     return out.reshape(b, n, s, d), lse.reshape(b, n, s)
 
@@ -774,7 +785,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, scale, causal, block_q,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=GATE.interpret,
-        name=_kernel_name(rule, "dq"),
+        name=_kernel_name(rule, "dq", window),
     )(q3, k3, v3, do3, lse3, delta, slopes3)
 
     dk, dv = pl.pallas_call(
@@ -815,7 +826,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, scale, causal, block_q,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=GATE.interpret,
-        name=_kernel_name(rule, "dkv"),
+        name=_kernel_name(rule, "dkv", window),
     )(k3, v3, q3, do3, lse3, delta, slopes3)
 
     rs = lambda x: x.reshape(b, n, s, d)  # noqa: E731
@@ -1033,7 +1044,7 @@ def _bsnd_fwd_pallas(q, k, v, alibi_slopes, *, heads, scale, causal,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        name=_kernel_name(rule, "fwd"),
+        name=_kernel_name(rule, "fwd", window),
     )(q, k, v, _bsnd_slopes(alibi_slopes, heads))
 
 
@@ -1071,7 +1082,7 @@ def _bsnd_bwd_pallas(q, k, v, o, lse, do, alibi_slopes, *, heads, scale,
         scratch_shapes=per_cell * [
             pltpu.VMEM((block_q, d), jnp.float32), column, column],
         compiler_params=params, interpret=interpret,
-        name=_kernel_name(rule, "dq"),
+        name=_kernel_name(rule, "dq", window),
     )(q, k, v, do, o, lse, slopes)
 
     sp = _bsnd_specs(
@@ -1091,7 +1102,7 @@ def _bsnd_bwd_pallas(q, k, v, o, lse, do, alibi_slopes, *, heads, scale,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32), column, column],
         compiler_params=params, interpret=interpret,
-        name=_kernel_name(rule, "dkv"),
+        name=_kernel_name(rule, "dkv", window),
     )(k, v, q, do, lse, delta, slopes)
     return dq, dk, dv
 
@@ -1439,7 +1450,10 @@ def flash_attention(q, k, v, causal=True, scale=None,
 
     ``window``: sliding-window band (key j visible to query i iff
     0 <= i - j < window); blocks fully outside the band are skipped, so
-    compute scales with seq * window instead of seq^2.
+    compute scales with seq * window instead of seq^2. Such calls are
+    named ``window_attention_*`` and counted once more as
+    ``kernels/dispatch/flash_attention_window_<path>``, with the band's
+    tiles.
     ``alibi_slopes``: per-head [heads] slopes adding the key-position
     alibi bias inside the kernel. Treated as NON-DIFFERENTIABLE (the
     returned cotangent is zero, matching the CUDA flash-attention

@@ -6,10 +6,12 @@ examples/dcgan (DCGAN).
 """
 
 from apex_tpu.models.transformer_lm import (  # noqa: F401
+    AttentionKind,
     ParallelAttention,
     ParallelMLP,
     ParallelTransformerLayer,
     ParallelTransformer,
+    RopeScaling,
     TransformerConfig,
 )
 from apex_tpu.models.gpt import (  # noqa: F401

@@ -38,10 +38,12 @@ class GPTStage(nn.Module):
 
     def setup(self):
         cfg = self.config
-        if cfg.sliding_window_pattern > 1 or cfg.no_rope_layer_interval:
+        if (cfg.sliding_window_pattern > 1 or cfg.no_rope_layer_interval
+                or cfg.layer_attention is not None):
             raise ValueError(
-                "per-layer alternation (sliding_window_pattern > 1 or "
-                "no_rope_layer_interval) is not supported under SPMD "
+                "per-layer alternation (sliding_window_pattern > 1, "
+                "no_rope_layer_interval or layer_attention) is not "
+                "supported under SPMD "
                 "pipelining: every stage runs the same program with "
                 "per-stage layer numbering, so the alternation would "
                 "silently restart at each stage boundary")

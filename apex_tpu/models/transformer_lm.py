@@ -37,21 +37,68 @@ class RopeScaling:
     wavelengths shorter than ``original_max/high_freq_factor``, divides
     those longer than ``original_max/low_freq_factor`` by ``factor``,
     and smoothly interpolates in between
-    (_compute_llama3_parameters). All-scalar and frozen, so
-    TransformerConfig remains hashable for static jit arguments."""
+    (_compute_llama3_parameters). ``rope_type="yarn"`` (YaRN,
+    arXiv:2309.00071; HF _compute_yarn_parameters) keeps the frequencies
+    that turn more than ``beta_fast`` times over
+    ``original_max_position_embeddings``, divides those that turn fewer
+    than ``beta_slow`` times by ``factor``, ramps linearly between (the
+    correction range floored and ceiled), and multiplies cos and sin by
+    ``attention_factor`` (None: ``0.1 ln(factor) + 1``). All-scalar and
+    frozen, so TransformerConfig remains hashable for static jit
+    arguments."""
 
     rope_type: str = "llama3"
     factor: float = 8.0
     low_freq_factor: float = 1.0
     high_freq_factor: float = 4.0
     original_max_position_embeddings: int = 8192
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: Optional[float] = None
 
 
-def _scale_rope_freqs(inv, scaling: RopeScaling):
+ROPE_TYPES = ("linear", "llama3", "yarn")
+
+
+def yarn_correction_range(scaling: RopeScaling, base: float, dim: int):
+    """``(low, high)``: the frequency indices between which YaRN ramps
+    from the extrapolated frequencies to the interpolated ones, for a
+    rotary width of ``dim`` channels (HF find_correction_range with
+    ``truncate``)."""
+    import math
+
+    def at(rotations):
+        return (dim * math.log(scaling.original_max_position_embeddings
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    return (max(math.floor(at(scaling.beta_fast)), 0),
+            min(math.ceil(at(scaling.beta_slow)), dim - 1))
+
+
+def rope_attention_factor(scaling: Optional[RopeScaling]) -> float:
+    """What cos and sin are multiplied by: YaRN's ``attention_factor``
+    (``0.1 ln(factor) + 1`` where none is given), 1 otherwise."""
+    import math
+
+    if scaling is None or scaling.rope_type != "yarn":
+        return 1.0
+    if scaling.attention_factor is not None:
+        return float(scaling.attention_factor)
+    return 0.1 * math.log(scaling.factor) + 1.0 if scaling.factor > 1 \
+        else 1.0
+
+
+def _scale_rope_freqs(inv, scaling: RopeScaling, base=None, dim=None):
     import math
 
     if scaling.rope_type == "linear":
         return inv / scaling.factor
+    if scaling.rope_type == "yarn":
+        low, high = yarn_correction_range(scaling, base, dim)
+        ramp = jnp.clip((jnp.arange(inv.shape[0], dtype=jnp.float32) - low)
+                        / ((high - low) or 0.001), 0.0, 1.0)
+        return inv / scaling.factor * ramp + inv * (1.0 - ramp)
     if scaling.rope_type != "llama3":
         raise ValueError(f"unknown rope_type {scaling.rope_type!r}")
     old_len = scaling.original_max_position_embeddings
@@ -64,6 +111,33 @@ def _scale_rope_freqs(inv, scaling: RopeScaling):
     smoothed = ((1 - smooth) * scaled / scaling.factor + smooth * scaled)
     medium = (wavelen >= high_wavelen) & (wavelen <= low_wavelen)
     return jnp.where(medium, smoothed, scaled)
+
+
+def _check_rope_scaling(scaling: RopeScaling):
+    if scaling.rope_type not in ROPE_TYPES:
+        raise ValueError(f"unknown rope_type {scaling.rope_type!r}; "
+                         f"expected one of {ROPE_TYPES}")
+    if scaling.factor < 1.0:
+        raise ValueError(f"rope_scaling.factor ({scaling.factor}) must be "
+                         f">= 1")
+
+
+@dataclasses.dataclass(frozen=True)
+class AttentionKind:
+    """One kind of attention layer in a stack whose layers differ
+    (``TransformerConfig.attention_kinds``, Laguna's ``layer_types``):
+    its sliding window (None: full causal), its query heads and its rotary
+    positions. ``heads``, ``rotary_base``, ``rotary_percent`` and
+    ``rope_scaling`` left None take the config's ``num_attention_heads``,
+    ``rotary_base``, ``rotary_percent`` and ``rope_scaling``. Frozen, so
+    the config stays hashable."""
+
+    name: str
+    window: Optional[int] = None
+    heads: Optional[int] = None
+    rotary_base: Optional[float] = None
+    rotary_percent: Optional[float] = None
+    rope_scaling: Optional[RopeScaling] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -307,6 +381,26 @@ class TransformerConfig:
     # half alone (models/gpt.py; the loss is ``block_diffusion_loss_fn``).
     # Training only. None -> rows are what they are everywhere else.
     diffusion_block_length: Optional[int] = None
+    # Attention kinds by layer (Laguna: full attention first in each period
+    # of four, a 512-token window on the other three, each kind with its
+    # own query heads and rotary): ``attention_kinds`` holds one
+    # AttentionKind a kind, ``layer_attention`` the kind's name for each of
+    # the num_layers layers, in any order. Every kind keeps
+    # num_query_groups K/V heads. Training on an unrolled stack only: no
+    # decode, scan_layers, layer_pattern, context or sequence parallelism,
+    # pipeline stages, latent or sparse attention, block diffusion, alibi,
+    # and none of the per-layer knobs above (sliding_window and its pattern,
+    # rotary_base_local, no_rope_layer_interval), which the kinds replace.
+    # None -> every layer as those knobs say.
+    attention_kinds: Optional[tuple] = None
+    layer_attention: Optional[tuple] = None
+    # A per-head sigmoid gate on attention's output (Laguna's ``gating``):
+    # g = sigmoid(x W_g), x attention's normed input and W_g [hidden,
+    # heads] with no bias; each head's context is multiplied by its g
+    # before the output projection. Training, on the path of the dense
+    # kernels or the softmax: not with decode, context or sequence
+    # parallelism, latent or sparse attention.
+    attention_output_gate: bool = False
     # Tie the LM head to the word-embedding table (reference
     # parallel_lm_logits ties by default). Off here because the SPMD
     # pipeline harness needs untied heads (first/last stages run the same
@@ -393,15 +487,7 @@ class TransformerConfig:
             if self.position_embedding_type != "rope":
                 raise ValueError("rope_scaling requires "
                                  "position_embedding_type='rope'")
-            if self.rope_scaling.rope_type not in ("linear", "llama3"):
-                raise ValueError(
-                    f"unknown rope_type "
-                    f"{self.rope_scaling.rope_type!r}; expected 'linear' "
-                    f"or 'llama3'")
-            if self.rope_scaling.factor < 1.0:
-                raise ValueError(
-                    f"rope_scaling.factor ({self.rope_scaling.factor}) "
-                    f"must be >= 1")
+            _check_rope_scaling(self.rope_scaling)
         if (self.final_logit_softcapping is not None
                 and self.final_logit_softcapping <= 0):
             raise ValueError(
@@ -584,6 +670,70 @@ class TransformerConfig:
                     f"num_attention_heads ({self.num_attention_heads}) must "
                     f"be a positive multiple of num_query_groups "
                     f"({self.num_query_groups})")
+        if (self.attention_kinds is None) != (self.layer_attention is None):
+            raise ValueError("attention_kinds and layer_attention come "
+                             "together")
+        if self.attention_kinds is not None:
+            self._check_attention_kinds()
+        if self.attention_output_gate and (
+                self.kv_lora_rank is not None
+                or self.indexer_heads is not None
+                or self.context_parallel or self.sequence_parallel):
+            raise ValueError(
+                "attention_output_gate gates the dense attention path: not "
+                "with latent or sparse attention or context / sequence "
+                "parallelism")
+
+    def _check_attention_kinds(self):
+        object.__setattr__(self, "attention_kinds",
+                           tuple(self.attention_kinds))
+        object.__setattr__(self, "layer_attention",
+                           tuple(self.layer_attention))
+        names = [kind.name for kind in self.attention_kinds]
+        if len(set(names)) != len(names) or not names:
+            raise ValueError(f"attention_kinds must name each kind once, "
+                             f"got {names}")
+        if (len(self.layer_attention) != self.num_layers
+                or set(self.layer_attention) - set(names)):
+            raise ValueError(
+                f"layer_attention must give one of {names} for each of the "
+                f"{self.num_layers} layers, got {self.layer_attention}")
+        if (self.attn_mask_type != AttnMaskType.causal
+                or self.position_embedding_type != "rope"
+                or self.sliding_window is not None
+                or self.sliding_window_pattern != 1
+                or self.rotary_base_local is not None
+                or self.no_rope_layer_interval
+                or self.rope_sections is not None
+                or self.scan_layers or self.layer_pattern is not None
+                or self.context_parallel or self.sequence_parallel
+                or self.kv_lora_rank is not None
+                or self.indexer_heads is not None):
+            raise ValueError(
+                "attention_kinds describe causal rope attention layer by "
+                "layer on an unrolled stack: not with another mask rule, "
+                "sliding_window (and its pattern), rotary_base_local, "
+                "no_rope_layer_interval, rope_sections, scan_layers, "
+                "layer_pattern, context or sequence parallelism, latent or "
+                "sparse attention")
+        for kind in self.attention_kinds:
+            heads = kind.heads or self.num_attention_heads
+            percent = kind.rotary_percent or self.rotary_percent
+            if (kind.window is not None and kind.window < 1) \
+                    or heads % self.query_groups or not 0.0 < percent <= 1.0:
+                raise ValueError(
+                    f"attention kind {kind.name!r}: the window must be >= 1, "
+                    f"the heads ({heads}) a multiple of num_query_groups "
+                    f"({self.query_groups}) and rotary_percent in (0, 1]")
+            if kind.rope_scaling is not None:
+                _check_rope_scaling(kind.rope_scaling)
+
+    def attention_kind(self, layer: int) -> Optional[AttentionKind]:
+        """Layer ``layer``'s AttentionKind, or None without kinds."""
+        if self.layer_attention is None:
+            return None
+        name = self.layer_attention[layer]
+        return next(k for k in self.attention_kinds if k.name == name)
 
     @property
     def ffn_size(self):
@@ -675,7 +825,7 @@ def _rope_core(x, base, positions, freq_dim, interleaved=False,
     inv = 1.0 / (base ** (jnp.arange(0, freq_dim, 2, dtype=jnp.float32)
                           / freq_dim))
     if scaling is not None:
-        inv = _scale_rope_freqs(inv, scaling)
+        inv = _scale_rope_freqs(inv, scaling, base, freq_dim)
     freqs = positions[..., None].astype(jnp.float32) * inv  # [s(,b), d/2]
     if _has_components(positions, sections, s):
         # [c, s(,b), d/2]: frequency i keeps the component of its section
@@ -688,6 +838,9 @@ def _rope_core(x, base, positions, freq_dim, interleaved=False,
         freqs = freqs[:, None, :]
     cos = jnp.cos(freqs)[:, :, None, :]
     sin = jnp.sin(freqs)[:, :, None, :]
+    factor = rope_attention_factor(scaling)
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     xf = x.astype(jnp.float32)
     if interleaved:  # GPT-J: pairs are (even, odd) lanes
         x1, x2 = xf[..., 0::2], xf[..., 1::2]
@@ -975,10 +1128,20 @@ class ParallelAttention(nn.Module):
     # sliding_window_pattern (Gemma-2/3 alternation)
     layer_number: int = 0
 
+    def _layer_heads(self):
+        """This layer's query heads: its attention kind's, else
+        num_attention_heads."""
+        kind = self.config.attention_kind(self.layer_number)
+        return (kind and kind.heads) or self.config.num_attention_heads
+
     def _layer_window(self):
         """This layer's sliding window, or None when it runs full causal
-        attention (every sliding_window_pattern-th layer)."""
+        attention (every sliding_window_pattern-th layer; a kind without
+        a window)."""
         cfg = self.config
+        kind = cfg.attention_kind(self.layer_number)
+        if kind is not None:
+            return kind.window
         if cfg.sliding_window is None:
             return None
         if (cfg.sliding_window_pattern > 1
@@ -995,24 +1158,68 @@ class ParallelAttention(nn.Module):
         return (self.layer_number + 1) % cfg.no_rope_layer_interval != 0
 
     def _layer_rope(self):
-        """(rotary_base, rope_scaling) for THIS layer: Gemma-3 gives the
-        windowed (local) layers their own base with no frequency
-        rescaling, while global layers keep rotary_base/rope_scaling."""
+        """(rotary_base, rotary_percent, rope_scaling) for THIS layer: its
+        attention kind's; Gemma-3 gives the windowed (local) layers their
+        own base with no frequency rescaling, while global layers keep
+        rotary_base/rope_scaling."""
         cfg = self.config
+        kind = cfg.attention_kind(self.layer_number)
+        if kind is not None:
+            return (kind.rotary_base or cfg.rotary_base,
+                    kind.rotary_percent or cfg.rotary_percent,
+                    kind.rope_scaling or cfg.rope_scaling)
         if (cfg.rotary_base_local is not None
                 and self._layer_window() is not None):
-            return cfg.rotary_base_local, None
-        return cfg.rotary_base, cfg.rope_scaling
+            return cfg.rotary_base_local, cfg.rotary_percent, None
+        return cfg.rotary_base, cfg.rotary_percent, cfg.rope_scaling
 
     @nn.compact
     def __call__(self, hidden_states, attention_mask=None, position_ids=None):
+        """A layer whose attention runs under a window does all of it,
+        from the QKV projection to the output projection, under the scope
+        ``attention/window``. Under ``attention_kinds`` each layer counts
+        itself at trace time as ``attention/window_layers`` or
+        ``attention/full_layers`` and sets the gauge
+        ``attention/query_heads/<kind>``."""
         cfg = self.config
         tp = get_tensor_model_parallel_world_size()
-        np_local = cfg.num_attention_heads // tp
+        heads = self._layer_heads()
         if cfg.kv_lora_rank is not None:
             return self._latent_attention(cfg, hidden_states,
                                           attention_mask, position_ids,
-                                          np_local)
+                                          heads // tp)
+        if self.decode and (cfg.layer_attention is not None
+                            or cfg.attention_output_gate):
+            raise ValueError(
+                "attention_kinds and attention_output_gate are training "
+                "paths; there is no decode path for them")
+        s = hidden_states.shape[-3]
+        seq_full = s * tp if cfg.sequence_parallel else s
+        # a window covering the whole sequence is plain causal
+        win = self._layer_window()
+        win = win if (win is not None and win < seq_full) else None
+        if cfg.layer_attention is not None:
+            from apex_tpu.telemetry.registry import get_registry
+
+            reg = get_registry()
+            reg.counter("attention/window_layers" if win is not None
+                        else "attention/full_layers").inc()
+            reg.gauge("attention/query_heads/"
+                      + cfg.layer_attention[self.layer_number]).set(heads)
+        if win is None or self.decode:
+            return self._attention(hidden_states, attention_mask,
+                                   position_ids, heads, win)
+        with jax.named_scope("attention/window"):
+            return self._attention(hidden_states, attention_mask,
+                                   position_ids, heads, win)
+
+    def _attention(self, hidden_states, attention_mask, position_ids, heads,
+                   win):
+        """Attention over ``heads`` query heads with the band ``win`` (None:
+        none), from the QKV projection to the output projection."""
+        cfg = self.config
+        tp = get_tensor_model_parallel_world_size()
+        np_local = heads // tp
         kv = cfg.kv_channels
         s, b, h = hidden_states.shape[-3:]
         x = hidden_states.astype(cfg.compute_dtype)
@@ -1070,10 +1277,10 @@ class ParallelAttention(nn.Module):
         def heads_of(t):   # [s, b, n * kv] -> [s, b, n, kv]
             return t.reshape(*t.shape[:-1], -1, kv)
 
-        if cfg.query_groups == cfg.num_attention_heads:
+        if cfg.query_groups == heads:
             qkv = ColumnParallelLinear(
                 input_size=cfg.hidden_size,
-                output_size=3 * cfg.num_attention_heads * kv,
+                output_size=3 * heads * kv,
                 gather_output=False, bias=cfg.attention_bias,
                 params_dtype=cfg.params_dtype,
                 sequence_parallel_enabled=cfg.sequence_parallel,
@@ -1100,8 +1307,7 @@ class ParallelAttention(nn.Module):
             g_local = divide(cfg.query_groups, tp)
             proj = ColumnParallelLinear(
                 input_size=cfg.hidden_size,
-                output_size=(cfg.num_attention_heads
-                             + 2 * cfg.query_groups) * kv,
+                output_size=(heads + 2 * cfg.query_groups) * kv,
                 gather_output=False, bias=cfg.attention_bias,
                 params_dtype=cfg.params_dtype,
                 sequence_parallel_enabled=cfg.sequence_parallel,
@@ -1154,13 +1360,13 @@ class ParallelAttention(nn.Module):
 
         if (cfg.position_embedding_type == "rope"
                 and self._layer_uses_rope()):
-            rope_base, rope_scale = self._layer_rope()
+            rope_base, rope_percent, rope_scale = self._layer_rope()
             q = apply_rotary_emb(q, rope_base, position_ids,
-                                 cfg.rotary_percent,
+                                 rope_percent,
                                  cfg.rotary_interleaved,
                                  rope_scale, cfg.rope_sections)
             k = apply_rotary_emb(k, rope_base, position_ids,
-                                 cfg.rotary_percent,
+                                 rope_percent,
                                  cfg.rotary_interleaved,
                                  rope_scale, cfg.rope_sections)
         if k.shape[2] != np_local:
@@ -1168,12 +1374,6 @@ class ParallelAttention(nn.Module):
             rep = np_local // k.shape[2]
             k = jnp.repeat(k, rep, axis=2)
             v = jnp.repeat(v, rep, axis=2)
-
-        # a window covering the whole sequence is plain causal
-        layer_win = self._layer_window()
-        win = (layer_win
-               if (layer_win is not None and layer_win < seq_full)
-               else None)
 
         if cfg.indexer_heads is not None:
             from apex_tpu.contrib.fmha import sparse_attention
@@ -1276,7 +1476,28 @@ class ParallelAttention(nn.Module):
             ctx = ctx.transpose(2, 0, 1, 3)  # [s, b, n, d]
 
         ctx = ctx.reshape(ctx.shape[0], b, np_local * kv)
+        if cfg.attention_output_gate:
+            ctx = self._output_gate(cfg, x, ctx, heads)
         return self._output_proj(cfg, ctx)
+
+    def _output_gate(self, cfg, x, ctx, heads):
+        """Laguna's per-head output gate, under the scope ``attention/gate``:
+        each head's context times ``sigmoid(x W_g)`` of its head, ``x``
+        attention's normed input ``[s, b, hidden]`` and ``W_g`` ``[hidden,
+        heads]`` (column-parallel over the heads, no bias). Counted at trace
+        time as ``attention/gate_layers``."""
+        from apex_tpu.telemetry.registry import get_registry
+
+        get_registry().counter("attention/gate_layers").inc()
+        with jax.named_scope("attention/gate"):
+            g = ColumnParallelLinear(
+                input_size=cfg.hidden_size, output_size=heads,
+                gather_output=False, bias=False,
+                params_dtype=cfg.params_dtype, name="gate")(x)
+            g = jax.nn.sigmoid(g.astype(jnp.float32))
+            s, b, width = ctx.shape
+            ctx = ctx.reshape(s, b, g.shape[-1], -1) * g[..., None]
+            return ctx.reshape(s, b, width).astype(cfg.compute_dtype)
 
     def _latent_attention(self, cfg, hidden_states, attention_mask,
                           position_ids, n):
@@ -1379,7 +1600,7 @@ class ParallelAttention(nn.Module):
         """Shared row-parallel output projection (both attention paths —
         keep them on ONE 'dense' module so numerics can't diverge)."""
         return RowParallelLinear(
-            input_size=cfg.num_attention_heads * cfg.kv_channels,
+            input_size=self._layer_heads() * cfg.kv_channels,
             output_size=cfg.hidden_size,
             input_is_parallel=True, bias=cfg.attention_bias,
             params_dtype=cfg.params_dtype,
@@ -1412,13 +1633,13 @@ class ParallelAttention(nn.Module):
                 except Exception:
                     rank = 0
                 position_ids = rank * s + jnp.arange(s)
-            rope_base, rope_scale = self._layer_rope()
+            rope_base, rope_percent, rope_scale = self._layer_rope()
             q = apply_rotary_emb(q, rope_base, position_ids,
-                                 cfg.rotary_percent,
+                                 rope_percent,
                                  cfg.rotary_interleaved,
                                  rope_scale)
             k = apply_rotary_emb(k, rope_base, position_ids,
-                                 cfg.rotary_percent,
+                                 rope_percent,
                                  cfg.rotary_interleaved,
                                  rope_scale)
         if k.shape[2] != np_local:
@@ -1459,13 +1680,13 @@ class ParallelAttention(nn.Module):
                 and self._layer_uses_rope()):
             pos = (position_ids if position_ids is not None
                    else idx + jnp.arange(s))
-            rope_base, rope_scale = self._layer_rope()
+            rope_base, rope_percent, rope_scale = self._layer_rope()
             q = apply_rotary_emb(q, rope_base, pos,
-                                 cfg.rotary_percent,
+                                 rope_percent,
                                  cfg.rotary_interleaved,
                                  rope_scale)
             k = apply_rotary_emb(k, rope_base, pos,
-                                 cfg.rotary_percent,
+                                 rope_percent,
                                  cfg.rotary_interleaved,
                                  rope_scale)
         if not initialized:

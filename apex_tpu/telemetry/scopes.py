@@ -88,9 +88,13 @@ _COLLECTIVE = re.compile(r"ddp_allreduce_bucket_\d+|pp_\w+")
 _MODEL = re.compile(r"layer_\d+|layers?|transformer|\w+Model")
 # sub-blocks of attention: the two projections by their module names, the
 # kernel by a Pallas call (contrib/fmha.py and kernels/softmax.py name
-# theirs; an unnamed one ends in ``pallas_call``)
+# theirs; an unnamed one ends in ``pallas_call``), the per-head output gate
+# by its scope ``attention/gate``
 _ATTENTION_PARTS = {"query_key_value": "qkv", "dense": "dense",
-                    "pallas_call": "kernel"}
+                    "pallas_call": "kernel", "gate": "gate"}
+# a layer whose attention runs under a sliding window opens the scope
+# ``attention/window`` around all of it: its parts read
+# ``attention/window/<part>``, inside block attention
 # sub-blocks of the Mamba-2 mixer: the scopes it opens under ``ssm/``
 _SSM_PARTS = ("in_proj", "conv", "scan", "gate_norm", "out_proj")
 # sub-blocks of latent attention: the scopes it opens under ``mla/``
@@ -118,8 +122,10 @@ def classify(scope: str) -> tuple:
 
     ``block`` is set by the first component of the path that the table
     knows: ``embedding``, ``layernorm``, ``attention`` (``attention/qkv``,
-    ``attention/kernel``, ``attention/dense`` where a later component
-    says which part; ``indexer`` where a later component is the
+    ``attention/kernel``, ``attention/gate``, ``attention/dense`` where a
+    later component says which part, ``attention/window`` and
+    ``attention/window/<part>`` under a window layer's scope
+    ``attention/window``; ``indexer`` where a later component is the
     sparse-attention indexer), ``mlp`` (``moe`` where a later component
     is one of the expert layer's scopes), ``ssm`` (``ssm/in_proj``,
     ``ssm/conv``, ``ssm/scan``, ``ssm/gate_norm``, ``ssm/out_proj`` where
@@ -159,9 +165,12 @@ def classify(scope: str) -> tuple:
             return (f"{block}/{part}" if part in _PARTS[block]
                     else block), phase
         if block == "attention":
-            for sub in parts[i + 1:]:
+            rest = parts[i + 1:]
+            if ("attention", "window") in zip(rest, rest[1:]):
+                block = "attention/window"
+            for sub in rest:
                 if sub in _ATTENTION_PARTS:
-                    return f"attention/{_ATTENTION_PARTS[sub]}", phase
+                    return f"{block}/{_ATTENTION_PARTS[sub]}", phase
             return block, phase
         if block is not None:
             return block, phase

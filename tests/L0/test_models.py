@@ -261,7 +261,8 @@ def _equations(jaxpr):
 
 def _flash_forwards(grad, params, hidden):
     return sum(eqn.primitive.name == "pallas_call"
-               and eqn.params["name"] == "self_attention_flash_fwd"
+               and eqn.params["name"] in ("self_attention_flash_fwd",
+                                          "window_attention_flash_fwd")
                for eqn in _equations(
                    jax.make_jaxpr(grad)(params, hidden).jaxpr))
 
