@@ -14,16 +14,19 @@ the lanes; a grid cell takes the heads of one 128-lane column), and
 (queries and keys of a positionless and a rotary part beside narrower
 values, the rotary key one vector a token shared by the heads).
 
-Forward and backward are Pallas kernels over 3-D grids (batch*heads x
-outer-blocks x streamed-blocks, innermost/"arbitrary"): K/V (forward, dq)
-or Q/dO (dk/dv) stream through VMEM one tile at a time with fp32 scratch
-accumulators, so VMEM use is independent of sequence length (validated to
-seq 65536 on-chip; see PERF.md). The forward emits the per-row
-log-sum-exp; the backward recomputes p = exp(q k^T scale - lse) per tile
-(flash-attention v2 style) instead of materializing the [s, s] matrix.
+Forward and backward are Pallas kernels over 2-D grids (batch*heads x
+visits, the second axis "arbitrary"): the visits are the (q tile, kv
+tile) pairs that run, in a list the call builds from its shapes
+(:func:`_visit_list`) and hands the kernels by scalar prefetch. K/V
+(forward, dq) or Q/dO (dk/dv) stream through VMEM one tile at a time with
+fp32 scratch accumulators, so VMEM use is independent of sequence length
+(validated to seq 65536 on-chip; see PERF.md). The forward emits the
+per-row log-sum-exp; the backward recomputes p = exp(q k^T scale - lse)
+per tile (flash-attention v2 style) instead of materializing the [s, s]
+matrix.
 
-Causal, a tile above the diagonal (or outside a window's band) is skipped
-and never fetched, and every tile that runs is masked by position. A call
+Causal, a tile above the diagonal (or outside a window's band) is not
+visited, and every tile that runs is masked by position. A call
 with a window names its kernels ``window_attention_*`` (the others are
 ``self_attention_*``), so that a trace reads the band apart. One
 class of tile does less than that: with square blocks and no window (what
@@ -59,10 +62,11 @@ GATE = kernel_gate("flash_attention")
 
 # 512x512 measured fastest on-chip at seq 8192 (8.0 TFLOP/s vs 3.8 at
 # 128x128); both are min()'d down for shorter sequences. Causal, a tile of
-# these blocks is skipped (above the diagonal) or runs whole under the
-# mask; the forward and dq run a tile on the diagonal in strips that leave
-# out the keys no row of the strip sees: at 1024 positions two of the
-# three tiles that run, at 8192 16 of 136.
+# these blocks above the diagonal is not visited (the grid walks the 3 of
+# 4 tiles that run at 1024 positions, 136 of 256 at 8192); a tile that
+# runs runs whole under the mask, but the forward and dq run a tile on the
+# diagonal in strips that leave out the keys no row of the strip sees: at
+# 1024 positions two of the three, at 8192 16 of 136.
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 NEG_INF = -1e30
@@ -140,7 +144,8 @@ def _alibi_bias(slopes_ref, k0, shape):
 
 
 def _stream_kv_run(qi, kj, block_q, block_k, causal, window, rule=None):
-    """Does kv block kj contribute to q block qi? (fwd / dq kernels)"""
+    """Does kv block kj contribute to q block qi? Numpy over a grid of
+    tiles (:func:`_tile_runs`), or traced in the sparse kernels."""
     if rule is not None:
         return rule.tile_runs(qi, kj, block_q, block_k)
     if not causal:
@@ -151,61 +156,106 @@ def _stream_kv_run(qi, kj, block_q, block_k, causal, window, rule=None):
     return run
 
 
-def _stream_q_run(qi, kj, block_q, block_k, causal, window, rule=None):
-    """Does q block qi contribute to kv block kj? (dkv kernel)"""
-    if rule is not None:
-        return rule.tile_runs(qi, kj, block_q, block_k)
-    if not causal:
-        return True
-    run = (qi + 1) * block_q - 1 >= kj * block_k
-    if window is not None:
-        run = run & (qi * block_q <= _window_last_q_pos(kj, block_k,
-                                                        window))
-    return run
+def _tile_runs(seq, block_q, block_k, causal, window=None, rule=None):
+    """``[nq, nk]`` booleans from shapes alone: which (q tile, kv tile)
+    pairs of one head's ``seq x seq`` scores run."""
+    nq, nk = seq // block_q, seq // block_k
+    qi, kj = np.ogrid[:nq, :nk]
+    return np.broadcast_to(
+        _stream_kv_run(qi, kj, block_q, block_k, causal, window, rule),
+        (nq, nk))
 
 
-def _window_first_kv_block(qi, block_q, block_k, window):
-    """First kv block inside the band for q block qi (index-map clamp;
-    must stay consistent with _stream_kv_run's lower bound)."""
-    return jnp.maximum(qi * block_q - window + 1, 0) // block_k
+# ------------------------------------------------------- the walk's visits
+#
+# A dense call knows from its shapes which tiles run, so its grid is
+# ``(b * heads or cells, visits)``: the second axis walks a list of the
+# tiles that run, built in numpy at trace time and handed to the kernels
+# as a scalar-prefetched int32 operand, and no grid step is spent on a
+# tile that does not run. The forward and dq walk the list q-major (a q
+# tile's kv tiles ascending), dkv kv-major (a kv tile's q tiles
+# ascending): the order in which a full grid meets the tiles that run, so
+# every accumulator sums the same terms in the same order. A row's visits
+# are consecutive: its output block stays in VMEM over them and is
+# written back once, and an operand block that a row's visits share is
+# fetched once. The index maps read each visit's q and kv tile, so every
+# fetch is of a tile that runs. A visit is one int32: the q tile in bits
+# 16-30, the kv tile in bits 2-15, and whether it is the first and the
+# last visit of its row of the walk in bits 1 and 0 (the accumulators
+# start at the first; the row's output is written at the last). The list
+# lives in SMEM (1 MiB on a v5e: a quarter of a million visits).
+
+_KV_TILE = 2 ** 14 - 1      # the mask of a visit's kv tile, after >> 2
 
 
-def _window_last_q_pos(kj, block_k, window):
-    """Largest query index that can see any key in kv block kj."""
-    return (kj + 1) * block_k - 1 + window - 1
+def _visit_list(seq, block_q, block_k, causal, window=None, rule=None,
+                kv_major=False):
+    """The tiles that run, in the order a call's walk visits them: numpy
+    int32 ``[visits]``, one packed visit each (:func:`_visit`)."""
+    run = _tile_runs(seq, block_q, block_k, causal, window, rule)
+    rows, cols = np.nonzero(run.T if kv_major else run)
+    q, kv = (cols, rows) if kv_major else (rows, cols)
+    new_row = rows[1:] != rows[:-1]
+    first = np.concatenate([[True], new_row]).astype(np.int64)
+    last = np.concatenate([new_row, [True]]).astype(np.int64)
+    return (q << 16 | kv << 2 | first << 1 | last).astype(np.int32)
 
 
-def _fetched_kv_block(qi, kj, block_q, block_k, causal, window, rule=None):
-    """The kv block cell (qi, kj) of the forward / dq grid fetches. Causal:
-    masked blocks are clamped into the contributing range; Pallas skips
-    the DMA when a block index repeats, so fully-above-diagonal (and,
-    windowed, fully-below-band) K/V tiles are never fetched. Under a
-    ``rule`` (:class:`_BlockDiffusion`) the same, into its two ranges."""
-    if rule is not None:
-        return rule.fetched_kv_block(qi, kj, block_q, block_k)
-    if not causal:
-        return kj
-    last = ((qi + 1) * block_q - 1) // block_k
-    kj = jnp.minimum(kj, last)
-    if window is not None:
-        kj = jnp.maximum(kj, _window_first_kv_block(
-            qi, block_q, block_k, window))
-    return kj
+class _Step(NamedTuple):
+    """Where a kernel body's grid step stands: its q and kv tile, whether
+    it opens its row of the walk (the accumulators start), whether it
+    closes it (the row's output is written) and whether its tile runs: a
+    Python ``True`` where the walk visits only tiles that run (every
+    dense call), traced in the sparse calls, whose full grids visit every
+    tile."""
+
+    qi: object
+    kj: object
+    first: object
+    last: object
+    run: object = True
 
 
-def _fetched_q_block(kj, qi, block_q, block_k, causal, window, rule=None):
-    """The q block cell (kj, qi) of the dkv grid fetches (as
-    :func:`_fetched_kv_block`, for the streamed q side)."""
-    if rule is not None:
-        return rule.fetched_q_block(kj, qi, block_q, block_k)
-    if not causal:
-        return qi
-    first = (kj * block_k) // block_q
-    qi = jnp.maximum(qi, first)
-    if window is not None:
-        qi = jnp.minimum(
-            qi, _window_last_q_pos(kj, block_k, window) // block_q)
-    return qi
+def _q_tile(visits_ref, v):
+    return visits_ref[v] >> 16
+
+
+def _kv_tile(visits_ref, v):
+    return (visits_ref[v] >> 2) & _KV_TILE
+
+
+def _visit(visits_ref, v):
+    """Visit ``v`` of the list, as the body's :class:`_Step`."""
+    code = visits_ref[v]
+    return _Step(code >> 16, (code >> 2) & _KV_TILE, (code & 2) != 0,
+                 (code & 1) != 0)
+
+
+def _walk(body, visits, grid_cells, in_specs, out_specs, out_shape,
+          scratch_shapes, name, interpret):
+    """``pl.pallas_call`` of ``body`` over the ``(grid_cells, visits)``
+    grid, the body given each visit as its ``step``; the caller passes the
+    operands, which follow the visit list. Index maps take ``(cell, v,
+    visits_ref)``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def kernel(visits_ref, *refs):
+        body(*refs, step=_visit(visits_ref, pl.program_id(1)))
+
+    call = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(grid_cells, visits.size),
+            in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=scratch_shapes),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name=name,
+    )
+    return functools.partial(call, jnp.asarray(visits))
 
 
 # ------------------------------------------------- the block-diffusion rule
@@ -227,10 +277,10 @@ def _fetched_q_block(kj, qi, block_q, block_k, causal, window, rule=None):
 # ``block`` divides them, so the tiles that run are whole ranges: for a
 # clean q tile the clean kv tiles up to its own; for a noisy one the clean
 # kv tiles that hold an earlier block and the noisy ones that overlap it.
-# The index maps clamp a skipped tile into those ranges, so it is neither
-# run nor fetched. Every tile that runs runs whole under the mask (no
-# strips: the rule's diagonal is not a triangle's). These calls are named
-# ``blockdiff_attention_*``.
+# The visit list (:func:`_visit_list`) holds those ranges alone, so a tile
+# outside them is neither visited nor fetched. Every tile that runs runs
+# whole under the mask (no strips: the rule's diagonal is not a
+# triangle's). These calls are named ``blockdiff_attention_*``.
 
 BLOCKDIFF_ENTRY = "flash_attention_blockdiff"   # the calls' dispatch record
 # a windowed call's record, beside its entry's own: the band's tiles read
@@ -264,40 +314,6 @@ class _BlockDiffusion(NamedTuple):
         return ((~noisy_q & ~noisy_k & clean_clean)
                 | (noisy_q & ~noisy_k & noisy_clean)
                 | (noisy_q & noisy_k & noisy_noisy))
-
-    def fetched_kv_block(self, qi, kj, block_q, block_k):
-        """The kv tile cell (qi, kj) of the forward / dq grid fetches: a
-        tile that runs for ``qi``, the same as its neighbour's wherever
-        ``kj`` itself does not run."""
-        nk = self.length // block_k
-        noisy_q, q0 = self._half(qi, block_q)
-        q_last = q0 + block_q - 1
-        # clean keys [0, last_clean] (none at -1), then, for a noisy q
-        # tile, the noisy keys [first_noisy, last_noisy]
-        last_clean = jnp.where(noisy_q, (q_last - self.block) // block_k,
-                               q_last // block_k)
-        first_noisy = jnp.where(noisy_q, nk + q0 // block_k, last_clean)
-        last_noisy = jnp.where(noisy_q, nk + q_last // block_k, last_clean)
-        return jnp.where(kj <= last_clean, kj,
-                         jnp.clip(kj, first_noisy, last_noisy))
-
-    def fetched_q_block(self, kj, qi, block_q, block_k):
-        """The q tile cell (kj, qi) of the dkv grid fetches (as
-        :meth:`fetched_kv_block`, for the streamed q side)."""
-        nq = self.length // block_q
-        noisy_k, k0 = self._half(kj, block_k)
-        # a clean kv tile: the clean q tiles from its own on, then the
-        # noisy ones that hold a later block (none where first_noisy
-        # reaches 2 * nq); a noisy kv tile: the noisy q tiles over it
-        first_clean = k0 // block_q
-        first_noisy = nq + (k0 + self.block) // block_q
-        seen_by_clean = jnp.where(
-            qi < nq, jnp.maximum(qi, first_clean),
-            jnp.where(first_noisy < 2 * nq, jnp.maximum(qi, first_noisy),
-                      nq - 1))
-        seen_by_noisy = jnp.clip(qi, nq + k0 // block_q,
-                                 nq + (k0 + block_k - 1) // block_q)
-        return jnp.where(noisy_k, seen_by_noisy, seen_by_clean)
 
     def _block_of(self, positions):
         # the kernels take a power of two (:func:`_resolve_sizes`): a shift
@@ -388,19 +404,28 @@ def _diagonal_strips(block_q, block_k, causal, window):
             for r in range(0, block_q, STRIP)]
 
 
+def _when(run, f):
+    """``f`` under ``run``, or ``f`` itself where ``run`` is a Python
+    ``True``."""
+    from jax.experimental import pallas as pl
+
+    if run is True:
+        f()
+    else:
+        pl.when(run)(f)
+
+
 def _run_tile(step, run, qi, kj, block_q, block_k, causal, window):
     """Emit ``step(parts, in_strips)`` under ``run``: for a tile on the
     diagonal over its strips, where the call has them, and for every
     other tile (every tile, where it has none) over the whole of it."""
-    from jax.experimental import pallas as pl
-
     whole = [(slice(0, block_q), slice(0, block_k))]
     strips = _diagonal_strips(block_q, block_k, causal, window)
     if strips is None:
-        pl.when(run)(functools.partial(step, whole, False))
+        _when(run, functools.partial(step, whole, False))
         return
-    pl.when(run & (qi == kj))(functools.partial(step, strips, True))
-    pl.when(run & (qi != kj))(functools.partial(step, whole, False))
+    _when(_and_run(run, qi == kj), functools.partial(step, strips, True))
+    _when(_and_run(run, qi != kj), functools.partial(step, whole, False))
 
 
 def _mask_strip(s):
@@ -417,20 +442,19 @@ def _tile_classes(seq, block_q, block_k, causal, window=None, rule=None):
     """What the bodies do over one head's ``seq x seq`` scores, from
     shapes alone: the tiles the forward and dq run in strips
     (``tiles_diagonal``; dkv runs them whole), the tiles all three run
-    whole (``tiles_whole``) and the tiles none runs or fetches
-    (``tiles_skipped``), the (query, key) pairs the forward's and dq's
+    whole (``tiles_whole``) and the tiles none visits or fetches
+    (``tiles_skipped``), the grid steps each kernel walks over a head
+    (``grid_steps``: the visits of :func:`_visit_list`, every tile that
+    runs and no other), the (query, key) pairs the forward's and dq's
     steps compute and the pairs a query sees (``pairs_computed``,
     ``pairs_visible``). The fields of the entries' dispatch records.
     Under a ``rule`` (block diffusion over ``seq`` = ``2L``) every tile
     that runs runs whole, and a query sees ``L + block`` keys on
     average."""
-    nq, nk = seq // block_q, seq // block_k
-    qi, kj = np.ogrid[:nq, :nk]
-    run = np.broadcast_to(
-        _stream_kv_run(qi, kj, block_q, block_k, causal, window, rule),
-        (nq, nk))
+    run = _tile_runs(seq, block_q, block_k, causal, window, rule)
+    nq, nk = run.shape
     strips = _diagonal_strips(block_q, block_k, causal, window)
-    diagonal = int((run & (qi == kj)).sum()) if strips else 0
+    diagonal = int(np.trace(run)) if strips else 0
     whole = int(run.sum()) - diagonal
     visible = seq * seq
     if rule is not None:
@@ -442,6 +466,7 @@ def _tile_classes(seq, block_q, block_k, causal, window=None, rule=None):
         "tiles_diagonal": diagonal,
         "tiles_whole": whole,
         "tiles_skipped": nq * nk - diagonal - whole,
+        "grid_steps": diagonal + whole,
         "pairs_computed": whole * block_q * block_k + diagonal * sum(
             STRIP * cols.stop for _, cols in strips or ()),
         "pairs_visible": visible,
@@ -454,41 +479,35 @@ def _selected(sel_ref, rows=slice(None), cols=slice(None)):
     return sel_ref[0, rows, cols].astype(jnp.int32) != 0
 
 
-def _and_run(run, tile_selected):
-    """``run`` (a Python ``True`` off the causal path) and the tile's
-    flag, where the call carries a selection."""
-    if tile_selected is None:
+def _and_run(run, cond):
+    """``run`` and ``cond`` (``cond`` alone where ``run`` is a Python
+    ``True``; ``run`` alone where ``cond`` is ``None``)."""
+    if cond is None:
         return run
-    return tile_selected if run is True else run & tile_selected
+    return cond if run is True else run & cond
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, slopes_ref, o_ref, lse_ref,
-                      acc_ref, m_ref, l_ref, *, scale, causal, block_q,
-                      block_k, num_kv, window, alibi, sel_ref=None,
-                      tile_selected=None, rope=None, rule=None):
-    """One (head, q-block, kv-block) grid cell of online-softmax attention.
+                      acc_ref, m_ref, l_ref, *, step, scale, causal,
+                      block_q, block_k, window, alibi, sel_ref=None,
+                      rope=None, rule=None):
+    """One (q tile, kv tile) ``step`` of online-softmax attention.
 
     K/V arrive as [1, block_k, d] VMEM tiles streamed by the grid — VMEM
     use is independent of sequence length (the previous design staged the
     FULL [seq, d] K/V per program, which Mosaic refuses to compile beyond
-    seq ~8k). The kv axis is the innermost, "arbitrary" grid dimension;
-    running (acc, m, l) state lives in scratch across its iterations.
+    seq ~8k). A q tile's kv tiles are consecutive steps of the "arbitrary"
+    grid axis; running (acc, m, l) state lives in scratch across them.
     """
     from jax.experimental import pallas as pl
 
-    qi = pl.program_id(1)
-    kj = pl.program_id(2)
+    qi, kj = step.qi, step.kj
 
-    @pl.when(kj == 0)
+    @pl.when(step.first)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
-
-    # Causal: kv blocks entirely above the diagonal (or, windowed, fully
-    # below the band) contribute nothing.
-    run = _and_run(_stream_kv_run(qi, kj, block_q, block_k, causal, window,
-                                  rule), tile_selected)
 
     def _step(parts, in_strips):
         q = q_ref[0].astype(jnp.float32) * scale
@@ -526,9 +545,9 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, slopes_ref, o_ref, lse_ref,
             acc_ref[rows] = acc_ref[rows] * alpha + jnp.dot(
                 p, v[cols], preferred_element_type=jnp.float32)
 
-    _run_tile(_step, run, qi, kj, block_q, block_k, causal, window)
+    _run_tile(_step, step.run, qi, kj, block_q, block_k, causal, window)
 
-    @pl.when(kj == num_kv - 1)
+    @pl.when(step.last)
     def _finish():
         l = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
@@ -554,9 +573,28 @@ def _kernel_name(rule, which, window=None):
             else "self_attention") + "_flash_" + which
 
 
+def _head_major_specs(d, block_q, block_k):
+    """BlockSpecs over a ``(b * n, visits)`` walk of ``[b * n, s, *]``
+    operands: the q tile's and the kv tile's blocks of a visit, a head's
+    slope."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def spec(width, block, tile):
+        return pl.BlockSpec((1, block, width),
+                            lambda h, v, visits: (h, tile(visits, v), 0),
+                            memory_space=pltpu.VMEM)
+
+    return {
+        "q": spec(d, block_q, _q_tile), "kv": spec(d, block_k, _kv_tile),
+        "row": spec(1, block_q, _q_tile),
+        "slopes": pl.BlockSpec((1, 1, 1), lambda h, v, visits: (h, 0, 0),
+                               memory_space=pltpu.VMEM),
+    }
+
+
 def _flash_fwd_pallas(q, k, v, scale, causal, block_q, block_k,
                       window=None, alibi_slopes=None, rule=None):
-    from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, n, s, d = q.shape
@@ -565,37 +603,15 @@ def _flash_fwd_pallas(q, k, v, scale, causal, block_q, block_k,
     v3 = v.reshape(b * n, s, d)
     block_q = min(block_q, s)
     block_k = min(block_k, s)
-    num_kv = s // block_k
-    grid = (b * n, s // block_q, num_kv)
-    slopes3 = _slopes_input(alibi_slopes, b, n)
-    kernel = functools.partial(
-        _flash_fwd_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_k=block_k, num_kv=num_kv, window=window,
-        alibi=alibi_slopes is not None, rule=rule)
-
-    def kv_index(h, i, j):
-        return (h, _fetched_kv_block(i, j, block_q, block_k, causal,
-                                     window, rule), 0)
-
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda h, i, j: (h, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d), kv_index,
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d), kv_index,
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, 1), lambda h, i, j: (h, 0, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda h, i, j: (h, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_q, 1), lambda h, i, j: (h, i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
+    sp = _head_major_specs(d, block_q, block_k)
+    out, lse = _walk(
+        functools.partial(
+            _flash_fwd_kernel, scale=scale, causal=causal, block_q=block_q,
+            block_k=block_k, window=window, alibi=alibi_slopes is not None,
+            rule=rule),
+        _visit_list(s, block_q, block_k, causal, window, rule), b * n,
+        in_specs=[sp["q"], sp["kv"], sp["kv"], sp["slopes"]],
+        out_specs=[sp["q"], sp["row"]],
         out_shape=[
             jax.ShapeDtypeStruct((b * n, s, d), q.dtype),
             jax.ShapeDtypeStruct((b * n, s, 1), jnp.float32),
@@ -605,32 +621,25 @@ def _flash_fwd_pallas(q, k, v, scale, causal, block_q, block_k,
             pltpu.VMEM((block_q, 1), jnp.float32),   # running max
             pltpu.VMEM((block_q, 1), jnp.float32),   # running sum
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=GATE.interpret,
-        name=_kernel_name(rule, "fwd", window),
-    )(q3, k3, v3, slopes3)
+        name=_kernel_name(rule, "fwd", window), interpret=GATE.interpret,
+    )(q3, k3, v3, _slopes_input(alibi_slopes, b, n))
     return out.reshape(b, n, s, d), lse.reshape(b, n, s)
 
 
 def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                     slopes_ref, dq_ref, dq_acc, *, scale, causal,
-                     block_q, block_k, num_kv, window, alibi, sel_ref=None,
-                     tile_selected=None, rope=None, rule=None):
-    """dq for one q block, streaming kv blocks (innermost grid dim):
+                     slopes_ref, dq_ref, dq_acc, *, step, scale, causal,
+                     block_q, block_k, window, alibi, sel_ref=None,
+                     rope=None, rule=None):
+    """dq for one q tile, over the kv tiles of its consecutive steps:
     p = exp(q k^T scale - lse); ds = p * (do v^T - delta); dq += ds k scale.
     """
     from jax.experimental import pallas as pl
 
-    qi = pl.program_id(1)
-    kj = pl.program_id(2)
+    qi, kj = step.qi, step.kj
 
-    @pl.when(kj == 0)
+    @pl.when(step.first)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
-
-    run = _and_run(_stream_kv_run(qi, kj, block_q, block_k, causal, window,
-                                  rule), tile_selected)
 
     def _step(parts, in_strips):
         q = q_ref[0].astype(jnp.float32)
@@ -665,9 +674,9 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             if rope is not None:
                 rope.add_dq(ds, scale, rows, cols)
 
-    _run_tile(_step, run, qi, kj, block_q, block_k, causal, window)
+    _run_tile(_step, step.run, qi, kj, block_q, block_k, causal, window)
 
-    @pl.when(kj == num_kv - 1)
+    @pl.when(step.last)
     def _finish():
         dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
         if rope is not None:
@@ -675,28 +684,20 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
-                      slopes_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
-                      scale, causal, block_q, block_k, num_q, window,
-                      alibi, sel_ref=None, tile_selected=None, rope=None,
-                      rule=None):
-    """dk/dv for one kv block, streaming q blocks (innermost grid dim):
+                      slopes_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, step,
+                      scale, causal, block_q, block_k, window, alibi,
+                      sel_ref=None, rope=None, rule=None):
+    """dk/dv for one kv tile, over the q tiles of its consecutive steps:
     dv += p^T do;  dk += ds^T q scale."""
     from jax.experimental import pallas as pl
 
-    kj = pl.program_id(1)
-    qi = pl.program_id(2)
+    qi, kj = step.qi, step.kj
 
-    @pl.when(qi == 0)
+    @pl.when(step.first)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    # Causal: q blocks entirely above this kv block (or, windowed, beyond
-    # the band) contribute nothing.
-    run = _and_run(_stream_q_run(qi, kj, block_q, block_k, causal, window,
-                                 rule), tile_selected)
-
-    @pl.when(run)
     def _step():
         k = k_ref[0].astype(jnp.float32)
         v = v_ref[0].astype(jnp.float32)
@@ -726,7 +727,9 @@ def _flash_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
         if rope is not None:
             rope.add_dk(ds, scale)
 
-    @pl.when(qi == num_q - 1)
+    _when(step.run, _step)
+
+    @pl.when(step.last)
     def _finish():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
@@ -734,7 +737,6 @@ def _flash_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
 
 def _flash_bwd_pallas(q, k, v, o, lse, do, scale, causal, block_q,
                       block_k, window=None, alibi_slopes=None, rule=None):
-    from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, n, s, d = q.shape
@@ -744,77 +746,30 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, scale, causal, block_q,
     # delta_i = rowsum(do_i * o_i) — cheap elementwise+reduce, XLA-fused
     delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32),
                     axis=-1, keepdims=True)
-    num_q = s // block_q
-    num_kv = s // block_k
     slopes3 = _slopes_input(alibi_slopes, b, n)
-    alibi = alibi_slopes is not None
+    static = dict(scale=scale, causal=causal, block_q=block_q,
+                  block_k=block_k, window=window,
+                  alibi=alibi_slopes is not None, rule=rule)
+    sp = _head_major_specs(d, block_q, block_k)
 
-    def kv_index(h, i, j):
-        return (h, _fetched_kv_block(i, j, block_q, block_k, causal,
-                                     window, rule), 0)
-
-    def q_index_for_kv(h, j, i):
-        return (h, _fetched_q_block(j, i, block_q, block_k, causal,
-                                    window, rule), 0)
-
-    dq = pl.pallas_call(
-        functools.partial(_flash_dq_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, num_kv=num_kv,
-                          window=window, alibi=alibi, rule=rule),
-        grid=(b * n, num_q, num_kv),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda h, i, j: (h, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d), kv_index,
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d), kv_index,
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_q, d), lambda h, i, j: (h, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_q, 1), lambda h, i, j: (h, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_q, 1), lambda h, i, j: (h, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, 1), lambda h, i, j: (h, 0, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda h, i, j: (h, i, 0),
-                               memory_space=pltpu.VMEM),
+    dq = _walk(
+        functools.partial(_flash_dq_kernel, **static),
+        _visit_list(s, block_q, block_k, causal, window, rule), b * n,
+        in_specs=[sp["q"], sp["kv"], sp["kv"], sp["q"], sp["row"],
+                  sp["row"], sp["slopes"]],
+        out_specs=sp["q"],
         out_shape=jax.ShapeDtypeStruct((b * n, s, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=GATE.interpret,
-        name=_kernel_name(rule, "dq", window),
+        name=_kernel_name(rule, "dq", window), interpret=GATE.interpret,
     )(q3, k3, v3, do3, lse3, delta, slopes3)
 
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, num_q=num_q,
-                          window=window, alibi=alibi, rule=rule),
-        grid=(b * n, num_kv, num_q),
-        in_specs=[
-            pl.BlockSpec((1, block_k, d), lambda h, j, i: (h, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d), lambda h, j, i: (h, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_q, d), q_index_for_kv,
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_q, d), q_index_for_kv,
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_q, 1), q_index_for_kv,
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_q, 1), q_index_for_kv,
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, 1), lambda h, j, i: (h, 0, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda h, j, i: (h, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d), lambda h, j, i: (h, j, 0),
-                         memory_space=pltpu.VMEM),
-        ],
+    dk, dv = _walk(
+        functools.partial(_flash_dkv_kernel, **static),
+        _visit_list(s, block_q, block_k, causal, window, rule,
+                    kv_major=True), b * n,
+        in_specs=[sp["kv"], sp["kv"], sp["q"], sp["q"], sp["row"],
+                  sp["row"], sp["slopes"]],
+        out_specs=[sp["kv"], sp["kv"]],
         out_shape=[
             jax.ShapeDtypeStruct((b * n, s, d), k.dtype),
             jax.ShapeDtypeStruct((b * n, s, d), v.dtype),
@@ -823,10 +778,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, scale, causal, block_q,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=GATE.interpret,
-        name=_kernel_name(rule, "dkv", window),
+        name=_kernel_name(rule, "dkv", window), interpret=GATE.interpret,
     )(k3, v3, q3, do3, lse3, delta, slopes3)
 
     rs = lambda x: x.reshape(b, n, s, d)  # noqa: E731
@@ -895,7 +847,7 @@ class _HeadSlope:
 
 
 def _bsnd_fwd_kernel(q_ref, k_ref, v_ref, slopes_ref, o_ref, lse_ref,
-                     *scratch, heads, d, num_kv, **kw):
+                     *scratch, heads, d, step, **kw):
     """A cell's heads through :func:`_flash_fwd_kernel`, one after the
     other; ``scratch`` holds (acc, m, l, lse column) a head."""
     from jax.experimental import pallas as pl
@@ -906,28 +858,28 @@ def _bsnd_fwd_kernel(q_ref, k_ref, v_ref, slopes_ref, o_ref, lse_ref,
             _HeadLanes(q_ref, h, d), _HeadLanes(k_ref, h, d),
             _HeadLanes(v_ref, h, d), _HeadSlope(slopes_ref, h),
             _HeadLanes(o_ref, h, d), lse_col, acc_ref, m_ref, l_ref,
-            num_kv=num_kv, **kw)
+            step=step, **kw)
 
-    @pl.when(pl.program_id(2) == num_kv - 1)
+    @pl.when(step.last)
     def _lse_rows():
         for h in range(heads):
             lse_ref[0, 0, h:h + 1, :] = _turned(scratch[4 * h + 3][0])
 
 
 def _bsnd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, slopes_ref,
-                    dq_ref, delta_ref, *scratch, heads, d, **kw):
+                    dq_ref, delta_ref, *scratch, heads, d, step, **kw):
     """As :func:`_bsnd_fwd_kernel` for :func:`_flash_dq_kernel`;
     ``scratch`` holds (dq acc, lse column, delta column) a head. The q
-    block stays over the streamed kv blocks, so its first cell turns the
-    ``lse`` rows and forms delta_i = rowsum(do_i * o_i), which it also
-    writes out, as rows, for the dkv kernel."""
+    block stays over its row's visits, so the first turns the ``lse``
+    rows and forms delta_i = rowsum(do_i * o_i), which it also writes
+    out, as rows, for the dkv kernel."""
     from jax.experimental import pallas as pl
 
     for h in range(heads):
         dq_acc, lse_col, delta_col = scratch[3 * h:3 * h + 3]
         do_h = _HeadLanes(do_ref, h, d)
 
-        @pl.when(pl.program_id(2) == 0)
+        @pl.when(step.first)
         def _columns():
             lse_col[0] = _turned(lse_ref[0, 0, h:h + 1, :])
             delta = jnp.sum(
@@ -941,37 +893,29 @@ def _bsnd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, slopes_ref,
             _HeadLanes(q_ref, h, d), _HeadLanes(k_ref, h, d),
             _HeadLanes(v_ref, h, d), do_h, lse_col, delta_col,
             _HeadSlope(slopes_ref, h), _HeadLanes(dq_ref, h, d), dq_acc,
-            **kw)
+            step=step, **kw)
 
 
 def _bsnd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
-                     slopes_ref, dk_ref, dv_ref, *scratch, heads, d, **kw):
+                     slopes_ref, dk_ref, dv_ref, *scratch, heads, d, step,
+                     **kw):
     """As :func:`_bsnd_fwd_kernel` for :func:`_flash_dkv_kernel`;
     ``scratch`` holds (dk acc, dv acc, lse column, delta column) a head.
-    The q side streams, so every cell that runs turns its rows."""
-    from jax.experimental import pallas as pl
-
-    run = _stream_q_run(pl.program_id(2), pl.program_id(1), kw["block_q"],
-                        kw["block_k"], kw["causal"], kw["window"],
-                        kw.get("rule"))
+    The q side streams, so every visit turns its rows."""
     for h in range(heads):
         dk_acc, dv_acc, lse_col, delta_col = scratch[4 * h:4 * h + 4]
-
-        @pl.when(run)
-        def _columns():
-            lse_col[0] = _turned(lse_ref[0, 0, h:h + 1, :])
-            delta_col[0] = _turned(delta_ref[0, 0, h:h + 1, :])
-
+        lse_col[0] = _turned(lse_ref[0, 0, h:h + 1, :])
+        delta_col[0] = _turned(delta_ref[0, 0, h:h + 1, :])
         _flash_dkv_kernel(
             _HeadLanes(k_ref, h, d), _HeadLanes(v_ref, h, d),
             _HeadLanes(q_ref, h, d), _HeadLanes(do_ref, h, d), lse_col,
             delta_col, _HeadSlope(slopes_ref, h), _HeadLanes(dk_ref, h, d),
-            _HeadLanes(dv_ref, h, d), dk_acc, dv_acc, **kw)
+            _HeadLanes(dv_ref, h, d), dk_acc, dv_acc, step=step, **kw)
 
 
-def _bsnd_specs(heads, d, block_q, block_k, q_of, kv_of):
-    """BlockSpecs over a ``(b * n / c, x, y)`` grid; ``q_of(x, y)`` and
-    ``kv_of(x, y)`` give the q and the kv block a cell fetches."""
+def _bsnd_specs(heads, d, block_q, block_k):
+    """BlockSpecs over a ``(b * n / c, visits)`` walk: the q tile's and
+    the kv tile's blocks of a visit, a cell's slopes."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -982,13 +926,14 @@ def _bsnd_specs(heads, d, block_q, block_k, q_of, kv_of):
         return pl.BlockSpec(shape, index, memory_space=pltpu.VMEM)
 
     return {
-        "q": spec((1, block_q, per_cell * d),
-                  lambda g, x, y: (g // cells, q_of(x, y), g % cells)),
-        "kv": spec((1, block_k, per_cell * d),
-                   lambda g, x, y: (g // cells, kv_of(x, y), g % cells)),
-        "row": spec((1, 1, per_cell, block_q),
-                    lambda g, x, y: (g // cells, g % cells, 0, q_of(x, y))),
-        "slopes": spec((per_cell, 1, 1), lambda g, x, y: (g % cells, 0, 0)),
+        "q": spec((1, block_q, per_cell * d), lambda g, v, visits: (
+            g // cells, _q_tile(visits, v), g % cells)),
+        "kv": spec((1, block_k, per_cell * d), lambda g, v, visits: (
+            g // cells, _kv_tile(visits, v), g % cells)),
+        "row": spec((1, 1, per_cell, block_q), lambda g, v, visits: (
+            g // cells, g % cells, 0, _q_tile(visits, v))),
+        "slopes": spec((per_cell, 1, 1),
+                       lambda g, v, visits: (g % cells, 0, 0)),
     }
 
 
@@ -1011,24 +956,19 @@ _bsnd_jit = functools.partial(jax.jit, static_argnames=(
 @_bsnd_jit
 def _bsnd_fwd_pallas(q, k, v, alibi_slopes, *, heads, scale, causal,
                      block_q, block_k, window, interpret, rule=None):
-    from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, s, width = q.shape
     d = width // heads
     per_cell = _heads_per_cell(heads, d)
     cells = heads // per_cell
-    num_kv = s // block_k
-    sp = _bsnd_specs(
-        heads, d, block_q, block_k, lambda i, j: i,
-        lambda i, j: _fetched_kv_block(i, j, block_q, block_k, causal,
-                                       window, rule))
-    return pl.pallas_call(
+    sp = _bsnd_specs(heads, d, block_q, block_k)
+    return _walk(
         functools.partial(
             _bsnd_fwd_kernel, heads=per_cell, d=d, scale=scale,
-            causal=causal, block_q=block_q, block_k=block_k, num_kv=num_kv,
-            window=window, alibi=alibi_slopes is not None, rule=rule),
-        grid=(b * cells, s // block_q, num_kv),
+            causal=causal, block_q=block_q, block_k=block_k, window=window,
+            alibi=alibi_slopes is not None, rule=rule),
+        _visit_list(s, block_q, block_k, causal, window, rule), b * cells,
         in_specs=[sp["q"], sp["kv"], sp["kv"], sp["slopes"]],
         out_specs=[sp["q"], sp["row"]],
         out_shape=[
@@ -1041,39 +981,29 @@ def _bsnd_fwd_pallas(q, k, v, alibi_slopes, *, heads, scale, causal,
             pltpu.VMEM((block_q, 1), jnp.float32),      # running sum
             pltpu.VMEM((1, block_q, 1), jnp.float32),   # lse column
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-        name=_kernel_name(rule, "fwd", window),
+        name=_kernel_name(rule, "fwd", window), interpret=interpret,
     )(q, k, v, _bsnd_slopes(alibi_slopes, heads))
 
 
 @_bsnd_jit
 def _bsnd_bwd_pallas(q, k, v, o, lse, do, alibi_slopes, *, heads, scale,
                      causal, block_q, block_k, window, interpret, rule=None):
-    from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, s, width = q.shape
     d = width // heads
     per_cell = _heads_per_cell(heads, d)
     cells = heads // per_cell
-    num_q, num_kv = s // block_q, s // block_k
     slopes = _bsnd_slopes(alibi_slopes, heads)
-    params = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
     static = dict(heads=per_cell, d=d, scale=scale, causal=causal,
                   block_q=block_q, block_k=block_k, window=window,
                   alibi=alibi_slopes is not None, rule=rule)
     column = pltpu.VMEM((1, block_q, 1), jnp.float32)
+    sp = _bsnd_specs(heads, d, block_q, block_k)
 
-    sp = _bsnd_specs(
-        heads, d, block_q, block_k, lambda i, j: i,
-        lambda i, j: _fetched_kv_block(i, j, block_q, block_k, causal,
-                                       window, rule))
-    dq, delta = pl.pallas_call(
-        functools.partial(_bsnd_dq_kernel, num_kv=num_kv, **static),
-        grid=(b * cells, num_q, num_kv),
+    dq, delta = _walk(
+        functools.partial(_bsnd_dq_kernel, **static),
+        _visit_list(s, block_q, block_k, causal, window, rule), b * cells,
         in_specs=[sp["q"], sp["kv"], sp["kv"], sp["q"], sp["q"], sp["row"],
                   sp["slopes"]],
         out_specs=[sp["q"], sp["row"]],
@@ -1081,18 +1011,13 @@ def _bsnd_bwd_pallas(q, k, v, o, lse, do, alibi_slopes, *, heads, scale,
                    jax.ShapeDtypeStruct(lse.shape, jnp.float32)],
         scratch_shapes=per_cell * [
             pltpu.VMEM((block_q, d), jnp.float32), column, column],
-        compiler_params=params, interpret=interpret,
-        name=_kernel_name(rule, "dq", window),
+        name=_kernel_name(rule, "dq", window), interpret=interpret,
     )(q, k, v, do, o, lse, slopes)
 
-    sp = _bsnd_specs(
-        heads, d, block_q, block_k,
-        lambda j, i: _fetched_q_block(j, i, block_q, block_k, causal,
-                                      window, rule),
-        lambda j, i: j)
-    dk, dv = pl.pallas_call(
-        functools.partial(_bsnd_dkv_kernel, num_q=num_q, **static),
-        grid=(b * cells, num_kv, num_q),
+    dk, dv = _walk(
+        functools.partial(_bsnd_dkv_kernel, **static),
+        _visit_list(s, block_q, block_k, causal, window, rule,
+                    kv_major=True), b * cells,
         in_specs=[sp["kv"], sp["kv"], sp["q"], sp["q"], sp["row"],
                   sp["row"], sp["slopes"]],
         out_specs=[sp["kv"], sp["kv"]],
@@ -1101,8 +1026,7 @@ def _bsnd_bwd_pallas(q, k, v, o, lse, do, alibi_slopes, *, heads, scale,
         scratch_shapes=per_cell * [
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32), column, column],
-        compiler_params=params, interpret=interpret,
-        name=_kernel_name(rule, "dkv", window),
+        name=_kernel_name(rule, "dkv", window), interpret=interpret,
     )(k, v, q, do, lse, delta, slopes)
     return dq, dk, dv
 
@@ -1113,9 +1037,11 @@ def _bsnd_bwd_pallas(q, k, v, o, lse, do, alibi_slopes, *, heads, scale,
 # may see (DeepSeek Sparse Attention: a learned indexer chooses them;
 # models/transformer_lm.py ``SparseIndexer``). It is one more operand of
 # the same three kernel bodies, streamed by tile and shared by a batch
-# row's heads; ``_tile_flags`` tells each grid cell, through scalar
-# prefetch, whether its tile selects anything, and cells whose tile does
-# not are skipped like those above the diagonal. These calls are named
+# row's heads. Which tiles run is known only at run time, so these calls
+# keep a full ``(b * n, q tile, kv tile)`` grid (kv-major for dkv):
+# ``_tile_flags`` tells each grid cell, through scalar prefetch, whether
+# its tile selects anything, and cells whose tile does not are skipped,
+# as are those above the diagonal. These calls are named
 # ``sparse_attention_*``.
 
 def _tile_flags(selection, block_q, block_k):
@@ -1131,40 +1057,50 @@ def _flag(flags_ref, row, qi, kj, num_q, num_kv):
     return flags_ref[(row * num_q + qi) * num_kv + kj] != 0
 
 
-def _sparse_fwd_kernel(flags_ref, q_ref, k_ref, v_ref, sel_ref, o_ref,
-                       lse_ref, acc_ref, m_ref, l_ref, *, heads, num_q,
-                       **kw):
+def _grid_step(flags_ref, heads, num_q, num_kv, kw, kv_major=False):
+    """The :class:`_Step` of a sparse call's grid cell: it opens and
+    closes its row of the full grid at its first and last tile, and its
+    tile runs where it is causal and selects something."""
     from jax.experimental import pallas as pl
 
-    tile = _flag(flags_ref, pl.program_id(0) // heads, pl.program_id(1),
-                 pl.program_id(2), num_q, kw["num_kv"])
+    outer, inner = pl.program_id(1), pl.program_id(2)
+    qi, kj = (inner, outer) if kv_major else (outer, inner)
+    tile = _flag(flags_ref, pl.program_id(0) // heads, qi, kj, num_q, num_kv)
+    run = _stream_kv_run(qi, kj, kw["block_q"], kw["block_k"], kw["causal"],
+                         None)
+    return _Step(qi, kj, inner == 0,
+                 inner == (num_q if kv_major else num_kv) - 1,
+                 _and_run(run, tile))
+
+
+def _sparse_fwd_kernel(flags_ref, q_ref, k_ref, v_ref, sel_ref, o_ref,
+                       lse_ref, acc_ref, m_ref, l_ref, *, heads, num_q,
+                       num_kv, **kw):
     _flash_fwd_kernel(q_ref, k_ref, v_ref, None, o_ref, lse_ref, acc_ref,
-                      m_ref, l_ref, sel_ref=sel_ref, tile_selected=tile,
-                      window=None, alibi=False, **kw)
+                      m_ref, l_ref, sel_ref=sel_ref, window=None,
+                      alibi=False,
+                      step=_grid_step(flags_ref, heads, num_q, num_kv, kw),
+                      **kw)
 
 
 def _sparse_dq_kernel(flags_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                       delta_ref, sel_ref, dq_ref, dq_acc, *, heads, num_q,
-                      **kw):
-    from jax.experimental import pallas as pl
-
-    tile = _flag(flags_ref, pl.program_id(0) // heads, pl.program_id(1),
-                 pl.program_id(2), num_q, kw["num_kv"])
+                      num_kv, **kw):
     _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, None,
-                     dq_ref, dq_acc, sel_ref=sel_ref, tile_selected=tile,
-                     window=None, alibi=False, **kw)
+                     dq_ref, dq_acc, sel_ref=sel_ref, window=None,
+                     alibi=False,
+                     step=_grid_step(flags_ref, heads, num_q, num_kv, kw),
+                     **kw)
 
 
 def _sparse_dkv_kernel(flags_ref, k_ref, v_ref, q_ref, do_ref, lse_ref,
                        delta_ref, sel_ref, dk_ref, dv_ref, dk_acc, dv_acc,
-                       *, heads, num_kv, **kw):
-    from jax.experimental import pallas as pl
-
-    tile = _flag(flags_ref, pl.program_id(0) // heads, pl.program_id(2),
-                 pl.program_id(1), kw["num_q"], num_kv)
+                       *, heads, num_q, num_kv, **kw):
     _flash_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref, None,
                       dk_ref, dv_ref, dk_acc, dv_acc, sel_ref=sel_ref,
-                      tile_selected=tile, window=None, alibi=False, **kw)
+                      window=None, alibi=False,
+                      step=_grid_step(flags_ref, heads, num_q, num_kv, kw,
+                                      kv_major=True), **kw)
 
 
 def _head_probs_kernel(flags_ref, q_ref, k_ref, lse_ref, sel_ref, p_ref, *,
@@ -1716,7 +1652,7 @@ def _mla_heads_per_cell(heads, widths):
 
 
 def _mla_fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref,
-                    *scratch, heads, dims, num_kv, **kw):
+                    *scratch, heads, dims, step, **kw):
     """As :func:`_bsnd_fwd_kernel`, with the rotary part."""
     from jax.experimental import pallas as pl
 
@@ -1726,11 +1662,11 @@ def _mla_fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref,
         _flash_fwd_kernel(
             _HeadLanes(qn_ref, h, dn), _HeadLanes(kn_ref, h, dn),
             _HeadLanes(v_ref, h, dv), None, _HeadLanes(o_ref, h, dv),
-            lse_col, acc_ref, m_ref, l_ref, num_kv=num_kv, window=None,
+            lse_col, acc_ref, m_ref, l_ref, step=step, window=None,
             alibi=False, rope=_RopePart(_HeadLanes(qr_ref, h, dr), kr_ref),
             **kw)
 
-    @pl.when(pl.program_id(2) == num_kv - 1)
+    @pl.when(step.last)
     def _lse_rows():
         for h in range(heads):
             lse_ref[0, 0, h:h + 1, :] = _turned(scratch[4 * h + 3][0])
@@ -1738,7 +1674,7 @@ def _mla_fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref,
 
 def _mla_dq_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, o_ref,
                    lse_ref, dqn_ref, dqr_ref, delta_ref, *scratch, heads,
-                   dims, **kw):
+                   dims, step, **kw):
     """As :func:`_bsnd_dq_kernel`; ``scratch`` holds (dq acc, rotary dq
     acc, lse column, delta column) a head."""
     from jax.experimental import pallas as pl
@@ -1748,7 +1684,7 @@ def _mla_dq_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, o_ref,
         dq_acc, dqr_acc, lse_col, delta_col = scratch[4 * h:4 * h + 4]
         do_h = _HeadLanes(do_ref, h, dv)
 
-        @pl.when(pl.program_id(2) == 0)
+        @pl.when(step.first)
         def _columns():
             lse_col[0] = _turned(lse_ref[0, 0, h:h + 1, :])
             delta = jnp.sum(
@@ -1762,14 +1698,15 @@ def _mla_dq_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, o_ref,
         _flash_dq_kernel(
             _HeadLanes(qn_ref, h, dn), _HeadLanes(kn_ref, h, dn),
             _HeadLanes(v_ref, h, dv), do_h, lse_col, delta_col, None,
-            _HeadLanes(dqn_ref, h, dn), dq_acc, window=None, alibi=False,
+            _HeadLanes(dqn_ref, h, dn), dq_acc, step=step, window=None,
+            alibi=False,
             rope=_RopePart(_HeadLanes(qr_ref, h, dr), kr_ref,
                            _HeadLanes(dqr_ref, h, dr), dqr_acc), **kw)
 
 
 def _mla_dkv_kernel(kn_ref, kr_ref, v_ref, qn_ref, qr_ref, do_ref, lse_ref,
                     delta_ref, dkn_ref, dkr_ref, dv_ref, *scratch, heads,
-                    dims, num_q, **kw):
+                    dims, step, **kw):
     """As :func:`_bsnd_dkv_kernel`; ``scratch`` holds (dk acc, dv acc,
     lse column, delta column) a head and, last, the shared rotary key's
     gradient, which all the cell's heads add to."""
@@ -1777,40 +1714,33 @@ def _mla_dkv_kernel(kn_ref, kr_ref, v_ref, qn_ref, qr_ref, do_ref, lse_ref,
 
     dn, dr, dv = dims
     dkr_acc = scratch[-1]
-    qi = pl.program_id(2)
 
-    @pl.when(qi == 0)
+    @pl.when(step.first)
     def _init():
         dkr_acc[...] = jnp.zeros_like(dkr_acc)
 
-    run = _stream_q_run(qi, pl.program_id(1), kw["block_q"], kw["block_k"],
-                        kw["causal"], None)
     for h in range(heads):
         dk_acc, dv_acc, lse_col, delta_col = scratch[4 * h:4 * h + 4]
-
-        @pl.when(run)
-        def _columns():
-            lse_col[0] = _turned(lse_ref[0, 0, h:h + 1, :])
-            delta_col[0] = _turned(delta_ref[0, 0, h:h + 1, :])
-
+        lse_col[0] = _turned(lse_ref[0, 0, h:h + 1, :])
+        delta_col[0] = _turned(delta_ref[0, 0, h:h + 1, :])
         _flash_dkv_kernel(
             _HeadLanes(kn_ref, h, dn), _HeadLanes(v_ref, h, dv),
             _HeadLanes(qn_ref, h, dn), _HeadLanes(do_ref, h, dv), lse_col,
             delta_col, None, _HeadLanes(dkn_ref, h, dn),
-            _HeadLanes(dv_ref, h, dv), dk_acc, dv_acc, num_q=num_q,
+            _HeadLanes(dv_ref, h, dv), dk_acc, dv_acc, step=step,
             window=None, alibi=False,
             rope=_RopePart(_HeadLanes(qr_ref, h, dr), kr_ref, acc=dkr_acc),
             **kw)
 
-    @pl.when(qi == num_q - 1)
+    @pl.when(step.last)
     def _finish():
         dkr_ref[0, 0] = dkr_acc[...]
 
 
-def _mla_specs(per_cell, cells, dims, block_q, block_k, q_of, kv_of):
-    """BlockSpecs over a ``(b * cells, x, y)`` grid, as
-    :func:`_bsnd_specs`: ``qn``/``qr``/``o`` follow the q block, ``kn``/
-    ``kr``/``v`` the kv block; ``dkr`` is the cell's part of the shared
+def _mla_specs(per_cell, cells, dims, block_q, block_k):
+    """BlockSpecs over a ``(b * cells, visits)`` walk, as
+    :func:`_bsnd_specs`: ``qn``/``qr``/``o`` follow the q tile, ``kn``/
+    ``kr``/``v`` the kv tile; ``dkr`` is the cell's part of the shared
     rotary key's gradient, ``[b, cells, s, d_rope]`` float32."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -1821,22 +1751,22 @@ def _mla_specs(per_cell, cells, dims, block_q, block_k, q_of, kv_of):
         return pl.BlockSpec(shape, index, memory_space=pltpu.VMEM)
 
     def q_side(width):
-        return spec((1, block_q, per_cell * width),
-                    lambda g, x, y: (g // cells, q_of(x, y), g % cells))
+        return spec((1, block_q, per_cell * width), lambda g, v, visits: (
+            g // cells, _q_tile(visits, v), g % cells))
 
     def kv_side(width):
-        return spec((1, block_k, per_cell * width),
-                    lambda g, x, y: (g // cells, kv_of(x, y), g % cells))
+        return spec((1, block_k, per_cell * width), lambda g, v, visits: (
+            g // cells, _kv_tile(visits, v), g % cells))
 
     return {
         "qn": q_side(dn), "qr": q_side(dr), "o": q_side(dv),
         "kn": kv_side(dn), "v": kv_side(dv),
-        "kr": spec((1, block_k, dr),
-                   lambda g, x, y: (g // cells, kv_of(x, y), 0)),
-        "row": spec((1, 1, per_cell, block_q),
-                    lambda g, x, y: (g // cells, g % cells, 0, q_of(x, y))),
-        "dkr": spec((1, 1, block_k, dr),
-                    lambda g, x, y: (g // cells, g % cells, kv_of(x, y), 0)),
+        "kr": spec((1, block_k, dr), lambda g, v, visits: (
+            g // cells, _kv_tile(visits, v), 0)),
+        "row": spec((1, 1, per_cell, block_q), lambda g, v, visits: (
+            g // cells, g % cells, 0, _q_tile(visits, v))),
+        "dkr": spec((1, 1, block_k, dr), lambda g, v, visits: (
+            g // cells, g % cells, _kv_tile(visits, v), 0)),
     }
 
 
@@ -1851,23 +1781,18 @@ _mla_jit = functools.partial(jax.jit, static_argnames=(
 @_mla_jit
 def _mla_fwd_pallas(q_nope, q_rope, k_nope, k_rope, v, *, heads, scale,
                     causal, block_q, block_k, interpret):
-    from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, s, _ = q_nope.shape
     dims = _mla_dims(q_nope, q_rope, v, heads)
     per_cell = _mla_heads_per_cell(heads, dims)
     cells = heads // per_cell
-    num_kv = s // block_k
-    sp = _mla_specs(
-        per_cell, cells, dims, block_q, block_k, lambda i, j: i,
-        lambda i, j: _fetched_kv_block(i, j, block_q, block_k, causal,
-                                       None))
-    return pl.pallas_call(
+    sp = _mla_specs(per_cell, cells, dims, block_q, block_k)
+    return _walk(
         functools.partial(
             _mla_fwd_kernel, heads=per_cell, dims=dims, scale=scale,
-            causal=causal, block_q=block_q, block_k=block_k, num_kv=num_kv),
-        grid=(b * cells, s // block_q, num_kv),
+            causal=causal, block_q=block_q, block_k=block_k),
+        _visit_list(s, block_q, block_k, causal), b * cells,
         in_specs=[sp["qn"], sp["qr"], sp["kn"], sp["kr"], sp["v"]],
         out_specs=[sp["o"], sp["row"]],
         out_shape=[
@@ -1880,37 +1805,27 @@ def _mla_fwd_pallas(q_nope, q_rope, k_nope, k_rope, v, *, heads, scale,
             pltpu.VMEM((block_q, 1), jnp.float32),          # running sum
             pltpu.VMEM((1, block_q, 1), jnp.float32),       # lse column
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-        name="mla_attention_flash_fwd",
+        name="mla_attention_flash_fwd", interpret=interpret,
     )(q_nope, q_rope, k_nope, k_rope, v)
 
 
 @_mla_jit
 def _mla_bwd_pallas(q_nope, q_rope, k_nope, k_rope, v, o, lse, do, *, heads,
                     scale, causal, block_q, block_k, interpret):
-    from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, s, _ = q_nope.shape
     dims = dn, dr, dv = _mla_dims(q_nope, q_rope, v, heads)
     per_cell = _mla_heads_per_cell(heads, dims)
     cells = heads // per_cell
-    num_q, num_kv = s // block_q, s // block_k
-    params = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
     static = dict(heads=per_cell, dims=dims, scale=scale, causal=causal,
                   block_q=block_q, block_k=block_k)
     column = pltpu.VMEM((1, block_q, 1), jnp.float32)
+    sp = _mla_specs(per_cell, cells, dims, block_q, block_k)
 
-    sp = _mla_specs(
-        per_cell, cells, dims, block_q, block_k, lambda i, j: i,
-        lambda i, j: _fetched_kv_block(i, j, block_q, block_k, causal,
-                                       None))
-    dq_nope, dq_rope, delta = pl.pallas_call(
-        functools.partial(_mla_dq_kernel, num_kv=num_kv, **static),
-        grid=(b * cells, num_q, num_kv),
+    dq_nope, dq_rope, delta = _walk(
+        functools.partial(_mla_dq_kernel, **static),
+        _visit_list(s, block_q, block_k, causal), b * cells,
         in_specs=[sp["qn"], sp["qr"], sp["kn"], sp["kr"], sp["v"], sp["o"],
                   sp["o"], sp["row"]],
         out_specs=[sp["qn"], sp["qr"], sp["row"]],
@@ -1920,17 +1835,12 @@ def _mla_bwd_pallas(q_nope, q_rope, k_nope, k_rope, v, o, lse, do, *, heads,
         scratch_shapes=per_cell * [
             pltpu.VMEM((block_q, dn), jnp.float32),
             pltpu.VMEM((block_q, dr), jnp.float32), column, column],
-        compiler_params=params, interpret=interpret,
-        name="mla_attention_flash_dq",
+        name="mla_attention_flash_dq", interpret=interpret,
     )(q_nope, q_rope, k_nope, k_rope, v, do, o, lse)
 
-    sp = _mla_specs(
-        per_cell, cells, dims, block_q, block_k,
-        lambda j, i: _fetched_q_block(j, i, block_q, block_k, causal, None),
-        lambda j, i: j)
-    dk_nope, dk_rope, dv_ = pl.pallas_call(
-        functools.partial(_mla_dkv_kernel, num_q=num_q, **static),
-        grid=(b * cells, num_kv, num_q),
+    dk_nope, dk_rope, dv_ = _walk(
+        functools.partial(_mla_dkv_kernel, **static),
+        _visit_list(s, block_q, block_k, causal, kv_major=True), b * cells,
         in_specs=[sp["kn"], sp["kr"], sp["v"], sp["qn"], sp["qr"], sp["o"],
                   sp["row"], sp["row"]],
         out_specs=[sp["kn"], sp["dkr"], sp["v"]],
@@ -1941,8 +1851,7 @@ def _mla_bwd_pallas(q_nope, q_rope, k_nope, k_rope, v, o, lse, do, *, heads,
             pltpu.VMEM((block_k, dn), jnp.float32),
             pltpu.VMEM((block_k, dv), jnp.float32), column, column]
         + [pltpu.VMEM((block_k, dr), jnp.float32)],
-        compiler_params=params, interpret=interpret,
-        name="mla_attention_flash_dkv",
+        name="mla_attention_flash_dkv", interpret=interpret,
     )(k_nope, k_rope, v, q_nope, q_rope, do, lse, delta)
     dk_rope = jnp.sum(dk_rope, axis=1).astype(k_rope.dtype)
     return dq_nope, dq_rope, dk_nope, dk_rope, dv_

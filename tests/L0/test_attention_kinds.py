@@ -291,14 +291,15 @@ def _records(f, *operands):
 
 def test_the_band_s_record_at_the_cell_s_shape():
     """8192 positions, a window of 512, tiles of 512: a head runs 31
-    tiles (the diagonal's 16 and the 15 below it) whole and skips 225;
-    they compute twice the pairs a query sees."""
+    tiles (the diagonal's 16 and the 15 below it) whole and skips 225,
+    and each kernel's walk takes those 31 grid steps; they compute twice
+    the pairs a query sees."""
     q = jax.ShapeDtypeStruct((2, 8192, 8 * 128), jnp.bfloat16)
     seen, summary = _records(lambda q, k, v: fmha.flash_attention_bsnd(
         q, k, v, 8, True, window=512), q, q, q)
     rec = seen[fmha.WINDOW_ENTRY]
     assert (rec["tiles_whole"], rec["tiles_skipped"],
-            rec["tiles_diagonal"]) == (31, 225, 0)
+            rec["tiles_diagonal"], rec["grid_steps"]) == (31, 225, 0, 31)
     assert (rec["pairs_computed"], rec["pairs_visible"]) == (
         8_126_464, 4_063_488)
     assert summary["gauges"]["kernels/flash_attention_window/tiles_whole"] \

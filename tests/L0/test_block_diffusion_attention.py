@@ -4,8 +4,8 @@ in interpret mode: a row of ``2L`` that holds ``L`` clean tokens and their
 ``L`` noised copies, the rule worked out from positions inside the kernels.
 Against the boolean-mask oracle (``block_diffusion_mask``), forward and all
 three gradients, through the batch-major entry (what 32 heads of 128 take)
-and the head-major one; the tile counts of the dispatch record; the index
-maps (no skipped tile is fetched); and what the rule means."""
+and the head-major one; the tile counts of the dispatch record; the walk's
+visits (no skipped tile is fetched); and what the rule means."""
 
 import jax
 import jax.numpy as jnp
@@ -136,13 +136,13 @@ def test_the_kernels_carry_the_rule_s_name(interpret):
     assert "512,512]" not in text
 
 
-# ------------------------------------------------------- tiles and index maps
+# ------------------------------------------------ tiles and the walk's visits
 
 def test_tile_classes_at_the_cell_s_shape():
     rule = fmha._BlockDiffusion(8192, 4)
     got = fmha._tile_classes(16384, 512, 512, False, None, rule)
     assert got == {"tiles_diagonal": 0, "tiles_whole": 288,
-                   "tiles_skipped": 736,
+                   "tiles_skipped": 736, "grid_steps": 288,
                    "pairs_computed": 288 * 512 * 512,
                    "pairs_visible": 67_141_632}
 
@@ -168,10 +168,10 @@ def test_tile_classes_are_a_direct_count(L, bl, bq, bk):
 
 @pytest.mark.parametrize("L,bl,bq,bk", GRIDS + [(8192, 4, 512, 512)])
 def test_no_skipped_tile_is_fetched(L, bl, bq, bk):
-    """Every grid cell of the forward and dq (kv streamed) and of dkv (q
-    streamed) fetches a tile that runs; a cell whose own tile runs fetches
-    that; and along the streamed axis a row fetches each of its tiles
-    once and no other."""
+    """Every visit of the forward's and dq's walk (kv streamed) and of
+    dkv's (q streamed) fetches a tile that runs; every tile that runs is
+    visited, so fetches itself; and along the streamed axis a row fetches
+    each of its tiles once and no other."""
     rule = fmha._BlockDiffusion(L, bl)
     nq, nk = 2 * L // bq, 2 * L // bk
     qi, kj = np.ogrid[:nq, :nk]
@@ -179,18 +179,19 @@ def test_no_skipped_tile_is_fetched(L, bl, bq, bk):
                            (nq, nk))
     assert runs.any(axis=1).all() and runs.any(axis=0).all()
 
-    kv = np.asarray(fmha._fetched_kv_block(qi, kj, bq, bk, False, None,
-                                           rule))
-    assert np.take_along_axis(runs, kv, axis=1).all()
-    np.testing.assert_array_equal(kv[runs], np.broadcast_to(kj, (nq, nk))[runs])
-    fetches = 1 + (np.diff(kv, axis=1) != 0).sum(axis=1)
-    np.testing.assert_array_equal(fetches, runs.sum(axis=1))
-
-    q = np.asarray(fmha._fetched_q_block(kj, qi, bq, bk, False, None, rule))
-    assert np.take_along_axis(runs, q, axis=0).all()
-    np.testing.assert_array_equal(q[runs], np.broadcast_to(qi, (nq, nk))[runs])
-    fetches = 1 + (np.diff(q, axis=0) != 0).sum(axis=0)
-    np.testing.assert_array_equal(fetches, runs.sum(axis=0))
+    for kv_major in (False, True):
+        visits = fmha._visit_list(2 * L, bq, bk, False, None, rule, kv_major)
+        step = fmha._visit(visits, np.arange(visits.size))
+        assert runs[step.qi, step.kj].all()
+        fetched = np.zeros((nq, nk), int)
+        np.add.at(fetched, (step.qi, step.kj), 1)
+        np.testing.assert_array_equal(fetched, runs)
+        row, streamed = (step.kj, step.qi) if kv_major else (step.qi,
+                                                            step.kj)
+        same_row = row[1:] == row[:-1]
+        assert (streamed[1:][same_row] != streamed[:-1][same_row]).all()
+        np.testing.assert_array_equal(
+            np.bincount(row), runs.sum(axis=0 if kv_major else 1))
 
 
 # ------------------------------------------------------- what the rule means

@@ -285,11 +285,12 @@ def test_a_checkpointed_layer_keeps_what_the_kernel_wrote(interpret):
     for call in kernels["self_attention_flash_fwd"]:
         assert avals(call.outvars) == [out, lse]
     for call in kernels["self_attention_flash_dq"]:
-        # q, k, v, do, out, lse, slopes -> dq, delta in lse's layout
-        assert avals(call.invars)[4:6] == [out, lse]
+        # the walk's visit list, q, k, v, do, out, lse, slopes -> dq,
+        # delta in lse's layout
+        assert avals(call.invars)[5:7] == [out, lse]
         assert avals(call.outvars) == [out, lse]
     for call in kernels["self_attention_flash_dkv"]:
-        assert avals(call.invars)[4:6] == [lse, lse]
+        assert avals(call.invars)[5:7] == [lse, lse]
     named = [eqn for eqn in eqns if eqn.primitive.name == "name"]
     assert sorted((eqn.params["name"], eqn.outvars[0].aval.shape)
                   for eqn in named) == sorted(

@@ -210,6 +210,7 @@ def _direct_count(s, bq, bk, causal, window):
     return {"tiles_diagonal": diagonal,
             "tiles_whole": int(some.sum()) - diagonal,
             "tiles_skipped": int((~some).sum()),
+            "grid_steps": int(some.sum()),
             "pairs_computed": (int(some.sum()) - diagonal) * bq * bk
             + in_strips,
             "pairs_visible": int(visible.sum())}
@@ -245,15 +246,16 @@ def test_the_dispatch_record_counts_the_tiles_as_a_direct_count_does(
 
 def test_a_gpt2_step_s_record(interpret):
     """2 tiles in strips, 1 whole and 1 skipped a head and kernel at 1024
-    positions under the default blocks; the strips take the pairs computed
-    from 1.5 times those a query sees to 1.12."""
+    positions under the default blocks, so each kernel's walk takes 3 grid
+    steps a head; the strips take the pairs computed from 1.5 times those
+    a query sees to 1.12."""
     q = jax.ShapeDtypeStruct((16, 1024, 1024), jnp.bfloat16)
     seen, _ = _records(
         lambda q, k, v: fmha.flash_attention_bsnd(q, k, v, 16, True), q, q,
         q)
     rec = seen["flash_attention_bsnd"]
     assert (rec["tiles_diagonal"], rec["tiles_whole"],
-            rec["tiles_skipped"]) == (2, 1, 1)
+            rec["tiles_skipped"], rec["grid_steps"]) == (2, 1, 1, 3)
     assert rec["pairs_visible"] == 1024 * 1025 // 2
     strips = 512 // fmha.STRIP
     assert rec["pairs_computed"] == (

@@ -125,11 +125,13 @@ def test_threshold_selection_is_an_exact_top_k(seq, topk):
 # forward + backward) as a jaxpr, source lines and addresses stripped.
 # Through PR 37 the hash of the commit before the selection operand
 # existed (PR 27); taken anew at PR 38, whose forward and dq run a tile on
-# the diagonal in strips (dkv's call is what it was). It holds that an
+# the diagonal in strips (dkv's call is what it was), and at PR 42, whose
+# three calls walk a scalar-prefetched list of the 3 tiles that run a head
+# where their grids had a step for each of the 4. It holds that an
 # optional operand (the selection, the rotary part, alibi, a window)
 # leaves GPT-2's program byte for byte alone.
 GPT2_FLASH_JAXPR = \
-    "cda857fa352bfbcf8459063361d650965e777009002efa47502b41807fb4c518"
+    "336d5563f1dafc7eae2dff15542a4066be6265fde3c4d3c5d2639ce6a06bf797"
 
 
 def test_without_a_selection_the_kernels_are_what_they_were(monkeypatch):
