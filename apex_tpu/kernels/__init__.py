@@ -18,6 +18,10 @@ existing Python entry points:
   int8 ``quant`` pair lives in ``compression`` itself)
 - :mod:`apex_tpu.kernels.fused_cc` — matmul + collective, the verify
   window's flash attention, int4 quantize + pack around a collective
+- :mod:`apex_tpu.kernels.indexer_scores` — the sparse-attention
+  indexer's scores, its heads' logits formed, rectified, weighted and
+  summed in VMEM over the causal tiles, with their backward (entry:
+  ``apex_tpu.models.transformer_lm.index_scores``)
 - :mod:`apex_tpu.kernels.topk_select` — the sparse-attention indexer's
   top-k selection with its rows of scores held in VMEM (entry:
   ``apex_tpu.models.transformer_lm.topk_selection``)
@@ -35,6 +39,7 @@ See docs/kernels.md for the rule, parity bounds, and wire formats.
 
 from apex_tpu.kernels import (  # noqa: F401
     grouped_matmul,
+    indexer_scores,
     optim,
     quant4,
     softmax,

@@ -899,6 +899,57 @@ def _make_norm(cfg, name):
 INDEXER_CHUNK = 512   # queries scored at a time (DSA's q_chunk_size)
 
 
+def index_scores(q, k, w):
+    """The indexer's scores ``I [b, s, s]`` float32 from ``q`` ``[b, s,
+    heads * dim]``, ``k`` ``[b, s, dim]`` and ``w`` ``[b, s, heads]``:
+    ``I[t, u] = sum_j w[t, j] * relu(q[t, j] . k[u])`` for ``u <= t``, 0
+    above the diagonal.
+
+    The kernel registry's one rule picks who computes it (counted as
+    ``kernels/dispatch/indexer_scores_<path>``): the Pallas kernels of
+    ``kernels/indexer_scores.py``, which form the heads' logits tile by
+    tile in VMEM and write only ``I``, or the jnp oracle below where the
+    shape does not fit (``indexer_scores.fits``), off the TPU and under
+    ``APEX_TPU_KERNELS=0``."""
+    from apex_tpu.kernels import indexer_scores
+
+    fits = indexer_scores.fits(q.shape, w.shape[-1])
+    if indexer_scores.GATE.path(fits) != "oracle":
+        return indexer_scores.indexer_scores(q, k, w)
+    return _index_scores_oracle(q, k, w)
+
+
+def _index_scores_oracle(q, k, w):
+    """:func:`index_scores` in jnp: the kernels' oracle. One sequence and
+    ``INDEXER_CHUNK`` queries at a time under ``jax.checkpoint``, so that
+    ``[heads, s, s]`` is never live; a chunk's logits ``[heads, chunk,
+    s]`` are formed in float32 against every key and read back for the
+    ``relu`` and the weighted sum."""
+    b, s, width = q.shape
+    heads = w.shape[-1]
+    chunk = INDEXER_CHUNK if s % INDEXER_CHUNK == 0 else s
+
+    @jax.checkpoint
+    def chunk_scores(qc, wc, first, kr):
+        # [c, heads, dim] x [s, dim] -> [heads, c, s]
+        logits = jnp.einsum("chd,ud->hcu", qc, kr,
+                            preferred_element_type=jnp.float32)
+        scores = jnp.einsum("hcu,ch->cu", jax.nn.relu(logits), wc)
+        causal = jnp.arange(s)[None, :] <= first + jnp.arange(chunk)[:, None]
+        return jnp.where(causal, scores, 0.0)
+
+    def row_scores(qwk):
+        qr, wr, kr = qwk        # one sequence: [s, heads, dim], ...
+        return jax.lax.map(
+            lambda qw: chunk_scores(*qw, kr),
+            (qr.reshape(s // chunk, chunk, heads, width // heads),
+             wr.reshape(s // chunk, chunk, heads),
+             jnp.arange(0, s, chunk))).reshape(s, s)
+
+    return jax.lax.map(row_scores, (q.reshape(b, s, heads, width // heads),
+                                    w, k))
+
+
 def topk_selection(scores, topk: int):
     """``[b, s, s]`` int8: for each query ``t`` the ``min(t + 1, topk)``
     keys ``u <= t`` of largest float32 ``scores[b, t, u]``. By a threshold
@@ -956,14 +1007,16 @@ class SparseIndexer(nn.Module):
     weights ``w = x Ww``; rotary on the whole of ``qI`` and ``kI`` from
     the first position component;
     ``I[t, u] = sum_j w[t, j] * heads^-1/2 * head_dim^-1/2 *
-    relu(qI[t, j] . kI[u])``. ``__call__`` -> ``(I [b, s, s] float32,
-    selection [b, s, s] int8)``, the selection being each query's
-    ``indexer_topk`` best causal keys (:func:`topk_selection`, under the
-    scope ``indexer/select``: one ``indexer_topk_select`` kernel over the
-    whole ``[b, s, s]`` where the kernel registry gives it, the jnp
-    bisection a sequence at a time elsewhere). Scores are computed one
-    sequence and ``INDEXER_CHUNK`` queries at a time, so ``[heads, s, s]``
-    is never live; the loss runs a sequence at a time too.
+    relu(qI[t, j] . kI[u])`` over the causal pairs, 0 above the diagonal
+    (:func:`index_scores`, under the scope ``indexer/scores``: the
+    ``indexer_scores_*`` kernels over the whole ``[b, s, s]`` where the
+    kernel registry gives them, the chunked jnp form elsewhere).
+    ``__call__`` -> ``(I [b, s, s] float32, selection [b, s, s] int8)``,
+    the selection being each query's ``indexer_topk`` best causal keys
+    (:func:`topk_selection`, under the scope ``indexer/select``: one
+    ``indexer_topk_select`` kernel over the whole ``[b, s, s]`` where the
+    kernel registry gives it, the jnp bisection a sequence at a time
+    elsewhere). The loss runs a sequence at a time.
 
     ``loss`` sows ``indexer_loss`` into ``moe_losses``:
     ``mean_t KL(p_t || softmax_{selected u} I[t, u])`` with ``p_t`` the
@@ -1008,27 +1061,10 @@ class SparseIndexer(nn.Module):
                 k.astype(cfg.compute_dtype)[:, :, None, :], cfg.rotary_base,
                 position_ids)[:, :, 0, :]
 
-        chunk = INDEXER_CHUNK if s % INDEXER_CHUNK == 0 else s
-
-        @jax.checkpoint
-        def chunk_scores(qc, wc, kr):
-            # [c, heads, dim] x [s, dim] -> [heads, c, s]
-            logits = jnp.einsum("chd,ud->hcu", qc, kr,
-                                preferred_element_type=jnp.float32)
-            return jnp.einsum("hcu,ch->cu", jax.nn.relu(logits), wc)
-
-        def row_scores(qwk):
-            qr, wr, kr = qwk        # one sequence: [s, heads, dim], ...
-            return jax.lax.map(
-                lambda qw: chunk_scores(*qw, kr),
-                (qr.reshape(s // chunk, chunk, heads, dim),
-                 wr.reshape(s // chunk, chunk, heads))).reshape(s, s)
-
-        # a sequence, and within it a chunk of queries, at a time
         with jax.named_scope("indexer/scores"):
-            scores = jax.lax.map(row_scores, (q.transpose(1, 0, 2, 3),
-                                              w.transpose(1, 0, 2),
-                                              k.transpose(1, 0, 2)))
+            scores = index_scores(
+                q.transpose(1, 0, 2, 3).reshape(b, s, heads * dim),
+                k.transpose(1, 0, 2), w.transpose(1, 0, 2))
         with jax.named_scope("indexer/select"):
             selection = topk_selection(jax.lax.stop_gradient(scores),
                                        cfg.indexer_topk)

@@ -670,6 +670,20 @@ def kernel_cases():
         lambda: (randn(25, (2, 8192, 8192), jnp.float32),), "exact",
         timed=True)
 
+    # -- the indexer's scores at the shape the Keye cell runs them (16 heads
+    # of 64 and one key head over 2 x 8192), forward and backward: the
+    # kernels' scores, dq, dk and dw against the chunked oracle's
+    def scores_fwd_bwd(q, k, w, d_scores):
+        out, vjp = jax.vjp(transformer_lm.index_scores, q, k, w)
+        return (out,) + vjp(d_scores)
+
+    add("indexer_scores fwd+dq+dk [2, 8192] 16 heads of 64",
+        scores_fwd_bwd, gates_off(scores_fwd_bwd),
+        lambda: (randn(50, (2, 8192, 1024)), randn(51, (2, 8192, 64)),
+                 randn(52, (2, 8192, 16), jnp.float32) * 0.03,
+                 randn(53, (2, 8192, 8192), jnp.float32)),
+        TOL_MXU, timed=True)
+
     # -- the experts' grouped matmul, forward and both gradients, at the
     # six shapes the three MoE cells run it, an eighth of the rows real (a
     # balanced router's share of Keye's; the rest is the tail, unspecified,

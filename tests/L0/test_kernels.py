@@ -92,8 +92,8 @@ def _expected_path(switch, interpret, on_tpu, fits):
 
 
 REGISTERED = ("adam", "expert_ffn", "flash_attention", "fused_cc",
-              "gqa_decode", "grouped_matmul", "lamb", "mla_decode", "quant",
-              "quant4", "softmax", "topk_select")
+              "gqa_decode", "grouped_matmul", "indexer_scores", "lamb",
+              "mla_decode", "quant", "quant4", "softmax", "topk_select")
 
 
 def _tiny_entries():
@@ -132,6 +132,9 @@ def _tiny_entries():
         "softmax": lambda: fsm.scaled_softmax(x, 0.5),
         "topk_select": lambda: transformer_lm.topk_selection(
             jnp.ones((1, 128, 128), f32), 8),
+        "indexer_scores": lambda: transformer_lm.index_scores(
+            jnp.ones((1, 128, 128), f32), jnp.ones((1, 128, 64), f32),
+            jnp.ones((1, 128, 2), f32)),
     }
 
 

@@ -122,6 +122,8 @@ KERNEL_NAMES = {
     "adam": ("fused_adam",),
     "lamb": ("fused_lamb",),
     "topk_select": ("indexer_topk_select",),
+    "indexer_scores": ("indexer_scores_fwd", "indexer_scores_dq",
+                       "indexer_scores_dk"),
     "grouped_matmul": ("moe_grouped_matmul_fwd", "moe_grouped_matmul_dlhs",
                        "moe_grouped_matmul_drhs"),
     "expert_ffn": ("moe_expert_ffn_in", "moe_expert_ffn_dh1",
@@ -186,6 +188,54 @@ def test_the_rows_walk_compiles_for_v5e_at_the_cells_shapes(tpu, shape):
                 walk, phase)
     for kernel in KERNEL_NAMES["grouped_matmul"]:
         assert kernel in text
+
+
+def test_the_indexer_s_kernels_are_block_indexer_in_a_train_step(tpu):
+    """A sparse-attention model's gradient, two checkpointed layers with
+    the Keye cell's indexer (16 heads of 64, one key head): the score
+    kernels (each layer's forward, its recomputation's, dq and dk) and the
+    selection run under ``indexer/scores`` and ``indexer/select``, so
+    ``classify`` gives them to block ``indexer`` in their phase, and none
+    bears a name an attention reader takes."""
+    from collections import Counter
+
+    from apex_tpu.models import GPTModel, TransformerConfig
+    from apex_tpu.models.gpt import gpt_loss_fn
+    from apex_tpu.models.transformer_lm import indexer_loss_from_variables
+
+    model = GPTModel(TransformerConfig(
+        hidden_size=256, num_layers=2, num_attention_heads=2, head_dim=128,
+        num_query_groups=1, vocab_size=512, max_position_embeddings=1024,
+        compute_dtype=BF16, normalization="rmsnorm",
+        position_embedding_type="rope", indexer_heads=16,
+        indexer_head_dim=64, indexer_topk=256, use_flash_attention=True,
+        activation_checkpointing=True))
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), I32))["params"])
+
+    def objective(params, tokens):
+        logits, sown = model.apply({"params": params}, tokens,
+                                   mutable=["moe_losses"])
+        return gpt_loss_fn(logits, tokens) + indexer_loss_from_variables(sown)
+
+    text = _compile(jax.grad(objective), tpu, params, _sds((2, 1024), I32))
+    seen = Counter()
+    for name, scope in instruction_scopes(text).items():
+        kernel = re.match(r"(indexer_\w+?)\.\d+$", name)
+        if kernel:
+            part = "select" if "topk" in kernel.group(1) else "scores"
+            assert f"/indexer/{part}/jit(" in scope, scope
+            seen[kernel.group(1), classify(scope)] += 1
+    assert seen == {
+        ("indexer_scores_fwd", ("indexer", "forward")): 2,
+        ("indexer_scores_fwd", ("indexer", "recompute")): 2,
+        ("indexer_scores_dq", ("indexer", "backward")): 2,
+        ("indexer_scores_dk", ("indexer", "backward")): 2,
+        ("indexer_topk_select", ("indexer", "forward")): 2,
+        ("indexer_topk_select", ("indexer", "recompute")): 2}
+    for name, _ in seen:
+        assert not re.search(r"_flash_(fwd|dq|dkv)$|^sparse_attention_|"
+                             r"^softmax_", name)
 
 
 def test_matmul_collectives_compile_under_tp4(tpu):
