@@ -38,15 +38,8 @@ class GPTStage(nn.Module):
 
     def setup(self):
         cfg = self.config
-        if (cfg.sliding_window_pattern > 1 or cfg.no_rope_layer_interval
-                or cfg.layer_attention is not None):
-            raise ValueError(
-                "per-layer alternation (sliding_window_pattern > 1, "
-                "no_rope_layer_interval or layer_attention) is not "
-                "supported under SPMD "
-                "pipelining: every stage runs the same program with "
-                "per-stage layer numbering, so the alternation would "
-                "silently restart at each stage boundary")
+        # every stage runs the same program and numbers its layers from 0
+        cfg.check_uniform("a pipeline stage")
         self.word_embeddings = VocabParallelEmbedding(
             num_embeddings=cfg.vocab_size, embedding_dim=cfg.hidden_size,
             params_dtype=cfg.params_dtype, name="word_embeddings")

@@ -11,6 +11,7 @@ enabled.
 """
 
 import dataclasses
+import functools
 from typing import Any, Optional
 
 import flax.linen as nn
@@ -141,6 +142,72 @@ class AttentionKind:
 
 
 @dataclasses.dataclass(frozen=True)
+class _LayerAttention:
+    """One layer's attention: its kind's name (None without kinds), query
+    heads, the configured window (None: full causal), whether rotary
+    positions apply, and their base, fraction and scaling."""
+
+    kind: Optional[str]
+    heads: int
+    window: Optional[int]
+    rope: bool
+    rotary_base: float
+    rotary_percent: float
+    rope_scaling: Optional[RopeScaling]
+
+
+@dataclasses.dataclass(frozen=True)
+class _Layer:
+    """One layer of the stack: its mixer ("attention", "mamba" or None),
+    its feed-forward ("dense", "moe" or None) and its attention, resolved
+    on every layer so that attention built on any layer number reads it."""
+
+    mixer: Optional[str]
+    ffn: Optional[str]
+    attention: _LayerAttention
+
+
+# the fields that make a stack's layers differ, each from its default
+_SCHEDULES = ("layer_pattern", "layer_attention", "sliding_window_pattern",
+              "no_rope_layer_interval", "moe_first_dense_layers",
+              "moe_layer_freq")
+_PATTERN_LETTERS = {"M": ("mamba", None), "*": ("attention", None),
+                    "E": (None, "moe")}
+
+
+def _resolve_layer(cfg, i: int) -> _Layer:
+    """Layer ``i`` of ``cfg``, from whichever of the config's schedules
+    it gives (each described at its field)."""
+    if cfg.layer_pattern is not None:
+        mixer, ffn = _PATTERN_LETTERS[cfg.layer_pattern[i]]
+    else:
+        after = i - cfg.moe_first_dense_layers
+        moe = (cfg.num_moe_experts is not None and after >= 0
+               and after % cfg.moe_layer_freq == 0)
+        mixer, ffn = "attention", "moe" if moe else "dense"
+    rope = cfg.position_embedding_type == "rope"
+    if cfg.layer_attention is not None:
+        kind = next(k for k in cfg.attention_kinds
+                    if k.name == cfg.layer_attention[i])
+        attention = _LayerAttention(
+            kind.name, kind.heads or cfg.num_attention_heads, kind.window,
+            rope, kind.rotary_base or cfg.rotary_base,
+            kind.rotary_percent or cfg.rotary_percent,
+            kind.rope_scaling or cfg.rope_scaling)
+    else:
+        pattern, nope = cfg.sliding_window_pattern, cfg.no_rope_layer_interval
+        window = (None if pattern > 1 and (i + 1) % pattern == 0
+                  else cfg.sliding_window)
+        local = cfg.rotary_base_local is not None and window is not None
+        attention = _LayerAttention(
+            None, cfg.num_attention_heads, window,
+            rope and not (nope and (i + 1) % nope == 0),
+            cfg.rotary_base_local if local else cfg.rotary_base,
+            cfg.rotary_percent, None if local else cfg.rope_scaling)
+    return _Layer(mixer, ffn, attention)
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     hidden_size: int = 1024
     num_layers: int = 24
@@ -169,7 +236,7 @@ class TransformerConfig:
     # of unrolling n layers (compile time O(1) in depth — the unrolled
     # 24-layer GPT costs minutes of XLA time per bench variant). Params
     # get a leading [num_layers] axis under 'layers'; requires a uniform
-    # stack (with MoE: moe_layer_freq == 1).
+    # stack (``uniform``: every entry of ``layers`` the same).
     scan_layers: bool = False
     # Per-layer activation recompute (reference tensor_parallel/random.py
     # checkpoint). ON by default for the reference's memory profile; turn
@@ -435,15 +502,9 @@ class TransformerConfig:
             raise ValueError(
                 f"sliding_window_pattern ({self.sliding_window_pattern}) "
                 f"must be >= 1")
-        if self.sliding_window_pattern > 1:
-            if self.sliding_window is None:
-                raise ValueError(
-                    "sliding_window_pattern > 1 needs sliding_window set")
-            if self.scan_layers:
-                raise ValueError(
-                    "scan_layers needs a uniform stack: alternating "
-                    "local/global attention (sliding_window_pattern > 1) "
-                    "cannot be scanned")
+        if self.sliding_window_pattern > 1 and self.sliding_window is None:
+            raise ValueError(
+                "sliding_window_pattern > 1 needs sliding_window set")
         if self.query_pre_attn_scalar is not None and self.context_parallel:
             raise ValueError(
                 "query_pre_attn_scalar does not compose with context "
@@ -475,10 +536,6 @@ class TransformerConfig:
             if self.position_embedding_type != "rope":
                 raise ValueError("no_rope_layer_interval requires "
                                  "position_embedding_type='rope'")
-            if self.scan_layers:
-                raise ValueError(
-                    "scan_layers needs a uniform stack: NoPE alternation "
-                    "(no_rope_layer_interval) cannot be scanned")
         if self.rotary_base_local is not None and self.sliding_window is None:
             raise ValueError(
                 "rotary_base_local needs sliding_window set (it applies "
@@ -581,11 +638,11 @@ class TransformerConfig:
                     f"within num_moe_experts ({self.num_moe_experts})")
         if self.moe_first_dense_layers < 0 or (
                 self.moe_first_dense_layers and (
-                    self.num_moe_experts is None or self.scan_layers
+                    self.num_moe_experts is None
                     or self.layer_pattern is not None)):
             raise ValueError(
                 f"moe_first_dense_layers ({self.moe_first_dense_layers}) "
-                f"needs num_moe_experts and an unrolled stack without a "
+                f"needs num_moe_experts and a stack without a "
                 f"layer_pattern")
         if self.moe_ffn_hidden_size is not None and (
                 self.moe_ffn_hidden_size < 1 or self.num_moe_experts is None):
@@ -648,12 +705,11 @@ class TransformerConfig:
                     f"layer_pattern {self.layer_pattern!r} must be "
                     f"num_layers ({self.num_layers}) letters of 'M' "
                     f"(Mamba-2), 'E' (experts), '*' (attention)")
-            if (self.scan_layers or self.parallel_residual
-                    or self.sandwich_norm or not self.pre_norm):
+            if (self.parallel_residual or self.sandwich_norm
+                    or not self.pre_norm):
                 raise ValueError(
-                    "layer_pattern layers are x + f(norm(x)), unrolled: "
-                    "no scan_layers, parallel_residual, sandwich_norm or "
-                    "pre_norm=False")
+                    "layer_pattern layers are x + f(norm(x)): no "
+                    "parallel_residual, sandwich_norm or pre_norm=False")
             if "E" in self.layer_pattern and self.num_moe_experts is None:
                 raise ValueError("an 'E' layer needs num_moe_experts")
             if "M" in self.layer_pattern and (
@@ -683,6 +739,8 @@ class TransformerConfig:
                 "attention_output_gate gates the dense attention path: not "
                 "with latent or sparse attention or context / sequence "
                 "parallelism")
+        if self.scan_layers:
+            self.check_uniform("scan_layers")
 
     def _check_attention_kinds(self):
         object.__setattr__(self, "attention_kinds",
@@ -728,12 +786,29 @@ class TransformerConfig:
             if kind.rope_scaling is not None:
                 _check_rope_scaling(kind.rope_scaling)
 
-    def attention_kind(self, layer: int) -> Optional[AttentionKind]:
-        """Layer ``layer``'s AttentionKind, or None without kinds."""
-        if self.layer_attention is None:
-            return None
-        name = self.layer_attention[layer]
-        return next(k for k in self.attention_kinds if k.name == name)
+    @functools.cached_property
+    def layers(self) -> tuple:
+        """What each of the ``num_layers`` layers is (``_Layer``), resolved
+        once from whichever schedule the config gives; every per-layer
+        decision of the model reads it."""
+        return tuple(_resolve_layer(self, i) for i in range(self.num_layers))
+
+    @property
+    def uniform(self) -> bool:
+        """Is every layer the same? A scanned stack and a pipeline stage,
+        which number their layers from 0, need this."""
+        return len(set(self.layers)) <= 1
+
+    def check_uniform(self, who: str, instead: str = ""):
+        """Raise unless the stack is ``uniform``, naming the schedules
+        that make its layers differ."""
+        if not self.uniform:
+            schedules = [f.name for f in dataclasses.fields(self)
+                         if f.name in _SCHEDULES
+                         and getattr(self, f.name) != f.default]
+            raise ValueError(
+                f"{who} needs a uniform stack: the layers differ under "
+                f"{', '.join(schedules)}{instead}")
 
     @property
     def ffn_size(self):
@@ -1160,54 +1235,22 @@ class ParallelAttention(nn.Module):
 
     config: TransformerConfig
     decode: bool = False
-    # which layer this is — selects local vs global attention under
-    # sliding_window_pattern (Gemma-2/3 alternation)
+    # which layer this is: its entry of config.layers says its heads,
+    # window and rotary positions
     layer_number: int = 0
 
-    def _layer_heads(self):
-        """This layer's query heads: its attention kind's, else
-        num_attention_heads."""
-        kind = self.config.attention_kind(self.layer_number)
-        return (kind and kind.heads) or self.config.num_attention_heads
+    @property
+    def _layer(self) -> _LayerAttention:
+        return self.config.layers[self.layer_number].attention
 
-    def _layer_window(self):
-        """This layer's sliding window, or None when it runs full causal
-        attention (every sliding_window_pattern-th layer; a kind without
-        a window)."""
-        cfg = self.config
-        kind = cfg.attention_kind(self.layer_number)
-        if kind is not None:
-            return kind.window
-        if cfg.sliding_window is None:
-            return None
-        if (cfg.sliding_window_pattern > 1
-                and (self.layer_number + 1) % cfg.sliding_window_pattern
-                == 0):
-            return None
-        return cfg.sliding_window
-
-    def _layer_uses_rope(self):
-        """False on SmolLM3-style NoPE layers (every interval-th)."""
-        cfg = self.config
-        if not cfg.no_rope_layer_interval:
-            return True
-        return (self.layer_number + 1) % cfg.no_rope_layer_interval != 0
-
-    def _layer_rope(self):
-        """(rotary_base, rotary_percent, rope_scaling) for THIS layer: its
-        attention kind's; Gemma-3 gives the windowed (local) layers their
-        own base with no frequency rescaling, while global layers keep
-        rotary_base/rope_scaling."""
-        cfg = self.config
-        kind = cfg.attention_kind(self.layer_number)
-        if kind is not None:
-            return (kind.rotary_base or cfg.rotary_base,
-                    kind.rotary_percent or cfg.rotary_percent,
-                    kind.rope_scaling or cfg.rope_scaling)
-        if (cfg.rotary_base_local is not None
-                and self._layer_window() is not None):
-            return cfg.rotary_base_local, cfg.rotary_percent, None
-        return cfg.rotary_base, cfg.rotary_percent, cfg.rope_scaling
+    def _rotate(self, q, k, positions, sections=None):
+        """q and k under this layer's rotary positions."""
+        layer, cfg = self._layer, self.config
+        return tuple(apply_rotary_emb(t, layer.rotary_base, positions,
+                                      layer.rotary_percent,
+                                      cfg.rotary_interleaved,
+                                      layer.rope_scaling, sections)
+                     for t in (q, k))
 
     @nn.compact
     def __call__(self, hidden_states, attention_mask=None, position_ids=None):
@@ -1218,13 +1261,14 @@ class ParallelAttention(nn.Module):
         ``attention/full_layers`` and sets the gauge
         ``attention/query_heads/<kind>``."""
         cfg = self.config
+        layer = self._layer
         tp = get_tensor_model_parallel_world_size()
-        heads = self._layer_heads()
+        heads = layer.heads
         if cfg.kv_lora_rank is not None:
             return self._latent_attention(cfg, hidden_states,
                                           attention_mask, position_ids,
                                           heads // tp)
-        if self.decode and (cfg.layer_attention is not None
+        if self.decode and (layer.kind is not None
                             or cfg.attention_output_gate):
             raise ValueError(
                 "attention_kinds and attention_output_gate are training "
@@ -1232,16 +1276,15 @@ class ParallelAttention(nn.Module):
         s = hidden_states.shape[-3]
         seq_full = s * tp if cfg.sequence_parallel else s
         # a window covering the whole sequence is plain causal
-        win = self._layer_window()
+        win = layer.window
         win = win if (win is not None and win < seq_full) else None
-        if cfg.layer_attention is not None:
+        if layer.kind is not None:
             from apex_tpu.telemetry.registry import get_registry
 
             reg = get_registry()
             reg.counter("attention/window_layers" if win is not None
                         else "attention/full_layers").inc()
-            reg.gauge("attention/query_heads/"
-                      + cfg.layer_attention[self.layer_number]).set(heads)
+            reg.gauge("attention/query_heads/" + layer.kind).set(heads)
         if win is None or self.decode:
             return self._attention(hidden_states, attention_mask,
                                    position_ids, heads, win)
@@ -1394,17 +1437,8 @@ class ParallelAttention(nn.Module):
             return self._ring_attention(cfg, q, k, v, position_ids,
                                         np_local, kv, b)
 
-        if (cfg.position_embedding_type == "rope"
-                and self._layer_uses_rope()):
-            rope_base, rope_percent, rope_scale = self._layer_rope()
-            q = apply_rotary_emb(q, rope_base, position_ids,
-                                 rope_percent,
-                                 cfg.rotary_interleaved,
-                                 rope_scale, cfg.rope_sections)
-            k = apply_rotary_emb(k, rope_base, position_ids,
-                                 rope_percent,
-                                 cfg.rotary_interleaved,
-                                 rope_scale, cfg.rope_sections)
+        if self._layer.rope:
+            q, k = self._rotate(q, k, position_ids, cfg.rope_sections)
         if k.shape[2] != np_local:
             # broadcast each K/V group to its query heads
             rep = np_local // k.shape[2]
@@ -1636,7 +1670,7 @@ class ParallelAttention(nn.Module):
         """Shared row-parallel output projection (both attention paths —
         keep them on ONE 'dense' module so numerics can't diverge)."""
         return RowParallelLinear(
-            input_size=self._layer_heads() * cfg.kv_channels,
+            input_size=self._layer.heads * cfg.kv_channels,
             output_size=cfg.hidden_size,
             input_is_parallel=True, bias=cfg.attention_bias,
             params_dtype=cfg.params_dtype,
@@ -1661,23 +1695,14 @@ class ParallelAttention(nn.Module):
         from apex_tpu.transformer.parallel_state import CONTEXT_PARALLEL_AXIS
 
         s = q.shape[0]
-        if (cfg.position_embedding_type == "rope"
-                and self._layer_uses_rope()):
+        if self._layer.rope:
             if position_ids is None:
                 try:
                     rank = lax.axis_index(CONTEXT_PARALLEL_AXIS)
                 except Exception:
                     rank = 0
                 position_ids = rank * s + jnp.arange(s)
-            rope_base, rope_percent, rope_scale = self._layer_rope()
-            q = apply_rotary_emb(q, rope_base, position_ids,
-                                 rope_percent,
-                                 cfg.rotary_interleaved,
-                                 rope_scale)
-            k = apply_rotary_emb(k, rope_base, position_ids,
-                                 rope_percent,
-                                 cfg.rotary_interleaved,
-                                 rope_scale)
+            q, k = self._rotate(q, k, position_ids)
         if k.shape[2] != np_local:
             rep = np_local // k.shape[2]
             k = jnp.repeat(k, rep, axis=2)
@@ -1712,19 +1737,10 @@ class ParallelAttention(nn.Module):
         ci = self.variable("cache", "cache_index",
                            lambda: jnp.zeros((), jnp.int32))
         idx = ci.value
-        if (cfg.position_embedding_type == "rope"
-                and self._layer_uses_rope()):
+        if self._layer.rope:
             pos = (position_ids if position_ids is not None
                    else idx + jnp.arange(s))
-            rope_base, rope_percent, rope_scale = self._layer_rope()
-            q = apply_rotary_emb(q, rope_base, pos,
-                                 rope_percent,
-                                 cfg.rotary_interleaved,
-                                 rope_scale)
-            k = apply_rotary_emb(k, rope_base, pos,
-                                 rope_percent,
-                                 cfg.rotary_interleaved,
-                                 rope_scale)
+            q, k = self._rotate(q, k, pos)
         if not initialized:
             # init pass: create the variables, plain causal attention over
             # the given tokens (shapes/params identical to the real path)
@@ -1752,7 +1768,7 @@ class ParallelAttention(nn.Module):
                 sm = 1.0 / math.sqrt(cfg.query_pre_attn_scalar or kv)
                 ctx = gqa_decode.gqa_flash_decode(
                     qg[0], kt, vt, idx + s, sm,
-                    window=self._layer_window(),
+                    window=self._layer.window,
                     softcap=cfg.attn_logit_softcapping)
                 ctx = ctx.reshape(1, b, np_local * kv)
                 return self._output_proj(cfg, ctx)
@@ -1770,7 +1786,7 @@ class ParallelAttention(nn.Module):
                 sm = 1.0 / math.sqrt(cfg.query_pre_attn_scalar or kv)
                 ctx = fused_cc.window_attention(
                     qg, kt, vt, offset, sm,
-                    window=self._layer_window(),
+                    window=self._layer.window,
                     softcap=cfg.attn_logit_softcapping)
                 ctx = ctx.reshape(s, b, np_local * kv)
                 return self._output_proj(cfg, ctx)
@@ -1792,7 +1808,7 @@ class ParallelAttention(nn.Module):
         jpos = jnp.arange(kv_len)[None, :]
         ipos = offset + jnp.arange(s)[:, None]
         masked = jpos > ipos
-        decode_win = self._layer_window()
+        decode_win = self._layer.window
         if decode_win is not None:
             # stale cache entries beyond the window stay resident but
             # invisible (Mistral semantics: 0 <= i - j < window)
@@ -1896,39 +1912,34 @@ class ParallelTransformerLayer(nn.Module):
     layer_number: int = 0
     decode: bool = False
 
-    def _is_moe_layer(self) -> bool:
+    def _one_sub_block(self, layer, hidden_states, attention_mask,
+                       position_ids):
+        """A layer of one sub-block (a ``layer_pattern`` letter):
+        ``x + f(norm(x))``, ``f`` its mixer or its feed-forward."""
         cfg = self.config
-        after = self.layer_number - cfg.moe_first_dense_layers
-        return (cfg.num_moe_experts is not None and after >= 0
-                and after % cfg.moe_layer_freq == 0)
-
-    def _one_sub_block(self, hidden_states, attention_mask, position_ids):
-        """A ``layer_pattern`` layer: ``x + f(norm(x))``, ``f`` by this
-        layer's letter."""
-        cfg = self.config
-        kind = cfg.layer_pattern[self.layer_number]
-        if self.decode and kind == "M":
+        if self.decode and layer.mixer == "mamba":
             raise ValueError("the Mamba-2 mixer has no decode path")
         x = _make_norm(cfg, "input_layernorm")(
             hidden_states.astype(jnp.float32)).astype(cfg.compute_dtype)
-        if kind == "M":
+        if layer.mixer == "mamba":
             from apex_tpu.transformer.ssm import Mamba2Mixer
 
             out = Mamba2Mixer(cfg, name="mixer")(x)
-        elif kind == "*":
+        elif layer.mixer == "attention":
             out = ParallelAttention(cfg, decode=self.decode,
                                     layer_number=self.layer_number,
                                     name="self_attention")(
                 x, attention_mask, position_ids)
         else:
-            out = _make_mlp(cfg, True)(x)
+            out = _make_mlp(cfg, layer.ffn == "moe")(x)
         return hidden_states + out.astype(hidden_states.dtype)
 
     @nn.compact
     def __call__(self, hidden_states, attention_mask=None, position_ids=None):
         cfg = self.config
-        if cfg.layer_pattern is not None:
-            return self._one_sub_block(hidden_states, attention_mask,
+        layer = cfg.layers[self.layer_number]
+        if layer.mixer is None or layer.ffn is None:
+            return self._one_sub_block(layer, hidden_states, attention_mask,
                                        position_ids)
         if cfg.pre_norm:
             ln1 = _make_norm(cfg, "input_layernorm")
@@ -1957,7 +1968,7 @@ class ParallelTransformerLayer(nn.Module):
         ln2 = (None if (cfg.parallel_residual_shared_ln
                         or not cfg.pre_norm)
                else _make_norm(cfg, "post_attention_layernorm"))
-        mlp = _make_mlp(cfg, self._is_moe_layer())
+        mlp = _make_mlp(cfg, layer.ffn == "moe")
         if ln2 is not None:
             mlp_in = ln2(hidden_states.astype(jnp.float32)).astype(
                 cfg.compute_dtype)
@@ -1982,8 +1993,9 @@ class ParallelTransformerLayer(nn.Module):
 
 class _ScanBlock(nn.Module):
     """lax.scan body for ParallelTransformer(scan_layers=True): one
-    uniform layer, (carry, out) signature; params carry a leading
-    [num_layers] axis under 'layers/layer'."""
+    layer, layer 0's entry (the config refuses a scan over layers that
+    differ), (carry, out) signature; params carry a leading [num_layers]
+    axis under 'layers/layer'."""
 
     config: TransformerConfig
     decode: bool = False
@@ -2051,10 +2063,6 @@ class ParallelTransformer(nn.Module):
                     if self.activation_checkpointing is None
                     else self.activation_checkpointing)
         if cfg.scan_layers:
-            if cfg.num_moe_experts is not None and cfg.moe_layer_freq != 1:
-                raise ValueError(
-                    "scan_layers needs a uniform stack: moe_layer_freq "
-                    "must be 1 (every layer MoE) or num_moe_experts None")
             block = _ScanBlock
             if remat_on and not self.decode:
                 block = _remat_keeping_flash_residuals(block, 1,

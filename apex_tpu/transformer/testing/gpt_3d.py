@@ -66,15 +66,10 @@ def build_gpt_3d_harness(cfg, mesh, opt, scaler, *, pp, seq, microbatch,
         raise ValueError(
             "the pipelined harness does not support expert parallelism "
             "(ep > 1); use transformer.testing.gpt_moe (dp x ep x tp)")
-    if moe and cfg.moe_layer_freq != 1:
-        # Stage-local layer numbering: each stage numbers its layers
-        # 0..layers_per_stage-1, so a global every-Nth-layer MoE pattern
-        # would silently shift per stage. A uniform stack (every layer
-        # MoE) is placement-invariant and composes; refuse the rest.
-        raise ValueError(
-            "MoE under the pipelined harness needs moe_layer_freq == 1 "
-            "(uniform stack); for sparse placement use "
-            "transformer.testing.gpt_moe (dp x ep x tp)")
+    # each stage numbers its layers 0..layers_per_stage-1
+    cfg.check_uniform("the pipelined harness",
+                      "; transformer.testing.gpt_moe (dp x ep x tp) "
+                      "trains one unpipelined")
     V = vpp or 1
     if cfg.num_layers % (pp * V):
         raise ValueError(

@@ -106,9 +106,9 @@ def test_scan_moe_requires_uniform_stack():
     import pytest
 
     parallel_state.destroy_model_parallel()
-    cfg = _cfg(scan_layers=True, num_moe_experts=2, moe_layer_freq=2)
     x = jnp.ones((4, 1, 32))
     with pytest.raises(ValueError, match="uniform"):
+        cfg = _cfg(scan_layers=True, num_moe_experts=2, moe_layer_freq=2)
         ParallelTransformer(cfg).init(jax.random.PRNGKey(0), x)
 
 
